@@ -1,12 +1,12 @@
 """Pallas decode-attention study surface — now a thin shim over the PRODUCT
 kernel module ``vtpu/ops/decode_attn.py``.
 
-History (VERDICT r5 weak #4 → ISSUE 10 resolution): standalone, the fused
+History: standalone, the fused
 dense-cache kernel beats XLA at the T=1 long-window cells
 (DECODE_ATTN_r05.json, two-chain-difference timing — bf16 1.1-1.6x from
 window 1024, int8 1.9x at 2048, ~760 GB/s; int8@1024 and T=4 chunks lost —
 the shipped auto router keys on exactly those cells). In the TRUNK it lost
-everywhere (MFU_r05 decode): a pallas operand must be materialized while the
+everywhere: a pallas operand must be materialized while the
 serving cache is being scatter-updated, so XLA copied the layer view — the
 copy cost more than the kernel saved, r6 removed the route and parked the
 kernel here. The park verdict named what re-promotion needed: a shard_map

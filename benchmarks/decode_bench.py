@@ -695,4 +695,7 @@ def run_fused_spec(a) -> None:
 
 
 if __name__ == "__main__":
+    from vtpu.util.jaxcache import place_compile_cache
+
+    place_compile_cache()
     main()

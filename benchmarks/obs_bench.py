@@ -637,4 +637,7 @@ def fleet_arm(a, n_requests: int) -> None:
 
 
 if __name__ == "__main__":
+    from vtpu.util.jaxcache import place_compile_cache
+
+    place_compile_cache()
     main()

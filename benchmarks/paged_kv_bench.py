@@ -603,4 +603,7 @@ def run_attn_kernel(a) -> None:
 
 
 if __name__ == "__main__":
+    from vtpu.util.jaxcache import place_compile_cache
+
+    place_compile_cache()
     main()

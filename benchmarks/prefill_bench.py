@@ -285,4 +285,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from vtpu.util.jaxcache import place_compile_cache
+
+    place_compile_cache()
     main()

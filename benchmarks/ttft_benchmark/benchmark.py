@@ -5,6 +5,11 @@ then M timed requests against a streaming endpoint; per-request TTFT is the
 wall time from request start to the first streamed token, per-token latency
 the mean gap between subsequent tokens. One JSON object per timed request is
 appended to --out (JSONL), which report.py aggregates.
+
+A request that streams no first token, is refused, or whose server-reported
+terminal is not OK is a FAILURE: it carries ``"failed"`` and no ``ttft_ms``
+(so it never enters a percentile as a 0 ms sample), is counted in the
+summary, and makes the run exit non-zero.
 """
 
 from __future__ import annotations
@@ -22,32 +27,48 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from report import pct  # noqa: E402
 
 
-def one_request(url: str, prompt_len: int, max_tokens: int) -> dict:
+def one_request(url: str, prompt_len: int, max_tokens: int,
+                timeout: float = 120.0) -> dict:
+    """One streamed request. On failure the record has ``"failed"`` (the
+    reason) and no ``ttft_ms``; ``http`` and ``status`` are what the server
+    said (``status`` None from a server that sends no terminal line)."""
     body = json.dumps({"prompt_len": prompt_len, "max_tokens": max_tokens}).encode()
     req = urllib.request.Request(
         f"{url}/generate", data=body, headers={"Content-Type": "application/json"}
     )
     start = time.monotonic()
-    ttft = None
     stamps: list[float] = []
-    with urllib.request.urlopen(req, timeout=120) as resp:
-        for raw in resp:
-            if not raw.startswith(b"data: "):
-                continue
-            now = time.monotonic()
-            if ttft is None:
-                ttft = now - start
-            stamps.append(now)
+    http = status = None
+    try:
+        with urllib.request.urlopen(req, timeout=timeout) as resp:
+            http = resp.status
+            for raw in resp:
+                if raw.startswith(b"data: "):
+                    stamps.append(time.monotonic())
+                elif raw.startswith(b"status: "):
+                    status = raw[len(b"status: "):].strip().decode()
+    except OSError as exc:  # refused, HTTP error status, timeout
+        http = getattr(exc, "code", http)
+        failed = repr(exc)
+    else:
+        failed = ("no first token" if not stamps
+                  else f"status {status}" if status not in (None, "OK")
+                  else None)
+    if failed:
+        return {"failed": failed, "http": http, "status": status,
+                "tokens": len(stamps), "ts": time.time()}
     gaps = [b - a for a, b in zip(stamps, stamps[1:])]
     return {
-        "ttft_ms": (ttft or 0.0) * 1e3,
+        "ttft_ms": (stamps[0] - start) * 1e3,
+        "http": http,
+        "status": status,
         "tokens": len(stamps),
         "per_token_ms": statistics.mean(gaps) * 1e3 if gaps else 0.0,
         # raw inter-token gaps: report.py aggregates run-level ITL
         # percentiles from these (a per-request mean hides tail stalls —
         # exactly what admission bursts inflict)
         "gaps_ms": [round(g * 1e3, 3) for g in gaps],
-        "total_ms": (stamps[-1] - start) * 1e3 if stamps else 0.0,
+        "total_ms": (stamps[-1] - start) * 1e3,
         "ts": time.time(),
     }
 
@@ -79,8 +100,10 @@ def main() -> None:
             samples.append(sample)
             out.write(json.dumps(sample) + "\n")
             out.flush()
-            print(f"run {i + 1}/{args.runs}: ttft={sample['ttft_ms']:.1f}ms",
-                  end="\r", file=sys.stderr)
+            shown = (f"FAILED ({sample['failed']})" if "failed" in sample
+                     else f"ttft={sample['ttft_ms']:.1f}ms")
+            print(f"run {i + 1}/{args.runs}: {shown}", end="\r",
+                  file=sys.stderr)
             if args.interval:
                 time.sleep(max(0.0, args.interval - (time.monotonic() - t0)))
     print(file=sys.stderr)
@@ -105,19 +128,30 @@ def main() -> None:
                                   "label": args.label,
                                   "ts": time.time()}) + "\n")
 
+    failures = [s for s in samples if "failed" in s]
+    samples = [s for s in samples if "failed" not in s]
     ttfts = sorted(s["ttft_ms"] for s in samples)
     itl = sorted(g for s in samples for g in s["gaps_ms"])
+
+    def rpct(vals: list, q: float):
+        # no sample is "no number", never a 0 ms percentile
+        return round(pct(vals, q), 2) if vals else None
+
     print(json.dumps({
         "runs": len(samples),
-        "p50_ttft_ms": round(statistics.median(ttfts), 2),
-        "p95_ttft_ms": round(pct(ttfts, 0.95), 2),
-        "p99_ttft_ms": round(pct(ttfts, 0.99), 2),
-        "p50_itl_ms": round(pct(itl, 0.50), 2),
-        "p95_itl_ms": round(pct(itl, 0.95), 2),
-        "p99_itl_ms": round(pct(itl, 0.99), 2),
+        "failed": len(failures),
+        "failures": sorted({s["failed"] for s in failures}),
+        "p50_ttft_ms": round(statistics.median(ttfts), 2) if ttfts else None,
+        "p95_ttft_ms": rpct(ttfts, 0.95),
+        "p99_ttft_ms": rpct(ttfts, 0.99),
+        "p50_itl_ms": rpct(itl, 0.50),
+        "p95_itl_ms": rpct(itl, 0.95),
+        "p99_itl_ms": rpct(itl, 0.99),
         "server_trace": server_trace,
         "out": args.out,
     }))
+    if failures:
+        sys.exit(1)
 
 
 if __name__ == "__main__":

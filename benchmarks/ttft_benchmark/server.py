@@ -3,8 +3,13 @@
 Serves the flagship vtpu.models transformer. POST /generate with
 ``{"prompt_len": N, "max_tokens": M}`` streams one line per generated token
 (`data: {"token": t, "ts": server_time}`) so the client can timestamp the
-first token, mirroring the reference's vLLM streaming benchmark server shape
-(reference benchmarks/ai-benchmark/benchmark.py client contract).
+first token, then one `status: <Request.status>` line, mirroring the
+reference's vLLM streaming benchmark server shape (reference
+benchmarks/ai-benchmark/benchmark.py client contract).
+
+The served model is the flagship preset and needs a TPU: without one the
+server exits non-zero unless `--preset cpu` (a toy model for the CPU tests)
+was asked for by name.
 
 When launched inside a vtpu-scheduled pod, libvtpu caps this process's HBM
 and TensorCore duty per the pod's fractional ask — no server-side changes.
@@ -17,14 +22,45 @@ import json
 import logging
 import os
 import sys
+import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
+
+import numpy as np
 
 # runnable as a plain script (the deployment Jobs do `python .../server.py`):
 # put the repo root on sys.path so `vtpu` imports without an install
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
 
 log = logging.getLogger("ttft-server")
+
+
+def preset(name: str):
+    """(ModelConfig, ServingConfig) of a named preset: ``tpu`` is the
+    flagship the benchmark serves, ``cpu`` a toy for the CPU tests. Nothing
+    here looks at the backend — the caller names the preset."""
+    import jax.numpy as jnp
+
+    from vtpu.models import ModelConfig
+    from vtpu.serving import ServingConfig
+
+    if name == "tpu":
+        cfg = ModelConfig(
+            vocab=8192, d_model=1024, n_heads=8, n_layers=12, d_ff=4096,
+            max_seq=1280, head_dim=128, dtype=jnp.bfloat16, use_pallas=True,
+        )
+        serving = ServingConfig(slots=4, prefill_buckets=(128, 256, 512, 1024),
+                                max_new_tokens=64)
+    elif name == "cpu":
+        cfg = ModelConfig(
+            vocab=512, d_model=128, n_heads=4, n_layers=2, d_ff=256,
+            max_seq=160, head_dim=32, dtype=jnp.float32, use_pallas=False,
+        )
+        serving = ServingConfig(slots=2, prefill_buckets=(32, 64, 128),
+                                max_new_tokens=32)
+    else:
+        raise ValueError(f"unknown preset {name!r}")
+    return cfg, serving
 
 
 class Engine:
@@ -34,39 +70,33 @@ class Engine:
     jointly — the real multi-request serving path, not a lock-serialized
     batch-1 loop."""
 
-    def __init__(self, preset: str = "auto"):
+    def __init__(self, preset_name: str = "tpu"):
         import jax
-        import jax.numpy as jnp
 
-        from vtpu.models import ModelConfig, init_params
-        from vtpu.serving import ServingConfig, ServingEngine
+        from vtpu.models import init_params
+        from vtpu.serving import ServingEngine
 
-        if preset == "tpu" or (preset == "auto" and jax.default_backend() == "tpu"):
-            cfg = ModelConfig(
-                vocab=8192, d_model=1024, n_heads=8, n_layers=12, d_ff=4096,
-                max_seq=1280, head_dim=128, dtype=jnp.bfloat16, use_pallas=True,
-            )
-            serving = ServingConfig(slots=4, prefill_buckets=(128, 256, 512, 1024),
-                                    max_new_tokens=64)
-        else:
-            cfg = ModelConfig(
-                vocab=512, d_model=128, n_heads=4, n_layers=2, d_ff=256,
-                max_seq=160, head_dim=32, dtype=jnp.float32, use_pallas=False,
-            )
-            serving = ServingConfig(slots=2, prefill_buckets=(32, 64, 128),
-                                    max_new_tokens=32)
+        cfg, serving = preset(preset_name)
+        if preset_name == "tpu" and jax.default_backend() != "tpu":
+            raise RuntimeError(
+                f"preset 'tpu' needs a TPU, JAX found "
+                f"{jax.default_backend()!r}; pass --preset cpu for the toy "
+                "model")
         self.cfg = cfg
-        self.jax = jax
-        self.jnp = jnp
+        self._rng = np.random.default_rng(0)
+        self._rng_lock = threading.Lock()
         self.params = jax.jit(lambda k: init_params(k, cfg))(jax.random.key(0))
         jax.block_until_ready(self.params)
         self.engine = ServingEngine(self.params, cfg, serving)
         self.engine.start()
         # warm EVERY prefill bucket (plus the shared decode step) so no real
-        # request ever pays an XLA compile — this is a TTFT benchmark.
+        # request ever pays an XLA compile — this is a TTFT benchmark. A
+        # bucket that streams nothing is a dead engine, not a fast one.
         for bucket in serving.prefill_buckets:
-            for _ in self.generate(bucket, 2):
-                pass
+            got = sum(1 for _ in self.generate(bucket, 2))
+            if got != 2:
+                raise RuntimeError(
+                    f"warm-up at bucket {bucket} streamed {got} of 2 tokens")
 
     def trace_stats(self) -> dict:
         """Engine-side span telemetry, re-derived from the trace substrate
@@ -88,23 +118,32 @@ class Engine:
             "disagg", "handoffs", "handoff_copies", "prefill_backlog",
             "tick_phase_ms", "trace_events_recorded")}
 
-    def generate(self, prompt_len: int, max_tokens: int):
-        """Yield (token_id, monotonic_ts) per generated token."""
+    def submit(self, prompt_len: int, max_tokens: int):
+        """Submit one random prompt; raises if the engine cannot take it
+        (a dead serving loop raises here, before any HTTP status is sent)."""
         limit = self.engine.serving.prefill_buckets[-1]
         prompt_len = max(1, min(prompt_len, limit))
         # keep prompt + generation inside the KV cache; a request asking for
         # more tokens than fit is clamped, never allowed to wrap the cache
         max_tokens = max(1, min(max_tokens, self.cfg.max_seq - prompt_len - 1))
-        tokens = self.jax.random.randint(
-            self.jax.random.key(int(time.time() * 1e3) % (2**31)),
-            (prompt_len,), 0, self.cfg.vocab, self.jnp.int32,
-        )
-        req = self.engine.submit(tokens, max_new_tokens=max_tokens)
+        # numpy, not an eager jax.random op: a device op of a new shape on
+        # the request thread would compile once per distinct prompt length
+        with self._rng_lock:
+            tokens = self._rng.integers(
+                0, self.cfg.vocab, (prompt_len,), dtype=np.int32)
+        return self.engine.submit(tokens, max_new_tokens=max_tokens)
+
+    @staticmethod
+    def stream(req):
+        """Yield (token_id, monotonic_ts) per generated token of *req*."""
         try:
             for token in req.stream():
                 yield token, time.monotonic()
         finally:
             req.cancel()  # client gone mid-stream: free the slot next tick
+
+    def generate(self, prompt_len: int, max_tokens: int):
+        return self.stream(self.submit(prompt_len, max_tokens))
 
 
 def make_handler(engine: Engine):
@@ -136,13 +175,22 @@ def make_handler(engine: Engine):
             req = json.loads(self.rfile.read(length) or b"{}")
             prompt_len = int(req.get("prompt_len", 128))
             max_tokens = int(req.get("max_tokens", 16))
+            try:
+                handle = engine.submit(prompt_len, max_tokens)
+            except (RuntimeError, ValueError) as exc:
+                self.send_error(503, explain=repr(exc))
+                return
             self.send_response(200)
             self.send_header("Content-Type", "text/event-stream")
             self.end_headers()
-            for token, ts in engine.generate(prompt_len, max_tokens):
+            for token, ts in engine.stream(handle):
                 line = json.dumps({"token": token, "ts": ts})
                 self.wfile.write(f"data: {line}\n".encode())
                 self.wfile.flush()
+            # the typed terminal: a stream the engine ended early (FAULTED,
+            # shed) is told apart from one that ran to its budget
+            self.wfile.write(f"status: {handle.status}\n".encode())
+            self.wfile.flush()
 
     return Handler
 
@@ -151,16 +199,17 @@ def main() -> None:
     parser = argparse.ArgumentParser("ttft-server")
     parser.add_argument("--port", type=int, default=8100)
     parser.add_argument("--host", default="0.0.0.0")
-    parser.add_argument("--preset", default="auto", choices=["auto", "tpu", "cpu"])
+    parser.add_argument("--preset", default="tpu", choices=["tpu", "cpu"])
     args = parser.parse_args()
     logging.basicConfig(level=logging.INFO)
 
-    if args.preset == "cpu":
-        # env vars are read too early when a sitecustomize imports jax at
-        # interpreter start; go through jax.config like tests/conftest.py
-        import jax
+    import jax
 
+    from vtpu.util.jaxcache import place_compile_cache
+
+    if args.preset == "cpu":
         jax.config.update("jax_platforms", "cpu")
+    place_compile_cache()
 
     engine = Engine(args.preset)
     httpd = ThreadingHTTPServer((args.host, args.port), make_handler(engine))

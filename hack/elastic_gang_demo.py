@@ -1,7 +1,7 @@
 """Elastic gang recovery, end to end: kill a slice worker, reschedule it,
 repair its rank, resume training from the checkpoint on a different mesh.
 
-VERDICT r3 #9: gang-rank repair and orbax elastic restore each had tests, but
+Gang-rank repair and orbax elastic restore each had tests, but
 no artifact showed the RECOVERY STORY they exist for. This demo ties them:
 
   Act 1 (control plane) - a 2-worker gang lands on one physical slice with

@@ -1,5 +1,5 @@
-"""Kubelet device-plugin conformance for the REAL plugin binary (VERDICT r4
-#7): the registration dance and allocation protocol a live kubelet drives,
+"""Kubelet device-plugin conformance for the REAL plugin binary: the
+registration dance and allocation protocol a live kubelet drives,
 executed here against `python -m vtpu.plugin` because kind/docker are
 unavailable on this rig (hack/e2e-kind.sh falls back to this harness so its
 phases execute instead of sitting as dead code; the kind path remains the

@@ -1,12 +1,12 @@
 """QoS priority-gate BENEFIT, measured where same-chip co-tenancy is
-constructible (VERDICT r4 #2).
+constructible.
 
-The dev rig's session pool schedules concurrent real-chip sessions onto
-DISJOINT chips (CHIP_ISOLATION_r05.json: 9 concurrent sessions all at full
-solo throughput), so the reference's benefit scenario — a high tenant
-recovering its solo latency when the monitor gates a co-located low tenant
-(cmd/vGPUmonitor/feedback.go:75-135) — cannot be produced through any
-process topology on the real chip. It IS constructible one layer down: the
+A real chip belongs to one process at a time under the installed libtpu (a
+second client fails at start-up; measured in PR 21), so the reference's
+benefit scenario — a high tenant recovering its solo latency when the
+monitor gates a co-located low tenant (cmd/vGPUmonitor/feedback.go:75-135)
+— cannot be produced by two processes on one real chip. It IS
+constructible one layer down: the
 fake PJRT plugin's FAKE_PJRT_SHARED_QUEUE backs its serial busy-queue with
 an mmap'd file, so two PROCESSES (real libvtpu shims, real regions, the
 real monitor binary's feedback loop) contend on one emulated chip with
@@ -19,11 +19,11 @@ fake_pjrt.so, python -m vtpu.monitor):
   protected  + the monitor binary: census sees H active, gates L
              (recent_kernel=-1 -> libvtpu's execute gate), H returns to solo
 
-Criteria (the r4 verdict's shape): contended - solo >= 10% (engineered:
+Criteria: contended - solo >= 10% (engineered:
 expect ~2x), protected within ~10% of solo (scheduling jitter on a shared
 CPU host is the noise floor here), low tenant demonstrably gated.
 
-Writes QOS_BENEFIT_r05.json.
+Writes build/QOS_BENEFIT.json.
 """
 
 from __future__ import annotations
@@ -156,9 +156,10 @@ def main() -> int:
     protected_pct = (protected - solo) / solo * 100
     evidence = {
         "harness": "hack/qos_benefit_c.py",
-        "why_not_real_chip": "session pool isolates concurrent sessions onto "
-                             "disjoint chips (CHIP_ISOLATION_r05.json); the "
-                             "real-chip gate mechanics are PRIORITY_r05.json",
+        "why_not_real_chip": "a real chip belongs to one process at a time "
+                             "under the installed libtpu (PR 21 attach "
+                             "probe); this is an EMULATED chip, its times "
+                             "are the fake plugin's, not a device's",
         "stack": "pjrt_smoke -> libvtpu.so (real shim) -> fake_pjrt.so with "
                  "FAKE_PJRT_SHARED_QUEUE (cross-process serial chip), real "
                  "vtpu.monitor feedback loop",
@@ -178,7 +179,8 @@ def main() -> int:
         },
     }
     evidence["ok"] = all(evidence["criteria"].values())
-    (REPO / "QOS_BENEFIT_r05.json").write_text(
+    (REPO / "build").mkdir(exist_ok=True)
+    (REPO / "build" / "QOS_BENEFIT.json").write_text(
         json.dumps(evidence, indent=2) + "\n")
     print(json.dumps(evidence, indent=2))
     return 0 if evidence["ok"] else 1

@@ -1,9 +1,9 @@
 // Calibration oracle: device-truth busy attestation via compiled
 // known-duration probes.
 //
-// Why it exists (R5_NOTES item 1, final bullet / BENCH_VALIDATION_r05_13):
+// Why it exists (round-5 validation run 13):
 // on a proxied PJRT runtime EVERY passively observed busy signal — D2H
-// walls, completion-event intervals, attach probes — inflates with tunnel
+// walls, completion-event intervals, attach probes — inflates with transport
 // weather, so the sync-wall charger accreted four generations of
 // compensators (floor, charge cap, weather band, event-fed budget) and a
 // storm still charged one tenant 60.9 s of phantom duty. HAMi-core never
@@ -40,7 +40,7 @@
 //                                   stretched calibration walls cannot match
 //                                   its claimed event durations)
 //                TRANSPORT_POLLUTED when E >> D (real completion events whose
-//                                   delivery rides the tunnel; the attested
+//                                   delivery rides a proxy transport; the attested
 //                                   baseline T is deducted from event settles
 //                                   and the compensator tower stays engaged
 //                                   as the explicit fallback)

@@ -137,7 +137,7 @@ uint64_t DutyCycleLimiter::uncovered_and_insert(uint64_t s, uint64_t e) {
 }
 
 // A single CLIENT-OBSERVED wall interval far beyond the pacing window is a
-// transport anomaly (a wedged tunnel was observed billing one D2H 60 s —
+// transport anomaly (a wedged proxy transport was observed billing one D2H 60 s —
 // which at a 20% limit would owe FIVE MINUTES of pacing), not chip busy:
 // clamp those charges to the same 10-window horizon the util view uses.
 // Applied ONLY to the sync-wall path (charge_interval) — completion-event

@@ -55,7 +55,7 @@ class DutyCycleLimiter {
 
   // Charge a wall-clock interval the process spent blocked ON the runtime
   // (D2H reads, event waits). This is the busy signal of last resort:
-  // proxied/tunneled runtimes fulfill completion events at ENQUEUE (observed:
+  // proxied runtimes fulfill completion events at ENQUEUE (observed:
   // 70 settlements totalling 22 ms for ~8 s of real compute), so submission-
   // side intervals are the only truthful clock there. Union accounting makes
   // it a no-op wherever faithful completion events already charged the time.

@@ -123,9 +123,8 @@ std::atomic<uint64_t> g_busy_until{0};
 
 // FAKE_PJRT_SHARED_QUEUE=<path>: back the busy-until with an mmap'd file so
 // SEPARATE PROCESSES serialize on the same emulated chip. This is the one
-// place same-chip co-tenancy is constructible on the dev rig (the session
-// pool schedules real-chip sessions onto disjoint chips —
-// CHIP_ISOLATION_r05.json), so the QoS-benefit experiment contends here.
+// place same-chip co-tenancy is constructible (a real chip belongs to one
+// process at a time), so the QoS-benefit experiment contends here.
 // CLOCK_MONOTONIC is comparable across processes on one host.
 static std::atomic<uint64_t>* busy_until() {
   static std::atomic<uint64_t>* p = []() -> std::atomic<uint64_t>* {

@@ -28,6 +28,8 @@ from types import SimpleNamespace
 
 import pytest
 
+from vtpu.util.jaxcache import compile_cache_dir
+
 ROOT = Path(__file__).resolve().parent.parent
 ENV_TIMEOUT = 420
 # the subprocesses share conftest's persistent XLA compilation cache (via
@@ -35,7 +37,7 @@ ENV_TIMEOUT = 420
 # identically every CI run, and the cache is what keeps nine quick
 # iterations inside the tier-1 wall-clock budget on throttle-prone runners
 ENV = {"JAX_PLATFORMS": "cpu", "PATH": "/usr/bin:/bin", "HOME": "/tmp",
-       "JAX_COMPILATION_CACHE_DIR": str(ROOT / ".jax_cache"),
+       "JAX_COMPILATION_CACHE_DIR": compile_cache_dir(),
        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0"}
 
 
@@ -137,10 +139,9 @@ MULTI_DEVICE_RUNS = {"paged_kv_tp2", "decode_loop_k", "migrate"}
 def _env_for(name):
     if name not in MULTI_DEVICE_RUNS:
         return ENV
-    env = dict(ENV)
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
-    env.pop("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", None)
-    return env
+    # the bench mains place the cache themselves (place_compile_cache), so
+    # dropping the directory would not keep them off it: switch it off
+    return {**ENV, "JAX_ENABLE_COMPILATION_CACHE": "false"}
 
 
 # consuming test -> run, so the fixture can launch ONLY what the selected

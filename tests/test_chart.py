@@ -47,7 +47,7 @@ def test_template_value_paths_exist():
 
 
 def test_certgen_flow_without_cert_manager():
-    """VERDICT r2 missing #3: with certManager disabled the chart must self-
+    """With certManager disabled the chart must self-
     provision webhook TLS — a create job (secret) + patch job (caBundle),
     gated on the certgen toggle and mutually exclusive with cert-manager."""
     values = _values()

@@ -501,11 +501,10 @@ def test_tcp_sigkill_child_failover_token_equal(params, refs, monkeypatch):
     mirror, with the survivors leak-clean (conftest audits them)."""
     import os
     import signal
-    from pathlib import Path
 
-    root = Path(__file__).resolve().parent.parent
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
-                       str(root / ".jax_cache"))
+    from vtpu.util.jaxcache import compile_cache_dir
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", compile_cache_dir())
     monkeypatch.setenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
     spec = {"model": dict(vocab=64, d_model=32, n_heads=2, n_layers=1,
                           d_ff=64, max_seq=32, head_dim=16,

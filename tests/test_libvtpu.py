@@ -9,7 +9,7 @@ import subprocess
 from pathlib import Path
 import pytest
 
-# Heavyweight tier (VERDICT r2 weak #7): compile-bound or sleep-bound; CI
+# Heavyweight tier: compile-bound or sleep-bound; CI
 # runs the slow tier separately so the unit tier stays under two minutes.
 pytestmark = pytest.mark.slow
 
@@ -132,7 +132,7 @@ def test_monitor_block_gates_running_workload(libvtpu_build, tmp_path):
 def test_gate_timeout_is_region_controlled(libvtpu_build, tmp_path):
     """A gated execute may only proceed without an unblock when the
     monitor-written gate_timeout_ms elapses, and that release is counted
-    (no silent leak — VERDICT round-1 weak #5)."""
+    (no silent leak)."""
     import os
     import subprocess as sp
     import time

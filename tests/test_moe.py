@@ -9,7 +9,7 @@ from vtpu.models.moe import MoEConfig, init_moe_params, moe_forward, moe_loss, r
 from vtpu.parallel.expert import ep_moe_forward, moe_param_shardings
 from vtpu.parallel.mesh import make_axis_mesh, make_dp_ep_mesh
 
-# Heavyweight tier (VERDICT r2 weak #7): compile-bound or sleep-bound; CI
+# Heavyweight tier: compile-bound or sleep-bound; CI
 # runs the slow tier separately so the unit tier stays under two minutes.
 pytestmark = pytest.mark.slow
 

@@ -339,7 +339,7 @@ def test_allocate_charge_floor_passthrough(mock_chips, tmp_path):
 
 
 def test_allocate_init_container_slot(served_plugin):
-    """VERDICT r3 #3: an init container's device ask allocates correctly —
+    """An init container's device ask allocates correctly —
     its decision slot is first (kubelet allocates init containers before app
     ones), and the container response is built for the INIT container's
     name (per-container shared-region dir)."""

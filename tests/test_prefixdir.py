@@ -373,8 +373,10 @@ def test_hot_prefix_replicates_without_copies(params, prefix_refs):
         assert list(req.stream()) == prefix_refs["prefix"]
         _wait(lambda: len(fleet.prefixdir.residents(cpid)) == 2,
               msg="hot replication onto the second engine")
+        # the monitor publishes the resident before it bumps the counter
+        _wait(lambda: fleet.stats()["prefix_replications"] >= 1,
+              msg="replication counted")
         s = fleet.stats()
-        assert s["prefix_replications"] >= 1
         for n in ("a", "b"):
             assert s["engines"][n]["prefix_install_copies"] == 0
             assert s["engines"][n]["prefix_tier_installs"] == 0

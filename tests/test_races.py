@@ -496,8 +496,7 @@ def test_engine_chaos_seeded_lifecycle_races(seed):
 @pytest.mark.fuzz
 @pytest.mark.parametrize("seed", [11, 23, 37, 53, 71])
 def test_gang_multislice_churn_fuzzer(seed):
-    """Randomized churn over the gang/multislice state machine (VERDICT r4
-    #8): workers dying mid-stamp (deleted between Filter and any bind),
+    """Randomized churn over the gang/multislice state machine: workers dying mid-stamp (deleted between Filter and any bind),
     slices deregistering and returning, DCN scores flapping, scheduler
     restarts replaying informer state — across hundreds of iterations the
     refusal paths in _constrain_to_gang_slice/_constrain_multislice may

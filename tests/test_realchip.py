@@ -22,8 +22,11 @@ REPO = pathlib.Path(__file__).resolve().parent.parent
     reason="opt-in: set VTPU_REALCHIP=1 with a live TPU attachment",
 )
 def test_realchip_proof():
+    # conftest pins this process to the CPU; the proof's child needs the
+    # chip, so it must not inherit that choice
+    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
     r = subprocess.run(
         [sys.executable, str(REPO / "hack" / "realchip_proof.py")],
-        capture_output=True, text=True, timeout=580,
+        capture_output=True, text=True, timeout=580, env=env,
     )
     assert r.returncode == 0, f"realchip proof failed:\n{r.stdout}\n{r.stderr}"

@@ -16,7 +16,7 @@ from vtpu.models.ssm import (
     ssm_loss,
 )
 
-# Heavyweight tier (VERDICT r2 weak #7): compile-bound or sleep-bound; CI
+# Heavyweight tier: compile-bound or sleep-bound; CI
 # runs the slow tier separately so the unit tier stays under two minutes.
 pytestmark = pytest.mark.slow
 

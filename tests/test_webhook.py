@@ -72,7 +72,7 @@ def test_webhook_quota_precheck():
 
 
 def test_webhook_mutates_init_container_and_patches_spec():
-    """VERDICT r3 #3: a device ask in an init container must be normalized at
+    """A device ask in an init container must be normalized at
     admission like an app container's (the reference webhook walks only
     spec.containers — that hole is closed here), and the JSONPatch must
     carry the mutated initContainers back."""

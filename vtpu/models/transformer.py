@@ -481,17 +481,20 @@ def _mlp_block(lp, x):
 
 
 def transformer_layer(
-    cfg: ModelConfig, lp: dict[str, jax.Array], x: jax.Array, cos, sin, positions
+    cfg: ModelConfig, lp: dict[str, jax.Array], x: jax.Array, cos, sin,
+    positions, mesh=None,
 ) -> tuple[jax.Array, tuple[jax.Array, jax.Array]]:
     """One decoder block over a full sequence. x: [B, S, D] -> (x, (k, v)).
 
     Shared by the dense prefill scan and the pipelined stage body
-    (vtpu/parallel/pipeline.py) so the block exists exactly once.
+    (vtpu/parallel/pipeline.py) so the block exists exactly once. ``mesh``
+    (the serving ('tp',) mesh) reaches the flash kernel, which must run
+    per head shard under it.
     """
     b, s, _ = x.shape
     q, k, v = _qkv(cfg, lp, x, cos, sin, positions)
     if cfg.use_pallas and s % 128 == 0 and s >= FLASH_MIN_SEQ:
-        attn = flash_attention(q, k, v)
+        attn = flash_attention(q, k, v, mesh=mesh)
     else:
         attn = causal_attention(q, k, v)
     x = x + attn.reshape(b, s, cfg.qkv_dim) @ lp["wo"]
@@ -501,9 +504,10 @@ def transformer_layer(
 
 def prefill(
     params: Params, cfg: ModelConfig, tokens: jax.Array,
-    logits_at: Optional[jax.Array] = None,
+    logits_at: Optional[jax.Array] = None, mesh=None,
 ) -> tuple[jax.Array, dict[str, jax.Array]]:
     """Full-sequence forward. tokens: [B, S] int32. Returns (logits, kv_cache).
+    ``mesh``: the serving ('tp',) mesh when the params are tensor-parallel.
 
     ``logits_at`` ([B] int32 positions) gathers the trunk output at one
     position per row BEFORE the vocab projection, returning [B, vocab]
@@ -517,7 +521,7 @@ def prefill(
     x = params["embed"][tokens].astype(cfg.dtype)
 
     def layer(x, lp):
-        return transformer_layer(cfg, lp, x, cos, sin, positions)
+        return transformer_layer(cfg, lp, x, cos, sin, positions, mesh=mesh)
 
     x, (ks, vs) = jax.lax.scan(layer, x, params["layers"])
     x = rms_norm(x, params["final_norm"])
@@ -732,7 +736,7 @@ def spec_verify_loop(
         # no gathered window ever materialize. This is the re-promotion of
         # the r5 study: the pool operand aliases straight into the
         # pallas_call, killing the copy that routed every trunk cell to
-        # XLA back then (MFU_r05).
+        # XLA back then.
         if use_kernel:
             if quant:
                 attn = paged_decode_attention_int8kv(

@@ -277,6 +277,8 @@ ALLOWLIST: set = {
     "spec_disabled_reason",  # free-form string: diagnosable from stats()/
                              # trace ("spec_disabled" event), not a metric
     "loop_policy",           # policy class name (string) — config echo
+    "loop_error",            # repr of the exception that killed the loop
+                             # (None on a live engine); submit() raises it
 }
 
 # ------------------------------------------------------------------- fleet

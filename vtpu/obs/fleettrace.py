@@ -55,7 +55,7 @@ import time
 from typing import IO, Optional, Union
 
 from vtpu.obs.tickprof import LATENCY_BUCKETS_MS, BoundedHistogram
-from vtpu.obs.trace import RequestTrace, pct
+from vtpu.obs.trace import RequestTrace, SeqCounter, pct
 
 # The fleet control-event vocabulary (the engine-side EVENT_KINDS
 # analogue). ``engine`` names the subject; ``jid`` ties request-scoped
@@ -138,7 +138,7 @@ class FleetTrace:
         self.capacity = int(capacity)
         self.enabled = self.capacity > 0
         self._mu = threading.Lock()
-        self._ctr = itertools.count()
+        self._ctr = SeqCounter()
         self._ring: "collections.deque[dict]" = collections.deque(
             maxlen=max(self.capacity, 1))
         self._engines: dict[str, RequestTrace] = {}
@@ -194,7 +194,7 @@ class FleetTrace:
         if not self.enabled:
             return
         rec = {
-            "seq": next(self._ctr),
+            "seq": self._ctr.next(),
             "ts_ns": time.monotonic_ns(),
             "event": event,
             "engine": engine,
@@ -212,7 +212,7 @@ class FleetTrace:
 
     @property
     def events_recorded(self) -> int:
-        return self._ctr.__reduce__()[1][0]
+        return self._ctr.issued
 
     @property
     def events_dropped(self) -> int:

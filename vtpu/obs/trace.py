@@ -9,10 +9,9 @@ preallocated ring and derives the spans offline:
            -> token* -> [park -> (evict -> swap_out?)* -> resume
            -> (swap_in | fault_recompute)? -> token*]* -> retire
 
-Recording cost is the contract: one ``itertools.count`` bump (atomic under
-the GIL — the "lock" in lock-light), one ``time.monotonic_ns`` stamp, one
-tuple, one list-slot store. No locks on the hot path, no allocation beyond
-the tuple, and NOTHING device-side — tracing can never add a host sync
+Recording cost is the contract: one sequence bump under an uncontended
+lock (SeqCounter), one ``time.monotonic_ns`` stamp, one tuple, one
+list-slot store. No allocation beyond the tuple, and NOTHING device-side — tracing can never add a host sync
 (benchmarks/obs_bench.py gates ``device_gets_per_tick == 1.0`` and the
 2% tokens/sec envelope with tracing on).
 
@@ -31,7 +30,6 @@ families in export.py). These stay live even with the event ring disabled
 from __future__ import annotations
 
 import collections
-import itertools
 import json
 import threading
 import time
@@ -151,6 +149,28 @@ def pct(sorted_vals, q: float):
     return sorted_vals[min(len(sorted_vals) - 1, int(len(sorted_vals) * q))]
 
 
+class SeqCounter:
+    """Hands out 0, 1, 2, ... to concurrent writers and can say how many it
+    has handed out. (An ``itertools.count`` does the first lock-free but can
+    only be peeked through its pickle support, which Python 3.14 removes.)"""
+
+    __slots__ = ("_n", "_mu")
+
+    def __init__(self):
+        self._n = 0
+        self._mu = threading.Lock()
+
+    def next(self) -> int:
+        with self._mu:
+            n = self._n
+            self._n = n + 1
+        return n
+
+    @property
+    def issued(self) -> int:
+        return self._n
+
+
 class RequestTrace:
     """Bounded ring of lifecycle events + the latency reservoirs/histograms
     derived views are built over. One instance per ServingEngine."""
@@ -159,7 +179,7 @@ class RequestTrace:
         self.capacity = int(capacity)
         self.enabled = self.capacity > 0
         self._buf: list = [None] * max(self.capacity, 1)
-        self._ctr = itertools.count()  # next(ctr) is atomic under the GIL
+        self._ctr = SeqCounter()
         # latency substrate (always on, ring or no ring): bounded
         # reservoirs for percentiles + monotonic histograms for export.
         # One uncontended lock serializes reservoir appends (loop thread)
@@ -186,7 +206,7 @@ class RequestTrace:
         slot; a reader may see a torn WINDOW, never a torn event)."""
         if not self.enabled:
             return
-        seq = next(self._ctr)
+        seq = self._ctr.next()
         self._buf[seq % self.capacity] = (
             seq, time.monotonic_ns(), event, rid, slot, val)
 
@@ -199,7 +219,7 @@ class RequestTrace:
         tell observed from interpolated."""
         if not self.enabled:
             return
-        seq = next(self._ctr)
+        seq = self._ctr.next()
         self._buf[seq % self.capacity] = (seq, ts_ns, event, rid, slot, val)
 
     def note_itl(self, gap_s: float) -> None:
@@ -229,9 +249,7 @@ class RequestTrace:
     @property
     def events_recorded(self) -> int:
         """Total events ever recorded (including any the ring dropped)."""
-        # peek the counter without consuming: copy it (count objects are
-        # cheap value types; __reduce__ exposes the current value)
-        return self._ctr.__reduce__()[1][0]
+        return self._ctr.issued
 
     @property
     def events_dropped(self) -> int:

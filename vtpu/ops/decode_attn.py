@@ -2,11 +2,10 @@
 product path that attends over pool blocks in place.
 
 History. The dense kernel below started as an in-trunk route (r5): standalone
-it beats XLA at the T=1 long-window cells (DECODE_ATTN_r05.json, two-chain-
-difference timing — bf16 1.1-1.6x from window 1024, int8 1.9x at 2048, ~760
-GB/s; int8@1024 and T=4 chunks lost), but in the trunk it lost everywhere
-(MFU_r05):
-a pallas operand must be materialized while the serving cache is being
+it beat XLA at the T=1 long-window cells (bf16 1.1-1.6x from window 1024,
+int8 1.9x at 2048; int8@1024 and T=4 chunks lost), but in the trunk it lost
+everywhere: a pallas operand must be materialized while the serving cache is
+being
 scatter-updated, so XLA copied the layer view it would otherwise fuse windowed
 reads from — the copy cost more than the kernel saved. r6 parked it as a
 standalone study under benchmarks/decode_attn_kernel.py, whose verdict named
@@ -48,22 +47,22 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5 exports it under experimental only
-    from jax.experimental.shard_map import shard_map
 
 _NEG_INF = -1e30
 
 
 # --------------------------------------------------------------------------
 # Measured shape routing (the FLASH_MIN_SEQ discipline applied to the paged
-# decode path). Basis: the standalone study DECODE_ATTN_r05.json (real v5e,
-# RTT-cancelled two-chain timing), read cell by cell:
+# decode path). Basis: the standalone DENSE-kernel study DECODE_ATTN_r05.json,
+# measured in round 5 on a v5e through a rig since removed; re-measure
+# (ROADMAP Speed #3-#5) — PR 21's chip_smoke.py timed the IN-TRUNK paged
+# kernel at 18.5 ms a tick against the gather route's 2.2 ms at window 1024
+# x 4 slots (bf16; 14.0 vs 2.4 int8), so these floors do not describe it.
+# The study, read cell by cell:
 #   bf16 T=1: pallas/XLA 1.64 (b8 w1024), 1.43 (b8 w2048), 1.10 (b32
 #     w1024), 1.23 (b32 w2048) — the kernel wins every measured bf16
 #     decode cell from window 1024 up.
@@ -200,8 +199,8 @@ def _decode_kernel(q_ref, k_ref, v_ref, lens_ref, o_ref,
     """One batch row x one KV S-block, all heads unrolled in-kernel.
 
     Decode attention on the XLA path is dispatch-bound, not byte-bound
-    (MFU_r04: 33% HBM BW at batch 8 — M=1 batched matmuls, a materialized
-    [B,H,T,S] mask/score tensor, separate softmax ops). Here the whole
+    (M=1 batched matmuls, a materialized [B,H,T,S] mask/score tensor,
+    separate softmax ops). Here the whole
     attention for a batch row is one kernel: K/V stream through VMEM as
     contiguous (S_blk, H*Dh) tiles read straight from the cache's native
     [B, S, H*Dh] view (a [B,H,S,Dh] relayout would copy the entire window
@@ -262,14 +261,14 @@ def decode_attention(
     cache's FULL per-layer view (a contiguous leading-dim slice, zero
     copy) instead of a ``[:, :bucket]`` slice: a pallas operand must be
     materialized, so the sliced form forced XLA to copy the whole window
-    every tick — measured 27 ms vs XLA's 6.8 ms at batch 32 / 2048 before
-    this (MFU_r05 first pass), erasing the kernel's standalone win.
+    every tick, erasing the kernel's standalone win.
 
     Single-chip DENSE-cache kernel — the shipped serving route is the paged
     ``paged_decode_attention`` below, which resolves both of the study's
     re-promotion requirements (whole-pool operand aliasing + a shard_map
     tp wrapper); this entry point stays as the standalone study surface
-    hack/decode_attn_bench.py measures.
+    hack/decode_attn_bench.py measures. ``interpret=None``: compiled on a
+    TPU, interpreted elsewhere (see paged_decode_attention).
     """
     b, t, h, dh = q.shape
     s = k.shape[1]
@@ -407,9 +406,13 @@ def _paged_call(q, k_pool, v_pool, k_scale_pool, v_scale_pool, table,
     n_layers, nb, page = k_pool.shape[:3]
     wp = table.shape[1]
     scale = 1.0 / math.sqrt(dh)
-    # [L, nb, page, H, Dh] -> [L, nb, page, H*Dh] is a free reshape
-    # (contiguous trailing dims) of the pool buffer itself — the operand
-    # the scatter-updated pool aliases into, with nothing materialized
+    # [L, nb, page, H, Dh] -> [L, nb, page, H*Dh]: contiguous trailing
+    # dims, so free on the CPU — but NOT under TPU tiling, where the
+    # compiled step carries one whole-pool relayout per K and V plane per
+    # layer (24 reshapes of bf16[12,1025,16,1024], ~790 MiB more temp than
+    # the gather route; PR 21 compile-only probe) and the kernel route
+    # measured 8x slower than gather. Fixing the pool layout is ROADMAP
+    # Speed #4.
     kf = k_pool.reshape(n_layers, nb, page, h * dh)
     vf = v_pool.reshape(n_layers, nb, page, h * dh)
     qf = q.reshape(b, t, h * dh)
@@ -490,7 +493,13 @@ def paged_decode_attention(
     collectives — compiled-HLO collective parity with the gather route is
     asserted in tests. Routing between this kernel and the gather path is
     measured per shape (paged_attn_route); the engine's ServingConfig
-    ``paged_attn`` forces either route."""
+    ``paged_attn`` forces either route.
+
+    ``interpret=None`` resolves from the backend: compiled (Mosaic) on a
+    TPU, the Pallas interpreter elsewhere — the CPU tests' numerics rig.
+    That a chip run executed the COMPILED kernel is proved, not assumed:
+    chip_smoke.py requires a ``tpu_custom_call`` in the engine's decode
+    step, tests/test_tpu_compile.py compiles it with interpret=False."""
     t = q.shape[1]
     kv_len = _norm_kv_len(kv_len, t)
     _check_pool(q, k_pool, table)
@@ -508,7 +517,7 @@ def paged_decode_attention(
                   P(None, None, None, "tp", None),
                   P(None, None), P(None, None), P(None)),  # table/lens/layer
         out_specs=P(None, None, "tp", None),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, k_pool, v_pool, table, kv_len, lay)
 
@@ -550,7 +559,7 @@ def paged_decode_attention_int8kv(
                   P(None, None, None, "tp"),
                   P(None, None), P(None, None), P(None)),
         out_specs=P(None, None, "tp", None),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, kq_pool, k_scale_pool, vq_pool, v_scale_pool, table,
               kv_len, lay)

@@ -6,15 +6,5 @@ import jax
 
 
 def pvary(x, axis: str):
-    """Mark x as varying over `axis` (zero-init scan carries under shard_map).
-
-    jax >= 0.9 renames `lax.pvary` to `lax.pcast(..., to='varying')`; support
-    both so the kernels track the live API without a hard version pin. jax
-    versions predating varying-axis tracking (< 0.5.3) have neither and need
-    no marking at all — carries are implicitly replicated-compatible there.
-    """
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axis, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axis)
-    return x
+    """Mark x as varying over `axis` (zero-init scan carries under shard_map)."""
+    return jax.lax.pcast(x, axis, to="varying")

@@ -20,10 +20,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5 exports it under experimental only
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from vtpu.models.moe import MoEConfig, expert_ffn, route

@@ -20,10 +20,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5 exports it under experimental only
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from vtpu.parallel.collectives import pvary

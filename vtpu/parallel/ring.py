@@ -14,11 +14,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5 exports it under experimental only
-    from jax.experimental.shard_map import shard_map
 
 from vtpu.parallel.collectives import pvary
 

@@ -18,11 +18,8 @@ from __future__ import annotations
 import functools
 
 import jax
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5 exports it under experimental only
-    from jax.experimental.shard_map import shard_map
 
 from vtpu.ops.attention import causal_attention
 

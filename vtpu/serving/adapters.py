@@ -285,7 +285,8 @@ class TransformerSlotModel:
         logits, new = prefill_into_slots(
             params, self.cfg, _constrain_paged(self, state), padded, slots,
             true_lens,
-            prefill_fn=lambda p, c, t: prefill(p, c, t, logits_at=true_lens - 1),
+            prefill_fn=lambda p, c, t: prefill(
+                p, c, t, logits_at=true_lens - 1, mesh=self.mesh),
             mesh=self.mesh,
         )
         return logits, _constrain_paged(self, new)

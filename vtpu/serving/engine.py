@@ -20,6 +20,7 @@ tenants each run one of these engines against their fractional chip share.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import itertools
 import logging
@@ -31,6 +32,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from vtpu.obs.tickprof import TickProfiler
 from vtpu.obs.trace import RequestTrace, TERMINAL_CODES, pct
@@ -86,8 +88,9 @@ class ServingConfig:
     # (see decode_loop_k below).
     spec_tokens: int = 0
     spec_ngram: int = 3
-    # Adaptive speculation: a verify tick costs ~1.06-1.35x a decode tick
-    # (MFU_r04 spec), so speculation LOSES on traffic whose drafts rarely
+    # Adaptive speculation: a verify tick costs more than a decode tick
+    # (1.06-1.35x measured in round 4 on a v5e, record removed with the
+    # rig), so speculation LOSES on traffic whose drafts rarely
     # verify. The engine tracks an EMA of mean emitted tokens per spec tick
     # and stops drafting while it sits below this threshold, re-probing
     # after spec_cooloff_ticks plain ticks (workloads change). 0 = always
@@ -328,8 +331,9 @@ class ServingConfig:
 
 
 def choose_kv_int8(slots: int, max_window: int) -> bool:
-    """Measured kv_int8 router (VERDICT r4 #3). INT8_AB_r05.json, real
-    v5e, 5 interleaved repeats per cell, RTT-cancelled timing:
+    """Measured kv_int8 router. INT8_AB_r05.json: measured in round 5 on
+    a v5e through a rig since removed, 5 interleaved repeats per cell;
+    re-measure (ROADMAP Speed #3-#5):
 
         batch  8 x 1024: int8 1.15x faster     batch  8 x 2048: 0.96x
         batch 32 x 1024: int8 1.22x faster     batch 32 x 2048: 1.21x
@@ -534,9 +538,11 @@ class Status:
                      aborted at the next flush boundary mid-stream
     - SHED_OVERLOAD  the shed policy dropped it from an overflowing
                      waiting line (ServingConfig.shed_queue_depth)
-    - FAULTED        a failure was contained to this one request: an
-                     exception escaped its dispatch/deliver path, or its
-                     prefill worker died past the retry budget
+    - FAULTED        a failure ended it: an exception escaped this one
+                     request's dispatch/deliver path (contained), its
+                     prefill worker died past the retry budget, or the
+                     serving loop itself died on an exception (then every
+                     stream it held ends FAULTED and submit() raises)
     """
 
     OK = "OK"
@@ -1053,12 +1059,16 @@ def _scatter_prefill_pages(
     return last, new_cache
 
 
-def pad_to_chunks(tokens: jax.Array, n: int, c: int) -> jax.Array:
+def pad_to_chunks(tokens, n: int, c: int) -> np.ndarray:
     """Right-pad an [n] prompt with zeros to a [1, ceil(n/c)*c] chunk grid
     (the one padding contract every chunked path shares; pads above the true
-    length are masked by the ragged reads and overwritten before use)."""
-    pad = -(-n // c) * c
-    return jnp.zeros((1, pad), jnp.int32).at[0, :n].set(tokens)
+    length are masked by the ragged reads and overwritten before use).
+    Built on the HOST: an eager device pad, and the eager slice each chunk
+    then takes from it, compile once per distinct prompt length — on the
+    loop thread, mid-serving."""
+    out = np.zeros((1, -(-n // c) * c), np.int32)
+    out[0, :n] = np.asarray(tokens)
+    return out
 
 
 def lookup_draft(history: list, k: int, max_ngram: int) -> Optional[list]:
@@ -1111,7 +1121,10 @@ def prefill_into_slot(
     family passes moe_prefill — same cache contract). Returns the first
     generated token's logits ([vocab]) and the updated pool cache.
     """
-    logits, seq_cache = (prefill_fn or prefill)(params, cfg, tokens)
+    if prefill_fn is None:
+        logits, seq_cache = prefill(params, cfg, tokens, mesh=mesh)
+    else:
+        logits, seq_cache = prefill_fn(params, cfg, tokens)
     # [L, 1, max_seq, H, Dh] -> the bucket's worth, written at (layer, slot, 0)
     # (int8 caches carry k_scale/v_scale alongside; copied the same way)
     s = tokens.shape[1]
@@ -1153,7 +1166,10 @@ def prefill_into_slots(
     detected by rank, so families without the fast path stay correct.
     Returns (last-position logits [N, vocab], updated pool cache).
     """
-    logits, seq_cache = (prefill_fn or prefill)(params, cfg, tokens)
+    if prefill_fn is None:
+        logits, seq_cache = prefill(params, cfg, tokens, mesh=mesh)
+    else:
+        logits, seq_cache = prefill_fn(params, cfg, tokens)
     s = tokens.shape[1]
     if "table" in cache:
         return _scatter_prefill_pages(
@@ -1170,6 +1186,13 @@ def prefill_into_slots(
     else:
         last = logits[jnp.arange(tokens.shape[0]), true_lens - 1]
     return last, new_cache
+
+
+def _committed(x, placement):
+    """*x* as a COMMITTED array at *placement* (a no-op for one that is)."""
+    if isinstance(x, jax.Array) and x.committed:
+        return x
+    return jax.device_put(x, placement)
 
 
 class ServingEngine:
@@ -1211,6 +1234,61 @@ class ServingEngine:
                 params, cfg, mesh=mesh, kv_page=serving.kv_page,
                 kv_pool_blocks=serving.kv_pool_blocks,
                 paged_attn=serving.paged_attn)
+        # HOME DEVICE: a mesh-less engine lives where its params were put
+        # (jax.device_put(params, dev) before construction), so N replicas
+        # in one process each own a chip instead of all landing on device
+        # 0. Everything the engine allocates eagerly — pool state, PRNG
+        # keys, admission buffers, prompt uploads — is created under
+        # jax.default_device(home) on the constructing thread, the loop
+        # thread and submit(); jitted steps follow the committed params.
+        # A mesh engine's placement is its shardings (None here).
+        mesh = getattr(model, "mesh", None)
+        devs = set() if mesh is not None else {
+            d for leaf in jax.tree_util.tree_leaves(model.params)
+            if isinstance(leaf, jax.Array) for d in leaf.devices()}
+        self._device = devs.pop() if len(devs) == 1 else None
+        # COMMITTED OPERANDS. jit keys its executables on each operand's
+        # sharding and on whether the operand is committed: a fresh
+        # ``jnp.zeros`` or host array (uncommitted) keys differently from
+        # the committed array a previous step returned. Everything a step
+        # both takes and returns — pool state, PRNG keys, the admission
+        # buffer, the [B] token vector — is therefore committed at this
+        # placement from the start (_place, _host_tokens): the home
+        # device, or replicated over the serving mesh. Otherwise the
+        # executable _warm_executables compiled is not the one the second
+        # tick looks up, and the same program compiles again mid-stream —
+        # seconds per read window on a chip (found in PR 21 when a fleet's
+        # one-second heartbeat fenced replicas stalled in that compile).
+        if mesh is not None:
+            self._placement = NamedSharding(mesh, PartitionSpec())
+        elif self._device is not None:
+            self._placement = SingleDeviceSharding(self._device)
+        else:
+            self._placement = None
+        with self._on_device():
+            self._build(model, cfg, serving, sample)
+
+    def _place(self, tree):
+        """Commit an engine-owned pytree (pool state, PRNG keys, buffers)
+        to the placement its steps will return it at."""
+        if self._placement is None:
+            return tree
+        return jax.tree_util.tree_map(
+            lambda x: _committed(x, self._placement), tree)
+
+    def _host_tokens(self) -> jax.Array:
+        """The host's [B] next-token mirror as a step operand, committed
+        like the token vector a step returns (see __init__)."""
+        return self._place(np.asarray(self._tokens, np.int32))
+
+    def _on_device(self):
+        """Context placing eager allocations on the engine's home device
+        (thread-local, so each thread that allocates enters its own)."""
+        if self._device is None:
+            return contextlib.nullcontext()
+        return jax.default_device(self._device)
+
+    def _build(self, model, cfg, serving: ServingConfig, sample) -> None:
         self.model = model
         self.params = model.params
         self.cfg = getattr(model, "cfg", cfg)
@@ -1272,7 +1350,7 @@ class ServingEngine:
                 f"provided model adapter was built with "
                 f"paged_attn={self._paged_attn!r}; pass paged_attn to the "
                 "adapter (or just params+cfg)")
-        self.state = model.init_state(b)
+        self.state = self._place(model.init_state(b))
         # Device-side sampling is the default: the sampler is fused into the
         # jitted decode step (adapters.sampled_decode_step), so a tick's
         # device->host transfer is [B] int32 tokens (+ optional [B] f32
@@ -1303,8 +1381,8 @@ class ServingEngine:
                 static_argnames=("kv_bucket", "unroll"),
                 donate_argnums=(1, 4),  # state + per-slot PRNG keys
             )
-            self._rng = jax.random.split(
-                jax.random.key(serving.sampling_seed), b)
+            self._rng = self._place(jax.random.split(
+                jax.random.key(serving.sampling_seed), b))
             # admission-time first tokens draw from their own stream (one
             # split per admission, host-side — admissions are rare next to
             # ticks); greedy never touches it
@@ -1467,7 +1545,7 @@ class ServingEngine:
             # stall the loop mid-serving (measured: 100-450 ms per eager
             # host-op shape on CPU — the exact stall class this admission
             # path exists to remove)
-            self._admit_buf = jnp.zeros((b,), jnp.int32)
+            self._admit_buf = self._place(jnp.zeros((b,), jnp.int32))
             self._set_buf1 = jax.jit(
                 lambda buf, i, v: buf.at[i].set(v), donate_argnums=(0,))
         else:
@@ -1660,9 +1738,7 @@ class ServingEngine:
             self._swap_gather = jax.jit(swap_page_gather(model))
             self._swap_scatter = jax.jit(
                 swap_page_scatter(model), donate_argnums=(0,))
-            # an explicitly-passed adapter carries its own mesh; the ctor
-            # arg only covers the default-constructed transformer
-            mesh = getattr(model, "mesh", mesh)
+            mesh = getattr(model, "mesh", None)
             if mesh is not None:
                 from vtpu.parallel.sharding import head_sharding
 
@@ -2008,6 +2084,13 @@ class ServingEngine:
         # Read by the fleet's fencing/failover path and by _loop's finally
         # (which must skip cleanup to preserve the crash semantics).
         self._died = False
+        # the exception that killed the loop thread outside the
+        # engine_death seam (a compile error in _warm_executables, a bug
+        # in a tick): recorded by _loop, raised by every later submit()
+        # and by the first stop(), shown by stats()["loop_error"]; the
+        # streams the loop held end FAULTED, never a clean CANCELLED
+        self._loop_error: Optional[BaseException] = None
+        self._loop_error_raised = False
 
     # ------------------------------------------------------------------ API
 
@@ -2236,8 +2319,6 @@ class ServingEngine:
             out["len"] = state["len"].at[slot].set(new_len)
             return out
 
-        from jax.sharding import NamedSharding
-
         def aval(x):
             sh = getattr(x, "sharding", None)
             if isinstance(sh, NamedSharding):
@@ -2274,6 +2355,9 @@ class ServingEngine:
         boundary — the probe a load-shedding client uses)."""
         if deadline_ms is not None and deadline_ms < 0:
             raise ValueError(f"deadline_ms must be >= 0, got {deadline_ms}")
+        if self._loop_error is not None:
+            raise RuntimeError(
+                "ServingEngine loop died") from self._loop_error
         if self._stop.is_set():
             raise RuntimeError("ServingEngine is stopped")
         if self._draining:
@@ -2290,7 +2374,8 @@ class ServingEngine:
             # diagnostic
             log.warning("submit() before start(): the request will not be "
                         "served until start() is called")
-        tokens = jnp.asarray(tokens, jnp.int32)
+        with self._on_device():
+            tokens = jnp.asarray(tokens, jnp.int32)
         # validate HERE, on the caller's thread: an oversized prompt must
         # raise to its submitter, not kill the serving loop (which would
         # hang every other client forever)
@@ -2362,7 +2447,8 @@ class ServingEngine:
             # raced with stop(): its drain may have missed this request; an
             # extra end-of-stream sentinel is harmless (finish is
             # idempotent), a missing one hangs the client in stream()
-            self._end_stream(req, Status.CANCELLED)
+            self._end_stream(req, Status.CANCELLED if self._loop_error is None
+                             else Status.FAULTED)
         return req
 
     # ------------------------------------------- failure-domain helpers
@@ -2557,6 +2643,11 @@ class ServingEngine:
                             "its exit path will retire remaining requests")
         else:
             self._drain_all()
+        if self._loop_error is not None and not self._loop_error_raised:
+            # reported once: stop() stays idempotent for teardown paths
+            self._loop_error_raised = True
+            raise RuntimeError(
+                "ServingEngine loop died") from self._loop_error
 
     def _drain_all(self) -> None:
         """End-of-stream for everyone still holding a Request: occupied slots
@@ -2564,18 +2655,21 @@ class ServingEngine:
         observe the None sentinel, not hang on a dead engine."""
         if self._disagg is not None:
             self._disagg.drain()
+        # a stream still running at shutdown did not complete: its
+        # terminal is CANCELLED (the engine abandoned it), never OK — and
+        # FAULTED when the loop died on an exception, so a dead engine
+        # cannot be read as a client walking away
+        ended = (Status.CANCELLED if self._loop_error is None
+                 else Status.FAULTED)
         for slot in range(len(self._slot_req)):
-            # a stream still running at shutdown did not complete: its
-            # terminal is CANCELLED (the engine abandoned it), never OK
-            self._retire(slot, status=Status.CANCELLED)
+            self._retire(slot, status=ended)
         for slot, adm in self._admitting.items():
-            self._end_stream(adm["req"],
-                             adm["req"]._abort or Status.CANCELLED)
+            self._end_stream(adm["req"], adm["req"]._abort or ended)
             self._free_slot_blocks(slot)
         self._admitting.clear()
         for req in list(self._parked):
             self._release_parked(self._parked.pop(req))
-            self._end_stream(req, req._abort or Status.CANCELLED)
+            self._end_stream(req, req._abort or ended)
         self._want_park.clear()
         self._park_unseen.clear()
         self._want_resume.clear()
@@ -2590,14 +2684,14 @@ class ServingEngine:
                 item["error"] = RuntimeError("engine stopped")
                 item["done"].set()
         for req in self._waiting:
-            self._end_stream(req, req._abort or Status.CANCELLED)
+            self._end_stream(req, req._abort or ended)
         self._waiting.clear()
         while True:
             try:
                 req = self._pending.get_nowait()
             except queue.Empty:
                 break
-            self._end_stream(req, req._abort or Status.CANCELLED)
+            self._end_stream(req, req._abort or ended)
         # unserved lifecycle commands die with the engine — but a migrate
         # TICKET has a caller blocked on its event (vtpu/serving/migrate):
         # fail it explicitly so migrate()/drain() observe the stop instead
@@ -3470,7 +3564,8 @@ class ServingEngine:
                 "req": req, "padded": pad_to_chunks(prompt, n, self._chunk),
                 "n": n, "off": 0, "base": 0}
             return
-        padded = jnp.zeros((1, bucket), jnp.int32).at[0, :n].set(prompt)
+        padded = np.zeros((1, bucket), np.int32)  # host-built, as above
+        padded[0, :n] = np.asarray(prompt)
         logits, self.state = self._prefill(
             self.params, self.state, padded, jnp.int32(slot), jnp.int32(n)
         )
@@ -4347,6 +4442,8 @@ class ServingEngine:
             s["repartitions"] = 0
             s["prefill_backlog"] = 0
             s["prefill_share_tokens"] = None
+        s["loop_error"] = (None if self._loop_error is None
+                           else repr(self._loop_error))
         return s
 
     @property
@@ -4384,23 +4481,35 @@ class ServingEngine:
         the prefill warm writes junk into slot 0's row, which is harmless —
         no request occupies it and admission overwrites slot state."""
         b = self.serving.slots
-        tokens = jnp.zeros((b,), jnp.int32)
+        tokens = self._place(np.zeros((b,), np.int32))
         inactive = jnp.zeros((b,), bool)
         for bucket in (self._kv_buckets if self._use_kv_buckets else (0,)):
+            # Each device-sampled step is dispatched TWICE: with tokens
+            # built on the host (a tick after idle) and with the tokens the
+            # step itself returned (every steady-state tick). Where the two
+            # carry the same sharding the second dispatch is a cache hit
+            # and costs one masked tick; where they do not (a mesh whose
+            # compiler picked another output sharding) the second
+            # executable is compiled HERE instead of mid-stream.
             if self._loop_k:
                 # the k-tick flush executable replaces the single-tick
                 # sampled step as the loop's only decode dispatch; warm it
                 # per read bucket (all-inactive, zero caps: k masked ticks
                 # advance nothing)
-                _, _, _, _, self.state, self._rng = self._decode_loop(
-                    self.params, self.state, tokens, inactive, self._rng,
-                    jnp.zeros((b,), jnp.int32), bucket, unroll=self._unroll,
-                )
+                fed = tokens
+                for _ in range(2):
+                    _, _, fed, _, self.state, self._rng = self._decode_loop(
+                        self.params, self.state, fed, inactive, self._rng,
+                        jnp.zeros((b,), jnp.int32), bucket,
+                        unroll=self._unroll,
+                    )
             elif self._device_sampling:
-                _, _, self.state, self._rng = self._decode_sampled(
-                    self.params, self.state, tokens, inactive, self._rng,
-                    bucket, unroll=self._unroll,
-                )
+                fed = tokens
+                for _ in range(2):
+                    fed, _, self.state, self._rng = self._decode_sampled(
+                        self.params, self.state, fed, inactive, self._rng,
+                        bucket, unroll=self._unroll,
+                    )
             else:
                 _, self.state = self._decode(
                     self.params, self.state, tokens, inactive, bucket,
@@ -4445,8 +4554,6 @@ class ServingEngine:
             for n in self._admit_sizes:
                 keys = jax.random.split(jax.random.key(0), n + 1)
                 _, _ = keys[0], keys[1:]
-            self._admit_buf = self._set_buf1(
-                self._admit_buf, jnp.int32(0), jnp.int32(0))
         else:
             for bucket in self._prefill_buckets:
                 logits, self.state = self._prefill(
@@ -4456,10 +4563,11 @@ class ServingEngine:
         if self._device_sampling:
             # the [B] token merge serves both the pipelined fed-merge and
             # the admission override — warm its one executable
-            self._merge_tokens(
-                jnp.zeros((b,), bool), jnp.zeros((b,), jnp.int32), tokens)
+            # (both token operands committed, as every serving call's are)
+            self._merge_tokens(jnp.zeros((b,), bool), tokens, tokens)
         vocab = getattr(self.cfg, "vocab", None)
-        row = (jnp.zeros((vocab,), jnp.float32) if vocab
+        # a logits row as the admission tails get it: a step's output
+        row = (self._place(jnp.zeros((vocab,), jnp.float32)) if vocab
                else None)
         if not self._async_admission and self._device_sampling \
                 and self.serving.temperature > 0.0:
@@ -4471,9 +4579,12 @@ class ServingEngine:
             # through these; warm them so a first prefix-cached admission
             # can't compile inside the loop
             if self.serving.temperature > 0.0:
-                self._sample1(row, jax.random.key(0))
+                first = self._sample1(row, jax.random.key(0))
             else:
-                self._argmax1(row)
+                first = self._argmax1(row)
+            # the single-slot buffer write takes that device-resident token
+            self._admit_buf = self._set_buf1(
+                self._admit_buf, jnp.int32(0), first)
         if self._prefill_chunk is not None:
             # one executable per (chunk, read-bucket) pair. EVERY bucket
             # >= chunk is reachable: prefix-cached admissions chunk from
@@ -4520,17 +4631,18 @@ class ServingEngine:
 
     def _loop(self) -> None:
         try:
-            self._warm_executables()
-            if self._disagg is not None:
-                self._disagg.started.set()
-            if self._fused_spec:
-                self._loop_fused()
-            elif self._loop_k:
-                self._loop_device()
-            elif self._pipeline:
-                self._loop_pipelined()
-            else:
-                self._loop_sync()
+            with self._on_device():
+                self._warm_executables()
+                if self._disagg is not None:
+                    self._disagg.started.set()
+                if self._fused_spec:
+                    self._loop_fused()
+                elif self._loop_k:
+                    self._loop_device()
+                elif self._pipeline:
+                    self._loop_pipelined()
+                else:
+                    self._loop_sync()
         except EngineDeath:
             # the engine_death seam: the loop thread vanishes WITHOUT its
             # shutdown sweep — no terminals, no releases, clients left
@@ -4539,6 +4651,14 @@ class ServingEngine:
             # fleet supervisor's job (ledger + failover), reclaiming the
             # host bookkeeping is its reap's.
             return
+        except Exception as exc:
+            # anything else that escapes — a compile error while warming,
+            # a bug in a tick — is recorded before the sweep below runs,
+            # so the streams it ends read FAULTED and submit()/stats()/
+            # stop() name the cause instead of a silently idle engine
+            self._loop_error = exc
+            self._stop.set()
+            log.exception("serving loop died")
         finally:
             if self._died:
                 return
@@ -4843,11 +4963,11 @@ class ServingEngine:
                         # carry stale device values the active mask ignores
                         tokens = inflight["tokens"]
                     elif inflight is None:
-                        tokens = jnp.asarray(self._tokens, jnp.int32)
+                        tokens = self._host_tokens()
                     else:
                         tokens = self._merge_tokens(
                             jnp.asarray(fed, bool), inflight["tokens"],
-                            jnp.asarray(self._tokens, jnp.int32))
+                            self._host_tokens())
                     over = [i for i in dispatch if self._admit_mask[i]]
                     if over:
                         # freshly admitted slots: their first tokens are
@@ -5020,11 +5140,11 @@ class ServingEngine:
                         # tokens straight back — no host upload, no merge
                         tokens = inflight["carry"]
                     elif inflight is None:
-                        tokens = jnp.asarray(self._tokens, jnp.int32)
+                        tokens = self._host_tokens()
                     else:
                         tokens = self._merge_tokens(
                             jnp.asarray(fed, bool), inflight["carry"],
-                            jnp.asarray(self._tokens, jnp.int32))
+                            self._host_tokens())
                     over = [i for i in dispatch if self._admit_mask[i]]
                     if over:
                         # freshly admitted slots: first tokens still
@@ -5260,7 +5380,7 @@ class ServingEngine:
                     self._idle_wait(admitted)
                 continue
             t_disp = time.perf_counter()
-            tokens = jnp.asarray(self._tokens, jnp.int32)
+            tokens = self._host_tokens()
             active = jnp.asarray(
                 [self._slot_req[i] is not None for i in range(b)], bool)
             # watchdog-capped ceiling, then the policy's pick within it
@@ -5492,7 +5612,7 @@ class ServingEngine:
             # delivery side feeds, so the telemetry is comparable with the
             # pipelined loop's
             t_disp = time.perf_counter()
-            tokens = jnp.asarray(self._tokens, jnp.int32)
+            tokens = self._host_tokens()
             over = [i for i in active_slots if self._admit_mask[i]]
             if over:
                 # freshly admitted slots' first tokens, still device-resident

@@ -38,6 +38,7 @@ from typing import Dict
 
 from vtpu.serving.fabric.transport import Channel, TcpChannel, TransportError
 from vtpu.serving.fabric.wire import PROTO_VERSION, json_safe
+from vtpu.util.jaxcache import place_compile_cache
 
 log = logging.getLogger(__name__)
 
@@ -499,6 +500,7 @@ def main(argv=None) -> int:
     ap.add_argument("--port", type=int, default=0)
     args = ap.parse_args(argv)
     spec = json.loads(args.spec)
+    place_compile_cache()
     _, engines = build_engines_from_spec(spec)
     host = EngineHost(engines)
     srv = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
@@ -527,17 +529,20 @@ def main(argv=None) -> int:
 def spawn_host(spec: dict, timeout: float = 120.0):
     """Launch a child engine-host process and return ``(proc, port)``.
     The child prints its port as a JSON line once listening; engine
-    warm-up (executable compiles) proceeds behind the accept loop."""
-    import os
+    warm-up (executable compiles) proceeds behind the accept loop.
+
+    The child inherits this process's environment unchanged — no platform
+    is chosen for it — and its stderr is this process's stderr. An
+    accelerator belongs to one process at a time, so on a TPU host the
+    child needs a chip its parent does not hold (a parent that has touched
+    JAX holds every chip it can see): give the child its own through the
+    environment, or keep the parent off JAX."""
     import subprocess
 
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     proc = subprocess.Popen(
         [sys.executable, "-m", "vtpu.serving.fabric.host",
          "--spec", json.dumps(spec)],
-        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, env=env,
-        text=True)
+        stdout=subprocess.PIPE, text=True)
     port_box: list = []
 
     def read_port():

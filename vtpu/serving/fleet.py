@@ -439,12 +439,22 @@ class EngineFleet:
         self._stop_ev.set()
         if self._mon is not None:
             self._mon.join(timeout=10)
-        for eng in self._engines.values():
-            eng.stop()
+        loop_errors = []
+        for name, eng in self._engines.items():
+            try:
+                eng.stop()
+            except RuntimeError as exc:
+                # a member whose loop died on an exception says so from
+                # stop(); the rest of the fleet still has to stop
+                loop_errors.append((name, exc))
         # every stream now carries a terminal (the engines' shutdown
         # sweeps deliver CANCELLED to stragglers): close their journeys
         # so a post-shutdown journeys() read sees only ended spans
         self._prune_assigned()
+        if loop_errors:
+            name, exc = loop_errors[0]
+            raise RuntimeError(
+                f"fleet member {name!r} had died: {exc}") from exc.__cause__
 
     def _make_hook(self, name: str):
         def hook(eng, _name=name):
