@@ -253,20 +253,20 @@ def tenant_main(a: argparse.Namespace) -> None:
             "rank": a.rank, "backend": backend, "ttfts": ttfts, "totals": totals,
             "engine": {k: es[k] for k in (
                 "device_gets_per_tick", "bytes_fetched_per_tick",
-                "host_ms_per_tick", "device_sampling", "pipelined",
+                "device_sampling", "pipelined",
                 "pipelined_ticks", "decode_ticks", "generated_tokens",
-                # admission data plane: host stall EMA in _tick_head,
-                # batched prefill dispatch sizes, blocking admission syncs
-                # (0 on the batched-async path), and this engine's own
-                # inter-token-latency percentiles
-                "admission_stall_ms", "prefill_batch_hist",
+                # admission data plane: batched prefill dispatch sizes,
+                # blocking admission syncs (0 on the batched-async path),
+                # and this engine's own inter-token-latency percentiles
+                # (the admission head's host time is tick_phase_ms below)
+                "prefill_batch_hist",
                 "admission_syncs", "batched_admission",
                 # multi-tick device loop: the configured k, flush/early-
                 # exit counters, and the per-token amortization of the
-                # fetch + host-bookkeeping contracts (1/k and EMA/k with
-                # the loop on; identical to the per-tick figures when off)
+                # fetch contract (1/k with the loop on; the host's share
+                # per inner tick is tick_phase_ms' mean_ms_per_tick)
                 "decode_loop_k", "loop_flushes", "loop_early_exits",
-                "device_gets_per_token", "host_ms_per_token",
+                "device_gets_per_token",
                 # span telemetry is re-derived from the trace substrate
                 # (vtpu/obs): the ITL reservoir is a view over the trace,
                 # and TTFT/queue-wait percentiles come from the same
@@ -277,8 +277,8 @@ def tenant_main(a: argparse.Namespace) -> None:
                 "itl_p50_ms", "itl_p99_ms",
                 "ttft_p50_ms", "ttft_p95_ms", "ttft_p99_ms",
                 "queue_wait_p50_ms", "queue_wait_p99_ms",
-                # tick-phase attribution (obs tickprof): where the host
-                # ms/tick EMA actually goes under this tenant's traffic
+                # tick-phase attribution (obs tickprof): where the host's
+                # time per tick goes under this tenant's traffic
                 "tick_phase_ms", "trace_events_recorded",
                 # KV-memory data plane: the per-tick read-window histogram
                 # (the dense path's global longest-sequence read tax made
@@ -828,10 +828,14 @@ def main() -> None:
     tenant_engine = [
         {"tenant": f"{t.tag}{t.rank}", **t.engine_stats}
         for t in tenants if t.engine_stats] or None
+    from vtpu.obs.tickprof import host_ms_per_tick
+
     for e in tenant_engine or []:
+        host_ms = host_ms_per_tick(e["tick_phase_ms"])
         log(f"engine[{e['tenant']}]: {e['device_gets_per_tick']} "
             f"device_gets/tick, {e['bytes_fetched_per_tick']} B/tick, "
-            f"host {e['host_ms_per_tick']} ms/tick, pipelined={e['pipelined']} "
+            f"host {None if host_ms is None else round(host_ms, 4)} ms/tick, "
+            f"pipelined={e['pipelined']} "
             f"({e['pipelined_ticks']}/{e['decode_ticks']} decode ticks)")
 
     # Interception cost attribution: per-execute /

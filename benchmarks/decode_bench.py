@@ -14,8 +14,8 @@ the sampling/pipelining configuration differs:
                while the host delivers tick t (one-tick lookahead).
 
 Reports tokens/sec and host-overhead µs/tick per arm (from the engine's own
-stats() telemetry: device_gets_per_tick, bytes_fetched_per_tick,
-host_ms_per_tick) plus the device/host speedup. Timed windows exclude
+stats() telemetry: device_gets_per_tick, bytes_fetched_per_tick, the
+tick_phase_ms totals) plus the device/host speedup. Timed windows exclude
 compiles: each arm runs one full warmup wave before measurement.
 
 --loop-k (ISSUE 11) switches to the multi-tick device-loop sweep: k in
@@ -61,6 +61,15 @@ import time
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+
+
+def _host_us(stats: dict):
+    """Host microseconds per inner decode tick outside the device fetch
+    (the stats()["tick_phase_ms"] totals), or None before any tick."""
+    from vtpu.obs.tickprof import host_ms_per_tick
+
+    ms = host_ms_per_tick(stats["tick_phase_ms"])
+    return None if ms is None else round(ms * 1e3, 2)
 
 
 def main() -> None:
@@ -160,9 +169,7 @@ def main() -> None:
             "arm": name,
             "tokens_per_sec": round(statistics.median(rates), 1),
             "tokens_per_sec_runs": [round(r, 1) for r in rates],
-            "host_overhead_us_per_tick": (
-                round(stats["host_ms_per_tick"] * 1e3, 1)
-                if stats["host_ms_per_tick"] is not None else None),
+            "host_overhead_us_per_tick": _host_us(stats),
             "device_gets_per_tick": stats["device_gets_per_tick"],
             "bytes_fetched_per_tick": stats["bytes_fetched_per_tick"],
             "device_sampling": stats["device_sampling"],
@@ -292,20 +299,13 @@ def run_loop_k(a) -> None:
             st = stats[k]
             ph = st["tick_phase_ms"]
             ticks = max(st["decode_ticks"], 1)
-            host_us = sum(
-                ph[p]["total_ms"]
-                for p in ("admission", "dispatch", "deliver", "swap_drain")
-            ) / ticks * 1e3
             cells.append({
                 "slots": slots, "k": k,
                 "tokens_per_sec": round(statistics.median(rates[k]), 1),
                 "tokens_per_sec_runs": [round(r, 1) for r in rates[k]],
-                "host_us_per_token": round(host_us, 2),
+                "host_us_per_token": _host_us(st),
                 "fetch_us_per_token": round(
                     ph["fetch"]["total_ms"] / ticks * 1e3, 2),
-                "host_us_per_token_ema": (
-                    round(st["host_ms_per_token"] * 1e3, 2)
-                    if st["host_ms_per_token"] is not None else None),
                 "device_gets_per_token": st["device_gets_per_token"],
                 "loop_flushes": st["loop_flushes"],
                 "loop_early_exits": st["loop_early_exits"],

@@ -107,6 +107,7 @@ def main() -> None:
     from vtpu.models import ModelConfig, init_params
     from vtpu.serving import ServingConfig, ServingEngine
     from vtpu.obs.summary import print_summary
+    from vtpu.obs.tickprof import host_ms_per_tick
     from vtpu.obs.trace import (
         DROP_RESTORE_SEQUENCE, SWAP_RESTORE_SEQUENCE, subsequence)
 
@@ -187,7 +188,7 @@ def main() -> None:
             "admission_syncs": stats["admission_syncs"],
             "trace_events_recorded": stats["trace_events_recorded"],
             "trace_events_dropped": stats["trace_events_dropped"],
-            "host_ms_per_tick": stats["host_ms_per_tick"],
+            "host_ms_per_tick": host_ms_per_tick(stats["tick_phase_ms"]),
             "tick_phase_ms": stats["tick_phase_ms"],
             "itl_p50_ms": stats["itl_p50_ms"],
             "ttft_p50_ms": stats["ttft_p50_ms"],

@@ -80,6 +80,7 @@ def main() -> None:
     import jax.numpy as jnp
 
     from vtpu.models import ModelConfig, init_params
+    from vtpu.obs.tickprof import host_ms_per_tick
     from vtpu.serving import ServingConfig, ServingEngine
 
     # tiny on purpose (see paged_kv_bench): a CPU tick is dominated by
@@ -206,7 +207,7 @@ def main() -> None:
             "parked_peak_vs_pool": round(
                 n_sessions * pages_per / pool_blocks, 2),
             "device_gets_per_tick": stats["device_gets_per_tick"],
-            "host_ms_per_tick": stats["host_ms_per_tick"],
+            "host_ms_per_tick": host_ms_per_tick(stats["tick_phase_ms"]),
         }
         print(f"ratio {ratio}x: {n_sessions} sessions over "
               f"{pool_blocks} blocks — resume p50 {row['resume_p50_ms']}ms "

@@ -18,8 +18,8 @@ only the admission configuration differs:
 
 Per arm: background-stream ITL p50/p99 during the burst window (per-token
 delivery gaps observed by client threads), burst TTFT p50/p99, and the
-engine's own admission telemetry (admission_stall_ms, admission_syncs,
-prefill_batch_hist). Headline: sync/async background ITL p99 ratio. A
+engine's own admission telemetry (the admission phase's mean ms a loop
+pass, admission_syncs, prefill_batch_hist). Headline: sync/async background ITL p99 ratio. A
 deterministic same-bucket K-burst drain phase also asserts the coalescing
 contract: K prompts drain in <= ceil(K/Nmax) prefill dispatches.
 
@@ -179,7 +179,7 @@ def run_mixed_arm(params, cfg, serving, a, name: str,
         "drain_dispatches": drain_dispatches,
         "drain_dispatch_bound": -(-bg_free // nmax) if drain else None,
         "admission_syncs": stats["admission_syncs"],
-        "admission_stall_ms": stats["admission_stall_ms"],
+        "admission_ms_per_pass": stats["tick_phase_ms"]["admission"]["mean_ms"],
         "prefill_batch_hist": stats["prefill_batch_hist"],
         "batched_admission": stats["batched_admission"],
         # TTFT attribution (the trace-substrate split) + the disagg

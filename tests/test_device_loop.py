@@ -211,12 +211,12 @@ def test_multi_tick_stats_are_exported(params):
     """Every new stats() key the loop added maps to a vtpu_serving_*
     family — the exporter coverage check's contract, pinned here by name
     so the keys can never be quietly allowlisted away."""
-    from vtpu.obs.export import COUNTERS, GAUGES
+    from vtpu.obs.export import COUNTERS, GAUGES, SPECIAL
 
     assert "loop_flushes" in COUNTERS and "loop_early_exits" in COUNTERS
     assert "decode_loop_k" in GAUGES
     assert "device_gets_per_token" in GAUGES
-    assert "host_ms_per_token" in GAUGES
+    assert "tick_phase_ms" in SPECIAL
 
 
 # --------------------------------------------- transfer + early-exit walls
@@ -237,8 +237,13 @@ def test_fetch_contract_and_early_exit_exact_budget(params):
     assert stats["device_gets_per_token"] == 0.25
     assert stats["device_gets_per_tick"] == 0.25
     assert stats["loop_flushes"] * 4 == stats["decode_ticks"]
-    assert stats["host_ms_per_token"] == pytest.approx(
-        stats["host_ms_per_tick"] / 4, abs=1e-3)
+    # a flush pays each host phase once for its k inner ticks: the
+    # per-tick share in tick_phase_ms divides by them
+    for phase in ("dispatch", "deliver"):
+        h = stats["tick_phase_ms"][phase]
+        assert h["ticks"] == 4 * h["count"] == stats["decode_ticks"]
+        assert h["mean_ms_per_tick"] == pytest.approx(
+            h["mean_ms"] / 4, abs=1e-3)
     assert [len(s) for s in streams] == budgets
     assert stats["loop_early_exits"] > 0
     base, _ = _run(params, _serving(None, max_new_tokens=10), prompts,

@@ -256,7 +256,7 @@ def test_tick_profiler_phases():
     prof.note("fetch", 0.0001)
     snap = prof.snapshot()
     assert set(snap) == {"admission", "dispatch", "fetch", "deliver",
-                         "swap_drain"}
+                         "swap_drain", "idle_wait"}
     assert snap["dispatch"]["count"] == 2
     assert snap["dispatch"]["mean_ms"] == pytest.approx(2.0)
     assert snap["fetch"]["count"] == 1
@@ -604,7 +604,8 @@ def test_serving_families_shape(params):
     assert any(s.name.endswith("_bucket") for s in ttft.samples)
     assert sum(1 for s in ttft.samples if s.name.endswith("_count")) == 1
     phases = by_name["vtpu_serving_tick_phase_seconds"]
-    assert {"admission", "dispatch", "fetch", "deliver", "swap_drain"} == {
+    assert {"admission", "dispatch", "fetch", "deliver", "swap_drain",
+            "idle_wait"} == {
         s.labels["phase"] for s in phases.samples if "phase" in s.labels}
 
 

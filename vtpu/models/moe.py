@@ -23,7 +23,10 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from vtpu.ops import scaled_normal, rms_norm, apply_rope, rope_angles, causal_attention
+from vtpu.models.transformer import (
+    _embed, _lm_head, _o_proj, _prefill_cache, _qkv,
+)
+from vtpu.ops import scaled_normal, rms_norm, rope_angles, causal_attention
 
 Params = dict[str, Any]
 
@@ -144,12 +147,15 @@ def moe_ffn(lp: dict[str, jax.Array], x: jax.Array, cfg: MoEConfig,
     b, s, d = x.shape
     flat = x.reshape(b * s, d)
     cap = capacity or cfg.capacity(b * s)
-    dispatch, combine, aux = route(
-        lp["router"], flat, cfg, cap,
-        pad_mask=None if pad_mask is None else pad_mask.reshape(b * s))
-    slots = jnp.einsum("tec,td->ecd", dispatch.astype(x.dtype), flat)  # [E, C, D]
-    out_slots = expert_ffn(lp, slots)
-    out = jnp.einsum("tec,ecd->td", combine.astype(x.dtype), out_slots)
+    with jax.named_scope("route"):
+        dispatch, combine, aux = route(
+            lp["router"], flat, cfg, cap,
+            pad_mask=None if pad_mask is None else pad_mask.reshape(b * s))
+    with jax.named_scope("experts"):
+        slots = jnp.einsum(
+            "tec,td->ecd", dispatch.astype(x.dtype), flat)  # [E, C, D]
+        out_slots = expert_ffn(lp, slots)
+        out = jnp.einsum("tec,ecd->td", combine.astype(x.dtype), out_slots)
     return out.reshape(b, s, d), aux
 
 
@@ -157,14 +163,13 @@ def _moe_layer(cfg: MoEConfig, lp, x, cos, sin, positions, ffn):
     """One MoE decoder block over a full sequence: the SINGLE copy of the
     attention trunk shared by the training forward (moe_forward) and the
     serving prefill (moe_prefill). Returns (out, aux, (k, v))."""
-    b, s, _ = x.shape
-    h, dh = cfg.n_heads, cfg.head_dim
-    normed = rms_norm(x, lp["attn_norm"])
-    q = apply_rope((normed @ lp["wq"]).reshape(b, s, h, dh), cos, sin, positions)
-    k = apply_rope((normed @ lp["wk"]).reshape(b, s, h, dh), cos, sin, positions)
-    v = (normed @ lp["wv"]).reshape(b, s, h, dh)
-    x = x + causal_attention(q, k, v).reshape(b, s, cfg.qkv_dim) @ lp["wo"]
-    moe_out, aux = ffn(lp, rms_norm(x, lp["mlp_norm"]), cfg)
+    q, k, v = _qkv(cfg, lp, x, cos, sin, positions)
+    with jax.named_scope("attn"):
+        attn = causal_attention(q, k, v)
+    x = _o_proj(lp, x, attn)
+    with jax.named_scope("route"):  # the norm rides with the router
+        normed = rms_norm(x, lp["mlp_norm"])
+    moe_out, aux = ffn(lp, normed, cfg)
     return x + moe_out, aux, (k, v)
 
 
@@ -179,7 +184,7 @@ def moe_forward(
     b, s = tokens.shape
     cos, sin = rope_angles(cfg.max_seq, cfg.head_dim)
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
-    x = params["embed"][tokens].astype(cfg.dtype)
+    x = _embed(params, cfg, tokens)
 
     def layer(carry, lp):
         x, aux = carry
@@ -187,9 +192,7 @@ def moe_forward(
         return (out, aux + layer_aux), None
 
     (x, aux), _ = jax.lax.scan(layer, (x, jnp.float32(0.0)), params["layers"])
-    x = rms_norm(x, params["final_norm"])
-    logits = (x @ params["embed"].T).astype(jnp.float32)
-    return logits, aux / cfg.n_layers
+    return _lm_head(params, x), aux / cfg.n_layers
 
 
 def moe_loss(params: Params, cfg: MoEConfig, tokens: jax.Array, ffn=moe_ffn) -> jax.Array:
@@ -215,7 +218,9 @@ def moe_decode_ffn(cfg: MoEConfig):
         # with capacity >= tokens, routing can never drop anyone. x is
         # [B, T, D]: T=1 for plain decode, K+1 for a speculative verify
         # chunk (the same trunk serves both).
-        out, _aux = moe_ffn(lp, rms_norm(x, lp["mlp_norm"]), cfg,
+        with jax.named_scope("route"):  # the norm rides with the router
+            normed = rms_norm(x, lp["mlp_norm"])
+        out, _aux = moe_ffn(lp, normed, cfg,
                             capacity=x.shape[0] * x.shape[1])
         return out
 
@@ -242,12 +247,10 @@ def moe_prefill(
     does too). Without true_len, capacity = full token count: no token
     (real or pad) can ever drop — exact, but O(E/cf) more dispatch memory.
     """
-    from vtpu.models.transformer import fill_kv_cache, init_kv_cache
-
     b, s = tokens.shape
     cos, sin = rope_angles(cfg.max_seq, cfg.head_dim)
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
-    x = params["embed"][tokens].astype(cfg.dtype)
+    x = _embed(params, cfg, tokens)
 
     pad_mask = None
     if true_len is not None:
@@ -271,10 +274,4 @@ def moe_prefill(
         return out, kv
 
     x, (ks, vs) = jax.lax.scan(layer, x, params["layers"])
-    x = rms_norm(x, params["final_norm"])
-    logits = (x @ params["embed"].T).astype(jnp.float32)
-
-    cache = init_kv_cache(cfg, b)
-    cache.update(fill_kv_cache(cache, ks, vs))
-    cache["len"] = jnp.full((b,), s, jnp.int32)
-    return logits, cache
+    return _lm_head(params, x), _prefill_cache(cfg, ks, vs)

@@ -172,6 +172,7 @@ def quantize_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
 
 
 
+@jax.named_scope("sample")
 def sample_tokens(
     logits: jax.Array,
     keys: jax.Array,
@@ -463,6 +464,7 @@ def multi_tick_spec_decode(
     return out, counts, tok, state
 
 
+@jax.named_scope("qkv")
 def _qkv(cfg, lp, x, cos, sin, positions):
     """Project to rotated q/k/v heads: [B, S, H, Dh] each."""
     b, s, _ = x.shape
@@ -474,10 +476,32 @@ def _qkv(cfg, lp, x, cos, sin, positions):
     return apply_rope(q, cos, sin, positions), apply_rope(k, cos, sin, positions), v
 
 
+@jax.named_scope("mlp")
 def _mlp_block(lp, x):
     normed = rms_norm(x, lp["mlp_norm"])
     gate = jax.nn.silu((normed @ lp["w_gate"]).astype(jnp.float32)).astype(x.dtype)
     return (gate * (normed @ lp["w_up"])) @ lp["w_down"]
+
+
+@jax.named_scope("o_proj")
+def _o_proj(lp, x, attn):
+    """The attention output projection and its residual add."""
+    return x + attn.reshape(x.shape[:2] + (-1,)) @ lp["wo"]
+
+
+@jax.named_scope("embed")
+def _embed(params, cfg, tokens):
+    return params["embed"][tokens].astype(cfg.dtype)
+
+
+@jax.named_scope("lm_head")
+def _lm_head(params, x, logits_at=None):
+    """Final norm and the tied output head; ``logits_at`` ([B] positions)
+    gathers one row a sequence before the vocabulary projection."""
+    x = rms_norm(x, params["final_norm"])
+    if logits_at is not None:
+        x = x[jnp.arange(x.shape[0]), logits_at]  # [B, D]
+    return (x @ params["embed"].T).astype(jnp.float32)
 
 
 def transformer_layer(
@@ -493,11 +517,12 @@ def transformer_layer(
     """
     b, s, _ = x.shape
     q, k, v = _qkv(cfg, lp, x, cos, sin, positions)
-    if cfg.use_pallas and s % 128 == 0 and s >= FLASH_MIN_SEQ:
-        attn = flash_attention(q, k, v, mesh=mesh)
-    else:
-        attn = causal_attention(q, k, v)
-    x = x + attn.reshape(b, s, cfg.qkv_dim) @ lp["wo"]
+    with jax.named_scope("attn"):
+        if cfg.use_pallas and s % 128 == 0 and s >= FLASH_MIN_SEQ:
+            attn = flash_attention(q, k, v, mesh=mesh)
+        else:
+            attn = causal_attention(q, k, v)
+    x = _o_proj(lp, x, attn)
     x = x + _mlp_block(lp, x)
     return x, (k, v)
 
@@ -518,21 +543,24 @@ def prefill(
     b, s = tokens.shape
     cos, sin = rope_angles(cfg.max_seq, cfg.head_dim)
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
-    x = params["embed"][tokens].astype(cfg.dtype)
+    x = _embed(params, cfg, tokens)
 
     def layer(x, lp):
         return transformer_layer(cfg, lp, x, cos, sin, positions, mesh=mesh)
 
     x, (ks, vs) = jax.lax.scan(layer, x, params["layers"])
-    x = rms_norm(x, params["final_norm"])
-    if logits_at is not None:
-        x = x[jnp.arange(b), logits_at]  # [B, D]
-    logits = (x @ params["embed"].T).astype(jnp.float32)
+    logits = _lm_head(params, x, logits_at)
+    return logits, _prefill_cache(cfg, ks, vs)
 
+
+@jax.named_scope("kv_write")
+def _prefill_cache(cfg, ks: jax.Array, vs: jax.Array) -> dict[str, jax.Array]:
+    """A fresh cache holding a prefill's [L, B, S, H, Dh] keys and values."""
+    b, s = ks.shape[1:3]
     cache = init_kv_cache(cfg, b)
     cache.update(fill_kv_cache(cache, ks, vs))
     cache["len"] = jnp.full((b,), s, jnp.int32)
-    return logits, cache
+    return cache
 
 
 def fill_kv_cache(
@@ -720,7 +748,7 @@ def spec_verify_loop(
     ragged_len = jnp.minimum(
         lens[:, None] + 1 + jnp.arange(t)[None, :], cfg.max_seq
     )
-    x = params["embed"][draft].astype(cfg.dtype)
+    x = _embed(params, cfg, draft)
     kv_keys = ("k", "v", "k_scale", "v_scale") if quant else ("k", "v")
 
     def layer(l, carry, lp=None):
@@ -728,7 +756,8 @@ def spec_verify_loop(
         if lp is None:
             lp = jax.tree_util.tree_map(lambda a: a[l], params["layers"])
         q, k, v = _qkv(cfg, lp, x, cos, sin, positions)
-        kv = write_kv(l, kv, k, v)
+        with jax.named_scope("kv_write"):
+            kv = write_kv(l, kv, k, v)
         # Paged KERNEL route: the fused table-walker takes the WHOLE
         # scatter-updated pool plus the layer index (a scalar-prefetch
         # operand — static under the unrolled serving loop, traced under
@@ -738,6 +767,7 @@ def spec_verify_loop(
         # pallas_call, killing the copy that routed every trunk cell to
         # XLA back then.
         if use_kernel:
+            # pool_relayout and paged_attn are named inside the kernel's call
             if quant:
                 attn = paged_decode_attention_int8kv(
                     q, kv["k"], kv["k_scale"], kv["v"], kv["v_scale"],
@@ -746,9 +776,14 @@ def spec_verify_loop(
                 attn = paged_decode_attention(
                     q, kv["k"], kv["v"], table_w, ragged_len, layer=l,
                     mesh=mesh)
-            x = x + attn.reshape(b, t, cfg.qkv_dim) @ lp["wo"]
-            x = x + ffn(lp, x)
-            return x, kv
+        else:
+            with jax.named_scope("attn" if table is None else "gather_attn"):
+                attn = window_attention(l, kv, q)
+        x = _o_proj(lp, x, attn)
+        x = x + ffn(lp, x)
+        return x, kv
+
+    def window_attention(l, kv, q):
         # Bounded window reads: with the UNROLLED loop (the serving
         # default) the static index is a contiguous leading-dim slice and
         # the [:, :bucket] view fuses into the attention reads; under
@@ -764,25 +799,20 @@ def spec_verify_loop(
             }
         if table is not None:
             if quant:
-                attn = paged_causal_attention_int8kv(
+                return paged_causal_attention_int8kv(
                     q, view["k"], view["k_scale"], view["v"],
                     view["v_scale"], table_w, kv_len=ragged_len, mesh=mesh)
-            else:
-                attn = paged_causal_attention(
-                    q, view["k"], view["v"], table_w, kv_len=ragged_len,
-                    mesh=mesh)
-        elif quant:
-            attn = causal_attention_int8kv(
+            return paged_causal_attention(
+                q, view["k"], view["v"], table_w, kv_len=ragged_len,
+                mesh=mesh)
+        if quant:
+            return causal_attention_int8kv(
                 q, view["k"][:, :bucket], view["k_scale"][:, :bucket],
                 view["v"][:, :bucket], view["v_scale"][:, :bucket],
                 kv_len=ragged_len)
-        else:
-            attn = causal_attention(
-                q, view["k"][:, :bucket], view["v"][:, :bucket],
-                kv_len=ragged_len)
-        x = x + attn.reshape(b, t, cfg.qkv_dim) @ lp["wo"]
-        x = x + ffn(lp, x)
-        return x, kv
+        return causal_attention(
+            q, view["k"][:, :bucket], view["v"][:, :bucket],
+            kv_len=ragged_len)
 
     kv0 = {key: cache[key] for key in kv_keys}
     if unroll:
@@ -793,8 +823,7 @@ def spec_verify_loop(
         x, new_kv = carry
     else:
         x, new_kv = jax.lax.fori_loop(0, cfg.n_layers, layer, (x, kv0))
-    x = rms_norm(x, params["final_norm"])
-    logits = (x @ params["embed"].T).astype(jnp.float32)
+    logits = _lm_head(params, x)
     if table is not None:
         # the table is read-only inside the trunk (the engine owns it,
         # updating rows host-side at admission); pass it through so the

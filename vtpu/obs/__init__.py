@@ -1,9 +1,13 @@
 """Serving-engine observability: request-lifecycle tracing, tick-phase
 profiling, and the unified ``vtpu_serving_*`` Prometheus exporter.
 
-Three pieces, all host-side (nothing here ever touches the device — the
-overhead contract benchmarks/obs_bench.py gates is that tracing adds zero
-host syncs and stays within 2% tokens/sec of tracing-off):
+All host-side (nothing here ever touches the device — the overhead
+contract benchmarks/obs_bench.py gates is that tracing adds zero host
+syncs and stays within 2% tokens/sec of tracing-off). What the DEVICE did
+is named by ``jax.named_scope``s from one vocabulary, ``vtpu.ops.SCOPES``
+(docs/design.md, observability: a new step or kernel takes a name from it
+or adds one), and by the programs' names: ``jit_step`` decodes,
+``jit_admit_step`` admits, ``jit_prefill_chunk_into_slot`` is a chunk.
 
 - trace.py:    a lock-light bounded ring of structured lifecycle events
                (submit .. retire) stamped ``time.monotonic_ns`` off the
@@ -14,8 +18,18 @@ host syncs and stays within 2% tokens/sec of tracing-off):
                control-event ring, the DEAD-engine flight recorder, and
                the merged multi-pid Chrome dump.
 - tickprof.py: per-tick decode-loop phase attribution (admission head,
-               dispatch, fetch, deliver, swap drain) into bounded
-               histograms — where ``host_ms_per_tick`` actually goes.
+               dispatch, fetch, deliver, swap drain, idle wait) into
+               bounded histograms, opened by one context manager,
+               ``TickProfiler.phase(name, ticks=1, **ids)``, that also
+               holds a profiler span ``vtpu.tick.<phase>`` (ids: ``tick``
+               and the caller's) on the profiler's own clock. The only
+               record of the host's share of a tick: the EMA keys
+               (``host_ms_per_tick``, ``host_ms_per_token``,
+               ``admission_stall_ms``) are gone; ``host_ms_per_tick()``
+               here reads the totals.
+- warmup.py:   ``stats()["warmup_s"]``: the warm-up's seconds by kind
+               (trace_lower, compile, cache_load, run) from JAX's own
+               monitoring events.
 - export.py:   the ``vtpu_serving_*`` Prometheus family set over
                ``ServingEngine.stats()`` + the span/phase histograms,
                registered into the monitor's collector so ONE scrape

@@ -39,6 +39,9 @@ COUNTERS = {
     "spec_emitted": ("spec_emitted_tokens",
                      "Tokens delivered by speculative ticks"),
     "prefill_chunks": ("prefill_chunks", "Chunked-prefill dispatches"),
+    "prefill_tokens": ("prefill_tokens",
+                       "Prompt tokens sent to the device by admission "
+                       "batches and chunks (pads not counted)"),
     "admissions": ("admissions", "Requests that began service"),
     "device_gets": ("device_gets", "Batched device->host fetches"),
     "bytes_fetched": ("fetched_bytes", "Device->host payload bytes"),
@@ -161,19 +164,12 @@ GAUGES = {
                              "Tick fetches / ticks (contract: 1.0)", 1),
     "bytes_fetched_per_tick": ("bytes_fetched_per_tick",
                                "Fetched bytes / ticks", 1),
-    "host_ms_per_tick": ("host_seconds_per_tick",
-                         "EMA host bookkeeping per delivered tick", 1e-3),
     "decode_loop_k": ("decode_loop_k",
                       "Inner decode ticks per compiled flush (1 = classic "
                       "loop)", 1),
     "device_gets_per_token": ("device_gets_per_token",
                               "Tick fetches / inner decode ticks "
                               "(contract: 1/decode_loop_k)", 1),
-    "host_ms_per_token": ("host_seconds_per_token",
-                          "EMA host bookkeeping amortized per token-step "
-                          "(host_ms_per_tick / decode_loop_k)", 1e-3),
-    "admission_stall_ms": ("admission_stall_seconds",
-                           "EMA host seconds per _tick_head pass", 1e-3),
     "itl_p50_ms": ("itl_p50_seconds",
                    "Inter-token latency p50 (trace reservoir)", 1e-3),
     "itl_p99_ms": ("itl_p99_seconds",
@@ -270,6 +266,8 @@ SPECIAL = {
     "kv_hbm_bytes",            # -> vtpu_serving_kv_hbm_bytes{layout=...}
     "kv_hbm_bytes_per_chip",   # -> ..._per_chip{layout=...}
     "tick_phase_ms",           # -> vtpu_serving_tick_phase_seconds{phase=...}
+    "warmup_s",                # -> vtpu_serving_warmup_seconds{kind=...}
+                               #    and vtpu_serving_warmup_programs
 }
 # Escape hatch for the coverage check: stats() keys that are DELIBERATELY
 # not exported go here, with a reason.
@@ -562,6 +560,23 @@ def serving_families(sources: dict[str, object]) -> Iterable:
                 if v is not None:
                     fam.add_metric((name, layout), float(v))
         yield fam
+    # why a pod is slow to turn ready: the warm-up's seconds by kind
+    fam = GaugeMetricFamily(
+        PREFIX + "warmup_seconds",
+        "Seconds of the engine's warm-up by kind (total, trace_lower, "
+        "compile, cache_load, run)", labels=("engine", "kind"))
+    programs = GaugeMetricFamily(
+        PREFIX + "warmup_programs",
+        "Programs the warm-up compiled or loaded from the compile cache",
+        labels=("engine",))
+    for name, s in snaps.items():
+        for kind, v in (s.get("warmup_s") or {}).items():
+            if kind == "programs":
+                programs.add_metric((name,), float(v))
+            else:
+                fam.add_metric((name, kind), float(v))
+    yield fam
+    yield programs
     # span/phase histograms straight off the trace substrate (monotonic
     # bucket counters — not the bounded percentile reservoirs)
     span_hists = (

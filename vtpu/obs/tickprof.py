@@ -1,11 +1,15 @@
 """Tick-phase profiler: where a serving tick's host time actually goes.
 
-The engine used to report one ``host_ms_per_tick`` EMA — a single number
-that says a tick costs 2 ms of host work without saying WHICH work. This
-module gives that number attribution: each loop pass notes the seconds it
-spent in each phase into a bounded histogram, so a TTFT p99 outlier can be
-blamed on admission head-of-line work vs the device fetch vs Python
-delivery bookkeeping vs swap-drain housekeeping.
+Each loop pass notes the seconds it spent in each phase into a bounded
+histogram, so a TTFT p99 outlier can be blamed on admission head-of-line
+work vs the device fetch vs Python delivery bookkeeping vs swap-drain
+housekeeping. The loop opens a phase with ``TickProfiler.phase()``, which
+also holds a ``jax.profiler.TraceAnnotation`` named ``vtpu.tick.<phase>``
+open for the same interval: in a profiler session the loop thread's phases
+lie on the trace's own clock beside the device's operations, so a device
+gap can be put down to the host phase it began in. Every span carries a
+``tick`` id (the engine's tick counter when it opened), which ties the
+spans of one pass together. Outside a session an annotation is a flag test.
 
 Phases (one histogram each):
 
@@ -19,7 +23,9 @@ Phases (one histogram each):
               share of the tick.
 - deliver:    pure-Python bookkeeping after the fetch (stream puts,
               budget/eos/retire, history).
-- swap_drain: landing completed D2H swap-out snapshots in the host pool.
+- swap_drain: landing completed D2H swap-out snapshots in the host pool
+              (opened inside admission, whose note excludes it).
+- idle_wait:  the loop blocked with nothing to serve.
 
 Everything is plain host arithmetic: a ``note()`` is one bisect over a
 static bucket table plus four scalar updates, cheap enough for five calls
@@ -30,7 +36,10 @@ other threads see monotonic counters (benign racing, same contract as
 
 from __future__ import annotations
 
+import contextlib
+import time
 from bisect import bisect_left
+from typing import Callable, Optional
 
 # Default bucket upper edges in MILLISECONDS. Tick phases live in the
 # 10 us .. 100 ms range on real rigs; span latencies (TTFT/ITL/queue wait,
@@ -44,7 +53,20 @@ LATENCY_BUCKETS_MS = (
     1000.0, 2500.0, 5000.0, 10000.0,
 )
 
-PHASES = ("admission", "dispatch", "fetch", "deliver", "swap_drain")
+PHASES = ("admission", "dispatch", "fetch", "deliver", "swap_drain",
+          "idle_wait")
+# the loop's own work: not the wait for the device, not the idle wait
+HOST_PHASES = ("admission", "dispatch", "deliver", "swap_drain")
+
+
+def host_ms_per_tick(tick_phase_ms: dict) -> Optional[float]:
+    """Host milliseconds per inner decode tick outside the device fetch,
+    from a ``stats()["tick_phase_ms"]`` snapshot: the totals of
+    HOST_PHASES over the ticks the dispatches covered."""
+    ticks = tick_phase_ms["dispatch"]["ticks"]
+    if not ticks:
+        return None
+    return sum(tick_phase_ms[p]["total_ms"] for p in HOST_PHASES) / ticks
 
 
 class BoundedHistogram:
@@ -112,13 +134,40 @@ class BoundedHistogram:
 
 
 class TickProfiler:
-    """One BoundedHistogram per decode-loop phase."""
+    """One BoundedHistogram per decode-loop phase. ``tick`` gives the
+    engine's tick counter, the id every span of ``phase()`` carries."""
 
-    __slots__ = ("phases",)
+    __slots__ = ("phases", "_tick", "_span", "_names", "_inner_s")
 
     def __init__(self, phases: tuple = PHASES,
-                 edges_ms: tuple = PHASE_BUCKETS_MS):
+                 edges_ms: tuple = PHASE_BUCKETS_MS,
+                 tick: Callable[[], int] = lambda: 0):
+        # imported here, not above: importing vtpu.obs (the exporter, the
+        # benchmarks' summary line) stays free of JAX
+        from jax.profiler import TraceAnnotation
+
         self.phases = {p: BoundedHistogram(edges_ms) for p in phases}
+        self._tick = tick
+        self._span = TraceAnnotation
+        self._names = {p: f"vtpu.tick.{p}" for p in phases}
+        # seconds of the phases closed inside the one now open (a phase
+        # opened inside another is taken out of the outer one's note)
+        self._inner_s = 0.0
+
+    @contextlib.contextmanager
+    def phase(self, name: str, ticks: int = 1, **ids):
+        """Time the enclosed block into ``name``'s histogram, as ``note()``
+        would, under a profiler span ``vtpu.tick.<name>`` with the ids
+        ``tick`` and ``**ids``. Loop thread only."""
+        with self._span(self._names[name], tick=self._tick(), **ids):
+            outer, self._inner_s = self._inner_s, 0.0
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                dt = time.perf_counter() - t0
+                self.phases[name].note(dt - self._inner_s, ticks=ticks)
+                self._inner_s = outer + dt
 
     def note(self, phase: str, seconds: float, ticks: int = 1) -> None:
         """Record one phase sample. ``ticks`` is how many inner decode

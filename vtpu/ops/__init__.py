@@ -7,7 +7,17 @@ JAX/XLA model under vTPU isolation, mirroring the reference's vLLM harness,
 reference benchmarks/ai-benchmark/benchmark.py:1-50).
 """
 
-from vtpu.ops.init import scaled_normal
+# The scope vocabulary: every part of a compiled step (decode, admission,
+# chunk) runs under exactly one of these ``jax.named_scope`` names, so a
+# profiler trace attributes device time to it (PERF.md section 3 says which
+# metric reads which). A new step or kernel takes a name from here or adds
+# one here and there.
+SCOPES = (
+    "embed", "qkv", "kv_write", "pool_relayout", "paged_attn", "gather_attn",
+    "attn", "o_proj", "mlp", "route", "experts", "lm_head", "sample",
+)
+
+from vtpu.ops.init import scaled_normal  # noqa: E402
 from vtpu.ops.norms import rms_norm
 from vtpu.ops.rope import apply_rope, rope_angles
 from vtpu.ops.attention import (
@@ -29,6 +39,7 @@ from vtpu.ops.decode_attn import (
 )
 
 __all__ = [
+    "SCOPES",
     "scaled_normal",
     "rms_norm",
     "apply_rope",

@@ -308,6 +308,7 @@ def decode_attention(
             out_shape=out_shape,
             scratch_shapes=scratch,
             interpret=interpret,
+            name="decode_attn",
         )(qf, kf, vf, lens3)
         return out.reshape(b, t, h, dh)
 
@@ -335,6 +336,7 @@ def decode_attention(
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
+        name="decode_attn",
     )(qf, kf, ks_t, vf, vs_t, lens3)
     return out.reshape(b, t, h, dh)
 
@@ -413,8 +415,9 @@ def _paged_call(q, k_pool, v_pool, k_scale_pool, v_scale_pool, table,
     # the gather route; PR 21 compile-only probe) and the kernel route
     # measured 8x slower than gather. Fixing the pool layout is ROADMAP
     # Speed #4.
-    kf = k_pool.reshape(n_layers, nb, page, h * dh)
-    vf = v_pool.reshape(n_layers, nb, page, h * dh)
+    with jax.named_scope("pool_relayout"):
+        kf = k_pool.reshape(n_layers, nb, page, h * dh)
+        vf = v_pool.reshape(n_layers, nb, page, h * dh)
     qf = q.reshape(b, t, h * dh)
     lens3 = kv_len[:, None, :]  # [B, 1, T]: rank-3 so block dims tile
     q_spec = pl.BlockSpec((1, t, h * dh), lambda i, j, *_: (i, 0, 0))
@@ -458,6 +461,7 @@ def _paged_call(q, k_pool, v_pool, k_scale_pool, v_scale_pool, table,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, t, h * dh), q.dtype),
         interpret=interpret,
+        name="paged_attn",  # the kernel's name, and its scope in a trace
     )(lay, table, *operands)
     return out.reshape(b, t, h, dh)
 

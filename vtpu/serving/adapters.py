@@ -161,17 +161,21 @@ def batched_admission_step(model: Any, temperature: float, top_k: int,
     dispatch picks the tokens up from ``buf`` with one static-shape merge
     (no per-batch-size host-op compiles in the serving loop). Greedy
     ignores ``keys``; the signature keeps them so the executable shape is
-    sampling-agnostic."""
+    sampling-agnostic. The closure's name is the program's in a profiler
+    trace (``jit_admit_step``); the decode closures above are ``jit_step``,
+    which is how the benchmark's readers tell the two apart."""
     from vtpu.models.transformer import sample_tokens
 
-    def step(params, state, buf, tokens, slots, true_lens, keys):
+    def admit_step(params, state, buf, tokens, slots, true_lens, keys):
         last, state = model.prefill_into_slots(
             params, state, tokens, slots, true_lens)
         tok, _, _ = sample_tokens(
             last, keys, temperature=temperature, top_k=top_k, top_p=top_p)
-        return tok, buf.at[slots].set(tok), state
+        with jax.named_scope("sample"):
+            buf = buf.at[slots].set(tok)
+        return tok, buf, state
 
-    return step
+    return admit_step
 
 
 def swap_page_gather(model: Any):

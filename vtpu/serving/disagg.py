@@ -219,6 +219,7 @@ class DisaggRuntime:
             # counted as prefix_cow_copies, exactly like slot admission)
             "handoff_copies": 0,
             "prefill_chunks": 0,
+            "prefill_tokens": 0,
             "first_tokens": 0,
             "fetches": 0,
             "bytes_fetched": 0,
@@ -742,6 +743,8 @@ class PrefillWorker(threading.Thread):
                         kv_bucket=kv_bucket, unroll=eng._unroll,
                         block_ids=row)
                 self.rt.bump("prefill_chunks")
+                self.rt.bump("prefill_tokens",
+                             min(base + off + c, total) - (base + off))
                 eng.trace.record("prefill_chunk", req.rid, -1, c)
             last_row = logits[0, (total - base - 1) - (pad - c)]
         else:

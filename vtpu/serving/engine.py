@@ -22,6 +22,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import functools
 import itertools
 import logging
 import queue
@@ -35,6 +36,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from vtpu.obs.tickprof import TickProfiler
+from vtpu.obs.warmup import WarmupClock
 from vtpu.obs.trace import RequestTrace, TERMINAL_CODES, pct
 from vtpu.ops.decode_attn import paged_attn_route
 from vtpu.serving.faults import EngineDeath, FaultInjected, FaultPlan
@@ -934,28 +936,7 @@ def chunked_prefill_into_slot(
     bucket = kv_bucket or cfg.max_seq
     quant = kv_quantized(cfg)
     kv_keys = ("k", "v", "k_scale", "v_scale") if quant else ("k", "v")
-    if block_ids is not None:
-        page = cache["k"].shape[2]
-        wp = bucket // page
-        view = {}
-        for key in kv_keys:
-            pool = cache[key]  # [L, n_blocks, page, ...]
-            g = pool[:, block_ids]  # [L, Wp, page, ...]
-            view[key] = g.reshape(
-                (pool.shape[0], 1, wp * page) + pool.shape[3:])
-        if mesh is not None:
-            from vtpu.parallel.sharding import constrain_paged_kv
-
-            view = constrain_paged_kv(view, mesh)
-    else:
-        view = {
-            key: jax.lax.dynamic_slice(
-                cache[key],
-                (0, slot) + (0,) * (cache[key].ndim - 2),
-                (cache[key].shape[0], 1, bucket) + cache[key].shape[3:],
-            )
-            for key in kv_keys
-        }
+    view = _chunk_window(cache, kv_keys, bucket, slot, block_ids, mesh)
     view["len"] = jnp.full((1,), offset, jnp.int32)
 
     def write_kv(l, kv, k, v):
@@ -978,6 +959,44 @@ def chunked_prefill_into_slot(
         params, cfg, view, chunk, bucket, write_kv, ffn_fn=ffn_fn,
         unroll=unroll, mesh=mesh,
     )
+    return logits, _chunk_write_back(
+        cache, new_view, kv_keys, bucket, c, slot, offset, new_len, block_ids)
+
+
+@jax.named_scope("gather_attn")
+def _chunk_window(cache, kv_keys, bucket: int, slot, block_ids, mesh):
+    """The slot's dense [L, 1, bucket] read window for a prefill chunk:
+    gathered from the pool's blocks when ``block_ids`` is given, else a
+    slice of the slot's row."""
+    if block_ids is not None:
+        page = cache["k"].shape[2]
+        wp = bucket // page
+        view = {}
+        for key in kv_keys:
+            pool = cache[key]  # [L, n_blocks, page, ...]
+            g = pool[:, block_ids]  # [L, Wp, page, ...]
+            view[key] = g.reshape(
+                (pool.shape[0], 1, wp * page) + pool.shape[3:])
+        if mesh is not None:
+            from vtpu.parallel.sharding import constrain_paged_kv
+
+            view = constrain_paged_kv(view, mesh)
+        return view
+    return {
+        key: jax.lax.dynamic_slice(
+            cache[key],
+            (0, slot) + (0,) * (cache[key].ndim - 2),
+            (cache[key].shape[0], 1, bucket) + cache[key].shape[3:],
+        )
+        for key in kv_keys
+    }
+
+
+@jax.named_scope("kv_write")
+def _chunk_write_back(cache, new_view, kv_keys, bucket: int, c: int, slot,
+                      offset, new_len, block_ids):
+    """The pool cache with a chunk's written span of ``new_view`` put back
+    (and the slot's length set)."""
     out = dict(cache)
     if block_ids is not None:
         # Scatter back ONLY the page span [offset, offset + c) can have
@@ -1005,7 +1024,7 @@ def chunked_prefill_into_slot(
         # slot may be the engine's out-of-range sentinel (prefix build):
         # drop the length write rather than clamp-corrupt the last slot
         out["len"] = cache["len"].at[slot].set(new_len, mode="drop")
-        return logits, out
+        return out
     for key in kv_keys:
         shape = new_view[key].shape  # [L, 1, S, H(, Dh)]
         sizes = (shape[0], 1, c) + shape[3:]
@@ -1014,9 +1033,10 @@ def chunked_prefill_into_slot(
         out[key] = jax.lax.dynamic_update_slice(
             cache[key], written, (0, slot, offset) + (0,) * (len(shape) - 3))
     out["len"] = cache["len"].at[slot].set(new_len)
-    return logits, out
+    return out
 
 
+@jax.named_scope("kv_write")
 def _scatter_prefill_pages(
     cache: dict[str, jax.Array],
     seq_cache: dict[str, jax.Array],
@@ -1134,10 +1154,12 @@ def prefill_into_slot(
             cache, seq_cache, logits, jnp.asarray(slot)[None],
             jnp.asarray(true_len)[None], s, mesh=mesh)
         return last[0], new_cache
-    for key in ("k", "v", "k_scale", "v_scale"):
-        if key in cache:
-            new_cache[key] = cache[key].at[:, slot, :s].set(seq_cache[key][:, 0, :s])
-    new_cache["len"] = cache["len"].at[slot].set(true_len)
+    with jax.named_scope("kv_write"):
+        for key in ("k", "v", "k_scale", "v_scale"):
+            if key in cache:
+                new_cache[key] = cache[key].at[:, slot, :s].set(
+                    seq_cache[key][:, 0, :s])
+        new_cache["len"] = cache["len"].at[slot].set(true_len)
     last = logits[0, true_len - 1]
     return last, new_cache
 
@@ -1175,12 +1197,13 @@ def prefill_into_slots(
         return _scatter_prefill_pages(
             cache, seq_cache, logits, slots, true_lens, s, mesh=mesh)
     new_cache = dict(cache)
-    for key in ("k", "v", "k_scale", "v_scale"):
-        if key in cache:
-            # one advanced-index scatter over the slot axis: [L, N, s, ...]
-            new_cache[key] = cache[key].at[:, slots, :s].set(
-                seq_cache[key][:, :, :s])
-    new_cache["len"] = cache["len"].at[slots].set(true_lens)
+    with jax.named_scope("kv_write"):
+        for key in ("k", "v", "k_scale", "v_scale"):
+            if key in cache:
+                # one advanced-index scatter over the slot axis: [L, N, s, ...]
+                new_cache[key] = cache[key].at[:, slots, :s].set(
+                    seq_cache[key][:, :, :s])
+        new_cache["len"] = cache["len"].at[slots].set(true_lens)
     if logits.ndim == 2:
         last = logits  # prefill_fn already gathered the final positions
     else:
@@ -1810,6 +1833,9 @@ class ServingEngine:
                        "spec_emitted": 0,
                        "spec_emitted_hist": [0] * (serving.spec_tokens + 2),
                        "prefill_chunks": 0, "admissions": 0,
+                       # true prompt tokens sent to the device by admission
+                       # batches and chunks (pads not counted)
+                       "prefill_tokens": 0,
                        # per-tick transfer accounting: every loop
                        # device->host read goes through _fetch, which counts
                        # calls and payload bytes — the proof behind the
@@ -1935,12 +1961,6 @@ class ServingEngine:
         # speculation drafts AND for overcommit (a parked session's cache
         # contents must be recomputable from tokens when its pages fault)
         self._track_history = bool(self._spec_tokens or self._swap_enabled)
-        # EMA of host bookkeeping ms per delivered tick (the Python work the
-        # pipelined loop hides under the next dispatch)
-        self._host_ms_ema: Optional[float] = None
-        # EMA of host ms per _tick_head pass (admission work sitting inside
-        # the tick loop — the stall the batched-async path shrinks)
-        self._admission_ms_ema: Optional[float] = None
         # per-slot inter-token latency: timestamp of the last delivery per
         # slot (a slot's FIRST token records no gap — that interval is
         # TTFT). The gap/TTFT/queue-wait reservoirs themselves live in the
@@ -1948,15 +1968,16 @@ class ServingEngine:
         self._itl_last: list[Optional[float]] = [None] * b
         # observability substrate (vtpu/obs): the request-lifecycle event
         # ring + latency reservoirs/histograms, and the tick-phase
-        # profiler that attributes host_ms_per_tick (admission head,
-        # dispatch, fetch, deliver, swap drain). Host-only by
+        # profiler that attributes the loop thread's time (admission head,
+        # dispatch, fetch, deliver, swap drain, idle wait). Host-only by
         # construction: nothing here can add a device sync.
         self.trace = RequestTrace(capacity=serving.trace_events)
         if self._spec_disabled_reason is not None:
             # one-time event (val = the requested draft length): the trace
             # dump shows WHY the configured speculation never ran
             self.trace.record("spec_disabled", -1, -1, serving.spec_tokens)
-        self._prof = TickProfiler()
+        self._prof = TickProfiler(tick=self._tick_count)
+        self._warmup = WarmupClock()
         self._req_ctr = itertools.count()
         # registered prompt prefixes: id -> {tokens, buffers, len, pad,
         # last_logits}; install is a device copy, suffixes chunk from the
@@ -3569,6 +3590,7 @@ class ServingEngine:
         logits, self.state = self._prefill(
             self.params, self.state, padded, jnp.int32(slot), jnp.int32(n)
         )
+        self._stats["prefill_tokens"] += n
         self._stats["prefill_batch_hist"][1] += 1
         self._finish_admit(slot, req, self._sample_first(logits), n)
 
@@ -3595,11 +3617,14 @@ class ServingEngine:
         # them, so the signature is sampling-config-agnostic.
         keys = jax.random.split(self._admit_key, n + 1)
         self._admit_key, batch_keys = keys[0], keys[1:]
-        tok, self._admit_buf, self.state = self._admit_step(
-            self.params, self.state, self._admit_buf, padded,
-            np.asarray(slots, np.int32), np.asarray(lens, np.int32),
-            batch_keys,
-        )
+        with jax.profiler.TraceAnnotation(
+                "vtpu.admit.batch", n=n, bucket=bucket, tokens=sum(lens)):
+            tok, self._admit_buf, self.state = self._admit_step(
+                self.params, self.state, self._admit_buf, padded,
+                np.asarray(slots, np.int32), np.asarray(lens, np.int32),
+                batch_keys,
+            )
+        self._stats["prefill_tokens"] += sum(lens)
         rows = []
         for i, (slot, req) in enumerate(zip(slots, reqs)):
             self._begin_slot(slot, req, lens[i])
@@ -3822,15 +3847,20 @@ class ServingEngine:
                     m = min(len(blocks), wp)
                     row[:m] = blocks[:m]
                     extra["block_ids"] = row
-                logits, self.state = self._prefill_chunk(
-                    self.params, self.state, adm["padded"][:, off:off + c],
-                    jnp.int32(slot), jnp.int32(base + off),
-                    jnp.int32(min(base + off + c, n)),
-                    kv_bucket=kv_bucket, unroll=self._unroll, **extra,
-                )
+                real = min(base + off + c, n) - (base + off)  # less the pads
+                with jax.profiler.TraceAnnotation(
+                        "vtpu.admit.chunk", tokens=real):
+                    logits, self.state = self._prefill_chunk(
+                        self.params, self.state,
+                        adm["padded"][:, off:off + c],
+                        jnp.int32(slot), jnp.int32(base + off),
+                        jnp.int32(base + off + real),
+                        kv_bucket=kv_bucket, unroll=self._unroll, **extra,
+                    )
                 adm["off"] = off + c
                 budget -= c
                 self._stats["prefill_chunks"] += 1
+                self._stats["prefill_tokens"] += real
                 self.trace.record("prefill_chunk", req.rid, slot, c)
                 if adm["off"] >= adm["padded"].shape[1]:  # final chunk
                     del self._admitting[slot]
@@ -3899,18 +3929,18 @@ class ServingEngine:
             a.size * a.dtype.itemsize
             for a in jax.tree_util.tree_leaves(arrays))
         t0 = time.perf_counter()
-        spec = self._fire_fault("delayed_fetch")
-        if spec is not None:
-            # injected device stall: the fetch blocks like a wedged
-            # transfer would — what the watchdog below exists to catch
-            time.sleep(spec.arg or 0.05)
-        out = jax.device_get(arrays)
         # fetch phase = device wait + transfer: on the pipelined loop this
         # is the time the host blocks for the in-flight tick to finish —
         # the device-bound share of the tick, attributed separately from
         # the Python bookkeeping phases
+        with self._prof.phase("fetch", ticks=ticks):
+            spec = self._fire_fault("delayed_fetch")
+            if spec is not None:
+                # injected device stall: the fetch blocks like a wedged
+                # transfer would — what the watchdog below exists to catch
+                time.sleep(spec.arg or 0.05)
+            out = jax.device_get(arrays)
         dt = time.perf_counter() - t0
-        self._prof.note("fetch", dt, ticks=ticks)
         wd = self.serving.fetch_watchdog_ms
         if wd:
             if dt * 1e3 > wd:
@@ -3930,17 +3960,9 @@ class ServingEngine:
                     self._healthy_since = now
         return out
 
-    def _note_host_ms(self, seconds: float) -> None:
-        ms = seconds * 1e3
-        self._host_ms_ema = (
-            ms if self._host_ms_ema is None
-            else 0.9 * self._host_ms_ema + 0.1 * ms)
-
-    def _note_admission_ms(self, seconds: float) -> None:
-        ms = seconds * 1e3
-        self._admission_ms_ema = (
-            ms if self._admission_ms_ema is None
-            else 0.9 * self._admission_ms_ema + 0.1 * ms)
+    def _tick_count(self) -> int:
+        """The ``tick`` id of the loop's profiler spans."""
+        return self._stats["decode_ticks"] + self._stats["spec_ticks"]
 
     def _note_kv_window(self, kv_bucket: int, lens: list[int],
                         t: int = 1, ticks: int = 1) -> None:
@@ -4053,15 +4075,11 @@ class ServingEngine:
         if self._slot_budget[slot] <= 0 or tok == self.serving.eos_token:
             self._retire(slot)
 
-    def _deliver(self, tick: dict, extra_host_s: float = 0.0,
-                 firsts: Optional[list] = None) -> None:
+    def _deliver(self, tick: dict, firsts: Optional[list] = None) -> None:
         """Deliver one decode tick's device-sampled tokens: ONE batched
-        fetch, then pure-Python bookkeeping (stream, budget, eos, retire).
-        ``extra_host_s`` is host work already spent on this loop pass
-        outside this call (the pipelined loop's dispatch-side build), folded
-        into the same host_ms_per_tick sample so the telemetry reports the
-        full per-tick host cost, not just the delivery half. ``firsts`` is
-        this pass's async-admission manifest: the first-token arrays ride
+        fetch, then pure-Python bookkeeping (stream, budget, eos, retire),
+        the "deliver" phase. ``firsts`` is this pass's async-admission
+        manifest: the first-token arrays ride
         the SAME batched fetch (a few extra bytes, zero extra syncs) and
         are delivered before the tick's tokens, so a freshly admitted
         slot's stream always starts with its prefill-derived token.
@@ -4089,24 +4107,22 @@ class ServingEngine:
             # engines. Drop the whole delivery; the loop exits at its
             # next while-check without cleanup (crash semantics).
             return
-        t0 = time.perf_counter()
-        if firsts:
-            self._deliver_firsts(firsts, fetched=first_arrs)
-        now = time.perf_counter()
-        for slot, req in enumerate(tick["reqs"]):
-            if req is None or req is not self._slot_req[slot]:
-                continue
-            try:
-                self._emit(slot, int(toks[slot]),
-                           float(lps[slot]) if lps is not None else None,
-                           now=now)
-            except Exception:
-                # crash containment: an exception in ONE request's deliver
-                # path retires only that slot (typed FAULTED, blocks
-                # released) — the tick and every other stream keep going
-                self._contain_fault(slot)
-        self._prof.note("deliver", time.perf_counter() - t0)
-        self._note_host_ms(extra_host_s + time.perf_counter() - t0)
+        with self._prof.phase("deliver"):
+            if firsts:
+                self._deliver_firsts(firsts, fetched=first_arrs)
+            now = time.perf_counter()
+            for slot, req in enumerate(tick["reqs"]):
+                if req is None or req is not self._slot_req[slot]:
+                    continue
+                try:
+                    self._emit(slot, int(toks[slot]),
+                               float(lps[slot]) if lps is not None else None,
+                               now=now)
+                except Exception:
+                    # crash containment: an exception in ONE request's deliver
+                    # path retires only that slot (typed FAULTED, blocks
+                    # released) — the tick and every other stream keep going
+                    self._contain_fault(slot)
 
     def _emit(self, slot: int, tok: int, lp: Optional[float] = None,
               now: Optional[float] = None) -> None:
@@ -4266,30 +4282,16 @@ class ServingEngine:
             round(s["tick_fetches"] / ticks, 4) if ticks else None)
         s["bytes_fetched_per_tick"] = (
             round(s["bytes_fetched"] / ticks, 1) if ticks else None)
-        s["host_ms_per_tick"] = (
-            round(self._host_ms_ema, 4)
-            if self._host_ms_ema is not None else None)
         # multi-tick device loop: decode_ticks counts INNER ticks (k per
         # flush), so the transfer ratio above generalizes on its own —
         # device_gets_per_token is the explicit per-token reading of the
-        # same contract (1.0 with the loop off, 1/k with a k-tick loop),
-        # and host_ms_per_token amortizes the per-DELIVERY host EMA over
-        # the k tokens each delivery now carries per slot. These are the
-        # headline numbers decode_bench --loop-k sweeps.
-        k_eff = self._loop_k or 1
-        s["decode_loop_k"] = k_eff
+        # same contract (1.0 with the loop off, 1/k with a k-tick loop);
+        # the host's share per inner tick is tick_phase_ms'
+        # mean_ms_per_tick. These are the headline numbers decode_bench
+        # --loop-k sweeps.
+        s["decode_loop_k"] = self._loop_k or 1
         s["device_gets_per_token"] = (
             round(s["tick_fetches"] / ticks, 4) if ticks else None)
-        s["host_ms_per_token"] = (
-            round(self._host_ms_ema / k_eff, 4)
-            if self._host_ms_ema is not None else None)
-        # admission data plane: host ms spent in _tick_head (EMA — the
-        # stall batched-async admission takes off the decode loop) and the
-        # engine's own inter-token-latency percentiles as its streams
-        # experienced them (bounded reservoir of per-slot delivery gaps)
-        s["admission_stall_ms"] = (
-            round(self._admission_ms_ema, 4)
-            if self._admission_ms_ema is not None else None)
         # span telemetry is a VIEW over the trace substrate (vtpu/obs):
         # the ITL/TTFT/queue-wait reservoirs the engine feeds as it
         # delivers tokens — the same numbers the vtpu_serving_* exporter
@@ -4328,9 +4330,11 @@ class ServingEngine:
             round(min(self.trace.events_recorded, self.trace.capacity)
                   / self.trace.capacity, 4)
             if self.trace.enabled else None)
-        # tick-phase attribution: where host_ms_per_tick actually goes
-        # (admission head / dispatch / fetch / deliver / swap drain)
+        # tick-phase attribution: where the loop thread's time goes
+        # (admission head / dispatch / fetch / deliver / swap drain / idle
+        # wait), and the warm-up's seconds by kind
         s["tick_phase_ms"] = self._prof.snapshot()
+        s["warmup_s"] = self._warmup.snapshot()
         s["device_sampling"] = self._device_sampling
         s["pipelined"] = self._pipeline
         s["batched_admission"] = self._async_admission
@@ -4424,6 +4428,7 @@ class ServingEngine:
             # the moment disagg turns on (cross-mode dashboards compare it)
             s["queued"] += self._disagg.owned()
             s["prefill_chunks"] += rtc["prefill_chunks"]
+            s["prefill_tokens"] += rtc["prefill_tokens"]
             s["device_gets"] += rtc["fetches"]
             s["admission_fetches"] += rtc["fetches"]
             s["bytes_fetched"] += rtc["bytes_fetched"]
@@ -4479,74 +4484,79 @@ class ServingEngine:
         seconds at each bucket boundary. Runs on the loop thread (start()
         stays fast). The decode warm tick is all-inactive (advances nothing);
         the prefill warm writes junk into slot 0's row, which is harmless —
-        no request occupies it and admission overwrites slot state."""
+        no request occupies it and admission overwrites slot state. Each
+        program warms under a profiler span ``vtpu.warm`` (ids: program,
+        bucket), for a trace of a slow start read by hand."""
         b = self.serving.slots
+        span = functools.partial(jax.profiler.TraceAnnotation, "vtpu.warm")
         tokens = self._place(np.zeros((b,), np.int32))
         inactive = jnp.zeros((b,), bool)
         for bucket in (self._kv_buckets if self._use_kv_buckets else (0,)):
-            # Each device-sampled step is dispatched TWICE: with tokens
-            # built on the host (a tick after idle) and with the tokens the
-            # step itself returned (every steady-state tick). Where the two
-            # carry the same sharding the second dispatch is a cache hit
-            # and costs one masked tick; where they do not (a mesh whose
-            # compiler picked another output sharding) the second
-            # executable is compiled HERE instead of mid-stream.
-            if self._loop_k:
-                # the k-tick flush executable replaces the single-tick
-                # sampled step as the loop's only decode dispatch; warm it
-                # per read bucket (all-inactive, zero caps: k masked ticks
-                # advance nothing)
-                fed = tokens
-                for _ in range(2):
-                    _, _, fed, _, self.state, self._rng = self._decode_loop(
-                        self.params, self.state, fed, inactive, self._rng,
-                        jnp.zeros((b,), jnp.int32), bucket,
+            with span(program="decode", bucket=bucket):
+                # Each device-sampled step is dispatched TWICE: with tokens
+                # built on the host (a tick after idle) and with the tokens the
+                # step itself returned (every steady-state tick). Where the two
+                # carry the same sharding the second dispatch is a cache hit
+                # and costs one masked tick; where they do not (a mesh whose
+                # compiler picked another output sharding) the second
+                # executable is compiled HERE instead of mid-stream.
+                if self._loop_k:
+                    # the k-tick flush executable replaces the single-tick
+                    # sampled step as the loop's only decode dispatch; warm it
+                    # per read bucket (all-inactive, zero caps: k masked ticks
+                    # advance nothing)
+                    fed = tokens
+                    for _ in range(2):
+                        _, _, fed, _, self.state, self._rng = self._decode_loop(
+                            self.params, self.state, fed, inactive, self._rng,
+                            jnp.zeros((b,), jnp.int32), bucket,
+                            unroll=self._unroll,
+                        )
+                elif self._device_sampling:
+                    fed = tokens
+                    for _ in range(2):
+                        fed, _, self.state, self._rng = self._decode_sampled(
+                            self.params, self.state, fed, inactive, self._rng,
+                            bucket, unroll=self._unroll,
+                        )
+                else:
+                    _, self.state = self._decode(
+                        self.params, self.state, tokens, inactive, bucket,
                         unroll=self._unroll,
                     )
-            elif self._device_sampling:
-                fed = tokens
-                for _ in range(2):
-                    fed, _, self.state, self._rng = self._decode_sampled(
-                        self.params, self.state, fed, inactive, self._rng,
-                        bucket, unroll=self._unroll,
+                if self._spec is not None:
+                    _, _, self.state = self._spec(
+                        self.params, self.state,
+                        jnp.zeros((b, self._spec_tokens + 1), jnp.int32),
+                        inactive, jnp.zeros((b,), jnp.int32), bucket,
+                        unroll=self._unroll,
                     )
-            else:
-                _, self.state = self._decode(
-                    self.params, self.state, tokens, inactive, bucket,
-                    unroll=self._unroll,
-                )
-            if self._spec is not None:
-                _, _, self.state = self._spec(
-                    self.params, self.state,
-                    jnp.zeros((b, self._spec_tokens + 1), jnp.int32),
-                    inactive, jnp.zeros((b,), jnp.int32), bucket,
-                    unroll=self._unroll,
-                )
-            if self._fused_spec:
-                # the fused draft+verify flush; the traced k_dyn bound
-                # means this ONE executable serves every policy-picked
-                # k <= loop_k (the plain _decode_loop above stays warm
-                # too — it is the cooloff fallback dispatch)
-                _, _, _, self.state = self._decode_fused(
-                    self.params, self.state, tokens, inactive,
-                    jnp.zeros((b,), jnp.int32),
-                    jnp.zeros((b, self._hist_window), jnp.int32),
-                    jnp.zeros((b,), jnp.int32),
-                    jnp.int32(self._loop_k), bucket, unroll=self._unroll,
-                )
+                if self._fused_spec:
+                    # the fused draft+verify flush; the traced k_dyn bound
+                    # means this ONE executable serves every policy-picked
+                    # k <= loop_k (the plain _decode_loop above stays warm
+                    # too — it is the cooloff fallback dispatch)
+                    _, _, _, self.state = self._decode_fused(
+                        self.params, self.state, tokens, inactive,
+                        jnp.zeros((b,), jnp.int32),
+                        jnp.zeros((b, self._hist_window), jnp.int32),
+                        jnp.zeros((b,), jnp.int32),
+                        jnp.int32(self._loop_k), bucket, unroll=self._unroll,
+                    )
         if self._async_admission:
             # one executable per (batch size, bucket): the batched admission
             # step (prefill N rows + KV scatter + on-device first-token
             # sample + first-token buffer scatter)
             for bucket in self._prefill_buckets:
                 for n in self._admit_sizes:
-                    _, self._admit_buf, self.state = self._admit_step(
-                        self.params, self.state, self._admit_buf,
-                        jnp.zeros((n, bucket), jnp.int32),
-                        jnp.arange(n, dtype=jnp.int32),
-                        jnp.ones((n,), jnp.int32),
-                        jax.random.split(jax.random.key(0), n),
-                    )
+                    with span(program=f"admit_step[{n}]", bucket=bucket):
+                        _, self._admit_buf, self.state = self._admit_step(
+                            self.params, self.state, self._admit_buf,
+                            jnp.zeros((n, bucket), jnp.int32),
+                            jnp.arange(n, dtype=jnp.int32),
+                            jnp.ones((n,), jnp.int32),
+                            jax.random.split(jax.random.key(0), n),
+                        )
             # the admission path's HOST-side op shapes: key split + slices
             # per batch size, the static-shape token merge, the single-slot
             # buffer write. Each is trivial work but its first-use XLA
@@ -4556,10 +4566,12 @@ class ServingEngine:
                 _, _ = keys[0], keys[1:]
         else:
             for bucket in self._prefill_buckets:
-                logits, self.state = self._prefill(
-                    self.params, self.state, jnp.zeros((1, bucket), jnp.int32),
-                    jnp.int32(0), jnp.int32(1),
-                )
+                with span(program="prefill_into_slot", bucket=bucket):
+                    logits, self.state = self._prefill(
+                        self.params, self.state,
+                        jnp.zeros((1, bucket), jnp.int32),
+                        jnp.int32(0), jnp.int32(1),
+                    )
         if self._device_sampling:
             # the [B] token merge serves both the pipelined fed-merge and
             # the admission override — warm its one executable
@@ -4594,12 +4606,13 @@ class ServingEngine:
                 extra = (
                     {"block_ids": np.zeros((bkt // self._page,), np.int32)}
                     if self._paged else {})
-                _, self.state = self._prefill_chunk(
-                    self.params, self.state,
-                    jnp.zeros((1, self._chunk), jnp.int32),
-                    jnp.int32(0), jnp.int32(0), jnp.int32(1),
-                    kv_bucket=bkt, unroll=self._unroll, **extra,
-                )
+                with span(program="prefill_chunk_into_slot", bucket=bkt):
+                    _, self.state = self._prefill_chunk(
+                        self.params, self.state,
+                        jnp.zeros((1, self._chunk), jnp.int32),
+                        jnp.int32(0), jnp.int32(0), jnp.int32(1),
+                        kv_bucket=bkt, unroll=self._unroll, **extra,
+                    )
         if self._paged:
             # the per-admission table-row install and the boundary-block
             # COW copy: trivial ops, but their first-use compile must not
@@ -4632,7 +4645,8 @@ class ServingEngine:
     def _loop(self) -> None:
         try:
             with self._on_device():
-                self._warm_executables()
+                with self._warmup.timing():
+                    self._warm_executables()
                 if self._disagg is not None:
                     self._disagg.started.set()
                 if self._fused_spec:
@@ -4686,8 +4700,17 @@ class ServingEngine:
         (ServingConfig.prefill_budget), bypassed while nothing is decoding
         so an idle engine admits at full speed. In-flight chunks spend
         first: finishing an admission frees its head-of-line latency and
-        its budget claim. Returns whether any admission happened."""
-        t0 = time.perf_counter()
+        its budget claim. Returns whether any admission happened.
+
+        The whole head is the "admission" phase, less the swap drain
+        (a phase of its own, opened inside it): where a TTFT outlier's
+        host share of the tick went. Under the k-tick device loop the
+        head runs once per FLUSH, so its cost amortizes over k inner
+        ticks (tick_phase_ms mean_ms_per_tick)."""
+        with self._prof.phase("admission", ticks=self._loop_k or 1):
+            return self._tick_head_work()
+
+    def _tick_head_work(self) -> bool:
         # fleet supervision, in ledger-then-heartbeat-then-death order:
         # (1) the session ledger records recovery metadata as of the LAST
         # delivery (everything delivered so far is reflected; the
@@ -4706,7 +4729,6 @@ class ServingEngine:
         if self._fire_fault("engine_death"):
             self._died = True
             raise EngineDeath("injected engine_death at the flush boundary")
-        swap_s = 0.0
         if self._paged:
             self._drain_prefix_work()
         while True:
@@ -4720,10 +4742,8 @@ class ServingEngine:
             # parks, land READY swap-out transfers in the host pool (a
             # still-in-flight one waits — the tick never blocks on D2H)
             self._process_lifecycle()
-            t_sw = time.perf_counter()
-            self._drain_swap_outs()
-            swap_s = time.perf_counter() - t_sw
-            self._prof.note("swap_drain", swap_s, ticks=self._loop_k or 1)
+            with self._prof.phase("swap_drain", ticks=self._loop_k or 1):
+                self._drain_swap_outs()
         if self._disagg is not None and self._swap_enabled:
             # reclaim assist: a prefill worker's allocator miss posts the
             # needed block count — eviction of parked pages runs HERE, on
@@ -4770,15 +4790,6 @@ class ServingEngine:
             req = self._slot_req[slot]
             if req is not None and req.cancelled:
                 self._retire(slot)
-        self._note_admission_ms(time.perf_counter() - t0)
-        # phase attribution: the admission head minus the swap drain
-        # (profiled on its own above) — where a TTFT outlier's host share
-        # of the tick actually went. Under the k-tick device loop this
-        # head runs once per FLUSH, so its cost amortizes over k inner
-        # ticks — exactly the per-token attribution the loop exists to
-        # shrink (tick_phase_ms mean_ms_per_tick).
-        self._prof.note("admission", time.perf_counter() - t0 - swap_s,
-                        ticks=self._loop_k or 1)
         return admitted
 
     def _shed_deadlines(self) -> None:
@@ -4880,12 +4891,13 @@ class ServingEngine:
         # resume latency at this sleep (submit/park/resume all set _wake
         # AFTER enqueueing, so a consumed wake always finds its item on
         # the next _tick_head drain)
-        if self._wake.wait(timeout=0.05):
-            self._wake.clear()
-        try:
-            self._waiting.append(self._pending.get_nowait())
-        except queue.Empty:
-            return
+        with self._prof.phase("idle_wait"):
+            if self._wake.wait(timeout=0.05):
+                self._wake.clear()
+            try:
+                self._waiting.append(self._pending.get_nowait())
+            except queue.Empty:
+                return
 
     def _loop_pipelined(self) -> None:
         """One-tick-deep decode pipeline (device sampling on, speculation
@@ -4936,7 +4948,6 @@ class ServingEngine:
                 # piggyback on)
                 firsts = self._pending_firsts
                 self._pending_firsts = []
-                t_disp = time.perf_counter()
                 # fed[i]: slot i's next token is the in-flight tick's
                 # device sample (same request then and now; identity
                 # survives neither retire nor recycle)
@@ -4953,72 +4964,70 @@ class ServingEngine:
                     and self._slot_budget[i] - (1 if fed[i] else 0) > 0
                 ]
                 new_inflight = None
-                disp_s = 0.0
                 if dispatch:
-                    live = set(dispatch)
-                    if inflight is not None and all(fed[i] for i in dispatch):
-                        # steady state (no admit/retire since last tick):
-                        # feed the in-flight device tokens straight back —
-                        # no host upload, no where; non-dispatched rows
-                        # carry stale device values the active mask ignores
-                        tokens = inflight["tokens"]
-                    elif inflight is None:
-                        tokens = self._host_tokens()
-                    else:
-                        tokens = self._merge_tokens(
-                            jnp.asarray(fed, bool), inflight["tokens"],
-                            self._host_tokens())
-                    over = [i for i in dispatch if self._admit_mask[i]]
-                    if over:
-                        # freshly admitted slots: their first tokens are
-                        # still device-resident in _admit_buf (scattered
-                        # there inside the prefill dispatch) — one
-                        # static-shape jitted merge, no host visit and no
-                        # per-pattern compile
-                        tokens = self._merge_tokens(
-                            jnp.asarray([i in over for i in range(b)], bool),
-                            self._admit_buf, tokens)
-                        for i in over:
-                            self._admit_mask[i] = False
-                    if active_key != tuple(dispatch):
-                        active = jnp.asarray(
-                            [i in live for i in range(b)], bool)
-                        active_key = tuple(dispatch)
-                    if self._use_kv_buckets:
-                        # the host length mirror lags one tick for
-                        # in-flight slots; the read window must cover the
-                        # DEVICE length
-                        need = 1 + max(
-                            self._slot_len[i] + (1 if fed[i] else 0)
-                            for i in dispatch)
-                        kv_bucket = next(
-                            (bkt for bkt in self._kv_buckets if bkt >= need),
-                            self.model.max_context,
+                    with self._prof.phase("dispatch"):
+                        live = set(dispatch)
+                        if inflight is not None and all(fed[i] for i in dispatch):
+                            # steady state (no admit/retire since last tick):
+                            # feed the in-flight device tokens straight back —
+                            # no host upload, no where; non-dispatched rows
+                            # carry stale device values the active mask ignores
+                            tokens = inflight["tokens"]
+                        elif inflight is None:
+                            tokens = self._host_tokens()
+                        else:
+                            tokens = self._merge_tokens(
+                                jnp.asarray(fed, bool), inflight["tokens"],
+                                self._host_tokens())
+                        over = [i for i in dispatch if self._admit_mask[i]]
+                        if over:
+                            # freshly admitted slots: their first tokens are
+                            # still device-resident in _admit_buf (scattered
+                            # there inside the prefill dispatch) — one
+                            # static-shape jitted merge, no host visit and no
+                            # per-pattern compile
+                            tokens = self._merge_tokens(
+                                jnp.asarray([i in over for i in range(b)], bool),
+                                self._admit_buf, tokens)
+                            for i in over:
+                                self._admit_mask[i] = False
+                        if active_key != tuple(dispatch):
+                            active = jnp.asarray(
+                                [i in live for i in range(b)], bool)
+                            active_key = tuple(dispatch)
+                        if self._use_kv_buckets:
+                            # the host length mirror lags one tick for
+                            # in-flight slots; the read window must cover the
+                            # DEVICE length
+                            need = 1 + max(
+                                self._slot_len[i] + (1 if fed[i] else 0)
+                                for i in dispatch)
+                            kv_bucket = next(
+                                (bkt for bkt in self._kv_buckets if bkt >= need),
+                                self.model.max_context,
+                            )
+                        else:
+                            kv_bucket = 0
+                        self._note_kv_window(
+                            kv_bucket,
+                            [self._slot_len[i] + (1 if fed[i] else 0)
+                             for i in dispatch])
+                        tok_d, lp_d, self.state, self._rng = self._decode_sampled(
+                            self.params, self.state, tokens, active, self._rng,
+                            kv_bucket, unroll=self._unroll,
                         )
-                    else:
-                        kv_bucket = 0
-                    self._note_kv_window(
-                        kv_bucket,
-                        [self._slot_len[i] + (1 if fed[i] else 0)
-                         for i in dispatch])
-                    tok_d, lp_d, self.state, self._rng = self._decode_sampled(
-                        self.params, self.state, tokens, active, self._rng,
-                        kv_bucket, unroll=self._unroll,
-                    )
-                    self._stats["decode_ticks"] += 1
-                    if self._disagg is not None:
-                        # one decode tick elapsed: refill the controller's
-                        # prefill allowance at the current partition
-                        self._disagg.on_tick()
-                    if inflight is not None:
-                        self._stats["pipelined_ticks"] += 1
-                    new_inflight = {
-                        "tokens": tok_d, "logprobs": lp_d,
-                        "reqs": [self._slot_req[i] if i in live else None
-                                 for i in range(b)],
-                    }
-                    disp_s = time.perf_counter() - t_disp
-                    self._prof.note("dispatch", disp_s)
+                        self._stats["decode_ticks"] += 1
+                        if self._disagg is not None:
+                            # one decode tick elapsed: refill the controller's
+                            # prefill allowance at the current partition
+                            self._disagg.on_tick()
+                        if inflight is not None:
+                            self._stats["pipelined_ticks"] += 1
+                        new_inflight = {
+                            "tokens": tok_d, "logprobs": lp_d,
+                            "reqs": [self._slot_req[i] if i in live else None
+                                     for i in range(b)],
+                        }
             finally:
                 if locked:
                     self._state_mu.release()
@@ -5031,7 +5040,7 @@ class ServingEngine:
                     self._idle_wait(admitted)
                 continue
             if inflight is not None:
-                self._deliver(inflight, extra_host_s=disp_s, firsts=firsts)
+                self._deliver(inflight, firsts=firsts)
             elif firsts:
                 # no tick in flight to piggyback on (the engine was idle):
                 # one standalone batched fetch for the whole admission wave
@@ -5109,7 +5118,6 @@ class ServingEngine:
                 admitted = self._tick_head()
                 firsts = self._pending_firsts
                 self._pending_firsts = []
-                t_disp = time.perf_counter()
                 fed = [
                     inflight is not None
                     and inflight["reqs"][i] is not None
@@ -5132,85 +5140,83 @@ class ServingEngine:
                     and rem[i] > 0
                 ]
                 new_inflight = None
-                disp_s = 0.0
                 if dispatch:
-                    live = set(dispatch)
-                    if inflight is not None and all(fed[i] for i in dispatch):
-                        # steady state: feed the in-flight flush's final
-                        # tokens straight back — no host upload, no merge
-                        tokens = inflight["carry"]
-                    elif inflight is None:
-                        tokens = self._host_tokens()
-                    else:
-                        tokens = self._merge_tokens(
-                            jnp.asarray(fed, bool), inflight["carry"],
-                            self._host_tokens())
-                    over = [i for i in dispatch if self._admit_mask[i]]
-                    if over:
-                        # freshly admitted slots: first tokens still
-                        # device-resident in _admit_buf (see _loop_pipelined)
-                        tokens = self._merge_tokens(
-                            jnp.asarray([i in over for i in range(b)], bool),
-                            self._admit_buf, tokens)
-                        for i in over:
-                            self._admit_mask[i] = False
-                    if active_key != tuple(dispatch):
-                        active = jnp.asarray(
-                            [i in live for i in range(b)], bool)
-                        active_key = tuple(dispatch)
-                    # per-slot early-exit caps: remaining budget clamped to
-                    # k — the device freezes the slot after its cap'th
-                    # emission, so a flush can never overdraw a budget (or
-                    # the paged reservation denominated in it). _loop_cap
-                    # is k unless the fetch watchdog degraded the engine
-                    # to per-token flushes (then 1: same executable, the
-                    # cap does the clamping).
-                    pred = [min(rem[i], self._loop_cap) if i in live else 0
-                            for i in range(b)]
-                    cap = jnp.asarray(pred, jnp.int32)
-                    if self._use_kv_buckets:
-                        # the read window must cover the DEVICE length at
-                        # the END of this flush: host mirror + in-flight
-                        # predicted emissions + k more
-                        need = k + max(
-                            self._slot_len[i]
-                            + (inflight["pred"][i] if fed[i] else 0)
-                            for i in dispatch)
-                        kv_bucket = next(
-                            (bkt for bkt in self._kv_buckets if bkt >= need),
-                            self.model.max_context,
-                        )
-                    else:
-                        kv_bucket = 0
-                    self._note_kv_window(
-                        kv_bucket,
-                        [self._slot_len[i]
-                         + (inflight["pred"][i] if fed[i] else 0)
-                         for i in dispatch],
-                        ticks=k)
-                    out_d, cnt_d, carry_d, lp_d, self.state, self._rng = \
-                        self._decode_loop(
-                            self.params, self.state, tokens, active,
-                            self._rng, cap, kv_bucket, unroll=self._unroll)
-                    self._stats["decode_ticks"] += k
-                    self._stats["loop_flushes"] += 1
-                    if self._disagg is not None:
-                        # k decode ticks elapsed in one dispatch: the
-                        # controller's token bucket refills per inner tick
-                        # so the prefill partition is flush-rate-invariant
-                        for _ in range(k):
-                            self._disagg.on_tick()
-                    if inflight is not None:
-                        self._stats["pipelined_ticks"] += k
-                    new_inflight = {
-                        "tokens": out_d, "counts": cnt_d, "carry": carry_d,
-                        "logprobs": lp_d, "pred": pred,
-                        "t_disp_ns": time.monotonic_ns(),
-                        "reqs": [self._slot_req[i] if i in live else None
-                                 for i in range(b)],
-                    }
-                    disp_s = time.perf_counter() - t_disp
-                    self._prof.note("dispatch", disp_s, ticks=k)
+                    with self._prof.phase("dispatch", ticks=k):
+                        live = set(dispatch)
+                        if inflight is not None and all(fed[i] for i in dispatch):
+                            # steady state: feed the in-flight flush's final
+                            # tokens straight back — no host upload, no merge
+                            tokens = inflight["carry"]
+                        elif inflight is None:
+                            tokens = self._host_tokens()
+                        else:
+                            tokens = self._merge_tokens(
+                                jnp.asarray(fed, bool), inflight["carry"],
+                                self._host_tokens())
+                        over = [i for i in dispatch if self._admit_mask[i]]
+                        if over:
+                            # freshly admitted slots: first tokens still
+                            # device-resident in _admit_buf (see _loop_pipelined)
+                            tokens = self._merge_tokens(
+                                jnp.asarray([i in over for i in range(b)], bool),
+                                self._admit_buf, tokens)
+                            for i in over:
+                                self._admit_mask[i] = False
+                        if active_key != tuple(dispatch):
+                            active = jnp.asarray(
+                                [i in live for i in range(b)], bool)
+                            active_key = tuple(dispatch)
+                        # per-slot early-exit caps: remaining budget clamped to
+                        # k — the device freezes the slot after its cap'th
+                        # emission, so a flush can never overdraw a budget (or
+                        # the paged reservation denominated in it). _loop_cap
+                        # is k unless the fetch watchdog degraded the engine
+                        # to per-token flushes (then 1: same executable, the
+                        # cap does the clamping).
+                        pred = [min(rem[i], self._loop_cap) if i in live else 0
+                                for i in range(b)]
+                        cap = jnp.asarray(pred, jnp.int32)
+                        if self._use_kv_buckets:
+                            # the read window must cover the DEVICE length at
+                            # the END of this flush: host mirror + in-flight
+                            # predicted emissions + k more
+                            need = k + max(
+                                self._slot_len[i]
+                                + (inflight["pred"][i] if fed[i] else 0)
+                                for i in dispatch)
+                            kv_bucket = next(
+                                (bkt for bkt in self._kv_buckets if bkt >= need),
+                                self.model.max_context,
+                            )
+                        else:
+                            kv_bucket = 0
+                        self._note_kv_window(
+                            kv_bucket,
+                            [self._slot_len[i]
+                             + (inflight["pred"][i] if fed[i] else 0)
+                             for i in dispatch],
+                            ticks=k)
+                        out_d, cnt_d, carry_d, lp_d, self.state, self._rng = \
+                            self._decode_loop(
+                                self.params, self.state, tokens, active,
+                                self._rng, cap, kv_bucket, unroll=self._unroll)
+                        self._stats["decode_ticks"] += k
+                        self._stats["loop_flushes"] += 1
+                        if self._disagg is not None:
+                            # k decode ticks elapsed in one dispatch: the
+                            # controller's token bucket refills per inner tick
+                            # so the prefill partition is flush-rate-invariant
+                            for _ in range(k):
+                                self._disagg.on_tick()
+                        if inflight is not None:
+                            self._stats["pipelined_ticks"] += k
+                        new_inflight = {
+                            "tokens": out_d, "counts": cnt_d, "carry": carry_d,
+                            "logprobs": lp_d, "pred": pred,
+                            "t_disp_ns": time.monotonic_ns(),
+                            "reqs": [self._slot_req[i] if i in live else None
+                                     for i in range(b)],
+                        }
             finally:
                 if locked:
                     self._state_mu.release()
@@ -5226,14 +5232,13 @@ class ServingEngine:
                 # tax still amortizes over k, only the overlap is missing
                 if new_inflight is not None:
                     self._deliver_flush(
-                        new_inflight, extra_host_s=disp_s, firsts=firsts)
+                        new_inflight, firsts=firsts)
                 elif firsts:
                     self._deliver_firsts(firsts)
                 self._inflight_slots = set()
                 continue
             if inflight is not None:
-                self._deliver_flush(inflight, extra_host_s=disp_s,
-                                    firsts=firsts)
+                self._deliver_flush(inflight, firsts=firsts)
             elif firsts:
                 # no flush in flight to piggyback on (the engine was idle):
                 # one standalone batched fetch for the admission wave
@@ -5251,7 +5256,7 @@ class ServingEngine:
             # it exactly as there — a fenced engine never delivers late)
             self._deliver_flush(inflight)
 
-    def _deliver_flush(self, flush: dict, extra_host_s: float = 0.0,
+    def _deliver_flush(self, flush: dict,
                        firsts: Optional[list] = None) -> None:
         """Deliver one k-tick flush: ONE batched fetch for the [B, k]
         token matrix + per-slot emitted counts (+ optional logprobs), then
@@ -5285,64 +5290,62 @@ class ServingEngine:
             # fleet fencing, post-fetch (see _deliver): a DEAD-declared
             # engine must not emit — its sessions may live on survivors
             return
-        t0 = time.perf_counter()
-        if firsts:
-            self._deliver_firsts(firsts, fetched=first_arrs)
-        now = time.perf_counter()
-        now_ns = time.monotonic_ns()
-        # interpolation window: this flush's tokens were computed between
-        # its dispatch and this delivery, but a PIPELINED flush dispatches
-        # before the previous delivery — flooring at the previous
-        # delivery keeps synthesized stamps monotonic per slot
-        start_ns = max(flush["t_disp_ns"], self._last_flush_ns)
-        self.trace.record("loop_flush", -1, -1, k)
-        eos = self.serving.eos_token
-        for slot, req in enumerate(flush["reqs"]):
-            if req is None or req is not self._slot_req[slot]:
-                continue
-            try:
-                self._maybe_inject_dispatch()
-                cnt = int(counts[slot])
-                if cnt < k:
-                    # froze inside the loop: budget wall (cap < k) or eos
-                    # (or the watchdog's per-token degrade clamped the cap)
-                    self._stats["loop_early_exits"] += 1
-                if cnt == 0:
+        with self._prof.phase("deliver", ticks=k):
+            if firsts:
+                self._deliver_firsts(firsts, fetched=first_arrs)
+            now = time.perf_counter()
+            now_ns = time.monotonic_ns()
+            # interpolation window: this flush's tokens were computed between
+            # its dispatch and this delivery, but a PIPELINED flush dispatches
+            # before the previous delivery — flooring at the previous
+            # delivery keeps synthesized stamps monotonic per slot
+            start_ns = max(flush["t_disp_ns"], self._last_flush_ns)
+            self.trace.record("loop_flush", -1, -1, k)
+            eos = self.serving.eos_token
+            for slot, req in enumerate(flush["reqs"]):
+                if req is None or req is not self._slot_req[slot]:
                     continue
-                emitted = [int(t) for t in toks[slot, :cnt]]
-                # host/device reconciliation: mirror the device's length
-                # advance BEFORE any retire below, exactly like the spec
-                # path
-                self._slot_len[slot] += cnt
-                self._slot_budget[slot] -= cnt
-                span = max(now_ns - start_ns, 0)
-                for j, tok in enumerate(emitted):
-                    ts = start_ns + ((j + 1) * span) // cnt
-                    self.trace.record_at(ts, "token", req.rid, slot, 1)
-                    # logprob BEFORE the queue put (see _emit)
-                    if lps is not None:
-                        req.logprobs.append(float(lps[slot, j]))
-                    req.delivered += 1
-                    req.out.put(tok)
-                self._stats["generated_tokens"] += cnt
-                if self._track_history:
-                    self._history[slot].extend(emitted)
-                self._tokens[slot] = emitted[-1]
-                # one ITL gap per (slot, flush): the burst reaches the
-                # client in one delivery, so the user-visible ITL is the
-                # inter-flush gap — the spec-tick convention
-                self._note_itl(slot, now)
-                if self._slot_budget[slot] <= 0 or emitted[-1] == eos:
-                    self._retire(slot)
-            except Exception:
-                # crash containment, k-deep: one request's whole flush
-                # column dies with its slot — the flush and every other
-                # stream keep going (the PR-1 identity-check discipline
-                # applied to failures instead of recycles)
-                self._contain_fault(slot)
-        self._last_flush_ns = now_ns
-        self._prof.note("deliver", time.perf_counter() - t0, ticks=k)
-        self._note_host_ms(extra_host_s + time.perf_counter() - t0)
+                try:
+                    self._maybe_inject_dispatch()
+                    cnt = int(counts[slot])
+                    if cnt < k:
+                        # froze inside the loop: budget wall (cap < k) or eos
+                        # (or the watchdog's per-token degrade clamped the cap)
+                        self._stats["loop_early_exits"] += 1
+                    if cnt == 0:
+                        continue
+                    emitted = [int(t) for t in toks[slot, :cnt]]
+                    # host/device reconciliation: mirror the device's length
+                    # advance BEFORE any retire below, exactly like the spec
+                    # path
+                    self._slot_len[slot] += cnt
+                    self._slot_budget[slot] -= cnt
+                    span = max(now_ns - start_ns, 0)
+                    for j, tok in enumerate(emitted):
+                        ts = start_ns + ((j + 1) * span) // cnt
+                        self.trace.record_at(ts, "token", req.rid, slot, 1)
+                        # logprob BEFORE the queue put (see _emit)
+                        if lps is not None:
+                            req.logprobs.append(float(lps[slot, j]))
+                        req.delivered += 1
+                        req.out.put(tok)
+                    self._stats["generated_tokens"] += cnt
+                    if self._track_history:
+                        self._history[slot].extend(emitted)
+                    self._tokens[slot] = emitted[-1]
+                    # one ITL gap per (slot, flush): the burst reaches the
+                    # client in one delivery, so the user-visible ITL is the
+                    # inter-flush gap — the spec-tick convention
+                    self._note_itl(slot, now)
+                    if self._slot_budget[slot] <= 0 or emitted[-1] == eos:
+                        self._retire(slot)
+                except Exception:
+                    # crash containment, k-deep: one request's whole flush
+                    # column dies with its slot — the flush and every other
+                    # stream keep going (the PR-1 identity-check discipline
+                    # applied to failures instead of recycles)
+                    self._contain_fault(slot)
+            self._last_flush_ns = now_ns
 
     def _loop_fused(self) -> None:
         """Fused speculation flush loop: draft + verify run INSIDE the
@@ -5379,10 +5382,6 @@ class ServingEngine:
                 else:
                     self._idle_wait(admitted)
                 continue
-            t_disp = time.perf_counter()
-            tokens = self._host_tokens()
-            active = jnp.asarray(
-                [self._slot_req[i] is not None for i in range(b)], bool)
             # watchdog-capped ceiling, then the policy's pick within it
             k_cap = min(self._loop_cap or 1, kmax)
             k = k_cap
@@ -5394,88 +5393,91 @@ class ServingEngine:
                         "loop_policy.pick_k raised; using k=%d", k_cap)
                     k = k_cap
                 k = max(1, min(k, k_cap))
-            if not self._spec_allowed():
-                # cooloff: speculation is underwater — run this flush
-                # through the plain k-tick executable (token-equal by
-                # contract, same flush boundary), keep re-probing
-                pred = [min(self._slot_budget[i], k_cap)
-                        if i in active_slots else 0 for i in range(b)]
-                cap = jnp.asarray(pred, jnp.int32)
-                if self._use_kv_buckets:
-                    need = kmax + max(
-                        self._slot_len[i] for i in active_slots)
-                    kv_bucket = next(
-                        (bkt for bkt in self._kv_buckets if bkt >= need),
-                        self.model.max_context,
-                    )
-                else:
-                    kv_bucket = 0
-                self._note_kv_window(
-                    kv_bucket,
-                    [self._slot_len[i] for i in active_slots],
-                    ticks=kmax)
-                out_d, cnt_d, carry_d, lp_d, self.state, self._rng = \
-                    self._decode_loop(
-                        self.params, self.state, tokens, active,
-                        self._rng, cap, kv_bucket, unroll=self._unroll)
-                self._stats["decode_ticks"] += kmax
-                self._stats["loop_flushes"] += 1
-                disp_s = time.perf_counter() - t_disp
-                self._prof.note("dispatch", disp_s, ticks=kmax)
-                self._deliver_flush({
-                    "tokens": out_d, "counts": cnt_d, "carry": carry_d,
-                    "logprobs": lp_d, "pred": pred,
-                    "t_disp_ns": time.monotonic_ns(),
+            # cooloff: while speculation is underwater this flush runs
+            # through the plain k-tick executable (token-equal by
+            # contract, same flush boundary), and keeps re-probing
+            fused = self._spec_allowed()
+            with self._prof.phase("dispatch", ticks=k if fused else kmax):
+                tokens = self._host_tokens()
+                active = jnp.asarray(
+                    [self._slot_req[i] is not None for i in range(b)], bool)
+                flush = {
                     "reqs": [self._slot_req[i] if i in active_slots else None
-                             for i in range(b)],
-                }, extra_host_s=disp_s, firsts=firsts)
-                continue
-            # the draft window: each live slot's recent tokens,
-            # right-aligned into [B, W] (the device shifts accepted runs
-            # in as the flush progresses — the host only seeds it)
-            hist = np.zeros((b, w), np.int32)
-            hlen = np.zeros((b,), np.int32)
-            for i in active_slots:
-                h = self._history[i][-w:]
-                if h:
-                    hist[i, w - len(h):] = h
-                    hlen[i] = len(h)
-            cap = jnp.asarray(
-                [max(self._slot_budget[i], 0) if i in active_slots else 0
-                 for i in range(b)], jnp.int32)
-            if self._use_kv_buckets:
-                # the read window must cover the deepest possible advance:
-                # k inner ticks of a full K+1-token chunk each
-                need = k * chunk + max(
-                    self._slot_len[i] for i in active_slots)
-                kv_bucket = next(
-                    (bkt for bkt in self._kv_buckets if bkt >= need),
-                    self.model.max_context,
-                )
+                             for i in range(b)]}
+                if not fused:
+                    pred = [min(self._slot_budget[i], k_cap)
+                            if i in active_slots else 0 for i in range(b)]
+                    cap = jnp.asarray(pred, jnp.int32)
+                    if self._use_kv_buckets:
+                        need = kmax + max(
+                            self._slot_len[i] for i in active_slots)
+                        kv_bucket = next(
+                            (bkt for bkt in self._kv_buckets if bkt >= need),
+                            self.model.max_context,
+                        )
+                    else:
+                        kv_bucket = 0
+                    self._note_kv_window(
+                        kv_bucket,
+                        [self._slot_len[i] for i in active_slots],
+                        ticks=kmax)
+                    out_d, cnt_d, carry_d, lp_d, self.state, self._rng = \
+                        self._decode_loop(
+                            self.params, self.state, tokens, active,
+                            self._rng, cap, kv_bucket, unroll=self._unroll)
+                    self._stats["decode_ticks"] += kmax
+                    self._stats["loop_flushes"] += 1
+                    flush.update(
+                        tokens=out_d, counts=cnt_d, carry=carry_d,
+                        logprobs=lp_d, pred=pred)
+                else:
+                    # the draft window: each live slot's recent tokens,
+                    # right-aligned into [B, W] (the device shifts accepted
+                    # runs in as the flush progresses — the host only
+                    # seeds it)
+                    hist = np.zeros((b, w), np.int32)
+                    hlen = np.zeros((b,), np.int32)
+                    for i in active_slots:
+                        h = self._history[i][-w:]
+                        if h:
+                            hist[i, w - len(h):] = h
+                            hlen[i] = len(h)
+                    cap = jnp.asarray(
+                        [max(self._slot_budget[i], 0)
+                         if i in active_slots else 0 for i in range(b)],
+                        jnp.int32)
+                    if self._use_kv_buckets:
+                        # the read window must cover the deepest possible
+                        # advance: k inner ticks of a full K+1-token chunk
+                        need = k * chunk + max(
+                            self._slot_len[i] for i in active_slots)
+                        kv_bucket = next(
+                            (bkt for bkt in self._kv_buckets if bkt >= need),
+                            self.model.max_context,
+                        )
+                    else:
+                        kv_bucket = 0
+                    self._note_kv_window(
+                        kv_bucket,
+                        [self._slot_len[i] + k * chunk - 1
+                         for i in active_slots],
+                        t=chunk, ticks=k)
+                    out_d, cnt_d, _carry_d, self.state = self._decode_fused(
+                        self.params, self.state, tokens, active, cap,
+                        jnp.asarray(hist), jnp.asarray(hlen), jnp.int32(k),
+                        kv_bucket, unroll=self._unroll)
+                    self._stats["spec_ticks"] += k
+                    self._stats["loop_flushes"] += 1
+                    self._stats["fused_flushes"] += 1
+                    self._stats["fused_k_hist"][k] += 1
+                    flush.update(tokens=out_d, counts=cnt_d, k=k)
+                flush["t_disp_ns"] = time.monotonic_ns()
+            if fused:
+                self._deliver_fused_flush(flush, firsts=firsts)
             else:
-                kv_bucket = 0
-            self._note_kv_window(
-                kv_bucket,
-                [self._slot_len[i] + k * chunk - 1 for i in active_slots],
-                t=chunk, ticks=k)
-            out_d, cnt_d, _carry_d, self.state = self._decode_fused(
-                self.params, self.state, tokens, active, cap,
-                jnp.asarray(hist), jnp.asarray(hlen), jnp.int32(k),
-                kv_bucket, unroll=self._unroll)
-            self._stats["spec_ticks"] += k
-            self._stats["loop_flushes"] += 1
-            self._stats["fused_flushes"] += 1
-            self._stats["fused_k_hist"][k] += 1
-            disp_s = time.perf_counter() - t_disp
-            self._prof.note("dispatch", disp_s, ticks=k)
-            self._deliver_fused_flush({
-                "tokens": out_d, "counts": cnt_d, "k": k,
-                "t_disp_ns": time.monotonic_ns(),
-                "reqs": [self._slot_req[i] if i in active_slots else None
-                         for i in range(b)],
-            }, extra_host_s=disp_s, firsts=firsts)
+                self._deliver_flush(flush, firsts=firsts)
 
-    def _deliver_fused_flush(self, flush: dict, extra_host_s: float = 0.0,
+    def _deliver_fused_flush(self, flush: dict,
                              firsts: Optional[list] = None) -> None:
         """Deliver one fused-speculation flush: ONE batched fetch for the
         [B, k, K+1] token cube + [B, k] per-tick counts, then the spec
@@ -5494,82 +5496,80 @@ class ServingEngine:
             (flush["tokens"], flush["counts"]) + extra, ticks=k)
         if self._died:
             return  # fleet fencing, post-fetch (see _deliver)
-        t0 = time.perf_counter()
-        if firsts:
-            self._deliver_firsts(firsts, fetched=first_arrs)
-        now = time.perf_counter()
-        now_ns = time.monotonic_ns()
-        start_ns = max(flush["t_disp_ns"], self._last_flush_ns)
-        self.trace.record("loop_flush", -1, -1, k)
-        eos = self.serving.eos_token
-        hist_stats = self._stats["spec_emitted_hist"]
-        emitted_total = 0
-        participations = 0
-        for slot, req in enumerate(flush["reqs"]):
-            if req is None or req is not self._slot_req[slot]:
-                continue
-            try:
-                self._maybe_inject_dispatch()
-                per_tick = [
-                    [int(x) for x in toks[slot, i, :int(c)]]
-                    for i, c in enumerate(counts[slot]) if int(c) > 0
-                ]
-                if len(per_tick) < k:
-                    # froze inside the loop: budget wall or eos (or the
-                    # lane never ran — cap was already 0)
-                    self._stats["loop_early_exits"] += 1
-                if not per_tick:
+        with self._prof.phase("deliver", ticks=k):
+            if firsts:
+                self._deliver_firsts(firsts, fetched=first_arrs)
+            now = time.perf_counter()
+            now_ns = time.monotonic_ns()
+            start_ns = max(flush["t_disp_ns"], self._last_flush_ns)
+            self.trace.record("loop_flush", -1, -1, k)
+            eos = self.serving.eos_token
+            hist_stats = self._stats["spec_emitted_hist"]
+            emitted_total = 0
+            participations = 0
+            for slot, req in enumerate(flush["reqs"]):
+                if req is None or req is not self._slot_req[slot]:
                     continue
-                emitted = [t for run in per_tick for t in run]
-                # mirror the device's length advance BEFORE eos
-                # truncation so host and device lengths never diverge
-                self._slot_len[slot] += len(emitted)
-                if eos in emitted:
-                    emitted = emitted[: emitted.index(eos) + 1]
-                # acceptance accounting per (slot, inner tick), DELIVERED
-                # tokens only — the device's raw counts include the
-                # post-eos tail nobody receives
-                left = len(emitted)
-                for run in per_tick:
-                    d = min(len(run), max(left, 0))
-                    hist_stats[min(d, len(hist_stats) - 1)] += 1
-                    left -= d
-                participations += len(per_tick)
-                emitted_total += len(emitted)
-                span = max(now_ns - start_ns, 0)
-                cnt = len(emitted)
-                for j, tok in enumerate(emitted):
-                    ts = start_ns + ((j + 1) * span) // cnt
-                    self.trace.record_at(ts, "token", req.rid, slot, 1)
-                    req.delivered += 1
-                    req.out.put(tok)
-                self._stats["generated_tokens"] += cnt
-                self._slot_budget[slot] -= cnt
-                self._history[slot].extend(emitted)
-                self._tokens[slot] = emitted[-1]
-                # one ITL gap per (slot, flush): the spec-tick burst
-                # convention, k-deep
-                self._note_itl(slot, now)
-                if self._slot_budget[slot] <= 0 or emitted[-1] == eos:
-                    self._retire(slot)
-            except Exception:
-                # crash containment, k*(K+1)-deep: one request's whole
-                # flush column dies with its slot, the rest keep going
-                self._contain_fault(slot)
-        self._stats["spec_slot_ticks"] += participations
-        self._stats["spec_emitted"] += emitted_total
-        if participations:
-            # the cooloff EMA moves once per flush toward this flush's
-            # mean delivered-per-slot-tick — the same gate, same
-            # threshold, evaluated at the flush cadence
-            self._spec_ema = (
-                0.9 * self._spec_ema + 0.1 * emitted_total / participations)
-            if (self.serving.spec_min_mean
-                    and self._spec_ema < self.serving.spec_min_mean):
-                self._spec_cooloff = self.serving.spec_cooloff_ticks
-        self._last_flush_ns = now_ns
-        self._prof.note("deliver", time.perf_counter() - t0, ticks=k)
-        self._note_host_ms(extra_host_s + time.perf_counter() - t0)
+                try:
+                    self._maybe_inject_dispatch()
+                    per_tick = [
+                        [int(x) for x in toks[slot, i, :int(c)]]
+                        for i, c in enumerate(counts[slot]) if int(c) > 0
+                    ]
+                    if len(per_tick) < k:
+                        # froze inside the loop: budget wall or eos (or the
+                        # lane never ran — cap was already 0)
+                        self._stats["loop_early_exits"] += 1
+                    if not per_tick:
+                        continue
+                    emitted = [t for run in per_tick for t in run]
+                    # mirror the device's length advance BEFORE eos
+                    # truncation so host and device lengths never diverge
+                    self._slot_len[slot] += len(emitted)
+                    if eos in emitted:
+                        emitted = emitted[: emitted.index(eos) + 1]
+                    # acceptance accounting per (slot, inner tick), DELIVERED
+                    # tokens only — the device's raw counts include the
+                    # post-eos tail nobody receives
+                    left = len(emitted)
+                    for run in per_tick:
+                        d = min(len(run), max(left, 0))
+                        hist_stats[min(d, len(hist_stats) - 1)] += 1
+                        left -= d
+                    participations += len(per_tick)
+                    emitted_total += len(emitted)
+                    span = max(now_ns - start_ns, 0)
+                    cnt = len(emitted)
+                    for j, tok in enumerate(emitted):
+                        ts = start_ns + ((j + 1) * span) // cnt
+                        self.trace.record_at(ts, "token", req.rid, slot, 1)
+                        req.delivered += 1
+                        req.out.put(tok)
+                    self._stats["generated_tokens"] += cnt
+                    self._slot_budget[slot] -= cnt
+                    self._history[slot].extend(emitted)
+                    self._tokens[slot] = emitted[-1]
+                    # one ITL gap per (slot, flush): the spec-tick burst
+                    # convention, k-deep
+                    self._note_itl(slot, now)
+                    if self._slot_budget[slot] <= 0 or emitted[-1] == eos:
+                        self._retire(slot)
+                except Exception:
+                    # crash containment, k*(K+1)-deep: one request's whole
+                    # flush column dies with its slot, the rest keep going
+                    self._contain_fault(slot)
+            self._stats["spec_slot_ticks"] += participations
+            self._stats["spec_emitted"] += emitted_total
+            if participations:
+                # the cooloff EMA moves once per flush toward this flush's
+                # mean delivered-per-slot-tick — the same gate, same
+                # threshold, evaluated at the flush cadence
+                self._spec_ema = (
+                    0.9 * self._spec_ema + 0.1 * emitted_total / participations)
+                if (self.serving.spec_min_mean
+                        and self._spec_ema < self.serving.spec_min_mean):
+                    self._spec_cooloff = self.serving.spec_cooloff_ticks
+            self._last_flush_ns = now_ns
 
     def _loop_sync(self) -> None:
         """Synchronous tick loop: dispatch, deliver, repeat. Used when a
@@ -5607,182 +5607,174 @@ class ServingEngine:
             # 2. one decode tick for the whole pool; the read window is the
             # smallest bucket past the longest LIVE sequence (this tick
             # writes chunk tokens starting at len, so the view must cover
-            # len + chunk). Dispatch-side host work (array builds, bucket
-            # pick, draft scans) is timed into the same host_ms sample the
-            # delivery side feeds, so the telemetry is comparable with the
-            # pipelined loop's
-            t_disp = time.perf_counter()
-            tokens = self._host_tokens()
-            over = [i for i in active_slots if self._admit_mask[i]]
-            if over:
-                # freshly admitted slots' first tokens, still device-resident
-                # in _admit_buf: one static-shape jitted merge
-                tokens = self._merge_tokens(
-                    jnp.asarray([i in over for i in range(b)], bool),
-                    self._admit_buf, tokens)
-                for i in over:
-                    self._admit_mask[i] = False
-            active = jnp.asarray(
-                [self._slot_req[i] is not None for i in range(b)], bool
-            )
-            # speculative tick when any slot found a draft; else the plain
-            # step (same KV bytes, fewer FLOPs)
-            drafts = None
-            if self._spec_tokens and self._spec_allowed():
-                k = self._spec_tokens
-                drafts = [
-                    lookup_draft(self._history[i], k, self.serving.spec_ngram)
-                    if i in active_slots else None
-                    for i in range(b)
-                ]
-                if not any(d is not None for d in drafts):
-                    drafts = None
-            chunk = (self._spec_tokens + 1) if drafts is not None else 1
-            if self._use_kv_buckets:
-                need = chunk + max(self._slot_len[i] for i in active_slots)
-                kv_bucket = next(
-                    (bkt for bkt in self._kv_buckets if bkt >= need),
-                    self.model.max_context,
+            # len + chunk). The dispatch phase holds the host work on its
+            # side too (array builds, bucket pick, draft scans), as in the
+            # pipelined loop.
+            with self._prof.phase("dispatch"):
+                tokens = self._host_tokens()
+                over = [i for i in active_slots if self._admit_mask[i]]
+                if over:
+                    # freshly admitted slots' first tokens, still device-resident
+                    # in _admit_buf: one static-shape jitted merge
+                    tokens = self._merge_tokens(
+                        jnp.asarray([i in over for i in range(b)], bool),
+                        self._admit_buf, tokens)
+                    for i in over:
+                        self._admit_mask[i] = False
+                active = jnp.asarray(
+                    [self._slot_req[i] is not None for i in range(b)], bool
                 )
-            else:
-                kv_bucket = 0
-            self._note_kv_window(
-                kv_bucket,
-                [self._slot_len[i] + chunk - 1 for i in active_slots],
-                t=chunk)
-            if drafts is not None:
-                draft = jnp.asarray(
-                    [
-                        [self._tokens[i]] + (drafts[i] or [0] * k)
+                # speculative tick when any slot found a draft; else the plain
+                # step (same KV bytes, fewer FLOPs)
+                drafts = None
+                if self._spec_tokens and self._spec_allowed():
+                    k = self._spec_tokens
+                    drafts = [
+                        lookup_draft(self._history[i], k, self.serving.spec_ngram)
+                        if i in active_slots else None
                         for i in range(b)
-                    ],
-                    jnp.int32,
-                )
-                cap = jnp.asarray(
-                    [max(self._slot_budget[i], 0) for i in range(b)], jnp.int32
-                )
-                pred, count, self.state = self._spec(
-                    self.params, self.state, draft, active, cap, kv_bucket,
-                    unroll=self._unroll,
-                )
-                disp_s = time.perf_counter() - t_disp
-                self._prof.note("dispatch", disp_s)
+                    ]
+                    if not any(d is not None for d in drafts):
+                        drafts = None
+                chunk = (self._spec_tokens + 1) if drafts is not None else 1
+                if self._use_kv_buckets:
+                    need = chunk + max(self._slot_len[i] for i in active_slots)
+                    kv_bucket = next(
+                        (bkt for bkt in self._kv_buckets if bkt >= need),
+                        self.model.max_context,
+                    )
+                else:
+                    kv_bucket = 0
+                self._note_kv_window(
+                    kv_bucket,
+                    [self._slot_len[i] + chunk - 1 for i in active_slots],
+                    t=chunk)
+                if drafts is not None:
+                    draft = jnp.asarray(
+                        [
+                            [self._tokens[i]] + (drafts[i] or [0] * k)
+                            for i in range(b)
+                        ],
+                        jnp.int32,
+                    )
+                    cap = jnp.asarray(
+                        [max(self._slot_budget[i], 0) for i in range(b)], jnp.int32
+                    )
+                    pred, count, self.state = self._spec(
+                        self.params, self.state, draft, active, cap, kv_bucket,
+                        unroll=self._unroll,
+                    )
+                elif self._device_sampling:
+                    # fused device sampling: the tick returns [B] tokens, not
+                    # logits, and _deliver does the one batched fetch
+                    if locking:
+                        with self._state_mu:
+                            tok_d, lp_d, self.state, self._rng = \
+                                self._decode_sampled(
+                                    self.params, self.state, tokens, active,
+                                    self._rng, kv_bucket, unroll=self._unroll)
+                        self._disagg.on_tick()
+                    else:
+                        tok_d, lp_d, self.state, self._rng = self._decode_sampled(
+                            self.params, self.state, tokens, active, self._rng,
+                            kv_bucket, unroll=self._unroll,
+                        )
+                    self._stats["decode_ticks"] += 1
+                    # active_slots IS the set of non-None _slot_req entries
+                    # this iteration, so the snapshot is simply the list (the
+                    # pipelined loop's dispatch can be a strict subset; here it
+                    # cannot)
+                    tick = {"tokens": tok_d, "logprobs": lp_d,
+                            "reqs": list(self._slot_req)}
+                else:
+                    # host-sampler fallback: fetch the FULL logits once (still a
+                    # single batched device_get — never B per-slot syncs) and run
+                    # the callable per live row
+                    logits, self.state = self._decode(
+                        self.params, self.state, tokens, active, kv_bucket,
+                        unroll=self._unroll,
+                    )
+                    self._stats["decode_ticks"] += 1
+            if drafts is not None:
                 pred, count = self._fetch((pred, count))
                 if self._died:
                     return  # fleet fencing, post-fetch (see _deliver)
-                t0 = time.perf_counter()
-                emitted_total = 0
-                for slot in active_slots:
-                    try:
-                        self._maybe_inject_dispatch()
-                        emitted = [int(x)
-                                   for x in pred[slot, : int(count[slot])]]
-                        # the device advanced this slot's cache length by
-                        # count[slot]; mirror it BEFORE any eos truncation
-                        # so host and device lengths can never diverge
-                        self._slot_len[slot] += int(count[slot])
-                        eos = self.serving.eos_token
-                        if eos in emitted:
-                            emitted = emitted[: emitted.index(eos) + 1]
-                        req = self._slot_req[slot]
-                        for tok in emitted:
-                            self.trace.record("token", req.rid, slot)
-                            req.delivered += 1
-                            req.out.put(tok)
-                        # acceptance accounting uses DELIVERED tokens
-                        # (post-eos truncation): the device's raw count
-                        # includes tokens past eos nobody receives
-                        emitted_total += len(emitted)
-                        # acceptance histogram: delivered tokens per
-                        # (slot, spec tick) — the measured distribution
-                        # behind any speedup claim (index 0 = slot
-                        # emitted nothing usable)
-                        hist = self._stats["spec_emitted_hist"]
-                        bucket_i = min(len(emitted), len(hist) - 1)
-                        hist[bucket_i] += 1
-                        self._stats["generated_tokens"] += len(emitted)
-                        self._slot_budget[slot] -= len(emitted)
-                        self._history[slot].extend(emitted)
-                        if emitted:
-                            self._tokens[slot] = emitted[-1]
-                            # one gap per (slot, spec tick): the burst
-                            # reaches the client in one flush, so the
-                            # user-visible ITL is the inter-flush gap,
-                            # not intra-burst zeros
-                            self._note_itl(slot, t0)
-                        if (
-                            self._slot_budget[slot] <= 0
-                            or (emitted and emitted[-1] == eos)
-                        ):
-                            self._retire(slot)
-                    except Exception:
-                        # crash containment on the spec deliver path too:
-                        # one request's burst dies with its slot, the
-                        # verify tick and every other stream keep going
-                        self._contain_fault(slot)
-                self._stats["spec_ticks"] += 1
-                self._stats["spec_slot_ticks"] += len(active_slots)
-                self._stats["spec_emitted"] += emitted_total
-                # per-slot EMA drives the adaptive gate: below breakeven,
-                # stop paying for verification
-                self._spec_ema = (
-                    0.9 * self._spec_ema
-                    + 0.1 * emitted_total / max(len(active_slots), 1)
-                )
-                if (self.serving.spec_min_mean
-                        and self._spec_ema < self.serving.spec_min_mean):
-                    self._spec_cooloff = self.serving.spec_cooloff_ticks
-                self._prof.note("deliver", time.perf_counter() - t0)
-                self._note_host_ms(disp_s + time.perf_counter() - t0)
+                with self._prof.phase("deliver"):
+                    t0 = time.perf_counter()
+                    emitted_total = 0
+                    for slot in active_slots:
+                        try:
+                            self._maybe_inject_dispatch()
+                            emitted = [int(x)
+                                       for x in pred[slot, : int(count[slot])]]
+                            # the device advanced this slot's cache length by
+                            # count[slot]; mirror it BEFORE any eos truncation
+                            # so host and device lengths can never diverge
+                            self._slot_len[slot] += int(count[slot])
+                            eos = self.serving.eos_token
+                            if eos in emitted:
+                                emitted = emitted[: emitted.index(eos) + 1]
+                            req = self._slot_req[slot]
+                            for tok in emitted:
+                                self.trace.record("token", req.rid, slot)
+                                req.delivered += 1
+                                req.out.put(tok)
+                            # acceptance accounting uses DELIVERED tokens
+                            # (post-eos truncation): the device's raw count
+                            # includes tokens past eos nobody receives
+                            emitted_total += len(emitted)
+                            # acceptance histogram: delivered tokens per
+                            # (slot, spec tick) — the measured distribution
+                            # behind any speedup claim (index 0 = slot
+                            # emitted nothing usable)
+                            hist = self._stats["spec_emitted_hist"]
+                            bucket_i = min(len(emitted), len(hist) - 1)
+                            hist[bucket_i] += 1
+                            self._stats["generated_tokens"] += len(emitted)
+                            self._slot_budget[slot] -= len(emitted)
+                            self._history[slot].extend(emitted)
+                            if emitted:
+                                self._tokens[slot] = emitted[-1]
+                                # one gap per (slot, spec tick): the burst
+                                # reaches the client in one flush, so the
+                                # user-visible ITL is the inter-flush gap,
+                                # not intra-burst zeros
+                                self._note_itl(slot, t0)
+                            if (
+                                self._slot_budget[slot] <= 0
+                                or (emitted and emitted[-1] == eos)
+                            ):
+                                self._retire(slot)
+                        except Exception:
+                            # crash containment on the spec deliver path too:
+                            # one request's burst dies with its slot, the
+                            # verify tick and every other stream keep going
+                            self._contain_fault(slot)
+                    self._stats["spec_ticks"] += 1
+                    self._stats["spec_slot_ticks"] += len(active_slots)
+                    self._stats["spec_emitted"] += emitted_total
+                    # per-slot EMA drives the adaptive gate: below breakeven,
+                    # stop paying for verification
+                    self._spec_ema = (
+                        0.9 * self._spec_ema
+                        + 0.1 * emitted_total / max(len(active_slots), 1)
+                    )
+                    if (self.serving.spec_min_mean
+                            and self._spec_ema < self.serving.spec_min_mean):
+                        self._spec_cooloff = self.serving.spec_cooloff_ticks
                 continue
             if self._device_sampling:
-                # fused device sampling: the tick returns [B] tokens, not
-                # logits, and _deliver does the one batched fetch
-                if locking:
-                    with self._state_mu:
-                        tok_d, lp_d, self.state, self._rng = \
-                            self._decode_sampled(
-                                self.params, self.state, tokens, active,
-                                self._rng, kv_bucket, unroll=self._unroll)
-                    self._disagg.on_tick()
-                else:
-                    tok_d, lp_d, self.state, self._rng = self._decode_sampled(
-                        self.params, self.state, tokens, active, self._rng,
-                        kv_bucket, unroll=self._unroll,
-                    )
-                self._stats["decode_ticks"] += 1
-                # active_slots IS the set of non-None _slot_req entries
-                # this iteration, so the snapshot is simply the list (the
-                # pipelined loop's dispatch can be a strict subset; here it
-                # cannot)
-                disp_s = time.perf_counter() - t_disp
-                self._prof.note("dispatch", disp_s)
-                self._deliver({
-                    "tokens": tok_d, "logprobs": lp_d,
-                    "reqs": list(self._slot_req),
-                }, extra_host_s=disp_s, firsts=firsts)
+                self._deliver(tick, firsts=firsts)
                 continue
-            # host-sampler fallback: fetch the FULL logits once (still a
-            # single batched device_get — never B per-slot syncs) and run
-            # the callable per live row
-            logits, self.state = self._decode(
-                self.params, self.state, tokens, active, kv_bucket,
-                unroll=self._unroll,
-            )
-            self._stats["decode_ticks"] += 1
-            disp_s = time.perf_counter() - t_disp
-            self._prof.note("dispatch", disp_s)
             logits = self._fetch(logits)
             if self._died:
                 return  # fleet fencing, post-fetch (see _deliver)
-            t0 = time.perf_counter()
-            for slot in active_slots:
-                try:
-                    # the custom sampler runs INSIDE the containment: a
-                    # callable raising on one row faults one request,
-                    # never the loop serving everyone
-                    self._emit(slot, self.sample(logits[slot]))
-                except Exception:
-                    self._contain_fault(slot)
-            self._prof.note("deliver", time.perf_counter() - t0)
-            self._note_host_ms(disp_s + time.perf_counter() - t0)
+            with self._prof.phase("deliver"):
+                for slot in active_slots:
+                    try:
+                        # the custom sampler runs INSIDE the containment: a
+                        # callable raising on one row faults one request,
+                        # never the loop serving everyone
+                        self._emit(slot, self.sample(logits[slot]))
+                    except Exception:
+                        self._contain_fault(slot)
