@@ -10,17 +10,25 @@ for whole engines. A compile is not a run: numerics stay with the
 interpreted tests, speed with the chip.
 """
 
+import dataclasses
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from vtpu.models import ModelConfig, init_params
+from vtpu.models.moe import MoEConfig, init_moe_params
 from vtpu.ops.attention import flash_attention
 from vtpu.ops.decode_attn import (
+    count_pool_sized_ops,
     paged_decode_attention,
     paged_decode_attention_int8kv,
 )
+from vtpu.serving import ServingConfig, ServingEngine
+from vtpu.serving.adapters import MoeSlotModel
 
 # the compile-only client writes persistent-cache entries it cannot load
 # back ("DeserializeLoadedExecutable not implemented"): it recompiles, fine
@@ -119,6 +127,123 @@ def test_tp4_shard_map_wrappers_compile(v5e):
         _custom_calls(
             lambda q, k, v: flash_attention(q, k, v, interpret=False),
             x, x, x)
+
+
+# ------------------------------------------------- the whole decode step
+# The engine's decode step on the kernel route, compiled for the v5e: the
+# pool reaches the paged kernel as the buffer it is stored in (ISSUE 26).
+# Toy widths at TPU-legal head sizes; the pool is abstract and sized like a
+# deployment's (128 MiB a bf16 plane a chip), because a plane of a few MiB
+# is prefetched whole into the fast memory space and reads as a copy.
+
+STEP_BUCKET, STEP_SLOTS, STEP_BLOCKS = 128, 4, 2048
+STEP_DENSE = ModelConfig(
+    vocab=128, d_model=64, n_heads=8, n_layers=3, d_ff=64, max_seq=256,
+    head_dim=128, dtype=jnp.bfloat16, use_pallas=False)
+STEP_MOE = MoEConfig(
+    vocab=128, d_model=64, n_heads=8, n_layers=3, d_ff=64, n_experts=4,
+    top_k=2, max_seq=256, head_dim=128, dtype=jnp.bfloat16)
+PLANES = ("k", "v", "k_scale", "v_scale")
+# what may be as large as a plane: the planes, and the scatters that write
+# them in place, each inside its fusion
+POOL_SIZED_OK = {"parameter", "bitcast", "get-tuple-element", "scatter",
+                 "fusion"}
+
+
+def _step_engine(family: str, int8: bool, tp: int):
+    serving = ServingConfig(
+        slots=STEP_SLOTS, prefill_buckets=(STEP_BUCKET,), max_new_tokens=4,
+        kv_page=PAGE, kv_pool_blocks=15, paged_attn="kernel",
+        prefill_chunk=PAGE)
+    if family == "moe":
+        cfg = dataclasses.replace(STEP_MOE, kv_int8=int8)
+        model = MoeSlotModel(
+            init_moe_params(jax.random.key(0), cfg), cfg, kv_page=PAGE,
+            kv_pool_blocks=15, paged_attn="kernel")
+        return ServingEngine(serving=serving, model=model)
+    # under tp the heads are the 7B model's, 32: eight a chip
+    cfg = dataclasses.replace(
+        STEP_DENSE, kv_int8=int8, n_heads=32 if tp else STEP_DENSE.n_heads)
+    mesh = Mesh(np.array(jax.devices()[:tp]), ("tp",)) if tp else None
+    return ServingEngine(
+        init_params(jax.random.key(0), cfg), cfg, serving, mesh=mesh)
+
+
+def _compiled_decode_step(eng, v5e, tp: int, monkeypatch):
+    """(the decode step compiled as the engine's warm-up lowers it, its
+    pool planes as given to it): on v5e devices, the pool abstract at
+    STEP_BLOCKS blocks, eight heads a chip."""
+    tpu_mesh = Mesh(np.array(v5e[:tp]), ("tp",)) if tp else None
+    if tp:  # what the trunk closes over must name the same devices
+        eng.model.mesh = tpu_mesh
+
+    def to_tpu(x):
+        sharding = getattr(x, "sharding", None)
+        if isinstance(sharding, NamedSharding):
+            sharding = NamedSharding(tpu_mesh, sharding.spec)
+        elif tp:
+            sharding = NamedSharding(tpu_mesh, P())
+        else:
+            sharding = SingleDeviceSharding(v5e[0])
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    state = {
+        key: jax.ShapeDtypeStruct(
+            val.shape[:1] + (STEP_BLOCKS,) + val.shape[2:], val.dtype,
+            sharding=val.sharding) if key in PLANES else val
+        for key, val in eng.state.items()}
+    b = eng.serving.slots
+    args = jax.tree.map(to_tpu, (
+        eng.params, state, jnp.zeros((b,), jnp.int32), jnp.zeros((b,), bool),
+        eng._rng))
+    # the kernel asks the backend whether to interpret: steer it here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = eng._decode_sampled.lower(
+        *args, STEP_BUCKET, unroll=eng._unroll).compile()
+    return compiled, {k: v for k, v in args[1].items() if k in PLANES}
+
+
+STEP_CASES = [("dense", False, 0), ("dense", True, 0), ("moe", False, 0),
+              ("moe", True, 0), ("dense", False, 4), ("dense", True, 4)]
+
+
+@pytest.mark.parametrize(
+    "family,int8,tp", STEP_CASES,
+    ids=[f"{f}-{'int8' if q else 'bf16'}-tp{t or 1}" for f, q, t in STEP_CASES])
+def test_decode_step_moves_no_pool_plane(v5e, monkeypatch, family, int8, tp):
+    """Between the kv_write scatter and the paged kernel nothing of a pool
+    plane's size is computed: no reshape, copy, transpose or slice of K or
+    V (a), so the step's temporaries stay under one plane (b), and a plane
+    is an argument of exactly its logical bytes (c). The parent of PR 26
+    fails (a) and (b): a reshape a layer a plane, both planes in temp."""
+    eng = _step_engine(family, int8, tp)
+    compiled, planes = _compiled_decode_step(eng, v5e, tp, monkeypatch)
+    chips = max(tp, 1)
+    layers = eng.model.cfg.n_layers
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == layers
+    plane = planes["k"]
+    plane_bytes = math.prod(plane.shape) * plane.dtype.itemsize // chips
+    # (a) K and V
+    ops = count_pool_sized_ops(text, math.prod(plane.shape) // chips)
+    assert set(ops) <= POOL_SIZED_OK, ops
+    assert ops["scatter"] == ops["fusion"] == 2 * layers, ops
+    if int8:
+        # the scale pools: written in place too, and converted between the
+        # argument's layout and the kernel's twice a step a plane (on entry
+        # and on exit, as on the parent), never once a layer
+        small = count_pool_sized_ops(
+            text, math.prod(planes["k_scale"].shape) // chips)
+        assert small["scatter"] == 4 * layers, small
+        assert small.get("copy", 0) <= 4, small  # 6 if it were a layer
+        assert not {"reshape", "transpose", "slice", "dynamic-slice"} & set(
+            small), small
+    # (b)
+    assert compiled.memory_analysis().temp_size_in_bytes < plane_bytes
+    # (c)
+    held = jax.jit(lambda k, v: k[0, 0, 0, 0, 0] + v[0, 0, 0, 0, 0]).lower(
+        planes["k"], planes["v"]).compile().memory_analysis()
+    assert held.argument_size_in_bytes == 2 * plane_bytes
 
 
 def test_flash_attention_tp_matches_single_device():

@@ -767,7 +767,8 @@ def spec_verify_loop(
         # pallas_call, killing the copy that routed every trunk cell to
         # XLA back then.
         if use_kernel:
-            # pool_relayout and paged_attn are named inside the kernel's call
+            # the pools go in as stored; pool_relayout (the query's
+            # preparation) and paged_attn are named inside the call
             if quant:
                 attn = paged_decode_attention_int8kv(
                     q, kv["k"], kv["k_scale"], kv["v"], kv["v_scale"],

@@ -14,17 +14,20 @@ input/output aliasing so the cache feeds the kernel without materialization.
 
 The PAGED pool is what finally delivers both. ``paged_decode_attention``
 takes the WHOLE donated block pool ``[L, n_blocks, page, H, Dh]`` as its
-operand — no per-layer slice, no gathered window, nothing for XLA to
-materialize: the scatter-updated pool buffer is already a whole array and
-aliases straight into the pallas_call. The page table rides in as a
-SCALAR-PREFETCH operand, so the kernel's BlockSpec index map walks the table
-itself: grid step (b, j) DMAs pool block ``table[b, j]`` into VMEM and the
-online softmax runs across window pages — the O(window) gather
-(`ops.attention.gather_kv_pages`) that every paged decode tick used to pay
-simply never exists. Under a ('tp',) mesh the call wraps in shard_map: every
-chip walks its own head shard of the pool with the replicated table, zero
-collectives and zero gathers (asserted on compiled HLO by
-tests/test_paged_attn_kernel.py and the paged_kv_bench audit).
+operand — no per-layer slice, no gathered window, no reshape: the kernel's
+BlockSpec is a ``(1, 1, page, H, Dh)`` block of the buffer the kv_write
+scatter just updated in place, so the pool aliases straight into the
+pallas_call. (Until PR 26 the call merged H and Dh first, which under TPU
+tiling copied every plane once a layer: 82 % of a decode step.) The page
+table rides in as a SCALAR-PREFETCH operand, so the kernel's BlockSpec
+index map walks the table itself: grid step (b, j) DMAs pool block
+``table[b, j]`` into VMEM and the online softmax runs across window pages —
+the O(window) gather (`ops.attention.gather_kv_pages`) that every paged
+decode tick used to pay simply never exists. Under a ('tp',) mesh the call
+wraps in shard_map: every chip walks its own head shard of the pool with the
+replicated table, zero collectives and zero gathers (asserted on compiled
+HLO by tests/test_paged_attn_kernel.py; that nothing else of a plane's size
+is computed either, by tests/test_tpu_compile.py).
 
 int8 is the kernel's NATIVE layout: the quantized planes stream as int8
 bytes and convert to the compute dtype in VMEM — the halving the cache
@@ -59,9 +62,11 @@ _NEG_INF = -1e30
 # Measured shape routing (the FLASH_MIN_SEQ discipline applied to the paged
 # decode path). Basis: the standalone DENSE-kernel study DECODE_ATTN_r05.json,
 # measured in round 5 on a v5e through a rig since removed; re-measure
-# (ROADMAP Speed #3-#5) — PR 21's chip_smoke.py timed the IN-TRUNK paged
+# (ROADMAP Speed #7) — PR 21's chip_smoke.py timed the IN-TRUNK paged
 # kernel at 18.5 ms a tick against the gather route's 2.2 ms at window 1024
 # x 4 slots (bf16; 14.0 vs 2.4 int8), so these floors do not describe it.
+# Most of that was the call's copy of the pool, gone since PR 26: read the
+# two routes again before moving a floor (Speed #7c).
 # The study, read cell by cell:
 #   bf16 T=1: pallas/XLA 1.64 (b8 w1024), 1.43 (b8 w2048), 1.10 (b32
 #     w1024), 1.23 (b32 w2048) — the kernel wins every measured bf16
@@ -372,10 +377,16 @@ def _paged_kernel(lay_ref, tbl_ref, q_ref, k_ref, v_ref, lens_ref, o_ref,
     t = lens.shape[0]
     k_pos = j * page + jax.lax.broadcasted_iota(jnp.int32, (t, page), 1)
     valid = k_pos < lens[:, None]
+    # The block arrives as the pool stores it, (page, H, Dh). It is loaded
+    # whole and its heads merged in VMEM, so that a head is a lane-aligned
+    # slice: reading k_ref[0, 0, :, h, :] a head at a time gathers 16 rows
+    # from 16 tiles and measured 7-10 % slower at 32 heads (PERF.md, PR 26).
+    kb = k_ref[0, 0].reshape(page, nheads * dh)
+    vb = v_ref[0, 0].reshape(page, nheads * dh)
     for h in range(nheads):
         q = q_ref[0, :, h * dh:(h + 1) * dh]  # (T, Dh)
-        k = k_ref[0, 0, :, h * dh:(h + 1) * dh].astype(q.dtype)
-        v = v_ref[0, 0, :, h * dh:(h + 1) * dh].astype(q.dtype)
+        k = kb[:, h * dh:(h + 1) * dh].astype(q.dtype)
+        v = vb[:, h * dh:(h + 1) * dh].astype(q.dtype)
         _attend_head(
             q, k, v, valid, scale, h, m_ref, d_ref, acc_ref,
             k_scale_vec=None if ks_ref is None else ks_ref[0, 0, :, h],
@@ -405,30 +416,28 @@ def _paged_call(q, k_pool, v_pool, k_scale_pool, v_scale_pool, table,
                 kv_len, lay, interpret: bool):
     """Single-chip pallas_call over (possibly head-LOCAL) pool planes."""
     b, t, h, dh = q.shape
-    n_layers, nb, page = k_pool.shape[:3]
+    page = k_pool.shape[2]
     wp = table.shape[1]
     scale = 1.0 / math.sqrt(dh)
-    # [L, nb, page, H, Dh] -> [L, nb, page, H*Dh]: contiguous trailing
-    # dims, so free on the CPU — but NOT under TPU tiling, where the
-    # compiled step carries one whole-pool relayout per K and V plane per
-    # layer (24 reshapes of bf16[12,1025,16,1024], ~790 MiB more temp than
-    # the gather route; PR 21 compile-only probe) and the kernel route
-    # measured 8x slower than gather. Fixing the pool layout is ROADMAP
-    # Speed #4.
+    # The pools are the kernel's operands in the layout they are stored in:
+    # a block is (page, H, Dh) of the 5-d buffer the kv_write scatter just
+    # updated in place. Merging H and Dh out here is a copy of the whole
+    # pool under TPU tiling, once a layer a plane (162 ms of a 197 ms step
+    # at 3.5 GB x 15 layers until PR 26), so nothing of a pool's size may
+    # run under this scope: it holds what preparation is left, the query
+    # and the lengths, and ``pool_relayout_ms_per_step`` is the guard.
     with jax.named_scope("pool_relayout"):
-        kf = k_pool.reshape(n_layers, nb, page, h * dh)
-        vf = v_pool.reshape(n_layers, nb, page, h * dh)
-    qf = q.reshape(b, t, h * dh)
-    lens3 = kv_len[:, None, :]  # [B, 1, T]: rank-3 so block dims tile
+        qf = q.reshape(b, t, h * dh)
+        lens3 = kv_len[:, None, :]  # [B, 1, T]: rank-3 so block dims tile
     q_spec = pl.BlockSpec((1, t, h * dh), lambda i, j, *_: (i, 0, 0))
     kv_spec = pl.BlockSpec(
-        (1, 1, page, h * dh),
-        lambda i, j, lay_ref, tbl_ref: (lay_ref[0], tbl_ref[i, j], 0, 0))
+        (1, 1, page, h, dh),
+        lambda i, j, lay_ref, tbl_ref: (lay_ref[0], tbl_ref[i, j], 0, 0, 0))
     len_spec = pl.BlockSpec((1, 1, t), lambda i, j, *_: (i, 0, 0))
     kern = functools.partial(
         _paged_kernel, scale=scale, nheads=h, dh=dh, page=page, n_wp=wp)
     in_specs = [q_spec, kv_spec, kv_spec, len_spec]
-    operands = [qf, kf, vf, lens3]
+    operands = [qf, k_pool, v_pool, lens3]
     if k_scale_pool is not None:
         # scale pools [L, nb, page, H] walk the same table; the (page, H)
         # tile is tiny next to the value blocks, so the cache-native layout
@@ -448,7 +457,7 @@ def _paged_call(q, k_pool, v_pool, k_scale_pool, v_scale_pool, table,
         kern = kern8
         in_specs = [q_spec, kv_spec, scale_spec, kv_spec, scale_spec,
                     len_spec]
-        operands = [qf, kf, k_scale_pool, vf, v_scale_pool, lens3]
+        operands = [qf, k_pool, k_scale_pool, v_pool, v_scale_pool, lens3]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # layer index + page table
         grid=(b, wp),
@@ -591,10 +600,28 @@ def _check_pool(q: jax.Array, pool: jax.Array, table: jax.Array) -> None:
 
 
 # --------------------------------------------------------------------------
-# HLO audit: prove the pool gather disappeared from a compiled step.
+# HLO audits: prove the pool gather, and every other pool-sized result,
+# disappeared from a compiled step.
 
 
-_HLO_GATHER = re.compile(r"=\s*[a-z0-9]+\[([0-9,]*)\][^=]*?\bgather\(")
+_HLO_RESULT = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.\-]+ = [a-z0-9]+\[([0-9,]*)\]\S* ([\w\-]+)\(", re.M)
+
+
+def count_pool_sized_ops(hlo_text: str, min_elements: int) -> dict:
+    """{opcode: count} of the HLO instructions whose array RESULT holds at
+    least ``min_elements`` elements. At one pool plane's size a compiled
+    kernel-route step may hold the planes themselves (``parameter``,
+    ``bitcast``), the kv_write scatters and the fusions that wrap them one
+    for one, and nothing else: a ``copy``, ``reshape``, ``transpose`` or
+    ``slice`` of that size is the pool being moved
+    (tests/test_tpu_compile.py)."""
+    ops: dict = {}
+    for m in _HLO_RESULT.finditer(hlo_text):
+        elems = math.prod(int(d) for d in m.group(1).split(",") if d)
+        if elems >= min_elements:
+            ops[m.group(2)] = ops.get(m.group(2), 0) + 1
+    return ops
 
 
 def count_pool_gathers(hlo_text: str, min_elements: int) -> int:
@@ -604,13 +631,4 @@ def count_pool_gathers(hlo_text: str, min_elements: int) -> int:
     from the small embedding/table lookups that legitimately remain.
     The bench and tests pass the exact k-plane window size and assert 0 on
     the kernel route, > 0 on the gather route."""
-    n = 0
-    for m in _HLO_GATHER.finditer(hlo_text):
-        dims = m.group(1)
-        elems = 1
-        for d in dims.split(","):
-            if d:
-                elems *= int(d)
-        if elems >= min_elements:
-            n += 1
-    return n
+    return count_pool_sized_ops(hlo_text, min_elements).get("gather", 0)
