@@ -306,6 +306,30 @@ def test_prefill_budget_defers_admission_while_decoding(params):
     eng.stop()
 
 
+@pytest.mark.parametrize("budget, in_flight", [(0, 4), (8, 1), (16, 2)])
+def test_prefill_budget_caps_chunked_admissions_in_flight(
+        params, budget, in_flight):
+    """Four long prompts at an idle engine with four free slots: without a
+    budget all four start their chunks at once; with one, only as many as a
+    tick's budget advances (budget // prefill_chunk), oldest first, and the
+    rest keep their place in the queue until a lane ends. Every stream
+    still comes out whole."""
+    serving = ServingConfig(slots=4, prefill_buckets=(8,), max_new_tokens=4,
+                            prefill_chunk=8, prefill_budget=budget)
+    eng = ServingEngine(params, CFG, serving)
+    reqs = [eng.submit(_prompt(40 + i, 20), max_new_tokens=3)
+            for i in range(4)]
+    eng._tick_head()
+    assert eng.stats()["admitting_slots"] == in_flight
+    assert [r for r in reqs if r in eng._waiting] == reqs[in_flight:]
+    eng.start()
+    try:
+        streams = [list(r.stream()) for r in reqs]
+    finally:
+        eng.stop()
+    assert all(len(s) == 3 for s in streams)
+
+
 def test_idle_wait_admits_into_first_free_slot(params):
     """Regression for the hardcoded `_admit(0, req)`: _idle_wait must never
     pick a slot itself — the request joins the waiting list and the next

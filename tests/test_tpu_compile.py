@@ -12,6 +12,7 @@ interpreted tests, speed with the chip.
 
 import dataclasses
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -260,3 +261,54 @@ def test_flash_attention_tp_matches_single_device():
     want = flash_attention(q, k, v)
     got = flash_attention(q, k, v, mesh=mesh)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_latent_decode_step_moves_no_pool_plane(v5e):
+    """The latent family's decode step at the published widths (one dense
+    and one sparse layer, 4 slots, a pool of 256 blocks of 64, window
+    4096): nothing of a pool plane's size is computed but the in-place
+    scatters and the fusions that wrap them, neither for the latent plane
+    nor for the indexer's keys, and the step's temporaries stay under the
+    latent plane. With rows of 576 the compiler lays the plane out
+    blocks-minor to save the lanes' padding and converts the whole pool
+    on the way in and out (2 x 1.84 GB a step at the cell's pool, compiled
+    in PR 28): ``LatentConfig.stored_width`` pads the row to 640 instead."""
+    from vtpu.models import latent as M
+
+    cfg = M.LatentConfig(
+        vocab=16160, d_model=7168, n_heads=128, n_dense_layers=1,
+        n_sparse_layers=1, d_ff=18432, d_ff_expert=2048, q_rank=1536,
+        kv_rank=512, nope_dim=128, rope_dim=64, v_dim=128, index_heads=64,
+        index_dim=128, index_topk=2048, n_experts=256, held=(0, 16), top_k=8,
+        n_group=8, topk_group=4, max_seq=32768)
+    assert cfg.stored_width == 640 and cfg.latent_width == 576
+    chip = SingleDeviceSharding(v5e[0])
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: M.init_latent_params(jax.random.key(0), cfg)))
+    state = on_chip(jax.eval_shape(
+        lambda: M.init_latent_cache(cfg, 4, 64, STEP_BLOCKS)))
+    compiled = jax.jit(
+        M.latent_decode_step, static_argnums=(1, 5), donate_argnums=(2,)
+    ).lower(params, cfg, state, on_chip(jnp.zeros((4,), jnp.int32)),
+            on_chip(jnp.zeros((4,), bool)), 4096).compile()
+    text = compiled.as_text()
+    for plane in ("ckv", "ik"):
+        layers, blocks, page, row = state[plane].shape
+        # the plane as stored, or its rows view (blocks and page merged)
+        shapes = (f"[{layers},{blocks},{page},{row}]",
+                  f"[{layers},{blocks * page},{row}]")
+        ops = {}
+        for line in text.splitlines():
+            m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+(\[[0-9,]*\])\S* "
+                         r"([\w\-]+)\(", line)
+            if m and m.group(1) in shapes:
+                ops[m.group(2)] = ops.get(m.group(2), 0) + 1
+        assert set(ops) <= POOL_SIZED_OK, (plane, ops)
+        assert ops["scatter"] == ops["fusion"] == cfg.n_layers, (plane, ops)
+    latent_plane = math.prod(state["ckv"].shape) * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < latent_plane

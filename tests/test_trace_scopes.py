@@ -11,11 +11,12 @@ import numpy as np
 import pytest
 
 from vtpu.models import ModelConfig, init_params
+from vtpu.models.latent import LatentConfig, init_latent_params
 from vtpu.models.moe import MoEConfig, init_moe_params
 from vtpu.obs.tickprof import HOST_PHASES, TickProfiler, host_ms_per_tick
 from vtpu.ops import SCOPES
 from vtpu.serving import ServingConfig, ServingEngine
-from vtpu.serving.adapters import MoeSlotModel
+from vtpu.serving.adapters import LatentSlotModel, MoeSlotModel
 
 PAGE, CHUNK, BUCKET = 8, 8, 16
 DENSE = ModelConfig(
@@ -24,7 +25,16 @@ DENSE = ModelConfig(
 MOE = MoEConfig(
     vocab=64, d_model=32, n_heads=2, n_layers=2, d_ff=32, n_experts=4,
     top_k=2, max_seq=32, head_dim=16, dtype=jnp.float32)
+LATENT = LatentConfig(
+    vocab=64, d_model=32, n_heads=2, d_ff=64, d_ff_expert=16, q_rank=16,
+    kv_rank=16, nope_dim=8, rope_dim=4, v_dim=8, index_heads=2, index_dim=8,
+    index_topk=4, n_experts=8, held=(2, 4), top_k=2, n_group=2, topk_group=1,
+    max_seq=32, dtype=jnp.float32)
 BLOCK = {"dense": {"mlp"}, "moe": {"route", "experts"}}
+# the latent family: a dense layer then sparse ones, and its attention's
+# three parts nested under ``attn`` (the name vbench/scopes.py knows)
+SPARSE_ATTN = {"attn", "indexer", "select", "latent_attn", "mlp", "route",
+               "experts"}
 ROUTE = {"kernel": {"pool_relayout", "paged_attn"}, "gather": {"gather_attn"},
          None: {"attn"}}
 TRUNK = {"embed", "qkv", "kv_write", "o_proj", "lm_head"}
@@ -32,11 +42,17 @@ TRUNK = {"embed", "qkv", "kv_write", "o_proj", "lm_head"}
 
 def _engine(family: str, route, **serving):
     paged = {} if route is None else {
-        "kv_page": PAGE, "paged_attn": route, "prefill_chunk": CHUNK}
+        "kv_page": PAGE, "prefill_chunk": CHUNK,
+        "paged_attn": None if route == "paged" else route}
     cfg = ServingConfig(slots=2, prefill_buckets=(BUCKET,), max_new_tokens=4,
                         **paged, **serving)
     if family == "dense":
         return ServingEngine(init_params(jax.random.key(0), DENSE), DENSE, cfg)
+    if family == "latent":
+        model = LatentSlotModel(
+            init_latent_params(jax.random.key(0), LATENT), LATENT,
+            kv_page=cfg.kv_page)
+        return ServingEngine(serving=cfg, model=model)
     model = MoeSlotModel(
         init_moe_params(jax.random.key(0), MOE), MOE,
         kv_page=cfg.kv_page, paged_attn=cfg.paged_attn)
@@ -81,6 +97,9 @@ CASES = [
     ("dense", "kernel", "chunk", TRUNK | BLOCK["dense"]
      | {"gather_attn", "attn"}),
     ("moe", "kernel", "chunk", TRUNK | BLOCK["moe"] | {"gather_attn", "attn"}),
+    ("latent", "paged", "decode", TRUNK | SPARSE_ATTN | {"sample"}),
+    ("latent", "paged", "admit", TRUNK | SPARSE_ATTN | {"sample"}),
+    ("latent", "paged", "chunk", TRUNK | SPARSE_ATTN),
 ]
 
 

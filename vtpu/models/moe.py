@@ -105,7 +105,7 @@ def route(
     dispatch = jnp.zeros((t, e, capacity), jnp.float32)
     combine = jnp.zeros((t, e, capacity), jnp.float32)
     prev_counts = jnp.zeros((e,), jnp.int32)
-    for j in range(cfg.top_k):  # static unroll (top_k is 2)
+    for j in range(cfg.top_k):  # static unroll, one pass a choice
         onehot = jax.nn.one_hot(gate_idx[:, j], e, dtype=jnp.int32)  # [T, E]
         if pad_mask is not None:
             onehot = onehot * pad_mask.astype(jnp.int32)[:, None]
@@ -122,6 +122,54 @@ def route(
     frac = jnp.mean(jax.nn.one_hot(gate_idx[:, 0], e, dtype=jnp.float32), axis=0)
     aux = e * jnp.sum(frac * jnp.mean(probs, axis=0))
     return dispatch, combine, aux
+
+
+def grouped_route(
+    router_w: jax.Array, bias: jax.Array, x: jax.Array, top_k: int,
+    n_group: int, topk_group: int, scale: float,
+) -> jax.Array:
+    """Group-limited routing with sigmoid scores (DeepSeek-V3's router)
+    over flat tokens x: [T, D], router_w [D, E] and bias [E] in float32.
+
+    A token's affinity to an expert is ``g = sigmoid(x . w)``. The choice
+    is made on ``g + bias`` (the bias balances load and weighs nothing):
+    the experts lie in ``n_group`` equal groups, a group scores the sum of
+    its two best choice scores, the ``topk_group`` best groups are kept and
+    the ``top_k`` best choice scores among the kept groups are chosen. The
+    weights are ``g`` at the chosen experts, divided by their sum, times
+    ``scale``. Returns them as gates [T, E] float32, zero at every expert
+    not chosen: a column is what that expert's output is weighed by, so
+    the holder of a share of the experts reads its own columns and needs
+    nothing else of the routing. Nothing is dropped."""
+    t, e = x.shape[0], router_w.shape[1]
+    g = jax.nn.sigmoid(jnp.matmul(
+        x.astype(jnp.float32), router_w, precision=jax.lax.Precision.HIGHEST))
+    choice = g + bias
+    groups = choice.reshape(t, n_group, e // n_group)
+    group_score = jnp.sum(jax.lax.top_k(groups, 2)[0], axis=-1)  # [T, G]
+    _, kept = jax.lax.top_k(group_score, topk_group)
+    keep = jnp.sum(jax.nn.one_hot(kept, n_group, dtype=jnp.float32), axis=1)
+    choice = jnp.where(keep[:, :, None] > 0, groups, -jnp.inf).reshape(t, e)
+    _, chosen = jax.lax.top_k(choice, top_k)  # [T, k]
+    hot = jnp.sum(jax.nn.one_hot(chosen, e, dtype=jnp.float32), axis=1)
+    weights = g * hot
+    return weights / jnp.sum(weights, axis=-1, keepdims=True) * scale
+
+
+def held_experts_ffn(lp_e: dict[str, jax.Array], x: jax.Array,
+                     gates: jax.Array) -> jax.Array:
+    """This holder's part of an expert layer's result: the experts whose
+    stacks it is given ([H, D, F] / [H, F, D]: a share of the layer's,
+    told which by the ``gates [T, H]`` it is handed, the router's columns
+    for exactly these experts), each a SwiGLU over x [T, D], weighed by its
+    gate and summed. Every held expert computes over all T rows and the
+    gate zeroes the rows not routed to it (exact, static shapes, H times
+    the rows' products: PERF.md says what that costs)."""
+    gate = jnp.einsum("td,hdf->htf", x, lp_e["w_gate"])
+    up = jnp.einsum("td,hdf->htf", x, lp_e["w_up"])
+    act = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+    act = (act * gates.T[:, :, None]).astype(x.dtype)  # weighed, then summed
+    return jnp.einsum("htf,hfd->td", act, lp_e["w_down"])
 
 
 def expert_ffn(lp_e: dict[str, jax.Array], slots: jax.Array) -> jax.Array:

@@ -85,6 +85,14 @@ COUNTERS = {
                         "Live pages gathered by decode reads"),
     "read_pages_window": ("read_pages_window",
                           "Window pages spanned by decode reads"),
+    "attn_visible_tokens": ("attn_visible_tokens",
+                            "Cached tokens visible to the dispatched slots, "
+                            "summed over decode ticks (models whose "
+                            "attention reads a selection)"),
+    "attn_selected_tokens": ("attn_selected_tokens",
+                             "Of those, the tokens attention read: the "
+                             "smaller of a slot's length and the "
+                             "selection's size"),
     "paged_attn_kernel_ticks": ("paged_attn_kernel_ticks",
                                 "Ticks routed to the fused paged-attention "
                                 "kernel (table walked in place)"),

@@ -15,11 +15,12 @@ reference benchmarks/ai-benchmark/benchmark.py:1-50).
 SCOPES = (
     "embed", "qkv", "kv_write", "pool_relayout", "paged_attn", "gather_attn",
     "attn", "o_proj", "mlp", "route", "experts", "lm_head", "sample",
+    "indexer", "select", "latent_attn",
 )
 
 from vtpu.ops.init import scaled_normal  # noqa: E402
 from vtpu.ops.norms import rms_norm
-from vtpu.ops.rope import apply_rope, rope_angles
+from vtpu.ops.rope import apply_rope, rope_angles, yarn_rope_angles
 from vtpu.ops.attention import (
     causal_attention,
     causal_attention_int8kv,
@@ -44,6 +45,7 @@ __all__ = [
     "rms_norm",
     "apply_rope",
     "rope_angles",
+    "yarn_rope_angles",
     "causal_attention",
     "causal_attention_int8kv",
     "flash_attention",

@@ -606,3 +606,110 @@ class SsmSlotModel:
             "conv": jnp.where(keep, new["conv"], state["conv"]),
             "h": jnp.where(keep, new["h"], state["h"]),
         }
+
+
+class LatentSlotModel:
+    """Latent attention under a learned sparse selection, over two stacks
+    of layers (vtpu/models/latent): a paged pool of latents with the
+    indexer's key pool beside it, both walked by the one page table, so the
+    engine's allocator, chunked admission and sampler serve it as they
+    serve the other two families.
+
+    It states what the engine cannot know of it: ``read_windows`` (a
+    32 k context needs read windows where no whole-prompt bucket exists),
+    ``kv_bytes_per_token`` (a latent row and an indexer key a layer, not
+    heads), ``attn_select_topk`` (what a decode tick reads of what it
+    sees) and ``pool_planes``. Paged only. Not supported, and refused by
+    name: a mesh, an int8 cache, speculation and the swap tier (a forced
+    ``ServingConfig.paged_attn`` the engine refuses itself: there is one
+    route, so ``paged_attn`` is None)."""
+
+    supports_kv_buckets = True
+    mesh = None
+    paged_attn = None
+    pool_planes = ("ckv", "ik")
+
+    def __init__(self, params: Any, cfg: Any, kv_page: Optional[int] = None,
+                 kv_pool_blocks: Optional[int] = None,
+                 read_windows: Optional[tuple] = None,
+                 mesh: Optional[Any] = None):
+        if mesh is not None:
+            raise ValueError(
+                "LatentSlotModel serves one chip's share of each layer and "
+                "has no sharding rule: pass no mesh")
+        if kv_page is None:
+            raise ValueError(
+                "LatentSlotModel has a paged cache only: set kv_page")
+        if getattr(cfg, "kv_int8", False):
+            raise ValueError("LatentSlotModel has no int8 cache")
+        for w in read_windows or ():
+            if w % kv_page or w > cfg.max_seq:
+                raise ValueError(
+                    f"read window {w} must be a multiple of kv_page "
+                    f"{kv_page} and at most max_seq {cfg.max_seq}")
+        self.params = params
+        self.cfg = cfg
+        self.max_context = cfg.max_seq
+        self.kv_page = kv_page
+        self.kv_pool_blocks = kv_pool_blocks
+        self.n_kv_blocks = None
+        self.read_windows = tuple(sorted(read_windows)) if read_windows else None
+        self.kv_bytes_per_token = cfg.kv_bytes_per_token
+        self.attn_select_topk = cfg.index_topk
+
+    def check_serving(self, serving) -> None:
+        """Refuse the ServingConfig options this family cannot serve."""
+        if serving.spec_tokens:
+            raise ValueError(
+                "LatentSlotModel has no spec_step (a verify chunk would "
+                "need a selection a draft position): set spec_tokens=0")
+        if serving.kv_swap is not None:
+            raise ValueError(
+                "LatentSlotModel's two pool planes have no swap staging: "
+                "set kv_swap=None")
+
+    def init_state(self, slots: int):
+        from vtpu.models.latent import init_latent_cache
+
+        if self.kv_pool_blocks is not None and self.kv_pool_blocks < 1:
+            raise ValueError(
+                f"kv_pool_blocks must be >= 1, got {self.kv_pool_blocks}")
+        usable = (self.kv_pool_blocks if self.kv_pool_blocks is not None
+                  else slots * (self.max_context // self.kv_page))
+        self.n_kv_blocks = usable + 1  # + the reserved null block 0
+        return init_latent_cache(
+            self.cfg, slots, self.kv_page, self.n_kv_blocks)
+
+    def prefill_into_slot(self, params, state, padded, slot, true_len):
+        logits, new = self.prefill_into_slots(
+            params, state, padded, jnp.asarray(slot)[None],
+            jnp.asarray(true_len)[None])
+        return logits[0], new
+
+    def prefill_into_slots(self, params, state, padded, slots, true_lens):
+        from vtpu.models.latent import latent_prefill_rows
+
+        return latent_prefill_rows(
+            params, self.cfg, state, padded, slots, true_lens)
+
+    def decode_step(self, params, state, tokens, active, kv_bucket,
+                    unroll=False):
+        from vtpu.models.latent import latent_decode_step
+
+        del unroll  # five layers in two stacks: always walked unrolled
+        return latent_decode_step(
+            params, self.cfg, state, tokens, active,
+            kv_bucket or self.max_context)
+
+    def prefill_chunk_into_slot(self, params, state, chunk, slot, offset,
+                                new_len, kv_bucket=0, unroll=False,
+                                block_ids=None):
+        from vtpu.models.latent import latent_prefill_chunk
+
+        del unroll
+        window = kv_bucket or self.max_context
+        if block_ids is None:  # the slot's own table row
+            block_ids = state["table"][slot, :window // self.kv_page]
+        return latent_prefill_chunk(
+            params, self.cfg, state, chunk, slot, offset, new_len, window,
+            block_ids)

@@ -161,7 +161,11 @@ class ServingConfig:
     # slot is decoding: an idle engine admits at full speed for the lowest
     # possible TTFT. Must cover the smallest prefill bucket (and the
     # prefill chunk, when chunking is on) or admission could starve until
-    # the engine drains idle; validated at engine construction.
+    # the engine drains idle; validated at engine construction. It also
+    # caps the chunked admissions in flight at budget // prefill_chunk,
+    # decoding or not: the chunks one tick buys go to the oldest prompts
+    # and the rest keep their place in the queue (first tokens come one
+    # after another, not all at the end of a burst of long prompts).
     prefill_budget: int = 0
     # --- paged KV cache (the KV-memory data plane) -----------------------
     # kv_page (tokens per block; None = dense, bit-identical to the classic
@@ -1316,6 +1320,10 @@ class ServingEngine:
         self.params = model.params
         self.cfg = getattr(model, "cfg", cfg)
         self.serving = serving
+        if hasattr(model, "check_serving"):
+            # a slot model that cannot serve every option refuses here,
+            # by name, instead of the engine dropping one in silence
+            model.check_serving(serving)
         # speculation verifies against argmax, so it is only sound under
         # greedy sampling (the device default at temperature 0); a custom
         # sampler or temperature > 0 would make the emitted stream diverge
@@ -1366,6 +1374,7 @@ class ServingEngine:
         # trunk closes over its attribute at trace time, so the engine's
         # per-tick route counters must read the same value)
         self._paged_attn = getattr(model, "paged_attn", None)
+        self._select_topk = getattr(model, "attn_select_topk", None)
         if (serving.paged_attn is not None
                 and self._paged_attn != serving.paged_attn):
             raise ValueError(
@@ -1600,8 +1609,12 @@ class ServingEngine:
         # tick from the longest LIVE sequence (decode bandwidth scales with
         # the read window, not the context cap)
         ctx = model.max_context
+        # a slot model may state its read windows (``read_windows``): one
+        # whose context is far longer than any whole-prompt admission needs
+        # windows where it has no prefill bucket
+        windows = getattr(model, "read_windows", None) or serving.prefill_buckets
         self._kv_buckets = tuple(
-            sorted({min(bkt, ctx) for bkt in serving.prefill_buckets} | {ctx})
+            sorted({min(bkt, ctx) for bkt in windows} | {ctx})
         ) if ctx else (0,)
         unroll = serving.decode_unroll
         self._unroll = model.supports_kv_buckets if unroll is None else unroll
@@ -1640,6 +1653,11 @@ class ServingEngine:
                     f"admission unit {floor} (largest bucket"
                     + (f" / prefill chunk {self._chunk}" if self._chunk else "")
                     + ")")
+        # chunked admissions in flight at once: as many as one tick's budget
+        # advances (each takes one chunk a tick). More would hold a slot and
+        # its pages and rotate for the same chunks, so every first token
+        # would come late; the rest wait their turn in the queue. 0 = no cap
+        self._chunk_lanes = budget // self._chunk if self._chunk else 0
         # --- paged pool bookkeeping (host side of the block pool) --------
         if self._paged:
             page = self._page
@@ -1671,7 +1689,8 @@ class ServingEngine:
             # copy-on-write for a prefix's partial boundary block: one
             # [L, page, ...] block copy per plane, src -> dst
             planes = tuple(
-                key for key in ("k", "v", "k_scale", "v_scale")
+                key for key in getattr(
+                    model, "pool_planes", ("k", "v", "k_scale", "v_scale"))
                 if key in self.state)
 
             def copy_block(state, src, dst):
@@ -1914,6 +1933,13 @@ class ServingEngine:
                        "failover_prefix_reuses": 0,
                        "read_pages_live": 0, "read_pages_window": 0,
                        "read_pages_hist": {},
+                       # a slot model whose attention reads a selection
+                       # (``attn_select_topk``): cached tokens visible to
+                       # the dispatched slots, summed over decode ticks,
+                       # and how many of them attention read (the smaller
+                       # of a slot's length and the selection's size); 0
+                       # for every other model
+                       "attn_visible_tokens": 0, "attn_selected_tokens": 0,
                        # KV overcommit: parks/resumes are lifecycle events;
                        # evicted_blocks counts pool blocks reclaimed from
                        # parked sessions; swap_out/in_bytes are the D2H/H2D
@@ -3691,6 +3717,8 @@ class ServingEngine:
             if head.prefix is not None or self._bucket(n_head) is None:
                 # chunked routes park and pay their prompt tokens from the
                 # budget as their chunks advance (see _advance_admissions)
+                if self._chunk_lanes and len(self._admitting) >= self._chunk_lanes:
+                    break  # the budget's chunks are all spoken for: head waits
                 if self._paged and not self._reserve_paged(free[0], head):
                     break  # pool exhausted: head parks (backpressure)
                 self._waiting.popleft()
@@ -3982,6 +4010,11 @@ class ServingEngine:
         hist = self._stats["kv_bucket_hist"]
         key = int(kv_bucket) or int(self.model.max_context or 0)
         hist[key] = hist.get(key, 0) + ticks
+        if self._select_topk:
+            # + 1: a step sees the token it writes
+            self._stats["attn_visible_tokens"] += (sum(lens) + len(lens)) * ticks
+            self._stats["attn_selected_tokens"] += sum(
+                min(ln + 1, self._select_topk) for ln in lens) * ticks
         if self._paged and lens:
             page = self._page
             live = sum(-(-(ln + 1) // page) for ln in lens)
@@ -4347,8 +4380,9 @@ class ServingEngine:
         s["kv_page"] = self._page
         cfg = self.cfg
         # SSM configs have no attention geometry (no KV cache to estimate)
-        bpt = (kv_bytes_per_token(cfg)
-               if cfg is not None and hasattr(cfg, "head_dim") else None)
+        bpt = getattr(self.model, "kv_bytes_per_token", None) or (
+            kv_bytes_per_token(cfg)
+            if cfg is not None and hasattr(cfg, "head_dim") else None)
         ctx = self.model.max_context
         # Under a tp mesh the cache/pool shards its head axis, so each chip
         # holds 1/tp of the global bytes — and the per-container
