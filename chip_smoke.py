@@ -231,7 +231,8 @@ def engine_decode_hlo(eng: ServingEngine, bucket: int) -> str:
 # Kernel route vs gather route, one decode step on the same state, as
 # max|dlogit| / max|logit| over all slots x vocab logits. The two routes are
 # the same mathematics in a different order (online softmax per page vs the
-# whole window; probabilities rounded to bf16 before or after normalising):
+# whole window; the kernel's probabilities stay float32, the gather route
+# rounds them to bf16):
 # in float32 they agree to 1e-5 on the CPU (tests/test_paged_attn_kernel.py
 # holds streams token-equal there), so a difference here is bf16 rounding
 # carried through 12 random-weight layers. Measured (PR 21): 0.025 bf16 and

@@ -229,6 +229,152 @@ def test_paged_kernel_rejects_layer_slice():
                                interpret=True)
 
 
+@pytest.mark.parametrize("heads,quant", [(6, False), (12, False),
+                                         (2, True), (12, True)])
+def test_paged_kernel_takes_heads_its_copies_cannot_cut(heads, quant):
+    """Mosaic cuts a (page, H, Dh) block out of a plane only at whole tiles
+    of heads, and no block at all out of the int8 scale pools: such a call
+    takes the window walk (a BlockSpec's window a page, dead steps
+    skipped), never an error, and equals the gather route (that these
+    counts compile: tests/test_tpu_compile.py)."""
+    from vtpu.ops import decode_attn
+    assert quant or not decode_attn._copies_cut(heads, 4)
+    rng = np.random.RandomState(heads)
+    q = jnp.asarray(rng.randn(3, 2, heads, 16), jnp.float32)
+    if quant:
+        kq, ks, vq, vs = _int8_pool(rng, h=heads)
+        got = paged_decode_attention_int8kv(
+            q, kq, ks, vq, vs, TABLE, LENS, layer=1, interpret=True)
+        want = paged_causal_attention_int8kv(
+            q, kq[1], ks[1], vq[1], vs[1], TABLE, kv_len=LENS)
+    else:
+        kp, vp = _pool(rng, h=heads)
+        got = paged_decode_attention(q, kp, vp, TABLE, LENS, layer=1,
+                                     interpret=True)
+        want = paged_causal_attention(q, kp[1], vp[1], TABLE, kv_len=LENS)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+# ------------------------------------------- the walk: live pages, in groups
+# ISSUE 29: a slot costs cdiv(kv_len, page) pages, copied a group at a time.
+# One table serves every case: a row that ends inside a group, a slot with
+# nothing live (kv_len 1, all null) BETWEEN live rows (the copy of a slot's
+# first group is started by the slot before it), a row that ends on a page's
+# edge, one on the last page's last token, one a token into a page. Every
+# entry past a live row's pages names a block full of NaN.
+
+WALK_NAN_BLOCK = 29
+
+
+def _walk_case(wp: int, t: int):
+    """(table [5, wp], kv_len [5, t]) over a pool of 30 blocks of 8."""
+    last = np.asarray([2 * PAGE + 3, 1, 2 * PAGE, wp * PAGE, PAGE + 1])
+    pages = -(-last // PAGE)
+    table = np.full((5, wp), WALK_NAN_BLOCK, np.int32)
+    table[1] = 0  # the idle slot maps the null block throughout
+    ids = iter(range(1, WALK_NAN_BLOCK))
+    for row in (0, 2, 3, 4):
+        table[row, :pages[row]] = [next(ids) for _ in range(pages[row])]
+    # ragged: query i of a row reads i tokens fewer than the last (never 0)
+    lens = np.maximum(last[:, None] - np.arange(t)[::-1][None], 1)
+    return jnp.asarray(table), jnp.asarray(lens, jnp.int32), pages
+
+
+# (tokens a group, window pages): the window smaller than a group (the
+# group is clamped to it), equal to it, no multiple of it, and three groups
+WALK_GROUPS = [(64, 4), (32, 4), (24, 4), (16, 6)]
+WALK_IDS = [f"group{g // PAGE}-wp{w}" for g, w in WALK_GROUPS]
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["exact", "int8"])
+@pytest.mark.parametrize("t", [1, 4], ids=["t1", "t4ragged"])
+@pytest.mark.parametrize("group_tokens,wp", WALK_GROUPS, ids=WALK_IDS)
+def test_paged_kernel_walks_live_pages(monkeypatch, group_tokens, wp, t,
+                                       quant):
+    """Equal to the gather route wherever a row ends, and a block named
+    past a row's last page is never read: it holds NaN, which the gather
+    route itself could not mask (0 x NaN), so the reference runs on a
+    pool with that block zeroed."""
+    from vtpu.ops import decode_attn
+    monkeypatch.setattr(decode_attn, "_GROUP_TOKENS", group_tokens)
+    rng = np.random.RandomState(29)
+    table, lens, _ = _walk_case(wp, t)
+    q = jnp.asarray(rng.randn(5, t, 2, 16), jnp.float32)
+    if quant:
+        kq, ks, vq, vs = _int8_pool(rng, nb=30)
+        ks_nan = ks.at[:, WALK_NAN_BLOCK].set(jnp.nan)
+        vs_nan = vs.at[:, WALK_NAN_BLOCK].set(jnp.nan)
+        got = paged_decode_attention_int8kv(
+            q, kq, ks_nan, vq, vs_nan, table, lens, layer=1, interpret=True)
+        want = paged_causal_attention_int8kv(
+            q, kq[1], ks[1], vq[1], vs[1], table, kv_len=lens)
+    else:
+        kp, vp = _pool(rng, nb=30)
+        got = paged_decode_attention(
+            q, kp.at[:, WALK_NAN_BLOCK].set(jnp.nan),
+            vp.at[:, WALK_NAN_BLOCK].set(jnp.nan), table, lens, layer=1,
+            interpret=True)
+        want = paged_causal_attention(
+            q, kp.at[:, WALK_NAN_BLOCK].set(0.0)[1],
+            vp.at[:, WALK_NAN_BLOCK].set(0.0)[1], table, kv_len=lens)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+@pytest.mark.parametrize("group_tokens,wp", WALK_GROUPS, ids=WALK_IDS)
+def test_paged_kernel_copies_a_rows_live_pages_only(monkeypatch,
+                                                    group_tokens, wp):
+    """The mechanism, counted under the interpreter: the kernel starts one
+    copy a plane for each of a row's cdiv(kv_len, page) pages (one for a
+    slot with nothing live), not one for each page of the window."""
+    from vtpu.ops import decode_attn
+    monkeypatch.setattr(decode_attn, "_GROUP_TOKENS", group_tokens)
+    started = []
+    real_copy = decode_attn.pltpu.make_async_copy
+
+    class Counted:
+        def __init__(self, *args):
+            self.copy = real_copy(*args)
+
+        def start(self):
+            jax.debug.callback(lambda: started.append(1))
+            self.copy.start()
+
+        def wait(self):
+            self.copy.wait()
+
+    monkeypatch.setattr(decode_attn.pltpu, "make_async_copy", Counted)
+    rng = np.random.RandomState(30)
+    kp, vp = _pool(rng, nb=30)
+    table, lens, pages = _walk_case(wp, 1)
+    q = jnp.asarray(rng.randn(5, 1, 2, 16), jnp.float32)
+    out = paged_decode_attention(q, kp, vp, table, lens, layer=0,
+                                 interpret=True)
+    jax.block_until_ready(out)
+    jax.effects_barrier()
+    assert len(started) == 2 * int(pages.sum())  # K and V
+    assert int(pages.sum()) < 5 * wp
+
+
+@pytest.mark.parametrize("t", [1, 4], ids=["t1", "t4ragged"])
+def test_paged_window_walk_names_a_rows_live_blocks_only(t):
+    """The window walk's mechanism, read off its index map: over a row's
+    grid steps it names the blocks of the row's cdiv(kv_len, page) pages
+    and then the last of them again (no new copy), never an entry past
+    them."""
+    from vtpu.ops import decode_attn
+    wp = 6
+    table, lens, pages = _walk_case(wp, t)
+    table, lens = np.asarray(table), np.asarray(lens)
+    for row in range(5):
+        named = [int(decode_attn._window_block(
+            row, j, np.asarray([1]), table, lens, t=t, page=PAGE)[1])
+            for j in range(wp)]
+        live = list(table[row, :pages[row]])
+        assert named == live + [live[-1]] * (wp - pages[row])
+        assert WALK_NAN_BLOCK not in named
+
+
 # ----------------------------------------------------------- route resolver
 
 

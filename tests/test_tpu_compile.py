@@ -23,6 +23,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 from vtpu.models import ModelConfig, init_params
 from vtpu.models.moe import MoEConfig, init_moe_params
 from vtpu.ops.attention import flash_attention
+from vtpu.ops import decode_attn
 from vtpu.ops.decode_attn import (
     count_pool_sized_ops,
     paged_decode_attention,
@@ -92,6 +93,57 @@ def test_paged_decode_kernels_compile(v5e, t):
         q, aval(pool, jnp.int8), aval(pool[:-1], jnp.float32),
         aval(pool, jnp.int8), aval(pool[:-1], jnp.float32), table,
         kv_len) == 1
+
+
+# the benchmark's two paged configurations as their decode step calls the
+# kernel on the 4096 window: (slots, heads, layers, pool blocks)
+CELL_SHAPES = {"dsllm7b": (16, 32, 15, 898), "olmoe": (64, 16, 8, 2048)}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_paged_kernel_compiles_at_cell_shapes(v5e, cell):
+    """One Mosaic kernel, the pools left where they are: the program around
+    it holds no temporary of a plane's size, nor even of the VMEM the
+    kernel's page groups were sized for (ISSUE 29)."""
+    slots, heads, layers, blocks = CELL_SHAPES[cell]
+    aval = _on(SingleDeviceSharding(v5e[0]))
+    pool = aval((layers, blocks, PAGE, heads, DH), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, k, v, tb, ln: paged_decode_attention(
+            q, k, v, tb, ln, layer=3, interpret=False)
+    ).lower(aval((slots, 1, heads, DH), jnp.bfloat16), pool, pool,
+            aval((slots, 4096 // PAGE), jnp.int32),
+            aval((slots,), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < decode_attn._GROUP_VMEM_BYTES)
+
+
+# what the kernel's own copies cannot cut takes the window walk: head counts
+# that are no whole tile (a 12-head model; 40 heads over four chips; int8 at
+# 8 heads over four), and int8 pools at any window, VMEM a page at a time
+@pytest.mark.parametrize("heads,quant,window", [
+    (12, False, 1024), (6, False, 1024), (10, False, 4096),
+    (2, True, 2048), (12, True, 2048), (32, True, 4096), (32, True, 16384)])
+def test_paged_window_walk_compiles(v5e, heads, quant, window):
+    aval = _on(SingleDeviceSharding(v5e[0]))
+    pool = (LAYERS, 1 + B * window // PAGE, PAGE, heads, DH)
+    q = aval((B, 1, heads, DH), jnp.bfloat16)
+    table, kv_len = aval((B, window // PAGE), jnp.int32), aval((B,), jnp.int32)
+    if quant:
+        assert _custom_calls(
+            lambda q, k, ks, v, vs, tb, ln: paged_decode_attention_int8kv(
+                q, k, ks, v, vs, tb, ln, layer=3, interpret=False),
+            q, aval(pool, jnp.int8), aval(pool[:-1], jnp.float32),
+            aval(pool, jnp.int8), aval(pool[:-1], jnp.float32), table,
+            kv_len) == 1
+    else:
+        assert not decode_attn._copies_cut(heads, 2)
+        assert _custom_calls(
+            lambda q, k, v, tb, ln: paged_decode_attention(
+                q, k, v, tb, ln, layer=3, interpret=False),
+            q, aval(pool, jnp.bfloat16), aval(pool, jnp.bfloat16), table,
+            kv_len) == 1
 
 
 def test_tp4_shard_map_wrappers_compile(v5e):

@@ -14,27 +14,46 @@ input/output aliasing so the cache feeds the kernel without materialization.
 
 The PAGED pool is what finally delivers both. ``paged_decode_attention``
 takes the WHOLE donated block pool ``[L, n_blocks, page, H, Dh]`` as its
-operand — no per-layer slice, no gathered window, no reshape: the kernel's
-BlockSpec is a ``(1, 1, page, H, Dh)`` block of the buffer the kv_write
-scatter just updated in place, so the pool aliases straight into the
-pallas_call. (Until PR 26 the call merged H and Dh first, which under TPU
-tiling copied every plane once a layer: 82 % of a decode step.) The page
-table rides in as a SCALAR-PREFETCH operand, so the kernel's BlockSpec
-index map walks the table itself: grid step (b, j) DMAs pool block
-``table[b, j]`` into VMEM and the online softmax runs across window pages —
-the O(window) gather (`ops.attention.gather_kv_pages`) that every paged
-decode tick used to pay simply never exists. Under a ('tp',) mesh the call
-wraps in shard_map: every chip walks its own head shard of the pool with the
-replicated table, zero collectives and zero gathers (asserted on compiled
-HLO by tests/test_paged_attn_kernel.py; that nothing else of a plane's size
-is computed either, by tests/test_tpu_compile.py).
+operand — no per-layer slice, no gathered window, no reshape (a merge of H
+and Dh under TPU tiling copies every plane once a layer): the pool the
+kv_write scatter just updated in place stays in HBM and the kernel copies
+the blocks it needs out of it. The page table, the lengths and the layer
+index ride in as SCALAR-PREFETCH operands, and the kernel walks the table
+itself: one grid step a slot, and in it a loop over the slot's LIVE pages,
+``cdiv(kv_len, page)`` of them and not the read window's, a group of pages
+a wait, double-buffered, the next group (or the next slot's first) in
+flight while this one is computed (``_paged_kernel``). The O(window) gather
+(`ops.attention.gather_kv_pages`) that every paged decode tick used to pay
+never exists, and a page past a slot's last token is neither copied nor
+computed. Under a ('tp',) mesh the call wraps in shard_map: every chip
+walks its own head shard of the pool with the replicated table, zero
+collectives and zero gathers (asserted on compiled HLO by
+tests/test_paged_attn_kernel.py; that nothing else of a plane's size is
+computed either, by tests/test_tpu_compile.py).
 
-int8 is the kernel's NATIVE layout: the quantized planes stream as int8
-bytes and convert to the compute dtype in VMEM — the halving the cache
-quantization promises — with the per-token-per-head scales applied
-post-matmul exactly as ``causal_attention_int8kv`` (k_scale on the score
-tile before max/exp; v_scale on the probabilities only in the output
-accumulation, never in the softmax denominator).
+The arithmetic at T = 1 is a row against a matrix a head, and the vector
+unit does it in the layout the pool stores (``_attend_page``). Measured on
+a v5e at the benchmark's shapes and lengths and rejected (PERF.md, PR 29;
+ms a step of 15 / 15 / 8 layers, dense 16 x 1024 / dense 16 x 4096 / OLMoE
+64 x 4096, shipped form 4.72 / 6.16 / 4.09, the copies alone 4.41 / 5.76 /
+3.10, a grid step a window page with every head's product on the MXU 23.1 /
+88.9 / 144.1): the MXU a head over a group's block merged to (tokens, H*Dh)
+6.13 / 7.98 / 4.14; the same reading a head at a time from the stored block
+9.27 / 12.23 / 8.35; all heads as one product K (page*H, Dh) x q^T with the
+diagonal kept 7.79 / 10.39 / 6.64.
+
+What Mosaic's copies cannot cut out of a plane in HBM walks the same table
+through BlockSpec windows instead, one slot x one window page a grid step,
+the steps past a slot's live pages copying and computing nothing
+(``_paged_window_kernel``): a head count a chip that is no whole tile
+(``_copies_cut``: 6, 12, int8's 2), and every int8 pool, because the scale
+pools ``[L, n_blocks, page, H]`` have rows narrower than 128 lanes. int8 is
+that kernel's NATIVE layout: the quantized planes stream as int8 bytes and
+convert in VMEM — the halving the cache quantization promises — and the
+scale pools walk the same table, a (page, H) block a page, applied exactly
+as ``causal_attention_int8kv`` (k_scale on the scores before max/exp;
+v_scale on the probabilities only in the output accumulation, never in the
+softmax denominator).
 
 Both kernels equal their XLA references on the same operands
 (tests/test_ops.py drives the dense study; tests/test_paged_attn_kernel.py
@@ -139,9 +158,8 @@ def paged_attn_route(override: Optional[str], window: int,
 
 
 # --------------------------------------------------------------------------
-# Shared per-head online-softmax update (flash-style accumulation across
-# KV tiles), used by the dense study kernel and the paged table-walker —
-# the numerics exist exactly once.
+# Per-head online-softmax update (flash-style accumulation across KV
+# tiles) of the dense study kernel; the paged walks have ``_attend_page``.
 
 
 def _attend_head(q, k, v, valid, scale, h, m_ref, d_ref, acc_ref,
@@ -349,52 +367,203 @@ def decode_attention(
 # --------------------------------------------------------------------------
 # Paged table-walking decode kernel (the product serving route).
 
+# A group is the pages one wait brings into VMEM: as many as fit the budget
+# below in both buffer slots of every plane, at most _GROUP_TOKENS tokens,
+# never more than the read window has.
+_GROUP_TOKENS = 128
+_GROUP_VMEM_BYTES = 8 << 20
 
-def _paged_kernel(lay_ref, tbl_ref, q_ref, k_ref, v_ref, lens_ref, o_ref,
-                  acc_ref, m_ref, d_ref, *,
-                  scale: float, nheads: int, dh: int, page: int, n_wp: int,
-                  ks_ref=None, vs_ref=None):
-    """One slot x one WINDOW PAGE, all heads unrolled in-kernel.
 
-    The grid walks (batch row, window page); the BlockSpec index maps read
-    the scalar-prefetched page table, so grid step (b, j) DMAs pool block
-    ``table[b, j]`` — this kernel IS the gather, fused into the attention.
-    Window entries past a slot's live pages carry the reserved null block 0
-    (the engine's padding contract): consecutive revisits of an unchanged
-    block index skip the DMA, and the kv_len mask below keeps null-block
-    garbage unobservable — exactly the gather path's masking contract, so
-    the two routes stay token-equal. lay_ref/tbl_ref are the scalar-prefetch
-    operands ([1] layer index, [B, Wp] table); the index maps consumed them
-    before this body runs."""
-    del lay_ref, tbl_ref  # consumed by the BlockSpec index maps
-    j = pl.program_id(1)
+def _pages_per_group(page: int, h: int, dh: int, itemsize: int,
+                     wp: int) -> int:
+    per_page = 2 * page * h * dh * itemsize  # K and V
+    fit = _GROUP_VMEM_BYTES // (2 * per_page)
+    return max(1, min(fit, _GROUP_TOKENS // page, wp))
+
+
+def _copies_cut(h: int, itemsize: int) -> bool:
+    """Whether Mosaic cuts a (page, H, Dh) block out of a plane of ``h``
+    heads: it tiles the (H, Dh) rows by eight (by the next power of two for
+    fewer heads, never under a 32-bit word of rows) and slices a plane in
+    HBM only at whole tiles: 2 (bf16), 4, 8, 16, 24.. heads a chip, not 6,
+    12 or int8's 2 ("Slice shape along dimension 3 must be aligned to
+    tiling (8), but is 12")."""
+    return h % max(4 // itemsize, min(8, 1 << (h - 1).bit_length())) == 0
+
+
+def _live_pages(len_ref, row, t: int, page: int):
+    """The pages slot ``row`` visits: those up to its longest query's last
+    token, at least one (a slot with nothing live); never more than the
+    window has, because ``_paged_call`` clamps the lengths to its end."""
+    last = len_ref[row, 0]
+    for i in range(1, t):
+        last = jnp.maximum(last, len_ref[row, i])
+    return jnp.maximum(pl.cdiv(last, page), 1)
+
+
+def _sublane_column(x: jax.Array) -> jax.Array:
+    """(page, H) with H along the lanes -> (page, H, 1), H along the
+    sublanes as the score column has it: the diagonal of the row laid
+    under itself H times."""
+    page, h = x.shape
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (page, h, h), 1)
+           == jax.lax.broadcasted_iota(jnp.int32, (page, h, h), 2))
+    return jnp.sum(jnp.where(eye, x[:, None, :], 0.0), axis=-1, keepdims=True)
+
+
+def _attend_page(q, lens, first, k, v, k_scale, v_scale, carry):
+    """One page of one slot into the running softmax of each of its queries.
+
+    The vector unit's arithmetic, in the layout the pool stores: at T = 1 a
+    head's product is one row against a matrix, so scores are ``sum_d q * k``
+    (a lane reduction a token a head) and the output ``sum_tok p * v``.
+    q: (T, H, Dh) float32, scaled; k, v: (page, H, Dh) float32; ``first``:
+    the position of the page's first token; lens: the T lengths (query i
+    reads positions under lens[i], exactly as the gather route masks them);
+    k_scale, v_scale: (page, H, 1) int8 scales or None, applied where the
+    gather route applies them (k_scale on the scores before the maximum,
+    v_scale on the probabilities in the accumulation only). carry: a
+    (maximum (H, 1), denominator (H, 1), accumulator (H, Dh)) a query."""
+    page, h, _ = k.shape
+    pos = first + jax.lax.broadcasted_iota(jnp.int32, (page, h, 1), 0)
+    out = []
+    for ti, (m_prev, d_prev, acc) in enumerate(carry):
+        s = jnp.sum(k * q[ti], axis=-1, keepdims=True)  # (page, H, 1)
+        if k_scale is not None:
+            s = s * k_scale
+        s = jnp.where(pos < lens[ti], s, _NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))  # (H, 1)
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        d_new = d_prev * alpha + jnp.sum(p, axis=0)
+        if v_scale is not None:
+            p = p * v_scale
+        out.append((m_new, d_new, acc * alpha + jnp.sum(p * v, axis=0)))
+    return tuple(out)
+
+
+def _paged_kernel(lay_ref, tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  k_buf, v_buf, sems, turn_ref, *,
+                  scale: float, page: int, group: int):
+    """One slot a grid step; the slot's LIVE pages, a group at a time.
+
+    The pools stay in HBM. A slot visits ``_live_pages`` of them, read from
+    the scalar-prefetched lengths, not the read window's: pages past a
+    slot's last token are neither copied nor computed, whatever the table
+    names there. Pages come a group at a time (``_pages_per_group``) into
+    one of two VMEM slots, one copy a page a plane from
+    ``pool[layer, table[b, j]]``; while a group is computed the slot's next
+    group, or the next slot's first, is in flight (``turn_ref`` carries the
+    buffer's turn from one grid step to the next). The running maximum,
+    denominator and accumulator live in the loops' carries and touch no
+    scratch (``_attend_page``). PERF.md (PR 29) has the forms measured
+    against this one."""
+    planes = ((k_hbm, k_buf), (v_hbm, v_buf))
+    b, nb = pl.program_id(0), pl.num_programs(0)
+    t, h, dh = q_ref.shape[1:]
+    lay = lay_ref[0]
+    live = functools.partial(_live_pages, len_ref, t=t, page=page)
+
+    def copy_group(row, g, slot, wait: bool):
+        """Start (or wait for) the copies of slot ``row``'s group ``g``."""
+        n = jnp.minimum(live(row) - g * group, group)
+
+        def one(i, _):
+            blk = tbl_ref[row, g * group + i]
+            for p, (pool, buf) in enumerate(planes):
+                dma = pltpu.make_async_copy(
+                    pool.at[lay, blk], buf.at[slot, i], sems.at[p, slot])
+                if wait:
+                    dma.wait()
+                else:
+                    dma.start()
+            return _
+
+        jax.lax.fori_loop(0, n, one, 0)
+        return n
+
+    @pl.when(b == 0)
+    def _first():
+        turn_ref[0] = 0
+        copy_group(0, 0, 0, wait=False)
+
+    n_groups = pl.cdiv(live(b), group)
+    turn = turn_ref[0]
+    q = q_ref[0].astype(jnp.float32) * scale  # (T, H, Dh)
+    lens = [len_ref[b, i] for i in range(t)]
+
+    def attend_group(g, carry):
+        slot = (turn + g) % 2
+        last = g + 1 == n_groups
+
+        @pl.when(jnp.logical_not(last) | (b + 1 < nb))
+        def _prefetch():
+            copy_group(jnp.where(last, jnp.minimum(b + 1, nb - 1), b),
+                       jnp.where(last, 0, g + 1), 1 - slot, wait=False)
+
+        n = copy_group(b, g, slot, wait=True)
+        return jax.lax.fori_loop(
+            0, n, lambda i, c: _attend_page(
+                q, lens, (g * group + i) * page,
+                k_buf[slot, i].astype(jnp.float32),
+                v_buf[slot, i].astype(jnp.float32), None, None, c), carry)
+
+    init = tuple((jnp.full((h, 1), _NEG_INF, jnp.float32),
+                  jnp.zeros((h, 1), jnp.float32),
+                  jnp.zeros((h, dh), jnp.float32)) for _ in range(t))
+    state = jax.lax.fori_loop(0, n_groups, attend_group, init)
+    turn_ref[0] = (turn + n_groups) % 2
+    for ti, (_, d, acc) in enumerate(state):
+        o_ref[0, ti] = (acc / d).astype(o_ref.dtype)
+
+
+def _window_block(i, j, lay_ref, tbl_ref, len_ref, *, t: int, page: int):
+    """(layer, block) of grid step (slot i, window page j) of the window
+    walk: the table's entry for a live page, the slot's last live page
+    again past it (an unchanged block index is not copied a second time)."""
+    live = _live_pages(len_ref, i, t, page)
+    return lay_ref[0], tbl_ref[i, jnp.minimum(j, live - 1)]
+
+
+def _paged_window_kernel(lay_ref, tbl_ref, len_ref, q_ref, k_ref, v_ref,
+                         *refs, scale: float, page: int, quant: bool):
+    """The same walk where the kernel's own copies cannot make it: one slot
+    x one window page a grid step, the page brought by a BlockSpec whose
+    index map reads the table (``_window_block``).
+
+    Mosaic cuts no copy out of a plane of a head count that is no whole
+    tile (``_copies_cut``), nor out of the int8 scale pools
+    ``[L, nb, page, H]``, whose rows are narrower than 128 lanes; a
+    BlockSpec's window takes both. So int8 pools and such head counts walk
+    a static grid: a step past the slot's live pages copies nothing (its
+    index map names the last live block again) and computes nothing. The
+    softmax state crosses grid steps in VMEM scratch; the arithmetic is
+    ``_attend_page``'s."""
+    if quant:
+        ks_ref, vs_ref, *refs = refs
+    o_ref, m_ref, d_ref, acc_ref = refs
+    b, j = pl.program_id(0), pl.program_id(1)
+    t = q_ref.shape[1]
 
     @pl.when(j == 0)
     def _init():
         _init_accumulators(m_ref, d_ref, acc_ref)
 
-    lens = lens_ref[0, 0, :]  # (T,) int32: query i may read k_pos < lens[i]
-    t = lens.shape[0]
-    k_pos = j * page + jax.lax.broadcasted_iota(jnp.int32, (t, page), 1)
-    valid = k_pos < lens[:, None]
-    # The block arrives as the pool stores it, (page, H, Dh). It is loaded
-    # whole and its heads merged in VMEM, so that a head is a lane-aligned
-    # slice: reading k_ref[0, 0, :, h, :] a head at a time gathers 16 rows
-    # from 16 tiles and measured 7-10 % slower at 32 heads (PERF.md, PR 26).
-    kb = k_ref[0, 0].reshape(page, nheads * dh)
-    vb = v_ref[0, 0].reshape(page, nheads * dh)
-    for h in range(nheads):
-        q = q_ref[0, :, h * dh:(h + 1) * dh]  # (T, Dh)
-        k = kb[:, h * dh:(h + 1) * dh].astype(q.dtype)
-        v = vb[:, h * dh:(h + 1) * dh].astype(q.dtype)
-        _attend_head(
-            q, k, v, valid, scale, h, m_ref, d_ref, acc_ref,
-            k_scale_vec=None if ks_ref is None else ks_ref[0, 0, :, h],
-            v_scale_vec=None if vs_ref is None else vs_ref[0, 0, :, h])
+    @pl.when(j < _live_pages(len_ref, b, t, page))
+    def _attend():
+        state = _attend_page(
+            q_ref[0].astype(jnp.float32) * scale,
+            [len_ref[b, i] for i in range(t)], j * page,
+            k_ref[0, 0].astype(jnp.float32), v_ref[0, 0].astype(jnp.float32),
+            _sublane_column(ks_ref[0, 0]) if quant else None,
+            _sublane_column(vs_ref[0, 0]) if quant else None,
+            tuple((m_ref[i], d_ref[i], acc_ref[i]) for i in range(t)))
+        for i, (m, d, acc) in enumerate(state):
+            m_ref[i], d_ref[i], acc_ref[i] = m, d, acc
 
-    @pl.when(j == n_wp - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _emit():
-        _emit_heads(o_ref, acc_ref, d_ref, nheads, dh)
+        o_ref[0] = (acc_ref[...] / d_ref[...]).astype(o_ref.dtype)
 
 
 def _norm_kv_len(kv_len: jax.Array, t: int) -> jax.Array:
@@ -417,62 +586,63 @@ def _paged_call(q, k_pool, v_pool, k_scale_pool, v_scale_pool, table,
     """Single-chip pallas_call over (possibly head-LOCAL) pool planes."""
     b, t, h, dh = q.shape
     page = k_pool.shape[2]
+    quant = k_scale_pool is not None
     wp = table.shape[1]
-    scale = 1.0 / math.sqrt(dh)
-    # The pools are the kernel's operands in the layout they are stored in:
-    # a block is (page, H, Dh) of the 5-d buffer the kv_write scatter just
-    # updated in place. Merging H and Dh out here is a copy of the whole
-    # pool under TPU tiling, once a layer a plane (162 ms of a 197 ms step
-    # at 3.5 GB x 15 layers until PR 26), so nothing of a pool's size may
-    # run under this scope: it holds what preparation is left, the query
-    # and the lengths, and ``pool_relayout_ms_per_step`` is the guard.
+    # The pools are the kernel's operands in the layout they are stored in
+    # and nothing of a pool's size may run ahead of it: a merge of H and Dh
+    # out here is a copy of the whole pool. This scope holds what
+    # preparation is left, the lengths clamped to the window's end (the
+    # bound of the kernel's walk; the mask is the same with or without),
+    # and ``pool_relayout_ms_per_step`` is the guard.
     with jax.named_scope("pool_relayout"):
-        qf = q.reshape(b, t, h * dh)
-        lens3 = kv_len[:, None, :]  # [B, 1, T]: rank-3 so block dims tile
-    q_spec = pl.BlockSpec((1, t, h * dh), lambda i, j, *_: (i, 0, 0))
-    kv_spec = pl.BlockSpec(
-        (1, 1, page, h, dh),
-        lambda i, j, lay_ref, tbl_ref: (lay_ref[0], tbl_ref[i, j], 0, 0, 0))
-    len_spec = pl.BlockSpec((1, 1, t), lambda i, j, *_: (i, 0, 0))
-    kern = functools.partial(
-        _paged_kernel, scale=scale, nheads=h, dh=dh, page=page, n_wp=wp)
-    in_specs = [q_spec, kv_spec, kv_spec, len_spec]
-    operands = [qf, k_pool, v_pool, lens3]
-    if k_scale_pool is not None:
-        # scale pools [L, nb, page, H] walk the same table; the (page, H)
-        # tile is tiny next to the value blocks, so the cache-native layout
-        # streams as-is (no per-call transpose materialization — the exact
-        # trap the dense study's bucket-sliced transpose documents)
+        lens = jnp.minimum(kv_len.astype(jnp.int32), wp * page)  # [B, T]
+    q_spec = pl.BlockSpec((1, t, h, dh), lambda i, *_: (i, 0, 0, 0))
+    if quant or not _copies_cut(h, k_pool.dtype.itemsize):
+        block = functools.partial(_window_block, t=t, page=page)
+        kv_spec = pl.BlockSpec(
+            (1, 1, page, h, dh), lambda *a: (*block(*a), 0, 0, 0))
         scale_spec = pl.BlockSpec(
-            (1, 1, page, h),
-            lambda i, j, lay_ref, tbl_ref: (lay_ref[0], tbl_ref[i, j], 0, 0))
-
-        def kern8(lay_ref, tbl_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
-                  lens_ref, o_ref, acc_ref, m_ref, d_ref):
-            _paged_kernel(lay_ref, tbl_ref, q_ref, k_ref, v_ref, lens_ref,
-                          o_ref, acc_ref, m_ref, d_ref,
-                          scale=scale, nheads=h, dh=dh, page=page, n_wp=wp,
-                          ks_ref=ks_ref, vs_ref=vs_ref)
-
-        kern = kern8
-        in_specs = [q_spec, kv_spec, scale_spec, kv_spec, scale_spec,
-                    len_spec]
-        operands = [qf, k_pool, k_scale_pool, v_pool, v_scale_pool, lens3]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # layer index + page table
-        grid=(b, wp),
-        in_specs=in_specs,
-        out_specs=q_spec,
-        scratch_shapes=_softmax_scratch(h, t, dh),
-    )
-    out = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, t, h * dh), q.dtype),
+            (1, 1, page, h), lambda *a: (*block(*a), 0, 0))
+        operands, in_specs = [q, k_pool, v_pool], [q_spec, kv_spec, kv_spec]
+        if quant:  # the scale pools walk the same table
+            operands += [k_scale_pool, v_scale_pool]
+            in_specs += [scale_spec, scale_spec]
+        kernel = functools.partial(_paged_window_kernel, quant=quant)
+        grid = (b, wp)
+        scratch = [pltpu.VMEM((t, h, 1), jnp.float32),   # maximum
+                   pltpu.VMEM((t, h, 1), jnp.float32),   # denominator
+                   pltpu.VMEM((t, h, dh), jnp.float32)]  # accumulator
+        params = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"))
+    else:
+        group = _pages_per_group(page, h, dh, k_pool.dtype.itemsize, wp)
+        hbm = pl.BlockSpec(memory_space=pl.ANY)
+        operands, in_specs = [q, k_pool, v_pool], [q_spec, hbm, hbm]
+        kernel = functools.partial(_paged_kernel, group=group)
+        grid = (b,)
+        scratch = [
+            pltpu.VMEM((2, group) + k_pool.shape[2:], k_pool.dtype),
+            pltpu.VMEM((2, group) + v_pool.shape[2:], v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),  # plane x buffer slot
+            pltpu.SMEM((1,), jnp.int32),  # which buffer slot is next
+        ]
+        # a slot hands its buffer turn and its prefetch to the next; beside
+        # the groups VMEM holds the query and output blocks and a page's
+        # float32 temporaries
+        params = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_GROUP_VMEM_BYTES + (24 << 20))
+    return pl.pallas_call(
+        functools.partial(kernel, scale=1.0 / math.sqrt(dh), page=page),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,  # layer index, table, lengths
+            grid=grid, in_specs=in_specs, out_specs=q_spec,
+            scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=params,
         interpret=interpret,
         name="paged_attn",  # the kernel's name, and its scope in a trace
-    )(lay, table, *operands)
-    return out.reshape(b, t, h, dh)
+    )(lay, table, lens, *operands)
 
 
 def paged_decode_attention(
