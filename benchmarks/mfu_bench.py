@@ -239,7 +239,7 @@ def bench_spec_tick(cfg: ModelConfig, b: int, prompt_len: int, k: int,
     speedup at mean emitted E is E / ratio. Draft content is irrelevant to
     timing (shapes are static); acceptance only changes how often you tick.
     """
-    from vtpu.serving.engine import batched_spec_step
+    from vtpu.models.slots import batched_spec_step
 
     # The chained loop below pins cap=1 so the cache grows at most one token
     # per tick (timing is shape-static, so commit count is irrelevant to the

@@ -176,7 +176,7 @@ def test_moe_prefill_int8_kv_cache():
     import dataclasses
 
     from vtpu.models.moe import moe_decode_ffn, moe_prefill
-    from vtpu.serving.engine import batched_decode_step
+    from vtpu.models.slots import batched_decode_step
 
     cfg = MoEConfig(
         vocab=128, d_model=32, n_heads=2, n_layers=2, d_ff=64,

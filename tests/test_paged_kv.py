@@ -28,8 +28,9 @@ from vtpu.models import ModelConfig, init_params
 from vtpu.models.transformer import (
     decode_step, init_kv_cache, prefill,
 )
+from vtpu.models.slots import chunked_prefill_into_slot
 from vtpu.serving import BlockAllocator, ServingConfig, ServingEngine
-from vtpu.serving.engine import chunked_prefill_into_slot, pad_to_chunks
+from vtpu.serving.engine import pad_to_chunks
 
 CFG = ModelConfig(
     vocab=64, d_model=32, n_heads=2, n_layers=1, d_ff=64,

@@ -139,7 +139,7 @@ def test_tensor_parallel_serving(params):
     """The engine serves with tp-sharded weights and a head-sharded KV cache
     on a multi-device mesh; logits agree with the single-device path."""
     from vtpu.parallel.mesh import make_mesh
-    from vtpu.serving.engine import batched_decode_step, prefill_into_slot
+    from vtpu.models.slots import batched_decode_step, prefill_into_slot
     from vtpu.models.transformer import init_kv_cache
     from vtpu.parallel.sharding import shard_kv_cache, shard_params
 
@@ -553,7 +553,7 @@ def test_chunked_prefill_matches_oneshot_cache_and_logits(params):
     """ceil(n/C) chunk forwards must leave the same KV and final logits as
     the one-shot bucketed prefill (tolerances: different executables)."""
     from vtpu.models.transformer import init_kv_cache
-    from vtpu.serving.engine import chunked_prefill_into_slot, prefill_into_slot
+    from vtpu.models.slots import chunked_prefill_into_slot, prefill_into_slot
 
     n, c = 21, 8
     prompt = jnp.asarray(_prompt(9, n), jnp.int32)

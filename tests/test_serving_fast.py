@@ -386,7 +386,7 @@ def test_chunked_admission_interleaves_with_live_decode(params):
     assert len(long_toks) == 4
     # _warm_executables runs first: one decode per kv read bucket, one
     # chunk per bucket >= the chunk size — drop exactly those
-    warm_decodes = len(eng._kv_buckets) if eng._use_kv_buckets else 1
+    warm_decodes = len(eng._kv_buckets)
     warm_chunks = sum(1 for bkt in eng._kv_buckets if bkt >= 8)
     served = events[:]
     for _ in range(warm_decodes):

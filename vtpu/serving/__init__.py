@@ -1,5 +1,10 @@
 """TPU-native serving engine: continuous batching over a slot-based KV cache."""
 
+from vtpu.models.slots import (
+    batched_decode_step,
+    prefill_into_slot,
+    prefill_into_slots,
+)
 from vtpu.serving.disagg import DisaggConfig
 from vtpu.serving.engine import (
     BlockAllocator,
@@ -9,9 +14,6 @@ from vtpu.serving.engine import (
     Status,
     Terminal,
     WaitQueue,
-    batched_decode_step,
-    prefill_into_slot,
-    prefill_into_slots,
 )
 from vtpu.serving.fabric import (
     EngineHost,

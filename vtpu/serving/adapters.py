@@ -39,6 +39,33 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from vtpu.models import slots as slot_steps, transformer
+from vtpu.models.latent import (
+    init_latent_cache,
+    latent_decode_step,
+    latent_prefill_chunk,
+    latent_prefill_rows,
+)
+from vtpu.models.moe import moe_decode_ffn, moe_prefill
+from vtpu.models.ssm import init_ssm_state, ssm_decode_step, ssm_prefill
+from vtpu.models.transformer import (
+    init_kv_cache,
+    init_paged_kv_cache,
+    kv_quantized,
+    multi_tick_decode,
+    multi_tick_spec_decode,
+    prefill,
+)
+from vtpu.ops.decode_attn import PAGED_ATTN_ROUTES
+from vtpu.parallel.sharding import (
+    constrain_paged_kv,
+    head_sharding,
+    kv_cache_shardings,
+    paged_kv_shardings,
+    shard_moe_params,
+    shard_params,
+)
+
 
 def sampled_decode_step(model: Any, temperature: float, top_k: int,
                         top_p: float, logprobs: bool):
@@ -53,12 +80,12 @@ def sampled_decode_step(model: Any, temperature: float, top_k: int,
     statically here so XLA fuses filter + Gumbel + argmax into the decode
     executable; the per-tick transfer is then B*4 bytes of tokens instead
     of B*vocab*4 of logits."""
-    from vtpu.models.transformer import sample_tokens
-
+    # the sampler is looked up on its module when a step is traced: the
+    # benchmark's tests plant a faulty one there and must see it served
     def step(params, state, tokens, active, keys, kv_bucket, unroll=False):
         logits, state = model.decode_step(
             params, state, tokens, active, kv_bucket, unroll=unroll)
-        tok, lp, keys = sample_tokens(
+        tok, lp, keys = transformer.sample_tokens(
             logits, keys, temperature=temperature, top_k=top_k, top_p=top_p,
             return_logprobs=logprobs)
         return tok, lp, state, keys
@@ -86,8 +113,6 @@ def multi_tick_decode_step(model: Any, temperature: float, top_k: int,
     early-exit wall); ``eos_token`` freezes a slot the tick after it
     samples it. One flush replaces k dispatch/fetch/deliver round trips —
     the host tick tax amortizes over k tokens."""
-    from vtpu.models.transformer import multi_tick_decode, sample_tokens
-
     def step(params, state, tokens, active, keys, cap, kv_bucket,
              unroll=False):
         def decode(st, tok, act):
@@ -95,7 +120,7 @@ def multi_tick_decode_step(model: Any, temperature: float, top_k: int,
                                      unroll=unroll)
 
         def sample(logits, keys):
-            return sample_tokens(
+            return transformer.sample_tokens(
                 logits, keys, temperature=temperature, top_k=top_k,
                 top_p=top_p, return_logprobs=logprobs)
 
@@ -129,8 +154,6 @@ def fused_spec_decode_step(model: Any, k: int, spec_tokens: int,
     dispatch — traced, so every k <= the static maximum shares one
     executable. Speculation requires greedy sampling, so there are no keys
     and no logprobs on this path."""
-    from vtpu.models.transformer import multi_tick_spec_decode
-
     def step(params, state, tokens, active, cap, hist, hist_len, k_dyn,
              kv_bucket, unroll=False):
         def spec(st, draft, act, bud):
@@ -164,12 +187,10 @@ def batched_admission_step(model: Any, temperature: float, top_k: int,
     sampling-agnostic. The closure's name is the program's in a profiler
     trace (``jit_admit_step``); the decode closures above are ``jit_step``,
     which is how the benchmark's readers tell the two apart."""
-    from vtpu.models.transformer import sample_tokens
-
     def admit_step(params, state, buf, tokens, slots, true_lens, keys):
         last, state = model.prefill_into_slots(
             params, state, tokens, slots, true_lens)
-        tok, _, _ = sample_tokens(
+        tok, _, _ = transformer.sample_tokens(
             last, keys, temperature=temperature, top_k=top_k, top_p=top_p)
         with jax.named_scope("sample"):
             buf = buf.at[slots].set(tok)
@@ -195,8 +216,6 @@ def swap_page_gather(model: Any):
                 continue
             g = state[key][:, ids]  # [L, W, page, ...]
             if model.mesh is not None:
-                from vtpu.parallel.sharding import head_sharding
-
                 g = jax.lax.with_sharding_constraint(
                     g, head_sharding(
                         model.mesh, g.ndim,
@@ -224,7 +243,138 @@ def swap_page_scatter(model: Any):
     return scatter
 
 
-class TransformerSlotModel:
+class _CachedAttentionSlotModel:
+    """The slot model of a family that attends over a per-slot KV cache
+    (vtpu/models/slots): the state's allocation (dense rows or a paged
+    block pool, on one chip or head-sharded over a ('tp',) mesh) and the
+    five step methods, once. A family states what differs: how its
+    parameters shard (``_shard``), its whole-prompt forward (``_prefill_fn``;
+    None is the dense forward vtpu/models/slots defaults to) and the block
+    after attention (``_ffn``; None is the dense MLP).
+
+    kv_pool_blocks counts USABLE blocks; n_kv_blocks (resolved at
+    init_state once the slot count is known) includes the reserved null
+    block 0. ``paged_attn`` (None/"kernel"/"gather") is the paged
+    decode-attention route override the decode/spec steps thread into the
+    trunk — None resolves the measured per-shape router; forcing a route
+    without a paged pool is a config contradiction and raises.
+    """
+
+    supports_kv_buckets = True
+
+    def __init__(self, params: Any, cfg: Any, mesh: Optional[Any] = None,
+                 kv_page: Optional[int] = None,
+                 kv_pool_blocks: Optional[int] = None,
+                 paged_attn: Optional[str] = None):
+        if paged_attn is not None:
+            if paged_attn not in PAGED_ATTN_ROUTES:
+                raise ValueError(
+                    f"paged_attn must be one of {PAGED_ATTN_ROUTES} or None "
+                    f"(auto), got {paged_attn!r}")
+            if kv_page is None:
+                raise ValueError(
+                    "paged_attn forces a paged decode-attention route, but "
+                    "the cache is dense (kv_page=None) — there is no paged "
+                    "read path to route")
+        self.cfg = cfg
+        self.mesh = mesh
+        self.max_context = cfg.max_seq
+        self.kv_page = kv_page
+        self.kv_pool_blocks = kv_pool_blocks
+        self.n_kv_blocks = None
+        self.paged_attn = paged_attn
+        if mesh is None:
+            self.params = params
+        else:
+            _validate_serving_mesh(mesh, cfg)
+            self.params = self._shard(params)
+
+    def _shard(self, params):
+        raise NotImplementedError
+
+    def _prefill_fn(self, true_lens):
+        """The ``prefill_fn(params, cfg, tokens)`` of an admission whose
+        true lengths are *true_lens*: a scalar for one row, [N] for a
+        batch."""
+        raise NotImplementedError
+
+    def _ffn(self):
+        return None
+
+    def init_state(self, slots: int):
+        if self.kv_page is None:
+            make = lambda: init_kv_cache(self.cfg, slots)
+            shardings = kv_cache_shardings
+        else:
+            if self.kv_pool_blocks is not None and self.kv_pool_blocks < 1:
+                # an explicit 0 must never silently become the dense-
+                # equivalent default — the operator asked for a pool that
+                # cannot exist
+                raise ValueError(
+                    f"kv_pool_blocks must be >= 1, got {self.kv_pool_blocks}")
+            usable = (self.kv_pool_blocks if self.kv_pool_blocks is not None
+                      else slots * (self.max_context // self.kv_page))
+            self.n_kv_blocks = usable + 1  # + the reserved null block 0
+            make = lambda: init_paged_kv_cache(
+                self.cfg, slots, self.kv_page, self.n_kv_blocks)
+            shardings = paged_kv_shardings
+        if self.mesh is None:
+            return make()
+        # allocate the cache or pool directly head-sharded: one sized past
+        # a chip's HBM must never exist unsharded, not even for a device_put
+        return jax.jit(make, out_shardings=shardings(
+            self.mesh, quantized=kv_quantized(self.cfg)))()
+
+    def prefill_into_slot(self, params, state, padded, slot, true_len):
+        logits, new = slot_steps.prefill_into_slot(
+            params, self.cfg, _constrain_paged(self, state), padded, slot,
+            true_len,
+            prefill_fn=self._prefill_fn(true_len),
+            mesh=self.mesh,
+        )
+        return logits, _constrain_paged(self, new)
+
+    def prefill_into_slots(self, params, state, padded, slots, true_lens):
+        logits, new = slot_steps.prefill_into_slots(
+            params, self.cfg, _constrain_paged(self, state), padded, slots,
+            true_lens,
+            prefill_fn=self._prefill_fn(true_lens),
+            mesh=self.mesh,
+        )
+        return logits, _constrain_paged(self, new)
+
+    def decode_step(self, params, state, tokens, active, kv_bucket,
+                    unroll=False):
+        logits, new = slot_steps.batched_decode_step(
+            cfg=self.cfg, params=params, cache=_constrain_paged(self, state),
+            tokens=tokens, active=active, kv_bucket=kv_bucket,
+            ffn_fn=self._ffn(), unroll=unroll, mesh=self.mesh,
+            paged_attn=self.paged_attn,
+        )
+        return logits, _constrain_paged(self, new)
+
+    def spec_step(self, params, state, draft, active, cap, kv_bucket,
+                  unroll=False):
+        pred, count, new = slot_steps.batched_spec_step(
+            cfg=self.cfg, params=params, cache=_constrain_paged(self, state),
+            draft=draft, active=active, cap=cap, kv_bucket=kv_bucket,
+            ffn_fn=self._ffn(), unroll=unroll, mesh=self.mesh,
+            paged_attn=self.paged_attn,
+        )
+        return pred, count, _constrain_paged(self, new)
+
+    def prefill_chunk_into_slot(self, params, state, chunk, slot, offset,
+                                new_len, kv_bucket=0, unroll=False,
+                                block_ids=None):
+        logits, new = slot_steps.chunked_prefill_into_slot(
+            params, self.cfg, _constrain_paged(self, state), chunk, slot,
+            offset, new_len, kv_bucket=kv_bucket, unroll=unroll,
+            ffn_fn=self._ffn(), block_ids=block_ids, mesh=self.mesh,
+        )
+        return logits, _constrain_paged(self, new)
+
+
+class TransformerSlotModel(_CachedAttentionSlotModel):
     """Dense transformer with a slot-pooled KV cache (vtpu/models/transformer).
 
     With ``mesh`` (a ('tp',) Mesh), weights are tensor-parallel and the KV
@@ -236,107 +386,56 @@ class TransformerSlotModel:
     shard — no collectives beyond the dense TP path's.
     """
 
-    supports_kv_buckets = True
+    def _shard(self, params):
+        return shard_params(params, self.mesh)
 
-    def __init__(self, params: Any, cfg: Any, mesh: Optional[Any] = None,
-                 kv_page: Optional[int] = None,
-                 kv_pool_blocks: Optional[int] = None,
-                 paged_attn: Optional[str] = None):
-        self.cfg = cfg
-        self.mesh = mesh
-        self.max_context = cfg.max_seq
-        _init_paged_attrs(self, kv_page, kv_pool_blocks, paged_attn)
-        if mesh is None:
-            self.params = params
-        else:
-            from vtpu.parallel.sharding import shard_params
-
-            _validate_serving_mesh(mesh, cfg)
-            self.params = shard_params(params, mesh)
-
-    def init_state(self, slots: int):
-        from vtpu.models.transformer import init_kv_cache
-
-        if self.kv_page is not None:
-            return _init_paged_state(self, slots)
-        if self.mesh is None:
-            return init_kv_cache(self.cfg, slots)
-        from vtpu.models.transformer import kv_quantized
-        from vtpu.parallel.sharding import kv_cache_shardings
-
-        # allocate the cache directly sharded: a head-sharded cache that
-        # would not fit one chip must never be materialized unsharded
-        return jax.jit(
-            lambda: init_kv_cache(self.cfg, slots),
-            out_shardings=kv_cache_shardings(
-                self.mesh, quantized=kv_quantized(self.cfg)),
-        )()
-
-    def prefill_into_slot(self, params, state, padded, slot, true_len):
-        from vtpu.serving.engine import prefill_into_slot
-
-        logits, new = prefill_into_slot(
-            params, self.cfg, _constrain_paged(self, state), padded, slot,
-            true_len, mesh=self.mesh)
-        return logits, _constrain_paged(self, new)
-
-    def prefill_into_slots(self, params, state, padded, slots, true_lens):
-        from vtpu.models.transformer import prefill
-        from vtpu.serving.engine import prefill_into_slots
-
+    def _prefill_fn(self, true_lens):
+        if jnp.ndim(true_lens) == 0:
+            # one row: the plain whole-prompt forward, every position's
+            # logits back, which is what slots.prefill_into_slot runs
+            # when it is given none
+            return None
         # logits_at: gather each row's final position before the vocab
         # projection — the [N, bucket, vocab] intermediate never exists
-        logits, new = prefill_into_slots(
-            params, self.cfg, _constrain_paged(self, state), padded, slots,
-            true_lens,
-            prefill_fn=lambda p, c, t: prefill(
-                p, c, t, logits_at=true_lens - 1, mesh=self.mesh),
-            mesh=self.mesh,
-        )
-        return logits, _constrain_paged(self, new)
+        return lambda p, c, t: prefill(
+            p, c, t, logits_at=true_lens - 1, mesh=self.mesh)
 
-    def decode_step(self, params, state, tokens, active, kv_bucket,
-                    unroll=False):
-        from vtpu.serving.engine import batched_decode_step
 
-        logits, new = batched_decode_step(
-            cfg=self.cfg, params=params, cache=_constrain_paged(self, state),
-            tokens=tokens, active=active, kv_bucket=kv_bucket, unroll=unroll,
-            mesh=self.mesh, paged_attn=self.paged_attn,
-        )
-        return logits, _constrain_paged(self, new)
+class MoeSlotModel(_CachedAttentionSlotModel):
+    """Expert-parallel MoE (vtpu/models/moe): the transformer attention
+    trunk with routed experts as the post-attention block, so it shares the
+    slot-KV-cache machinery (including bounded decode read windows) and only
+    swaps the FFN into the shared decode loop.
 
-    def spec_step(self, params, state, draft, active, cap, kv_bucket,
-                  unroll=False):
-        from vtpu.serving.engine import batched_spec_step
+    With ``mesh`` (a ('tp',) Mesh) the attention trunk goes tensor-parallel
+    exactly like the dense family (heads column-sharded, KV cache/pool
+    head-sharded) and the expert stacks shard their E axis over the same
+    'tp' devices when it divides (vtpu/parallel/sharding.py
+    moe_tp_param_shardings — not expert.py's ep-axis moe_param_shardings)
+    — the serving mesh carries both parallelisms.
+    """
 
-        pred, count, new = batched_spec_step(
-            cfg=self.cfg, params=params, cache=_constrain_paged(self, state),
-            draft=draft, active=active, cap=cap, kv_bucket=kv_bucket,
-            unroll=unroll, mesh=self.mesh, paged_attn=self.paged_attn,
-        )
-        return pred, count, _constrain_paged(self, new)
+    def _shard(self, params):
+        return shard_moe_params(params, self.mesh, self.cfg.n_experts)
 
-    def prefill_chunk_into_slot(self, params, state, chunk, slot, offset,
-                                new_len, kv_bucket=0, unroll=False,
-                                block_ids=None):
-        from vtpu.serving.engine import chunked_prefill_into_slot
+    def _prefill_fn(self, true_lens):
+        # true_len (a scalar, or [N] for a batch: per-row routing masks)
+        # keeps pads out of routing, so capacity follows the cf formula
+        # instead of the full bucket; the full [N, bucket, vocab] logits
+        # come back and the caller gathers the final positions
+        return lambda p, c, t: moe_prefill(p, c, t, true_len=true_lens)
 
-        logits, new = chunked_prefill_into_slot(
-            params, self.cfg, _constrain_paged(self, state), chunk, slot,
-            offset, new_len, kv_bucket=kv_bucket, unroll=unroll,
-            block_ids=block_ids, mesh=self.mesh,
-        )
-        return logits, _constrain_paged(self, new)
+    def _ffn(self):
+        # moe_decode_ffn's capacity >= tokens guarantee covers chunk pads
+        # the same way it covers retired slots' garbage: nothing can drop
+        return moe_decode_ffn(self.cfg)
 
 
 def _validate_serving_mesh(mesh: Any, cfg: Any) -> None:
     """Construction-time checks for a tensor-parallel serving mesh — every
     rejection names the offending dimension, so a bad pairing fails loudly
     here instead of as a wrong-sharding surprise (or an XLA shape error)
-    mid-serving. Shared by the transformer and MoE adapter families."""
-    from vtpu.models.transformer import kv_quantized
-
+    mid-serving."""
     extra = {a: n for a, n in mesh.shape.items() if a != "tp" and n != 1}
     if extra:
         # decode ticks would replicate across every non-tp axis
@@ -364,187 +463,7 @@ def _constrain_paged(model: Any, state: Any) -> Any:
     through an unsharded layout the compiler picked for itself."""
     if model.mesh is None or getattr(model, "kv_page", None) is None:
         return state
-    from vtpu.parallel.sharding import constrain_paged_kv
-
     return constrain_paged_kv(state, model.mesh)
-
-
-def _init_paged_attrs(model: Any, kv_page: Optional[int],
-                      kv_pool_blocks: Optional[int],
-                      paged_attn: Optional[str] = None) -> None:
-    """Shared paged-pool attribute setup for KV-cache adapter families.
-    kv_pool_blocks counts USABLE blocks; n_kv_blocks (resolved at
-    init_state once the slot count is known) includes the reserved null
-    block 0. ``paged_attn`` (None/"kernel"/"gather") is the paged
-    decode-attention route override the decode/spec steps thread into the
-    trunk — None resolves the measured per-shape router; forcing a route
-    without a paged pool is a config contradiction and raises."""
-    from vtpu.ops.decode_attn import PAGED_ATTN_ROUTES
-
-    if paged_attn is not None:
-        if paged_attn not in PAGED_ATTN_ROUTES:
-            raise ValueError(
-                f"paged_attn must be one of {PAGED_ATTN_ROUTES} or None "
-                f"(auto), got {paged_attn!r}")
-        if kv_page is None:
-            raise ValueError(
-                "paged_attn forces a paged decode-attention route, but the "
-                "cache is dense (kv_page=None) — there is no paged read "
-                "path to route")
-    model.kv_page = kv_page
-    model.kv_pool_blocks = kv_pool_blocks
-    model.n_kv_blocks = None
-    model.paged_attn = paged_attn
-
-
-def _init_paged_state(model: Any, slots: int):
-    from vtpu.models.transformer import init_paged_kv_cache, kv_quantized
-
-    max_pages = model.max_context // model.kv_page
-    if model.kv_pool_blocks is not None and model.kv_pool_blocks < 1:
-        # an explicit 0 must never silently become the dense-equivalent
-        # default — the operator asked for a pool that cannot exist
-        raise ValueError(
-            f"kv_pool_blocks must be >= 1, got {model.kv_pool_blocks}")
-    usable = (model.kv_pool_blocks if model.kv_pool_blocks is not None
-              else slots * max_pages)
-    model.n_kv_blocks = usable + 1  # + the reserved null block 0
-    if model.mesh is None:
-        return init_paged_kv_cache(
-            model.cfg, slots, model.kv_page, model.n_kv_blocks)
-    from vtpu.parallel.sharding import paged_kv_shardings
-
-    # allocate the pool directly head-sharded (the same out_shardings
-    # discipline as the dense sharded cache above): a pool sized past one
-    # chip's HBM must never exist unsharded, not even for a device_put
-    return jax.jit(
-        lambda: init_paged_kv_cache(
-            model.cfg, slots, model.kv_page, model.n_kv_blocks),
-        out_shardings=paged_kv_shardings(
-            model.mesh, quantized=kv_quantized(model.cfg)),
-    )()
-
-
-class MoeSlotModel:
-    """Expert-parallel MoE (vtpu/models/moe): the transformer attention
-    trunk with routed experts as the post-attention block, so it shares the
-    slot-KV-cache machinery (including bounded decode read windows) and only
-    swaps the FFN into the shared decode loop.
-
-    With ``mesh`` (a ('tp',) Mesh) the attention trunk goes tensor-parallel
-    exactly like the dense family (heads column-sharded, KV cache/pool
-    head-sharded) and the expert stacks shard their E axis over the same
-    'tp' devices when it divides (vtpu/parallel/sharding.py
-    moe_tp_param_shardings — not expert.py's ep-axis moe_param_shardings)
-    — the serving mesh carries both parallelisms.
-    """
-
-    supports_kv_buckets = True
-
-    def __init__(self, params: Any, cfg: Any, mesh: Optional[Any] = None,
-                 kv_page: Optional[int] = None,
-                 kv_pool_blocks: Optional[int] = None,
-                 paged_attn: Optional[str] = None):
-        self.cfg = cfg
-        self.mesh = mesh
-        self.max_context = cfg.max_seq
-        _init_paged_attrs(self, kv_page, kv_pool_blocks, paged_attn)
-        if mesh is None:
-            self.params = params
-        else:
-            from vtpu.parallel.sharding import shard_moe_params
-
-            _validate_serving_mesh(mesh, cfg)
-            self.params = shard_moe_params(params, mesh, cfg.n_experts)
-
-    def init_state(self, slots: int):
-        from vtpu.models.transformer import init_kv_cache
-
-        if self.kv_page is not None:
-            return _init_paged_state(self, slots)
-        if self.mesh is None:
-            return init_kv_cache(self.cfg, slots)
-        from vtpu.models.transformer import kv_quantized
-        from vtpu.parallel.sharding import kv_cache_shardings
-
-        # same direct-sharded allocation as the dense family: never
-        # materialize a multi-chip cache unsharded
-        return jax.jit(
-            lambda: init_kv_cache(self.cfg, slots),
-            out_shardings=kv_cache_shardings(
-                self.mesh, quantized=kv_quantized(self.cfg)),
-        )()
-
-    def prefill_into_slot(self, params, state, padded, slot, true_len):
-        from vtpu.models.moe import moe_prefill
-        from vtpu.serving.engine import prefill_into_slot
-
-        # Forward true_len so pads are masked out of routing and capacity
-        # follows the cf formula instead of the full bucket (moe_prefill).
-        logits, new = prefill_into_slot(
-            params, self.cfg, _constrain_paged(self, state), padded, slot,
-            true_len,
-            prefill_fn=lambda p, c, t: moe_prefill(p, c, t, true_len=true_len),
-            mesh=self.mesh,
-        )
-        return logits, _constrain_paged(self, new)
-
-    def prefill_into_slots(self, params, state, padded, slots, true_lens):
-        from vtpu.models.moe import moe_prefill
-        from vtpu.serving.engine import prefill_into_slots
-
-        # moe_prefill natively takes [B] true_len (per-row routing masks);
-        # the full [N, bucket, vocab] logits come back and the engine
-        # gathers the final positions (rank-3 path)
-        logits, new = prefill_into_slots(
-            params, self.cfg, _constrain_paged(self, state), padded, slots,
-            true_lens,
-            prefill_fn=lambda p, c, t: moe_prefill(p, c, t, true_len=true_lens),
-            mesh=self.mesh,
-        )
-        return logits, _constrain_paged(self, new)
-
-    def decode_step(self, params, state, tokens, active, kv_bucket,
-                    unroll=False):
-        from vtpu.models.moe import moe_decode_ffn
-        from vtpu.serving.engine import batched_decode_step
-
-        logits, new = batched_decode_step(
-            cfg=self.cfg, params=params, cache=_constrain_paged(self, state),
-            tokens=tokens, active=active, kv_bucket=kv_bucket,
-            ffn_fn=moe_decode_ffn(self.cfg), unroll=unroll, mesh=self.mesh,
-            paged_attn=self.paged_attn,
-        )
-        return logits, _constrain_paged(self, new)
-
-    def spec_step(self, params, state, draft, active, cap, kv_bucket,
-                  unroll=False):
-        from vtpu.models.moe import moe_decode_ffn
-        from vtpu.serving.engine import batched_spec_step
-
-        pred, count, new = batched_spec_step(
-            cfg=self.cfg, params=params, cache=_constrain_paged(self, state),
-            draft=draft, active=active, cap=cap, kv_bucket=kv_bucket,
-            ffn_fn=moe_decode_ffn(self.cfg), unroll=unroll, mesh=self.mesh,
-            paged_attn=self.paged_attn,
-        )
-        return pred, count, _constrain_paged(self, new)
-
-    def prefill_chunk_into_slot(self, params, state, chunk, slot, offset,
-                                new_len, kv_bucket=0, unroll=False,
-                                block_ids=None):
-        from vtpu.models.moe import moe_decode_ffn
-        from vtpu.serving.engine import chunked_prefill_into_slot
-
-        # moe_decode_ffn's capacity >= tokens guarantee covers chunk pads
-        # the same way it covers retired slots' garbage: nothing can drop
-        logits, new = chunked_prefill_into_slot(
-            params, self.cfg, _constrain_paged(self, state), chunk, slot,
-            offset, new_len, kv_bucket=kv_bucket, unroll=unroll,
-            ffn_fn=moe_decode_ffn(self.cfg), block_ids=block_ids,
-            mesh=self.mesh,
-        )
-        return logits, _constrain_paged(self, new)
 
 
 class SsmSlotModel:
@@ -560,13 +479,9 @@ class SsmSlotModel:
         self.cfg = cfg
 
     def init_state(self, slots: int):
-        from vtpu.models.ssm import init_ssm_state
-
         return init_ssm_state(self.cfg, slots)
 
     def prefill_into_slot(self, params, state, padded, slot, true_len):
-        from vtpu.models.ssm import ssm_prefill
-
         logits, row = ssm_prefill(params, self.cfg, padded, true_len)
         new_state = {
             "conv": state["conv"].at[:, slot].set(row["conv"][:, 0]),
@@ -575,8 +490,6 @@ class SsmSlotModel:
         return logits[0, true_len - 1], new_state
 
     def prefill_into_slots(self, params, state, padded, slots, true_lens):
-        from vtpu.models.ssm import ssm_prefill
-
         # ssm_prefill gathers its recurrent state at ONE scalar position
         # (dynamic_slice start), so per-row true lengths go through vmap —
         # one fused batched executable, same layer math as the single-slot
@@ -597,8 +510,6 @@ class SsmSlotModel:
 
     def decode_step(self, params, state, tokens, active, kv_bucket,
                     unroll=False):
-        from vtpu.models.ssm import ssm_decode_step
-
         del kv_bucket, unroll  # O(1) state: nothing to window or unroll
         logits, new = ssm_decode_step(params, self.cfg, state, tokens)
         keep = active[None, :, None, None]
@@ -669,8 +580,6 @@ class LatentSlotModel:
                 "set kv_swap=None")
 
     def init_state(self, slots: int):
-        from vtpu.models.latent import init_latent_cache
-
         if self.kv_pool_blocks is not None and self.kv_pool_blocks < 1:
             raise ValueError(
                 f"kv_pool_blocks must be >= 1, got {self.kv_pool_blocks}")
@@ -687,15 +596,11 @@ class LatentSlotModel:
         return logits[0], new
 
     def prefill_into_slots(self, params, state, padded, slots, true_lens):
-        from vtpu.models.latent import latent_prefill_rows
-
         return latent_prefill_rows(
             params, self.cfg, state, padded, slots, true_lens)
 
     def decode_step(self, params, state, tokens, active, kv_bucket,
                     unroll=False):
-        from vtpu.models.latent import latent_decode_step
-
         del unroll  # five layers in two stacks: always walked unrolled
         return latent_decode_step(
             params, self.cfg, state, tokens, active,
@@ -704,8 +609,6 @@ class LatentSlotModel:
     def prefill_chunk_into_slot(self, params, state, chunk, slot, offset,
                                 new_len, kv_bucket=0, unroll=False,
                                 block_ids=None):
-        from vtpu.models.latent import latent_prefill_chunk
-
         del unroll
         window = kv_bucket or self.max_context
         if block_ids is None:  # the slot's own table row
