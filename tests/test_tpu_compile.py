@@ -203,29 +203,41 @@ POOL_SIZED_OK = {"parameter", "bitcast", "get-tuple-element", "scatter",
                  "fusion"}
 
 
-def _step_engine(family: str, int8: bool, tp: int):
+def _step_engine(family: str, int8: bool, tp: int, wide: bool = False):
+    """``wide``: the attention widths of the 7B model (hidden 4096, 32
+    heads) over zeros, so that a projection stack is of a size the compiler
+    will not keep in the fast memory."""
     serving = ServingConfig(
         slots=STEP_SLOTS, prefill_buckets=(STEP_BUCKET,), max_new_tokens=4,
         kv_page=PAGE, kv_pool_blocks=15, paged_attn="kernel",
         prefill_chunk=PAGE)
+    widths = {"d_model": 4096, "n_heads": 32} if wide else {}
+
+    def weights(init, cfg):
+        if not wide:
+            return init(jax.random.key(0), cfg)
+        return jax.tree.map(
+            lambda s: jnp.zeros(s.shape, s.dtype),
+            jax.eval_shape(lambda: init(jax.random.key(0), cfg)))
+
     if family == "moe":
-        cfg = dataclasses.replace(STEP_MOE, kv_int8=int8)
+        cfg = dataclasses.replace(STEP_MOE, kv_int8=int8, **widths)
         model = MoeSlotModel(
-            init_moe_params(jax.random.key(0), cfg), cfg, kv_page=PAGE,
+            weights(init_moe_params, cfg), cfg, kv_page=PAGE,
             kv_pool_blocks=15, paged_attn="kernel")
         return ServingEngine(serving=serving, model=model)
-    # under tp the heads are the 7B model's, 32: eight a chip
-    cfg = dataclasses.replace(
-        STEP_DENSE, kv_int8=int8, n_heads=32 if tp else STEP_DENSE.n_heads)
+    cfg = dataclasses.replace(STEP_DENSE, kv_int8=int8, **widths)
+    if tp:  # the heads are the 7B model's, 32: eight a chip
+        cfg = dataclasses.replace(cfg, n_heads=32)
     mesh = Mesh(np.array(jax.devices()[:tp]), ("tp",)) if tp else None
-    return ServingEngine(
-        init_params(jax.random.key(0), cfg), cfg, serving, mesh=mesh)
+    return ServingEngine(weights(init_params, cfg), cfg, serving, mesh=mesh)
 
 
-def _compiled_decode_step(eng, v5e, tp: int, monkeypatch):
+def _compiled_decode_step(eng, v5e, tp: int, monkeypatch, chunk=False):
     """(the decode step compiled as the engine's warm-up lowers it, its
     pool planes as given to it): on v5e devices, the pool abstract at
-    STEP_BLOCKS blocks, eight heads a chip."""
+    STEP_BLOCKS blocks, eight heads a chip. ``chunk``: the chunk program
+    in the step's place."""
     tpu_mesh = Mesh(np.array(v5e[:tp]), ("tp",)) if tp else None
     if tp:  # what the trunk closes over must name the same devices
         eng.model.mesh = tpu_mesh
@@ -251,9 +263,18 @@ def _compiled_decode_step(eng, v5e, tp: int, monkeypatch):
         eng._rng))
     # the kernel asks the backend whether to interpret: steer it here
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    compiled = eng._decode_sampled.lower(
-        *args, STEP_BUCKET, unroll=eng._unroll).compile()
-    return compiled, {k: v for k, v in args[1].items() if k in PLANES}
+    if chunk:
+        lowered = eng._prefill_chunk.lower(
+            *args[:2], *jax.tree.map(to_tpu, (
+                jnp.zeros((1, PAGE), jnp.int32), jnp.int32(0), jnp.int32(0),
+                jnp.int32(1))),
+            kv_bucket=STEP_BUCKET, unroll=eng._unroll,
+            block_ids=to_tpu(np.zeros((STEP_BUCKET // PAGE,), np.int32)))
+    else:
+        lowered = eng._decode_sampled.lower(
+            *args, STEP_BUCKET, unroll=eng._unroll)
+    return lowered.compile(), {
+        k: v for k, v in args[1].items() if k in PLANES}
 
 
 STEP_CASES = [("dense", False, 0), ("dense", True, 0), ("moe", False, 0),
@@ -297,6 +318,77 @@ def test_decode_step_moves_no_pool_plane(v5e, monkeypatch, family, int8, tp):
     held = jax.jit(lambda k, v: k[0, 0, 0, 0, 0] + v[0, 0, 0, 0, 0]).lower(
         planes["k"], planes["v"]).compile().memory_analysis()
     assert held.argument_size_in_bytes == 2 * plane_bytes
+
+
+_ENTRY_RESULT = re.compile(
+    r"\s*(?:ROOT )?%([\w.\-]+) = (\(?\w+\[.*?) ([\w\-]+)\(")
+_ARRAY = re.compile(r"(\w+)\[([0-9,]*)\]\{([0-9,]*)")
+_MOVES_NOTHING = {"parameter", "get-tuple-element", "tuple", "bitcast"}
+
+
+def _projection_relayouts(text: str, layer_elements: int, dtype: str) -> dict:
+    """{instruction: results} over the instructions of *text*'s entry
+    computation that yield arrays of one layer's projection size laid out
+    otherwise than a slice of a stored stack is (row-major, as every weight
+    argument): a stack copied into the layout a product wants. The parent
+    of PR 31 holds three ``slice_bitcast_fusion``, one a projection, each
+    taking a whole stack and yielding every layer's [d, H*Dh] with d
+    minor. What is nested in a fusion is no instruction of its own and
+    moves nothing; a prefetch keeps the stored layout."""
+    found, entry = {}, False
+    for line in text.splitlines():
+        if line.startswith("ENTRY "):
+            entry = True
+        elif entry and line.startswith("}"):
+            break
+        m = _ENTRY_RESULT.match(line) if entry else None
+        if m is None or m.group(3) in _MOVES_NOTHING:
+            continue
+        n = 0
+        for dt, dims, order in _ARRAY.findall(m.group(2)):
+            shape = [int(d) for d in dims.split(",") if d]
+            order = [int(d) for d in order.split(",") if d]
+            if (dt == dtype and math.prod(shape) == layer_elements
+                    and order != sorted(order, reverse=True)):
+                n += 1
+        if n:
+            found[m.group(1)] = n
+    return found
+
+
+def _holds_projections_as_stored(eng, v5e, tp, monkeypatch, chunk):
+    compiled, _ = _compiled_decode_step(eng, v5e, tp, monkeypatch, chunk=chunk)
+    cfg = eng.model.cfg
+    stack = eng.params["layers"]["wq"]  # whichever form the adapter holds
+    layer_elements = math.prod(stack.shape[1:]) // max(tp, 1)
+    moved = _projection_relayouts(
+        compiled.as_text(), layer_elements, "bf16")
+    assert not moved, moved
+    stack_bytes = cfg.n_layers * layer_elements * stack.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < stack_bytes
+
+
+@pytest.mark.parametrize(
+    "family,int8,tp", STEP_CASES,
+    ids=[f"{f}-{'int8' if q else 'bf16'}-tp{t or 1}" for f, q, t in STEP_CASES])
+def test_decode_step_lays_no_projection_out_anew(
+        v5e, monkeypatch, family, int8, tp):
+    """At the 7B model's attention widths no instruction of the decode step
+    yields a layer's wq, wk or wv in another layout than the stored one
+    (a), and the step's temporaries stay under one projection stack (b).
+    The parent of PR 31 fails (a) in every case (three fusions, each a
+    whole stack in and every layer's projection out) and (b) in every
+    case but bf16 over four chips."""
+    eng = _step_engine(family, int8, tp, wide=True)
+    _holds_projections_as_stored(eng, v5e, tp, monkeypatch, chunk=False)
+
+
+@pytest.mark.parametrize("family", ["dense", "moe"])
+def test_chunk_program_lays_no_projection_out_anew(v5e, monkeypatch, family):
+    """The same of the chunk program (16 rows a product where the step has
+    4): it calls the same ``_qkv``."""
+    eng = _step_engine(family, False, 0, wide=True)
+    _holds_projections_as_stored(eng, v5e, 0, monkeypatch, chunk=True)
 
 
 def test_flash_attention_tp_matches_single_device():

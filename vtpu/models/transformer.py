@@ -12,6 +12,7 @@ TPU-first choices:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import jax
@@ -464,15 +465,53 @@ def multi_tick_spec_decode(
     return out, counts, tok, state
 
 
+PROJECTIONS = ("wq", "wk", "wv")
+
+
+@functools.partial(jax.jit, static_argnames=("n_heads", "head_dim"))
+def _held_projection(w, n_heads: int, head_dim: int):
+    """[L, d, H*Dh] -> [L, H, Dh, d]."""
+    return jnp.transpose(
+        w.reshape(w.shape[:2] + (n_heads, head_dim)), (0, 2, 3, 1))
+
+
+def hold_projections(layers: dict[str, Any], cfg) -> dict[str, Any]:
+    """A new dict of stacked layer leaves with ``wq``, ``wk``, ``wv`` held
+    as a serving program's products read them, [L, H, Dh, d]: the head axis
+    explicit and the contraction axis minor, which is the layout the v5e
+    compiler gives a projection's weights (from the published
+    [L, d, H*Dh] every launch copied each whole stack into it first: PR
+    31). One jitted relayout a leaf; a shape stands for a leaf that is one
+    (a compile-only rehearsal)."""
+    held = functools.partial(
+        _held_projection, n_heads=cfg.n_heads, head_dim=cfg.head_dim)
+    out = dict(layers)
+    for name in PROJECTIONS:
+        leaf = layers[name]
+        if isinstance(leaf, jax.ShapeDtypeStruct):
+            shape = jax.eval_shape(held, leaf)
+            out[name] = jax.ShapeDtypeStruct(
+                shape.shape, shape.dtype, sharding=leaf.sharding)
+        else:
+            out[name] = held(leaf)
+    return out
+
+
 @jax.named_scope("qkv")
 def _qkv(cfg, lp, x, cos, sin, positions):
-    """Project to rotated q/k/v heads: [B, S, H, Dh] each."""
-    b, s, _ = x.shape
-    h, dh = cfg.n_heads, cfg.head_dim
+    """Project to rotated q/k/v heads: [B, S, H, Dh] each. A layer's
+    projections are the published [d, H*Dh] (init_params, training, the
+    pipeline) or a serving adapter's held [H, Dh, d] (hold_projections):
+    the leaf's rank says which, and either way an output is the same dot
+    product over d."""
     normed = rms_norm(x, lp["attn_norm"])
-    q = (normed @ lp["wq"]).reshape(b, s, h, dh)
-    k = (normed @ lp["wk"]).reshape(b, s, h, dh)
-    v = (normed @ lp["wv"]).reshape(b, s, h, dh)
+    if lp["wq"].ndim == 3:
+        q, k, v = (jnp.einsum("bsd,hed->bshe", normed, lp[name])
+                   for name in PROJECTIONS)
+    else:
+        heads = x.shape[:2] + (cfg.n_heads, cfg.head_dim)
+        q, k, v = ((normed @ lp[name]).reshape(heads)
+                   for name in PROJECTIONS)
     return apply_rope(q, cos, sin, positions), apply_rope(k, cos, sin, positions), v
 
 

@@ -46,9 +46,21 @@ def batch_sharding(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P("dp", None))
 
 
+def _follow_projection_ranks(specs: dict[str, Any], params: Any) -> dict[str, Any]:
+    """*specs* with the q/k/v rules following each leaf's rank: the head
+    axis over 'tp' is the last of the published [L, d, H*Dh] and the second
+    of a serving adapter's held [L, H, Dh, d]
+    (models.transformer.hold_projections)."""
+    layers = dict(specs["layers"])
+    for name in ("wq", "wk", "wv"):
+        if params["layers"][name].ndim == 4:
+            layers[name] = head_sharding(layers[name].mesh, 4, 1)
+    return {**specs, "layers": layers}
+
+
 def shard_params(params: Any, mesh: Mesh) -> Any:
     """Place a host pytree of params onto the mesh per param_shardings."""
-    specs = param_shardings(mesh)
+    specs = _follow_projection_ranks(param_shardings(mesh), params)
     return jax.tree.map(jax.device_put, params, specs)
 
 
@@ -159,5 +171,6 @@ def moe_tp_param_shardings(mesh: Mesh, n_experts: int) -> dict[str, Any]:
 def shard_moe_params(params: Any, mesh: Mesh, n_experts: int) -> Any:
     """Place a host pytree of MoE params onto the mesh per
     moe_tp_param_shardings."""
-    return jax.tree.map(
-        jax.device_put, params, moe_tp_param_shardings(mesh, n_experts))
+    specs = _follow_projection_ranks(
+        moe_tp_param_shardings(mesh, n_experts), params)
+    return jax.tree.map(jax.device_put, params, specs)

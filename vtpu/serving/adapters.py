@@ -49,6 +49,7 @@ from vtpu.models.latent import (
 from vtpu.models.moe import moe_decode_ffn, moe_prefill
 from vtpu.models.ssm import init_ssm_state, ssm_decode_step, ssm_prefill
 from vtpu.models.transformer import (
+    hold_projections,
     init_kv_cache,
     init_paged_kv_cache,
     kv_quantized,
@@ -283,6 +284,9 @@ class _CachedAttentionSlotModel:
         self.kv_pool_blocks = kv_pool_blocks
         self.n_kv_blocks = None
         self.paged_attn = paged_attn
+        # wq, wk, wv held as the serving programs' products read them, in
+        # a dict of the adapter's own: the caller's keeps what it was given
+        params = {**params, "layers": hold_projections(params["layers"], cfg)}
         if mesh is None:
             self.params = params
         else:
