@@ -55,7 +55,12 @@ SHAPES = {
     "longprompt4096": (16, 256, 32, 15, 898, lambda r: [*r.randint(1800, 3400, 5), *[1] * 11]),
     # olmoe_chat: 40 of 64 slots live, one past 1024
     "olmoe4096": (64, 256, 16, 8, 2048, lambda r: [*r.randint(100, 1000, 39), 1300, *[1] * 24]),
+    # granite4h_sessions: 64 resident; 8 key/value heads of 64 stored 4 rows
+    # of 128 lanes a token (the "heads" here), 32 query heads over them
+    "granite4096": (64, 256, 4, 4, 16384, lambda r: r.randint(1536, 4088, 64)),
 }
+# (query heads, head size, softmax scale) where they are not the pool's rows
+GROUPED = {"granite4096": (32, 64, 1 / 64)}
 
 
 def fill(name, rng):
@@ -97,8 +102,10 @@ def main():
         plane = jax.jit(lambda k: jax.random.normal(
             k, (layers, nb, PAGE, h, DH), jnp.float32).astype(dtype))
         kp, vp = plane(jax.random.fold_in(key, 1)), plane(jax.random.fold_in(key, 2))
-        q = jax.random.normal(jax.random.fold_in(key, 3), (b, 1, h, DH), dtype)
+        hq, dq, scale = GROUPED.get(name, (h, DH, None))
+        q = jax.random.normal(jax.random.fold_in(key, 3), (b, 1, hq, dq), dtype)
         tb, ln = jnp.asarray(table), jnp.asarray(lens)
+        kw = {} if scale is None else {"scale": scale}
         if args.int8:
             quant = jax.jit(lambda p: (
                 jnp.round(p.astype(jnp.float32) * 32).clip(-127, 127).astype(jnp.int8),
@@ -109,7 +116,8 @@ def main():
                 q, kp[-1], ks[-1], vp[-1], vs[-1], tb, kv_len=ln)
         else:
             pools = (kp, vp)
-            want = paged_causal_attention(q, kp[-1], vp[-1], tb, kv_len=ln)
+            want = paged_causal_attention(
+                q, kp[-1], vp[-1], tb, kv_len=ln[:, None], **kw)
         want = np.asarray(want.astype(jnp.float32))
         for tag, mod in mods.items():
             fn = (mod.paged_decode_attention_int8kv if args.int8
@@ -117,7 +125,7 @@ def main():
 
             def step(q, tb, ln, *pools):
                 outs = [fn(q, *pools, tb, ln, layer=i,
-                           interpret=True if args.tiny else None)
+                           interpret=True if args.tiny else None, **kw)
                         for i in range(layers)]
                 return sum(outs[1:], outs[0]), outs[-1]
 

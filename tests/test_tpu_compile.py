@@ -456,3 +456,80 @@ def test_latent_decode_step_moves_no_pool_plane(v5e):
         assert ops["scatter"] == ops["fusion"] == cfg.n_layers, (plane, ops)
     latent_plane = math.prod(state["ckv"].shape) * 2
     assert compiled.memory_analysis().temp_size_in_bytes < latent_plane
+
+
+# -- the hybrid family at its cell's sizes ----------------------------------
+
+HYBRID_SLOTS, HYBRID_BLOCKS = 64, 16385
+
+
+def _hybrid_shapes(v5e):
+    """granite-4.0-h-micro's ``HybridConfig`` at the published widths, with
+    the adapter's params and the engine state of `granite4h_sessions` (64
+    slots, 16384 blocks of 16) as shapes on the described chip."""
+    from vtpu.models import hybrid as M
+    from vtpu.models.transformer import hold_projections
+
+    cfg = M.HybridConfig(
+        vocab=100352, d_model=2048, n_heads=32, n_kv_heads=8, head_dim=64,
+        d_ff=8192, ssm_heads=64, ssm_head_dim=64, ssm_state=128, ssd_chunk=256,
+        layer_types=tuple((["mamba"] * 5 + ["attention"] + ["mamba"] * 4) * 4),
+        max_seq=16384)
+    chip = SingleDeviceSharding(v5e[0])
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: M.init_hybrid_params(jax.random.key(0), cfg)))
+    params["attention"] = hold_projections(params["attention"], cfg.attention)
+    state = on_chip(jax.eval_shape(lambda: M.init_hybrid_state(
+        cfg, HYBRID_SLOTS, 16, HYBRID_BLOCKS)))
+    return M, cfg, params, state, on_chip
+
+
+@pytest.mark.parametrize("program", ["step", "chunk", "admission"])
+def test_hybrid_programs_compile_at_the_cells_sizes(
+        v5e, monkeypatch, program):
+    """The decode step (window 8192: the kernel route, 8 queries a slot
+    over pool rows of [4, 128]), a 512-token chunk at that window and a
+    whole-prompt admission of 512 compile for a v5e and fit the chip
+    beside the state. The step updates the recurrent state in place: its
+    temporaries stay under ONE layer's state (134 MB of the 4.83 GB), so
+    there is no second copy of ``h``."""
+    # trace-time routing asks the backend: the kernel, compiled, as on a chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    M, cfg, params, state, on_chip = _hybrid_shapes(v5e)
+    i32 = lambda *shape: on_chip(jnp.zeros(shape, jnp.int32))  # noqa: E731
+    if program == "step":
+        compiled = jax.jit(
+            M.hybrid_decode_step, static_argnums=(1, 5), donate_argnums=(2,)
+        ).lower(params, cfg, state, i32(HYBRID_SLOTS),
+                on_chip(jnp.zeros((HYBRID_SLOTS,), bool)), 8192).compile()
+    elif program == "chunk":
+        compiled = jax.jit(
+            M.hybrid_prefill_chunk, static_argnums=(1, 7), donate_argnums=(2,)
+        ).lower(params, cfg, state, i32(1, 512), i32(), i32(), i32(), 8192,
+                i32(8192 // 16)).compile()
+    else:
+        compiled = jax.jit(
+            M.hybrid_prefill_rows, static_argnums=(1,), donate_argnums=(2,)
+        ).lower(params, cfg, state, i32(1, 512), i32(1), i32(1)).compile()
+    mem = compiled.memory_analysis()
+    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert peak < 0.95 * 16 * 2**30, peak
+    recurrent = math.prod(state["h"].shape) * 4
+    assert mem.alias_size_in_bytes > recurrent   # the state is updated in place
+    # and no program lays a pool plane out anew: a plane of four rows a
+    # token is tiled by four, and a gather or scatter of its pages as
+    # stored made the compiler convert the whole pool both ways
+    # (slots._token_rows_merged)
+    big = count_pool_sized_ops(compiled.as_text(), math.prod(state["k"].shape))
+    assert "copy" not in big and "transpose" not in big, big
+    if program == "step":
+        assert compiled.as_text().count("tpu_custom_call") == 4
+        one_layer = recurrent // cfg.n_ssm_layers
+        assert one_layer == 64 * 64 * 64 * 128 * 4
+        assert mem.temp_size_in_bytes < one_layer, mem.temp_size_in_bytes

@@ -11,12 +11,14 @@ import numpy as np
 import pytest
 
 from vtpu.models import ModelConfig, init_params
+from vtpu.models.hybrid import HybridConfig, init_hybrid_params
 from vtpu.models.latent import LatentConfig, init_latent_params
 from vtpu.models.moe import MoEConfig, init_moe_params
 from vtpu.obs.tickprof import HOST_PHASES, TickProfiler, host_ms_per_tick
 from vtpu.ops import SCOPES
 from vtpu.serving import ServingConfig, ServingEngine
-from vtpu.serving.adapters import LatentSlotModel, MoeSlotModel
+from vtpu.serving.adapters import (
+    HybridSlotModel, LatentSlotModel, MoeSlotModel)
 
 PAGE, CHUNK, BUCKET = 8, 8, 16
 DENSE = ModelConfig(
@@ -30,7 +32,15 @@ LATENT = LatentConfig(
     kv_rank=16, nope_dim=8, rope_dim=4, v_dim=8, index_heads=2, index_dim=8,
     index_topk=4, n_experts=8, held=(2, 4), top_k=2, n_group=2, topk_group=1,
     max_seq=32, dtype=jnp.float32)
+HYBRID = HybridConfig(
+    vocab=64, d_model=32, layer_types=("mamba", "attention", "mamba"),
+    n_heads=4, n_kv_heads=2, head_dim=64, d_ff=64, ssm_heads=4,
+    ssm_head_dim=16, ssm_state=8, ssd_chunk=4, max_seq=32, dtype=jnp.float32)
 BLOCK = {"dense": {"mlp"}, "moe": {"route", "experts"}}
+# the hybrid family: its Mamba layers' three parts nested under ``attn``
+# as the latent family's are, the SwiGLU of every layer, and the paged
+# pool's routes for its attention layers
+SSM = {"attn", "ssm_conv", "ssm_scan", "ssm_gate", "mlp"}
 # the latent family: a dense layer then sparse ones, and its attention's
 # three parts nested under ``attn`` (the name vbench/scopes.py knows)
 SPARSE_ATTN = {"attn", "indexer", "select", "latent_attn", "mlp", "route",
@@ -52,6 +62,11 @@ def _engine(family: str, route, **serving):
         model = LatentSlotModel(
             init_latent_params(jax.random.key(0), LATENT), LATENT,
             kv_page=cfg.kv_page)
+        return ServingEngine(serving=cfg, model=model)
+    if family == "hybrid":
+        model = HybridSlotModel(
+            init_hybrid_params(jax.random.key(0), HYBRID), HYBRID,
+            kv_page=cfg.kv_page, paged_attn=cfg.paged_attn)
         return ServingEngine(serving=cfg, model=model)
     model = MoeSlotModel(
         init_moe_params(jax.random.key(0), MOE), MOE,
@@ -100,6 +115,12 @@ CASES = [
     ("latent", "paged", "decode", TRUNK | SPARSE_ATTN | {"sample"}),
     ("latent", "paged", "admit", TRUNK | SPARSE_ATTN | {"sample"}),
     ("latent", "paged", "chunk", TRUNK | SPARSE_ATTN),
+    ("hybrid", "kernel", "decode", TRUNK | SSM | ROUTE["kernel"]
+     | {"sample"}),
+    ("hybrid", "gather", "decode", TRUNK | SSM | ROUTE["gather"]
+     | {"sample"}),
+    ("hybrid", "kernel", "admit", TRUNK | SSM | {"sample"}),
+    ("hybrid", "kernel", "chunk", TRUNK | SSM | {"gather_attn"}),
 ]
 
 

@@ -29,43 +29,14 @@ from vtpu.models.transformer import (
 )
 
 
-def batched_decode_step(
-    params: Params,
-    cfg: ModelConfig,
-    cache: dict[str, jax.Array],
-    tokens: jax.Array,
-    active: jax.Array,
-    kv_bucket: int = 0,
-    ffn_fn=None,
-    unroll: bool = False,
-    mesh=None,
-    paged_attn=None,
-) -> tuple[jax.Array, dict[str, jax.Array]]:
-    """One decode tick for the whole slot pool.
-
-    Unlike models.transformer.decode_step (lockstep: every row at the same
-    position), each slot writes its new KV at ITS OWN length via a batched
-    scatter, so staggered sequences coexist. tokens: [B] int32; active: [B]
-    bool. Inactive slots still compute (uniform work is free on the MXU) but
-    neither their cache nor their length advances.
-
-    kv_bucket (static; 0 = max_seq) bounds the attention READS: decode is
-    HBM-bandwidth-bound and streaming the whole static cache every step
-    wastes bandwidth proportional to max_seq / actual length, so the engine
-    passes the smallest bucket covering its longest live sequence. Writes
-    still target the full cache — only the read view shrinks.
-
-    ``mesh`` (paged caches under tensor-parallel serving) threads down to
-    the trunk so page gathers stay chip-local on the head shard; the paged
-    scatter below is head-sharded by propagation (blk_w/off index the
-    replicated block/page axes, the written values carry the q/k/v column
-    shard). ``paged_attn`` picks the paged READ route (fused table-walking
-    kernel vs gather — see spec_verify_loop); the scatter here is
-    route-oblivious.
-    """
-    b = tokens.shape[0]
+def decode_kv_writer(cfg, cache: dict[str, jax.Array], active: jax.Array):
+    """The ``write_kv(l, kv, k, v)`` of one decode tick over the slot pool:
+    each slot's new key and value ([B, 1, ...] as the cache's planes store
+    a token) land at ITS OWN length, in layer ``l`` of the planes; an
+    inactive slot writes nothing. ``batched_decode_step`` says why each
+    layout masks the way it does."""
     lens = cache["len"]
-    rows = jnp.arange(b)
+    rows = jnp.arange(lens.shape[0])
 
     if "table" in cache:
         # Paged pool: token t of slot b lands at (table[b, t // page],
@@ -125,6 +96,45 @@ def batched_decode_step(
                           kv["v"][l, rows, lens]))
             return out
 
+    return write_kv
+
+
+def batched_decode_step(
+    params: Params,
+    cfg: ModelConfig,
+    cache: dict[str, jax.Array],
+    tokens: jax.Array,
+    active: jax.Array,
+    kv_bucket: int = 0,
+    ffn_fn=None,
+    unroll: bool = False,
+    mesh=None,
+    paged_attn=None,
+) -> tuple[jax.Array, dict[str, jax.Array]]:
+    """One decode tick for the whole slot pool.
+
+    Unlike models.transformer.decode_step (lockstep: every row at the same
+    position), each slot writes its new KV at ITS OWN length via a batched
+    scatter, so staggered sequences coexist. tokens: [B] int32; active: [B]
+    bool. Inactive slots still compute (uniform work is free on the MXU) but
+    neither their cache nor their length advances.
+
+    kv_bucket (static; 0 = max_seq) bounds the attention READS: decode is
+    HBM-bandwidth-bound and streaming the whole static cache every step
+    wastes bandwidth proportional to max_seq / actual length, so the engine
+    passes the smallest bucket covering its longest live sequence. Writes
+    still target the full cache — only the read view shrinks.
+
+    ``mesh`` (paged caches under tensor-parallel serving) threads down to
+    the trunk so page gathers stay chip-local on the head shard; the paged
+    scatter below is head-sharded by propagation (blk_w/off index the
+    replicated block/page axes, the written values carry the q/k/v column
+    shard). ``paged_attn`` picks the paged READ route (fused table-walking
+    kernel vs gather — see spec_verify_loop); the scatter here is
+    route-oblivious.
+    """
+    lens = cache["len"]
+    write_kv = decode_kv_writer(cfg, cache, active)
     logits, new_kv = decode_layer_loop(
         params, cfg, cache, tokens, kv_bucket, write_kv, ffn_fn=ffn_fn,
         unroll=unroll, mesh=mesh, paged_attn=paged_attn,
@@ -322,6 +332,22 @@ def chunked_prefill_into_slot(
         cache, new_view, kv_keys, bucket, c, slot, offset, new_len, block_ids)
 
 
+def _token_rows_merged(pool: jax.Array) -> jax.Array:
+    """A pool plane whose token is fewer than eight rows ([L, n_blocks,
+    page, rows, 128]: grouped heads stored several a row,
+    ``transformer.kv_plane_shape``) viewed [L, n_blocks, page * rows, 128],
+    the same bytes in the same order. The chip tiles a plane of so few
+    rows by four, and a gather or scatter of its pages as they are makes
+    the compiler lay the WHOLE pool out by eight first and back after
+    (compiled for a v5e, a chunk copied both planes in and out: 4 x 1.07
+    GB a launch, 20 ms on the chip, PR 32); merged, a page is 64 rows and
+    nothing is laid out anew. Any other plane is returned as it is (rows
+    narrower than the 128 lanes do not merge for free)."""
+    if pool.ndim == 5 and pool.shape[3] < 8 and pool.shape[4] % 128 == 0:
+        return pool.reshape(pool.shape[:2] + (-1, pool.shape[4]))
+    return pool
+
+
 @jax.named_scope("gather_attn")
 def _chunk_window(cache, kv_keys, bucket: int, slot, block_ids, mesh):
     """The slot's dense [L, 1, bucket] read window for a prefill chunk:
@@ -333,7 +359,7 @@ def _chunk_window(cache, kv_keys, bucket: int, slot, block_ids, mesh):
         view = {}
         for key in kv_keys:
             pool = cache[key]  # [L, n_blocks, page, ...]
-            g = pool[:, block_ids]  # [L, Wp, page, ...]
+            g = _token_rows_merged(pool)[:, block_ids]  # [L, Wp, page, ...]
             view[key] = g.reshape(
                 (pool.shape[0], 1, wp * page) + pool.shape[3:])
         if mesh is not None:
@@ -373,13 +399,14 @@ def _chunk_write_back(cache, new_view, kv_keys, bucket: int, c: int, slot,
         p0 = jnp.minimum(offset // page, wp - span)
         ids_w = jax.lax.dynamic_slice(block_ids, (p0,), (span,))
         for key in kv_keys:
-            pool = cache[key]
+            pool = _token_rows_merged(cache[key])
             pages = new_view[key].reshape(
-                (pool.shape[0], wp, page) + pool.shape[3:])
+                (pool.shape[0], wp) + pool.shape[2:])
             written = jax.lax.dynamic_slice(
                 pages, (0, p0) + (0,) * (pages.ndim - 2),
                 (pool.shape[0], span) + pages.shape[2:])
-            out[key] = pool.at[:, ids_w].set(written)
+            out[key] = pool.at[:, ids_w].set(written).reshape(
+                cache[key].shape)
         # slot may be the engine's out-of-range sentinel (prefix build):
         # drop the length write rather than clamp-corrupt the last slot
         out["len"] = cache["len"].at[slot].set(new_len, mode="drop")
@@ -422,10 +449,10 @@ def _scatter_prefill_pages(
     for key in ("k", "v", "k_scale", "v_scale"):
         if key not in cache:
             continue
-        pool = cache[key]
+        pool = _token_rows_merged(cache[key])
         pages = seq_cache[key][:, :, :s].reshape(
-            (pool.shape[0], slots.shape[0], wp, page) + pool.shape[3:])
-        new_cache[key] = pool.at[:, blk].set(pages)
+            (pool.shape[0], slots.shape[0], wp) + pool.shape[2:])
+        new_cache[key] = pool.at[:, blk].set(pages).reshape(cache[key].shape)
     new_cache["len"] = cache["len"].at[slots].set(true_lens)
     if mesh is not None:
         from vtpu.parallel.sharding import constrain_paged_kv

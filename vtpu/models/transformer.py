@@ -49,9 +49,37 @@ class ModelConfig:
     # "auto": resolved at engine construction via the measured router
     # (serving.engine.choose_kv_int8 — INT8_AB_r05 cells).
     kv_int8: bool | str = False
+    # what a family with another attention states (None / True: the dense
+    # block's own): key/value heads each shared by n_heads / n_kv_heads
+    # query heads, no rotary positions, a softmax scale that is not
+    # 1 / sqrt(head_dim), the norms' epsilon
+    n_kv_heads: Optional[int] = None
+    rotary: bool = True
+    attn_scale: Optional[float] = None
+    eps: float = 1e-6
+
     @property
     def qkv_dim(self) -> int:
         return self.n_heads * self.head_dim
+
+
+def kv_heads(cfg) -> int:
+    """Key/value heads of ``cfg``'s attention (its query heads unless it
+    states fewer); getattr: MoEConfig shares this trunk."""
+    return getattr(cfg, "n_kv_heads", None) or cfg.n_heads
+
+
+def kv_plane_shape(cfg) -> tuple[int, int]:
+    """The two minor axes of a cache plane: (heads, head_dim), or, for
+    grouped key/value heads narrower than a row of 128 lanes that fill
+    whole rows, (rows, 128) with 128 // head_dim heads a row. The same
+    bytes in the same order; the chip would pad a 64-wide minor axis to
+    128 lanes, or lay the pool out otherwise and convert it around every
+    kernel call, and the paged kernel walks rows of whole lanes."""
+    h, dh = kv_heads(cfg), cfg.head_dim
+    if h != cfg.n_heads and dh < 128 and 128 % dh == 0 and (h * dh) % 128 == 0:
+        return (h * dh // 128, 128)
+    return (h, dh)
 
 
 def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
@@ -80,7 +108,7 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
 
 
 def init_kv_cache(cfg: ModelConfig, batch: int) -> dict[str, jax.Array]:
-    shape = (cfg.n_layers, batch, cfg.max_seq, cfg.n_heads, cfg.head_dim)
+    shape = (cfg.n_layers, batch, cfg.max_seq) + kv_plane_shape(cfg)
     if kv_quantized(cfg):
         return {
             "k": jnp.zeros(shape, jnp.int8),
@@ -120,7 +148,7 @@ def init_paged_kv_cache(
     if cfg.max_seq % page:
         raise ValueError(f"kv page {page} must divide max_seq {cfg.max_seq}")
     max_pages = cfg.max_seq // page
-    shape = (cfg.n_layers, n_blocks, page, cfg.n_heads, cfg.head_dim)
+    shape = (cfg.n_layers, n_blocks, page) + kv_plane_shape(cfg)
     cache: dict[str, jax.Array] = {
         "table": jnp.zeros((slots, max_pages), jnp.int32),
         "len": jnp.zeros((slots,), jnp.int32),
@@ -144,10 +172,10 @@ def kv_bytes_per_token(cfg) -> int:
     """HBM bytes one cached token costs across all layers — the unit the
     paged-vs-dense capacity estimates in ServingEngine.stats() and the
     paged_kv_bench HBM budgets are denominated in."""
-    per_plane = cfg.n_heads * cfg.head_dim
+    per_plane = kv_heads(cfg) * cfg.head_dim
     if kv_quantized(cfg):
         # int8 values + per-token-per-head f32 scales, two planes
-        per_layer = 2 * (per_plane * 1 + cfg.n_heads * 4)
+        per_layer = 2 * (per_plane * 1 + kv_heads(cfg) * 4)
     else:
         per_layer = 2 * per_plane * jnp.dtype(cfg.dtype).itemsize
     return cfg.n_layers * per_layer
@@ -483,11 +511,12 @@ def hold_projections(layers: dict[str, Any], cfg) -> dict[str, Any]:
     [L, d, H*Dh] every launch copied each whole stack into it first: PR
     31). One jitted relayout a leaf; a shape stands for a leaf that is one
     (a compile-only rehearsal)."""
-    held = functools.partial(
-        _held_projection, n_heads=cfg.n_heads, head_dim=cfg.head_dim)
     out = dict(layers)
     for name in PROJECTIONS:
         leaf = layers[name]
+        held = functools.partial(
+            _held_projection, head_dim=cfg.head_dim,
+            n_heads=cfg.n_heads if name == "wq" else kv_heads(cfg))
         if isinstance(leaf, jax.ShapeDtypeStruct):
             shape = jax.eval_shape(held, leaf)
             out[name] = jax.ShapeDtypeStruct(
@@ -503,15 +532,17 @@ def _qkv(cfg, lp, x, cos, sin, positions):
     projections are the published [d, H*Dh] (init_params, training, the
     pipeline) or a serving adapter's held [H, Dh, d] (hold_projections):
     the leaf's rank says which, and either way an output is the same dot
-    product over d."""
-    normed = rms_norm(x, lp["attn_norm"])
+    product over d. k and v have ``kv_heads(cfg)`` heads; a family without
+    rotary positions (``cfg.rotary`` False) gets q and k as projected."""
+    normed = rms_norm(x, lp["attn_norm"], getattr(cfg, "eps", 1e-6))
     if lp["wq"].ndim == 3:
         q, k, v = (jnp.einsum("bsd,hed->bshe", normed, lp[name])
                    for name in PROJECTIONS)
     else:
-        heads = x.shape[:2] + (cfg.n_heads, cfg.head_dim)
-        q, k, v = ((normed @ lp[name]).reshape(heads)
-                   for name in PROJECTIONS)
+        q, k, v = ((normed @ lp[name]).reshape(
+            x.shape[:2] + (-1, cfg.head_dim)) for name in PROJECTIONS)
+    if not getattr(cfg, "rotary", True):
+        return q, k, v
     return apply_rope(q, cos, sin, positions), apply_rope(k, cos, sin, positions), v
 
 
@@ -756,11 +787,62 @@ def spec_verify_loop(
     routes share the kv_len masking and null-block contracts verbatim, so
     streams stay token-equal across the routing decision.
     """
-    b, t = draft.shape
+    ffn = ffn_fn or _mlp_block
+    attend = cached_attention(
+        cfg, cache, draft.shape[1], kv_bucket, write_kv, unroll=unroll,
+        mesh=mesh, paged_attn=paged_attn)
+    x = _embed(params, cfg, draft)
+
+    def layer(l, carry, lp=None):
+        x, kv = carry
+        if lp is None:
+            lp = jax.tree_util.tree_map(lambda a: a[l], params["layers"])
+        attn, kv = attend(l, lp, x, kv)
+        x = _o_proj(lp, x, attn)
+        x = x + ffn(lp, x)
+        return x, kv
+
+    kv0 = {key: cache[key] for key in kv_planes(cache)}
+    if unroll:
+        carry = (x, kv0)
+        for l in range(cfg.n_layers):
+            lp = jax.tree_util.tree_map(lambda a: a[l], params["layers"])
+            carry = layer(l, carry, lp=lp)
+        x, new_kv = carry
+    else:
+        x, new_kv = jax.lax.fori_loop(0, cfg.n_layers, layer, (x, kv0))
+    logits = _lm_head(params, x)
+    if "table" in cache:
+        # the table is read-only inside the trunk (the engine owns it,
+        # updating rows host-side at admission); pass it through so the
+        # returned state pytree matches the input and donation can alias
+        new_kv = {**new_kv, "table": cache["table"]}
+    return logits, new_kv
+
+
+def kv_planes(cache) -> tuple:
+    """The names of a cache's key/value planes (int8 caches carry scales)."""
+    return (("k", "v", "k_scale", "v_scale") if "k_scale" in cache
+            else ("k", "v"))
+
+
+def cached_attention(cfg, cache, t: int, kv_bucket: int, write_kv,
+                     unroll: bool = False, mesh=None, paged_attn=None):
+    """The attention half of a layer over a per-slot KV cache, for a [B, T]
+    chunk whose row-i query sits at cache position len[b] + i: what
+    ``spec_verify_loop`` documents (the chunk's own KV scattered first by
+    the caller's ``write_kv``, the bounded window read under the ragged
+    mask, a paged pool read through its table by the kernel or the gather
+    route), once, for every family whose layers attend over this cache:
+    the dense and expert trunks above walk it every layer, a family of
+    several layer kinds (vtpu/models/hybrid.py) at its attention layers,
+    with ``l`` the layer's index among those. Returns
+    ``attend(l, lp, x, kv) -> (attn [B, T, H, Dh], kv)``."""
     bucket = kv_bucket or cfg.max_seq
     quant = "k_scale" in cache
-    ffn = ffn_fn or _mlp_block
-    cos, sin = rope_angles(cfg.max_seq, cfg.head_dim)
+    scale = getattr(cfg, "attn_scale", None)
+    cos, sin = (rope_angles(cfg.max_seq, cfg.head_dim)
+                if getattr(cfg, "rotary", True) else (None, None))
     lens = cache["len"]
     # Paged pool ("table" present): reads gather each slot's live pages
     # through its page-table row instead of slicing a per-slot ring. The
@@ -787,14 +869,13 @@ def spec_verify_loop(
     ragged_len = jnp.minimum(
         lens[:, None] + 1 + jnp.arange(t)[None, :], cfg.max_seq
     )
-    x = _embed(params, cfg, draft)
-    kv_keys = ("k", "v", "k_scale", "v_scale") if quant else ("k", "v")
+    kv_keys = kv_planes(cache)
+    plane = cache["k"].shape[-2:]  # kv_plane_shape: may pack heads a row
 
-    def layer(l, carry, lp=None):
-        x, kv = carry
-        if lp is None:
-            lp = jax.tree_util.tree_map(lambda a: a[l], params["layers"])
+    def attend(l, lp, x, kv):
         q, k, v = _qkv(cfg, lp, x, cos, sin, positions)
+        if k.shape[-2:] != plane:
+            k, v = (a.reshape(a.shape[:2] + plane) for a in (k, v))
         with jax.named_scope("kv_write"):
             kv = write_kv(l, kv, k, v)
         # Paged KERNEL route: the fused table-walker takes the WHOLE
@@ -809,19 +890,14 @@ def spec_verify_loop(
             # the pools go in as stored; pool_relayout (the query's
             # preparation) and paged_attn are named inside the call
             if quant:
-                attn = paged_decode_attention_int8kv(
+                return paged_decode_attention_int8kv(
                     q, kv["k"], kv["k_scale"], kv["v"], kv["v_scale"],
-                    table_w, ragged_len, layer=l, mesh=mesh)
-            else:
-                attn = paged_decode_attention(
-                    q, kv["k"], kv["v"], table_w, ragged_len, layer=l,
-                    mesh=mesh)
-        else:
-            with jax.named_scope("attn" if table is None else "gather_attn"):
-                attn = window_attention(l, kv, q)
-        x = _o_proj(lp, x, attn)
-        x = x + ffn(lp, x)
-        return x, kv
+                    table_w, ragged_len, layer=l, mesh=mesh), kv
+            return paged_decode_attention(
+                q, kv["k"], kv["v"], table_w, ragged_len, layer=l,
+                mesh=mesh, scale=scale), kv
+        with jax.named_scope("attn" if table is None else "gather_attn"):
+            return window_attention(l, kv, q), kv
 
     def window_attention(l, kv, q):
         # Bounded window reads: with the UNROLLED loop (the serving
@@ -844,7 +920,7 @@ def spec_verify_loop(
                     view["v_scale"], table_w, kv_len=ragged_len, mesh=mesh)
             return paged_causal_attention(
                 q, view["k"], view["v"], table_w, kv_len=ragged_len,
-                mesh=mesh)
+                mesh=mesh, scale=scale)
         if quant:
             return causal_attention_int8kv(
                 q, view["k"][:, :bucket], view["k_scale"][:, :bucket],
@@ -852,24 +928,9 @@ def spec_verify_loop(
                 kv_len=ragged_len)
         return causal_attention(
             q, view["k"][:, :bucket], view["v"][:, :bucket],
-            kv_len=ragged_len)
+            kv_len=ragged_len, scale=scale)
 
-    kv0 = {key: cache[key] for key in kv_keys}
-    if unroll:
-        carry = (x, kv0)
-        for l in range(cfg.n_layers):
-            lp = jax.tree_util.tree_map(lambda a: a[l], params["layers"])
-            carry = layer(l, carry, lp=lp)
-        x, new_kv = carry
-    else:
-        x, new_kv = jax.lax.fori_loop(0, cfg.n_layers, layer, (x, kv0))
-    logits = _lm_head(params, x)
-    if table is not None:
-        # the table is read-only inside the trunk (the engine owns it,
-        # updating rows host-side at admission); pass it through so the
-        # returned state pytree matches the input and donation can alias
-        new_kv = {**new_kv, "table": table}
-    return logits, new_kv
+    return attend
 
 
 def greedy_generate(

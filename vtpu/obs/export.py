@@ -93,6 +93,12 @@ COUNTERS = {
                              "Of those, the tokens attention read: the "
                              "smaller of a slot's length and the "
                              "selection's size"),
+    "ssm_rows_stepped": ("ssm_rows_stepped",
+                         "Slot rows of recurrent state the decode ticks "
+                         "updated: every slot's, the step's shape (models "
+                         "with state-space layers)"),
+    "ssm_rows_live": ("ssm_rows_live",
+                      "Of those, the rows of dispatched slots"),
     "paged_attn_kernel_ticks": ("paged_attn_kernel_ticks",
                                 "Ticks routed to the fused paged-attention "
                                 "kernel (table walked in place)"),
@@ -232,6 +238,10 @@ GAUGES = {
     "kv_page": ("kv_page_tokens", "Tokens per KV block (None = dense)", 1),
     "tp": ("tp_degree", "Tensor-parallel degree", 1),
     "kv_pool_blocks": ("kv_pool_blocks", "Usable pool blocks", 1),
+    "recurrent_state_bytes": ("recurrent_state_bytes",
+                              "Bytes of recurrent rows every slot holds "
+                              "beside the pool, whatever a session's "
+                              "length (0: no state-space layers)", 1),
     "kv_pool_free": ("kv_pool_free_blocks", "Free pool blocks", 1),
     "kv_pool_used": ("kv_pool_used_blocks", "Allocated pool blocks", 1),
     "kv_pool_used_hwm": ("kv_pool_used_blocks_hwm",

@@ -15,7 +15,7 @@ reference benchmarks/ai-benchmark/benchmark.py:1-50).
 SCOPES = (
     "embed", "qkv", "kv_write", "pool_relayout", "paged_attn", "gather_attn",
     "attn", "o_proj", "mlp", "route", "experts", "lm_head", "sample",
-    "indexer", "select", "latent_attn",
+    "indexer", "select", "latent_attn", "ssm_conv", "ssm_scan", "ssm_gate",
 )
 
 from vtpu.ops.init import scaled_normal  # noqa: E402

@@ -52,11 +52,44 @@ def _causal_mask(sq: int, sk: int, kv_len: jax.Array | None) -> jax.Array:
     return mask[None, None, :, :]
 
 
+def _fold_query_groups(q: jax.Array, k: jax.Array, v: jax.Array,
+                       kv_len: jax.Array | None):
+    """Grouped queries against fewer key/value heads, as the one einsum
+    below reads them: q [B, Sq, Hk * G, Dh] (query head h reads key/value
+    head h // G) becomes [B, Sq * G, Hk, Dh], a group's G heads laid along
+    the query axis under the same ragged length, and k, v stored with
+    several heads a row (``kv_plane_shape``: [B, Sk, rows, lanes]) are
+    read as [B, Sk, Hk, Dh], which is the same bytes. Returns (q, k, v,
+    kv_len, G); G == 1 and nothing changed where the head counts agree."""
+    b, sq, hq, dh = q.shape
+    if k.shape[2:] == (hq, dh):
+        return q, k, v, kv_len, 1
+    if kv_len is None or kv_len.ndim != 2:
+        raise ValueError("grouped queries need the ragged [B, Sq] kv_len")
+    k = k.reshape(k.shape[:2] + (-1, dh))
+    v = v.reshape(k.shape)
+    hk = k.shape[2]
+    g = hq // hk
+    q = q.reshape(b, sq, hk, g, dh).transpose(0, 1, 3, 2, 4).reshape(
+        b, sq * g, hk, dh)
+    return q, k, v, jnp.repeat(kv_len, g, axis=1), g
+
+
+def _unfold_query_groups(out: jax.Array, g: int) -> jax.Array:
+    """[B, Sq * G, Hk, Dh] back to [B, Sq, Hk * G, Dh]."""
+    if g == 1:
+        return out
+    b, sqg, hk, dh = out.shape
+    return out.reshape(b, sqg // g, g, hk, dh).transpose(0, 1, 3, 2, 4).reshape(
+        b, sqg // g, hk * g, dh)
+
+
 def causal_attention(
     q: jax.Array,
     k: jax.Array,
     v: jax.Array,
     kv_len: jax.Array | None = None,
+    scale: float | None = None,
 ) -> jax.Array:
     """Reference causal attention.
 
@@ -69,15 +102,22 @@ def causal_attention(
     was scattered at per-row offsets (kv_len[b, i] = len[b] + i + 1) — no
     suffix-position mask applies because the chunk does not sit at the
     window's end.
+
+    ``scale`` is the softmax scale (None: 1 / sqrt(Dh)). Fewer key/value
+    heads than query heads (q [B, Sq, Hk * G, Dh]; k, v of Hk heads, as
+    [.., Hk, Dh] or several heads a stored row) take the ragged kv_len:
+    ``_fold_query_groups``.
     """
+    q, k, v, kv_len, group = _fold_query_groups(q, k, v, kv_len)
     b, sq, h, dh = q.shape
     sk = k.shape[1]
-    scale = 1.0 / math.sqrt(dh)
+    if scale is None:
+        scale = 1.0 / math.sqrt(dh)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
     scores = jnp.where(_causal_mask(sq, sk, kv_len), scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
-    return out.astype(q.dtype)
+    return _unfold_query_groups(out.astype(q.dtype), group)
 
 
 def causal_attention_int8kv(
@@ -163,9 +203,11 @@ def paged_causal_attention(
     table: jax.Array,
     kv_len: jax.Array | None = None,
     mesh=None,
+    scale: float | None = None,
 ) -> jax.Array:
     """Causal attention over a paged KV window: gather each slot's live
-    pages from the shared block pool, then the reference attention.
+    pages from the shared block pool, then the reference attention
+    (``scale`` and grouped queries as ``causal_attention`` takes them).
 
     q: [B, Sq, H, Dh]; k_pool, v_pool: [n_blocks, page, H, Dh] (ONE layer's
     plane of the pool); table: [B, Wp] block ids with Wp*page >= the read
@@ -176,7 +218,7 @@ def paged_causal_attention(
     attention runs on each chip's heads, exactly like the dense TP path."""
     k = gather_kv_pages(k_pool, table, mesh=mesh)
     v = gather_kv_pages(v_pool, table, mesh=mesh)
-    return causal_attention(q, k, v, kv_len=kv_len)
+    return causal_attention(q, k, v, kv_len=kv_len, scale=scale)
 
 
 def paged_causal_attention_int8kv(

@@ -55,6 +55,14 @@ as ``causal_attention_int8kv`` (k_scale on the scores before max/exp;
 v_scale on the probabilities only in the output accumulation, never in the
 softmax denominator).
 
+Grouped queries (fewer key/value heads than query heads, the heads stored
+several a row of whole lanes: ``transformer.kv_plane_shape``) make the same
+walk with their arithmetic on the MXU (``_grouped_kernel``): a slot's
+queries of a pool row are a tile of 8 against a group's 128 tokens, where
+the vector unit's form paid a vreg a token a query (PERF.md, PR 32: 22.7 ms
+for four layers of the hybrid cell at any page size, its copies not the
+bound).
+
 Both kernels equal their XLA references on the same operands
 (tests/test_ops.py drives the dense study; tests/test_paged_attn_kernel.py
 drives the paged path against paged_causal_attention{,_int8kv}).
@@ -517,6 +525,101 @@ def _paged_kernel(lay_ref, tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         o_ref[0, ti] = (acc / d).astype(o_ref.dtype)
 
 
+def _grouped_kernel(lay_ref, tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                    k_buf, v_buf, sems, turn_ref, *,
+                    scale: float, page: int, group: int, rows: int):
+    """``_paged_kernel``'s walk for grouped queries, with both products on
+    the MXU (``_grouped_call`` has the operands' layout).
+
+    A slot's nq queries (``_pack_queries``; a multiple of 8) come as a
+    block (rows * nq, lanes), pool row r of query j at r * nq + j, and a
+    group of pages as (tokens * rows, lanes), row r of a token at
+    token * rows + r: the pool's bytes as they are stored. A pool row is
+    key/value heads of its own, so query row (r, j) attends over the
+    cached rows r alone: one product against the whole group gives every
+    query row against every cached row, the mask keeps the lanes of the
+    same pool row (and of positions under the query's length), and the
+    running softmax is one (rows * nq, tokens * rows) tile a group for all
+    queries; the probabilities, zero at every other lane, go into one
+    product with the values as stored. The buffers are zeroed once: a
+    group's unfilled pages are masked, and what is masked must still be
+    finite."""
+    planes = ((k_hbm, k_buf), (v_hbm, v_buf))
+    b, nb = pl.program_id(0), pl.num_programs(0)
+    nq = q_ref.shape[1] // rows
+    pr = page * rows                    # pool rows a page
+    width = group * pr                  # score lanes a group
+    lay = lay_ref[0]
+    live = functools.partial(_live_pages, len_ref, t=nq, page=page)
+
+    def copy_group(row, g, slot, wait: bool):
+        n = jnp.minimum(live(row) - g * group, group)
+
+        def one(i, _):
+            blk = tbl_ref[row, g * group + i]
+            at = pl.ds(pl.multiple_of(i * pr, pr), pr)
+            for p, (pool, buf) in enumerate(planes):
+                dma = pltpu.make_async_copy(
+                    pool.at[lay, blk], buf.at[slot, at], sems.at[p, slot])
+                if wait:
+                    dma.wait()
+                else:
+                    dma.start()
+            return _
+
+        jax.lax.fori_loop(0, n, one, 0)
+
+    @pl.when(b == 0)
+    def _first():
+        turn_ref[0] = 0
+        k_buf[...] = jnp.zeros(k_buf.shape, k_buf.dtype)
+        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+        copy_group(0, 0, 0, wait=False)
+
+    n_groups = pl.cdiv(live(b), group)
+    turn = turn_ref[0]
+    q = q_ref[0]                                         # (rows * nq, lanes)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (rows * nq, 1), 0)
+    lens = jnp.zeros((rows * nq, 1), jnp.int32)
+    for j in range(nq):
+        lens = jnp.where(sub % nq == j, len_ref[b, j], lens)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    same_row = sub // nq == lane % rows                  # (rows * nq, width)
+
+    def attend_group(g, carry):
+        m_prev, d_prev, acc = carry
+        slot = (turn + g) % 2
+        last = g + 1 == n_groups
+
+        @pl.when(jnp.logical_not(last) | (b + 1 < nb))
+        def _prefetch():
+            copy_group(jnp.where(last, jnp.minimum(b + 1, nb - 1), b),
+                       jnp.where(last, 0, g + 1), 1 - slot, wait=False)
+
+        copy_group(b, g, slot, wait=True)
+        s = jax.lax.dot_general(
+            q, k_buf[slot], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        valid = same_row & (g * group * page + lane // rows < lens)
+        s = jnp.where(valid, s, _NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # a row with nothing to read yet (an idle slot, a padded query)
+        # would weigh every lane by exp(0): keep the other rows' lanes out
+        p = jnp.where(same_row, jnp.exp(s - m_new), 0.0)
+        d_new = d_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        pv = jnp.dot(p.astype(v_buf.dtype), v_buf[slot],
+                     preferred_element_type=jnp.float32)
+        return m_new, d_new, acc * alpha + pv
+
+    init = (jnp.full((rows * nq, 1), _NEG_INF, jnp.float32),
+            jnp.zeros((rows * nq, 1), jnp.float32),
+            jnp.zeros(q.shape, jnp.float32))
+    _, d, acc = jax.lax.fori_loop(0, n_groups, attend_group, init)
+    turn_ref[0] = (turn + n_groups) % 2
+    o_ref[0] = (acc / d).astype(o_ref.dtype)
+
+
 def _window_block(i, j, lay_ref, tbl_ref, len_ref, *, t: int, page: int):
     """(layer, block) of grid step (slot i, window page j) of the window
     walk: the table's entry for a live page, the slot's last live page
@@ -582,8 +685,9 @@ def _layer_arr(layer) -> jax.Array:
 
 
 def _paged_call(q, k_pool, v_pool, k_scale_pool, v_scale_pool, table,
-                kv_len, lay, interpret: bool):
-    """Single-chip pallas_call over (possibly head-LOCAL) pool planes."""
+                kv_len, lay, interpret: bool, scale: Optional[float] = None):
+    """Single-chip pallas_call over (possibly head-LOCAL) pool planes;
+    ``scale`` is the softmax scale (None: 1 / sqrt(Dh))."""
     b, t, h, dh = q.shape
     page = k_pool.shape[2]
     quant = k_scale_pool is not None
@@ -633,7 +737,9 @@ def _paged_call(q, k_pool, v_pool, k_scale_pool, v_scale_pool, table,
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_GROUP_VMEM_BYTES + (24 << 20))
     return pl.pallas_call(
-        functools.partial(kernel, scale=1.0 / math.sqrt(dh), page=page),
+        functools.partial(
+            kernel, scale=1.0 / math.sqrt(dh) if scale is None else scale,
+            page=page),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,  # layer index, table, lengths
             grid=grid, in_specs=in_specs, out_specs=q_spec,
@@ -645,6 +751,50 @@ def _paged_call(q, k_pool, v_pool, k_scale_pool, v_scale_pool, table,
     )(lay, table, lens, *operands)
 
 
+def _grouped_call(packed, k_pool, v_pool, table, kv_len, lay,
+                  interpret: bool, scale: float):
+    """The walk for grouped queries (``_grouped_kernel``). packed [B, nq,
+    rows, lanes] (``_pack_queries``), kv_len [B, nq]; the pools go in
+    viewed [L, n_blocks, page * rows, lanes], a token's rows under one
+    another, which is the bytes as stored (no plane is laid out anew:
+    tests/test_tpu_compile.py holds it), so a group of pages is one
+    matrix of whole tiles."""
+    b, nq, rows, lanes = packed.shape
+    n_layers, n_blocks, page = k_pool.shape[:3]
+    wp = table.shape[1]
+    pad = -nq % 8  # whole sublane tiles of queries; a padded one reads nothing
+    with jax.named_scope("pool_relayout"):
+        lens = jnp.pad(jnp.minimum(kv_len.astype(jnp.int32), wp * page),
+                       ((0, 0), (0, pad)))
+        q = jnp.pad(packed, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        q = q.transpose(0, 2, 1, 3).reshape(b, rows * (nq + pad), lanes)
+    merged = (n_layers, n_blocks, page * rows, lanes)
+    group = _pages_per_group(page, rows, lanes, k_pool.dtype.itemsize, wp)
+    q_spec = pl.BlockSpec((1,) + q.shape[1:], lambda i, *_: (i, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    out = pl.pallas_call(
+        functools.partial(_grouped_kernel, scale=scale, page=page,
+                          group=group, rows=rows),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,  # layer index, table, lengths
+            grid=(b,), in_specs=[q_spec, hbm, hbm], out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((2, group * page * rows, lanes), k_pool.dtype),
+                pltpu.VMEM((2, group * page * rows, lanes), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),  # plane x buffer slot
+                pltpu.SMEM((1,), jnp.int32),  # which buffer slot is next
+            ]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_GROUP_VMEM_BYTES + (24 << 20)),
+        interpret=interpret,
+        name="paged_attn",  # the kernel's name, and its scope in a trace
+    )(lay, table, lens, q, k_pool.reshape(merged), v_pool.reshape(merged))
+    return out.reshape(b, rows, nq + pad, lanes).transpose(
+        0, 2, 1, 3)[:, :nq]
+
+
 def paged_decode_attention(
     q: jax.Array,
     k_pool: jax.Array,
@@ -654,6 +804,7 @@ def paged_decode_attention(
     layer=0,
     mesh=None,
     interpret: bool | None = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Fused paged decode/verify attention: walk the page table IN PLACE
     over the block pool — no gather_kv_pages, no dense window.
@@ -682,16 +833,33 @@ def paged_decode_attention(
     TPU, the Pallas interpreter elsewhere — the CPU tests' numerics rig.
     That a chip run executed the COMPILED kernel is proved, not assumed:
     chip_smoke.py requires a ``tpu_custom_call`` in the engine's decode
-    step, tests/test_tpu_compile.py compiles it with interpret=False."""
+    step, tests/test_tpu_compile.py compiles it with interpret=False.
+
+    ``scale`` is the softmax scale (None: 1 / sqrt(Dh)). Fewer key/value
+    heads than query heads, stored several a row of whole lanes
+    (``transformer.kv_plane_shape``), walk the same table as more queries
+    a slot (``_pack_queries``) with both products on the MXU
+    (``_grouped_kernel``); one chip only."""
     t = q.shape[1]
     kv_len = _norm_kv_len(kv_len, t)
     _check_pool(q, k_pool, table)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     lay = _layer_arr(layer)
+    if q.shape[2:] != k_pool.shape[3:]:
+        if mesh is not None:
+            raise ValueError("grouped queries have no head-sharded walk")
+        if scale is None:
+            scale = 1.0 / math.sqrt(q.shape[3])
+        packed, unpack = _pack_queries(q, k_pool.shape[3], k_pool.shape[4])
+        lens = jnp.repeat(kv_len, packed.shape[1] // t, axis=1)
+        return unpack(_grouped_call(packed, k_pool, v_pool, table, lens,
+                                    lay, interpret, scale))
     if mesh is None:
         return _paged_call(q, k_pool, v_pool, None, None, table, kv_len,
-                           lay, interpret)
+                           lay, interpret, scale)
+    if scale is not None:
+        raise ValueError("a softmax scale has no head-sharded walk")
     fn = shard_map(
         functools.partial(_shard_body, interpret=interpret, quant=False),
         mesh=mesh,
@@ -746,6 +914,38 @@ def paged_decode_attention_int8kv(
     )
     return fn(q, kq_pool, k_scale_pool, vq_pool, v_scale_pool, table,
               kv_len, lay)
+
+
+def _pack_queries(q: jax.Array, rows: int, lanes: int):
+    """Grouped queries as the kernel's pool rows read them. The pool stores
+    ``pack = lanes // Dh`` key/value heads a row (head ``r * pack + p`` in
+    lanes ``p * Dh ..`` of row ``r``); query head ``h`` reads key/value head
+    ``h // G``. A query of the kernel is a [rows, lanes] block whose row
+    ``r`` is multiplied lane by lane with a cached token's row ``r``, so a
+    slot's T x Hk x G query heads go in as T * pack * G queries: query
+    (t, p, g) holds head ``(r * pack + p) * G + g`` in lanes ``p * Dh ..``
+    of row ``r`` and zeros in the other lanes, whose products add nothing
+    to its scores. Its output holds that head's mix in the same lanes (the
+    others mix a neighbour's values under this head's weights and are
+    dropped). Returns (packed [B, T * pack * G, rows, lanes], unpack)."""
+    b, t, hq, dh = q.shape
+    pack = lanes // dh
+    g = hq // (rows * pack)
+    if pack * dh != lanes or g * rows * pack != hq:
+        raise ValueError(
+            f"{hq} query heads of {dh} do not group over pool rows "
+            f"[{rows}, {lanes}]")
+    eye = jnp.eye(pack, dtype=q.dtype)
+    packed = jnp.einsum(
+        "btrpgd,pq->btpgrqd", q.reshape(b, t, rows, pack, g, dh), eye
+    ).reshape(b, t * pack * g, rows, lanes)
+
+    def unpack(out):
+        out = out.reshape(b, t, pack, g, rows, pack, dh)
+        return jnp.einsum("btpgrqd,pq->btrpgd", out, eye).reshape(
+            b, t, hq, dh)
+
+    return packed, unpack
 
 
 def _shard_body(*args, interpret: bool, quant: bool):

@@ -3,10 +3,12 @@ and a model family.
 
 The reference middleware ships no data plane at all (SURVEY §2.6); vTPU's
 serving engine is model-agnostic so every family it schedules can also be
-served: the dense transformer (KV-cache decode, bounded read windows), and
-the selective SSM (O(1) recurrent state — no cache growth with context, the
-profile attention can't offer). An adapter owns the per-slot device state;
-the engine owns slots, admission, and streaming.
+served: the dense transformer (KV-cache decode, bounded read windows), the
+selective SSM (O(1) recurrent state — no cache growth with context, the
+profile attention can't offer), and models that keep both kinds of state a
+session (``HybridSlotModel``: recurrent rows beside a paged pool). An
+adapter owns the per-slot device state; the engine owns slots, admission,
+and streaming.
 
 Contract (all shapes static; the engine jits these with the state donated):
   params                        pytree passed back into every call
@@ -40,6 +42,12 @@ import jax
 import jax.numpy as jnp
 
 from vtpu.models import slots as slot_steps, transformer
+from vtpu.models.hybrid import (
+    hybrid_decode_step,
+    hybrid_prefill_chunk,
+    hybrid_prefill_rows,
+    init_hybrid_state,
+)
 from vtpu.models.latent import (
     init_latent_cache,
     latent_decode_step,
@@ -52,6 +60,7 @@ from vtpu.models.transformer import (
     hold_projections,
     init_kv_cache,
     init_paged_kv_cache,
+    kv_bytes_per_token,
     kv_quantized,
     multi_tick_decode,
     multi_tick_spec_decode,
@@ -310,15 +319,7 @@ class _CachedAttentionSlotModel:
             make = lambda: init_kv_cache(self.cfg, slots)
             shardings = kv_cache_shardings
         else:
-            if self.kv_pool_blocks is not None and self.kv_pool_blocks < 1:
-                # an explicit 0 must never silently become the dense-
-                # equivalent default — the operator asked for a pool that
-                # cannot exist
-                raise ValueError(
-                    f"kv_pool_blocks must be >= 1, got {self.kv_pool_blocks}")
-            usable = (self.kv_pool_blocks if self.kv_pool_blocks is not None
-                      else slots * (self.max_context // self.kv_page))
-            self.n_kv_blocks = usable + 1  # + the reserved null block 0
+            self.n_kv_blocks = _pool_blocks(self, slots)
             make = lambda: init_paged_kv_cache(
                 self.cfg, slots, self.kv_page, self.n_kv_blocks)
             shardings = paged_kv_shardings
@@ -460,6 +461,27 @@ def _validate_serving_mesh(mesh: Any, cfg: Any) -> None:
                f"(= n_heads = {cfg.n_heads})" if kv_quantized(cfg) else ""))
 
 
+def _pool_blocks(model: Any, slots: int) -> int:
+    """Blocks a paged adapter's pool allocates: ``kv_pool_blocks`` usable
+    ones (unset: every slot's whole context) and the reserved null block
+    0. An explicit 0 must never silently become the dense-equivalent
+    default: the operator asked for a pool that cannot exist."""
+    if model.kv_pool_blocks is not None and model.kv_pool_blocks < 1:
+        raise ValueError(
+            f"kv_pool_blocks must be >= 1, got {model.kv_pool_blocks}")
+    usable = (model.kv_pool_blocks if model.kv_pool_blocks is not None
+              else slots * (model.max_context // model.kv_page))
+    return usable + 1
+
+
+def _check_read_windows(read_windows, kv_page: int, max_seq: int) -> None:
+    for w in read_windows or ():
+        if w % kv_page or w > max_seq:
+            raise ValueError(
+                f"read window {w} must be a multiple of kv_page "
+                f"{kv_page} and at most max_seq {max_seq}")
+
+
 def _constrain_paged(model: Any, state: Any) -> Any:
     """Pin a paged pool pytree to its head shards at the step boundary
     (no-op for dense caches or single-chip pools). Applied on entry AND
@@ -557,11 +579,7 @@ class LatentSlotModel:
                 "LatentSlotModel has a paged cache only: set kv_page")
         if getattr(cfg, "kv_int8", False):
             raise ValueError("LatentSlotModel has no int8 cache")
-        for w in read_windows or ():
-            if w % kv_page or w > cfg.max_seq:
-                raise ValueError(
-                    f"read window {w} must be a multiple of kv_page "
-                    f"{kv_page} and at most max_seq {cfg.max_seq}")
+        _check_read_windows(read_windows, kv_page, cfg.max_seq)
         self.params = params
         self.cfg = cfg
         self.max_context = cfg.max_seq
@@ -584,12 +602,7 @@ class LatentSlotModel:
                 "set kv_swap=None")
 
     def init_state(self, slots: int):
-        if self.kv_pool_blocks is not None and self.kv_pool_blocks < 1:
-            raise ValueError(
-                f"kv_pool_blocks must be >= 1, got {self.kv_pool_blocks}")
-        usable = (self.kv_pool_blocks if self.kv_pool_blocks is not None
-                  else slots * (self.max_context // self.kv_page))
-        self.n_kv_blocks = usable + 1  # + the reserved null block 0
+        self.n_kv_blocks = _pool_blocks(self, slots)
         return init_latent_cache(
             self.cfg, slots, self.kv_page, self.n_kv_blocks)
 
@@ -618,5 +631,133 @@ class LatentSlotModel:
         if block_ids is None:  # the slot's own table row
             block_ids = state["table"][slot, :window // self.kv_page]
         return latent_prefill_chunk(
+            params, self.cfg, state, chunk, slot, offset, new_len, window,
+            block_ids)
+
+
+class HybridSlotModel:
+    """Mamba-2 layers among grouped-query attention layers
+    (vtpu/models/hybrid): a session's state is of two kinds, pages of the
+    paged pool for the attention layers and a slot-indexed row of
+    convolution window and recurrent state for the Mamba layers, in one
+    engine state, so the allocator, batched and chunked admission, the
+    read windows and the sampler serve it as they serve the other families.
+
+    Shared with ``_CachedAttentionSlotModel``, not copied: the pool and
+    page table (``init_paged_kv_cache`` over the attention stack's
+    ``ModelConfig``), the held projections, the decode tick's page scatter
+    (``slots.decode_kv_writer``), a chunk's window and write-back, a
+    whole-prompt admission's page install, and both read routes
+    (``transformer.cached_attention``; ``paged_attn`` forces one as the
+    dense family's does).
+
+    It states what the engine cannot know of it: ``read_windows``,
+    ``kv_bytes_per_token`` (the attention layers alone) and
+    ``recurrent_state_bytes`` (the rows, whatever a session's length).
+    Paged only. What a session of two kinds of state cannot do yet is
+    refused by name, each with the mechanism that is missing
+    (``check_serving``, ``refuses``)."""
+
+    supports_kv_buckets = True
+    mesh = None
+    # what the engine asks before an operation that is no ServingConfig
+    # field: a session's pages can be shared, parked or shipped, its
+    # recurrent rows cannot yet
+    refuses = {
+        "register_prefix": (
+            "a shared prefix is pages; a session that starts from one also "
+            "needs the recurrent rows as they stood at the prefix's last "
+            "token, and no snapshot of them is kept at a boundary"),
+        "drain": (
+            "migration ships a session as pool pages; the recurrent rows "
+            "have no staging"),
+    }
+
+    def __init__(self, params: Any, cfg: Any, kv_page: Optional[int] = None,
+                 kv_pool_blocks: Optional[int] = None,
+                 read_windows: Optional[tuple] = None,
+                 paged_attn: Optional[str] = None,
+                 mesh: Optional[Any] = None):
+        if mesh is not None:
+            raise ValueError(
+                "HybridSlotModel has no sharding rule for the recurrent "
+                "rows (a head-sharded state beside a head-sharded pool): "
+                "pass no mesh")
+        if kv_page is None:
+            raise ValueError(
+                "HybridSlotModel has a paged cache only: set kv_page")
+        if cfg.kv_int8:
+            raise ValueError(
+                "HybridSlotModel has no int8 cache: the pool stores several "
+                "key/value heads a row, which the per-head scales' planes "
+                "do not follow (kv_int8=False)")
+        if paged_attn is not None and paged_attn not in PAGED_ATTN_ROUTES:
+            raise ValueError(
+                f"paged_attn must be one of {PAGED_ATTN_ROUTES} or None "
+                f"(auto), got {paged_attn!r}")
+        _check_read_windows(read_windows, kv_page, cfg.max_seq)
+        self.cfg = cfg
+        self.max_context = cfg.max_seq
+        self.kv_page = kv_page
+        self.kv_pool_blocks = kv_pool_blocks
+        self.n_kv_blocks = None
+        self.paged_attn = paged_attn
+        self.read_windows = tuple(sorted(read_windows)) if read_windows else None
+        self.kv_bytes_per_token = kv_bytes_per_token(cfg.attention)
+        self.params = {**params, "attention": hold_projections(
+            params["attention"], cfg.attention)}
+
+    def check_serving(self, serving) -> None:
+        """Refuse the ServingConfig options this family cannot serve."""
+        if serving.spec_tokens:
+            raise ValueError(
+                "HybridSlotModel has no spec_step: a rejected draft would "
+                "need the recurrent rows rolled back to the last accepted "
+                "token, and a step keeps no earlier copy (spec_tokens=0)")
+        if serving.kv_swap is not None:
+            raise ValueError(
+                "HybridSlotModel cannot park or swap a session: its pages "
+                "could be staged, its recurrent rows have no snapshot at "
+                "the boundary (kv_swap=None; park, resume and migrate "
+                "need it)")
+        if serving.disagg is not None:
+            raise ValueError(
+                "HybridSlotModel has no slot-less prefill: a prefill worker "
+                "fills pool blocks, and the recurrent rows have no home "
+                "outside a slot (disagg=None)")
+
+    def recurrent_state_bytes(self, slots: int) -> int:
+        return slots * self.cfg.recurrent_bytes_per_slot
+
+    def init_state(self, slots: int):
+        self.n_kv_blocks = _pool_blocks(self, slots)
+        return init_hybrid_state(
+            self.cfg, slots, self.kv_page, self.n_kv_blocks)
+
+    def prefill_into_slot(self, params, state, padded, slot, true_len):
+        logits, new = self.prefill_into_slots(
+            params, state, padded, jnp.asarray(slot)[None],
+            jnp.asarray(true_len)[None])
+        return logits[0], new
+
+    def prefill_into_slots(self, params, state, padded, slots, true_lens):
+        return hybrid_prefill_rows(
+            params, self.cfg, state, padded, slots, true_lens)
+
+    def decode_step(self, params, state, tokens, active, kv_bucket,
+                    unroll=False):
+        del unroll  # attention layers unrolled, Mamba runs looped: _walk
+        return hybrid_decode_step(
+            params, self.cfg, state, tokens, active,
+            kv_bucket or self.max_context, paged_attn=self.paged_attn)
+
+    def prefill_chunk_into_slot(self, params, state, chunk, slot, offset,
+                                new_len, kv_bucket=0, unroll=False,
+                                block_ids=None):
+        del unroll
+        window = kv_bucket or self.max_context
+        if block_ids is None:  # the slot's own table row
+            block_ids = state["table"][slot, :window // self.kv_page]
+        return hybrid_prefill_chunk(
             params, self.cfg, state, chunk, slot, offset, new_len, window,
             block_ids)

@@ -787,6 +787,15 @@ class ServingEngine:
         with self._on_device():
             self._build(model, cfg, serving, sample)
 
+    def _refused(self, what: str) -> None:
+        """Raise if the slot model refuses operation ``what`` (its
+        ``refuses`` says why): one that is no ServingConfig field, so
+        ``check_serving`` could not refuse it at construction."""
+        why = getattr(self.model, "refuses", {}).get(what)
+        if why:
+            raise ValueError(
+                f"{type(self.model).__name__} cannot {what}: {why}")
+
     def _place(self, tree):
         """Commit an engine-owned pytree (pool state, PRNG keys, buffers)
         to the placement its steps will return it at."""
@@ -867,6 +876,11 @@ class ServingEngine:
         # per-tick route counters must read the same value)
         self._paged_attn = getattr(model, "paged_attn", None)
         self._select_topk = getattr(model, "attn_select_topk", None)
+        # bytes of recurrent rows the state holds beside its pages (a
+        # family with state-space layers says; 0 for every other)
+        self._recurrent_bytes = (
+            model.recurrent_state_bytes(b)
+            if hasattr(model, "recurrent_state_bytes") else 0)
         if (serving.paged_attn is not None
                 and self._paged_attn != serving.paged_attn):
             raise ValueError(
@@ -1410,6 +1424,12 @@ class ServingEngine:
                        # of a slot's length and the selection's size); 0
                        # for every other model
                        "attn_visible_tokens": 0, "attn_selected_tokens": 0,
+                       # a slot model that keeps recurrent rows beside its
+                       # pages (``recurrent_state_bytes``): slot rows a
+                       # decode tick's state update touched (every slot's,
+                       # the step's shape), and those of them that belonged
+                       # to a dispatched slot; 0 for every other model
+                       "ssm_rows_stepped": 0, "ssm_rows_live": 0,
                        # KV overcommit: parks/resumes are lifecycle events;
                        # evicted_blocks counts pool blocks reclaimed from
                        # parked sessions; swap_out/in_bytes are the D2H/H2D
@@ -1623,6 +1643,7 @@ class ServingEngine:
         Thread-safe: builds into its OWN single-slot cache, never touching
         the serving loop's pool state.
         """
+        self._refused("register_prefix")
         if not self._chunk:
             raise ValueError("register_prefix requires prefill_chunk")
         tokens = jnp.asarray(tokens, jnp.int32)
@@ -2136,6 +2157,7 @@ class ServingEngine:
         evacuation cannot finish inside *timeout*."""
         from vtpu.serving.migrate import drain_engine
 
+        self._refused("drain")
         return drain_engine(self, dst, timeout=timeout)
 
     def start(self) -> None:
@@ -3485,6 +3507,9 @@ class ServingEngine:
             self._stats["attn_visible_tokens"] += (sum(lens) + len(lens)) * ticks
             self._stats["attn_selected_tokens"] += sum(
                 min(ln + 1, self._select_topk) for ln in lens) * ticks
+        if self._recurrent_bytes:
+            self._stats["ssm_rows_stepped"] += self.serving.slots * ticks
+            self._stats["ssm_rows_live"] += len(lens) * ticks
         if self._paged and lens:
             page = self._page
             live = sum(-(-(ln + 1) // page) for ln in lens)
@@ -3871,6 +3896,9 @@ class ServingEngine:
                       if self._paged and bpt else None),
         }
         s["kv_hbm_bytes_per_chip"] = dict(s["kv_hbm_bytes"])
+        # a session's memory that does not grow with its length: the
+        # recurrent rows of every slot, beside the pool's bytes above
+        s["recurrent_state_bytes"] = self._recurrent_bytes
         if self._paged:
             usable = self._n_blocks - 1  # minus the reserved null block
             free = self._alloc.free_blocks
