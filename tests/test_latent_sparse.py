@@ -219,6 +219,113 @@ def test_absorbed_attention_equals_the_expanded_form():
     assert np.abs(got - want).max() < 1e-4
 
 
+def _layer_inputs(rng, n, t, stored, given):
+    """Random float32 planes, tables and queries of one layer at toy
+    widths: 4 heads, rank 32 + 8 rotated (rows stored ``stored`` wide), a
+    window of 64 positions in pages of 8, the queries its last ``t``."""
+    h, r, dr, dn, dv, hi, di, page, w = 4, 32, 8, 16, 16, 4, 16, 8, 64
+
+    def normal(*shape):
+        return jnp.asarray(rng.standard_normal(shape, np.float32))
+
+    tables = jnp.asarray(
+        1 + rng.permutation(n * w // page).reshape(n, w // page), jnp.int32)
+    blocks = 1 + n * w // page
+    positions = jnp.broadcast_to(jnp.arange(w - t, w, dtype=jnp.int32), (n, t))
+    idx = None
+    if given:  # 12 positions a query, those past its own do not count
+        idx = jnp.asarray(np.stack([[rng.choice(w, 12, replace=False)
+                                     for _ in range(t)] for _ in range(n)]),
+                          jnp.int32).at[..., 0].set(positions)
+    return dict(
+        ckv=normal(2, blocks, page, stored), ik=normal(2, blocks, page, di),
+        l=1, tables=tables, positions=positions, q_nope=normal(n, t, h, dn),
+        q_pe=normal(n, t, h, dr), w_uk=normal(h, dn, r) / math.sqrt(r),
+        w_uv=normal(h, r, dv) / math.sqrt(r), q_idx=normal(n, t, hi, di),
+        w_idx=normal(n, t, hi), topk=12, scale=0.3, given=idx)
+
+
+@pytest.mark.parametrize("stored,budget,given,tiles", [
+    (40, None, False, (4, 32)),      # all whole, the threshold's selection
+    (128, None, False, (4, 32)),     # ... a row stored wider than it is read
+    (128, None, True, (4, 32)),      # ... a selection given
+    (40, 1 << 15, False, (2, 32)),   # the heads two at a time
+    (128, 1 << 13, False, (1, 16)),  # a head at a time, its queries in two
+    (40, 1 << 13, True, (1, 16)),    # ... the same under a selection given
+])
+def test_a_chunks_expanded_form_equals_its_absorbed_form(
+        monkeypatch, stored, budget, given, tiles):
+    """The masking route in its two forms on one mask, float32: keys and
+    values made from the window (the scores made twice, the quotient on
+    the values' side) against ``masked_latent_attention`` through
+    ``w_uv``. The budgets cut the selection into blocks of queries, the
+    expanded form into groups of heads (and then blocks of queries) and
+    the absorbed form into blocks of queries, as the cell's windows do
+    (a chunk of 512 over 32 k: 8 heads a group, all its queries)."""
+    if budget is not None:
+        monkeypatch.setattr(L, "_BLOCK_BYTES", budget)
+        monkeypatch.setattr(L, "_SCORE_BYTES", budget // 2)
+    kw = _layer_inputs(np.random.default_rng(5), 2, 32, stored, given)
+    got = {}
+    for form, expand in (("absorbed", False), ("expanded", True)):
+        monkeypatch.setattr(L, "expands_window", lambda *a, e=expand: e)
+        got[form] = L.sparse_latent_attention(**kw)
+    assert L._expanded_tiles(2, 32, 4, 64) == tiles
+    (want, keep), (values, mask) = got["absorbed"], got["expanded"]
+    assert values.shape == want.shape == (2, 32, 4, 16)
+    assert (np.asarray(mask) == np.asarray(keep)).all()
+    assert np.asarray(keep).sum(-1).max() == 12
+    assert np.abs(np.asarray(values - want)).max() < 1e-4
+
+
+def test_the_chunk_attention_bench_runs_at_a_cut_down_shape(tmp_path):
+    """``benchmarks/latent_chunk_attn_bench.py --tiny`` (the table PERF.md's
+    PR 34 entry chose the form with) runs on the CPU and holds its two
+    forms to each other in float32; its times there are no speeds."""
+    import json
+    import pathlib
+    import subprocess
+    import sys
+
+    root = pathlib.Path(__file__).resolve().parent.parent
+    out = tmp_path / "bench.json"
+    done = subprocess.run(
+        [sys.executable, str(root / "benchmarks/latent_chunk_attn_bench.py"),
+         "--tiny", "--out", str(out)], capture_output=True, text=True,
+        timeout=300)
+    assert done.returncode == 0, done.stderr[-2000:]
+    got = json.loads(out.read_text())
+    assert got["device"]["platform"] == "cpu"
+    assert got["float32_highest_max_abs_diff"] < 1e-4
+    assert got["rows"][0]["expanded_tiles"] == [8, 64]
+
+
+@pytest.mark.parametrize("window,heads", [
+    (4096, 64), (8192, 32), (16384, 16), (24576, 8), (32768, 8)])
+def test_the_expanded_forms_tiles_at_the_cells_windows(window, heads):
+    """A chunk of 512 over each read window of `dsv32_longctx`: all its
+    queries in one block, as many of the 128 heads a group as keep the
+    group's scores under 512 MB as float32."""
+    assert L._expanded_tiles(1, 512, 128, window) == (heads, 512)
+    assert 512 * heads * window * 4 <= L._BLOCK_BYTES
+
+
+@pytest.mark.parametrize("queries,expanded", [
+    (1, False), (128, False), (170, False), (171, True), (256, True),
+    (512, True)])
+def test_the_form_follows_the_queries_at_the_published_widths(
+        queries, expanded):
+    """Rank 512, 128 + 128 a head: a window position and head costs
+    ``t * 1088`` multiply-adds absorbed and ``131072 + t * 320``
+    expanded, so the line lies between 170 and 171 queries: the step and
+    a short chunk absorbed, the whole-prompt bucket of 256 and the
+    engine's chunk of 512 expanded."""
+    assert L.expands_window(queries, 512, 128, 128) is expanded
+    absorbed = queries * ((512 + 64) + 512)
+    made = 512 * (128 + 128) + queries * ((128 + 64) + 128)
+    assert (made < absorbed) is expanded
+
+
 def test_yarn_frequencies_against_hand_computed_values():
     """dim 64, base 10000, factor 40 over 4096, beta 32 / 1: the pair that
     turns 32 times in 4096 positions is 64 ln(4096 / 64 pi) / (2 ln 1e4) =
@@ -303,10 +410,10 @@ def test_the_shares_add_up_to_the_uncut_layer():
     assert np.abs(np.asarray(gates - want)).max() < 1e-5
 
 
-def _engine(mc, params, **kw):
+def _engine(mc, params, chunk=CHUNK, **kw):
     serving = ServingConfig(slots=3, prefill_buckets=(16,), max_new_tokens=8,
                             kv_page=PAGE, kv_pool_blocks=40,
-                            prefill_chunk=CHUNK, **kw)
+                            prefill_chunk=chunk, **kw)
     model = LatentSlotModel(params, mc, kv_page=PAGE, kv_pool_blocks=40,
                             read_windows=(16, 32, 64))
     return ServingEngine(serving=serving, model=model)
@@ -344,6 +451,49 @@ def test_staggered_streams_through_the_engine_equal_single_streams(reference):
     # 16 of what a tick sees is read once a stream is past index_topk
     assert 0 < stats["attn_selected_tokens"] < stats["attn_visible_tokens"]
     assert stats["kv_hbm_bytes"]["paged"] == 41 * PAGE * 3 * (128 + 16) * 4
+
+
+@pytest.mark.parametrize("family,chunk,expanded", [
+    ("latent", 16, False), ("latent", 64, True), ("dense", 8, False)])
+def test_the_engine_counts_its_chunks_by_the_form_of_their_attention(
+        family, chunk, expanded):
+    """``chunk_attn_launches`` is every chunk dispatched for a model that
+    selects, ``chunk_attn_expanded`` those whose length the shape rule
+    expands (toy widths: more than 32 queries); a dense model counts
+    neither."""
+    if family == "latent":
+        mc, params = _both_sides(TOY)
+        eng = _engine(mc, params, chunk)
+        assert eng.model.chunk_attn_expands(chunk) is expanded
+        assert L.expands_window(chunk, mc.kv_rank, mc.nope_dim,
+                                mc.v_dim) is expanded
+    else:
+        from vtpu.models import ModelConfig, init_params
+        cfg = ModelConfig(vocab=64, d_model=32, n_heads=2, n_layers=1,
+                          d_ff=64, max_seq=128, head_dim=16,
+                          dtype=jnp.float32, use_pallas=False)
+        eng = ServingEngine(
+            init_params(jax.random.key(0), cfg), cfg,
+            ServingConfig(slots=2, prefill_buckets=(8,), max_new_tokens=4,
+                          prefill_chunk=chunk))
+    prompts = [np.random.default_rng(6).integers(1, 60, n).astype(np.int32)
+               for n in (70, 100)]
+    eng.start()
+    try:
+        for r in [eng.submit(p, max_new_tokens=3) for p in prompts]:
+            assert len(list(r.stream())) == 3
+        stats = eng.stats()
+    finally:
+        eng.stop()
+    assert stats["loop_error"] is None
+    chunks = sum(-(-len(p) // chunk) for p in prompts)
+    assert stats["prefill_chunks"] == chunks
+    if family == "dense":
+        assert stats["chunk_attn_launches"] == 0
+        assert stats["chunk_attn_expanded"] == 0
+    else:
+        assert stats["chunk_attn_launches"] == chunks
+        assert stats["chunk_attn_expanded"] == (chunks if expanded else 0)
 
 
 @pytest.mark.parametrize("what,match", [

@@ -407,16 +407,11 @@ def test_flash_attention_tp_matches_single_device():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-def test_latent_decode_step_moves_no_pool_plane(v5e):
-    """The latent family's decode step at the published widths (one dense
-    and one sparse layer, 4 slots, a pool of 256 blocks of 64, window
-    4096): nothing of a pool plane's size is computed but the in-place
-    scatters and the fusions that wrap them, neither for the latent plane
-    nor for the indexer's keys, and the step's temporaries stay under the
-    latent plane. With rows of 576 the compiler lays the plane out
-    blocks-minor to save the lanes' padding and converts the whole pool
-    on the way in and out (2 x 1.84 GB a step at the cell's pool, compiled
-    in PR 28): ``LatentConfig.stored_width`` pads the row to 640 instead."""
+def _latent_shapes(v5e):
+    """The latent family at the published widths (one dense and one
+    sparse layer, 4 slots, a pool of STEP_BLOCKS blocks of 64, positions
+    to 32768) as shapes on the described chip: (module, cfg, params,
+    state, on_chip)."""
     from vtpu.models import latent as M
 
     cfg = M.LatentConfig(
@@ -436,26 +431,109 @@ def test_latent_decode_step_moves_no_pool_plane(v5e):
         lambda: M.init_latent_params(jax.random.key(0), cfg)))
     state = on_chip(jax.eval_shape(
         lambda: M.init_latent_cache(cfg, 4, 64, STEP_BLOCKS)))
+    return M, cfg, params, state, on_chip
+
+
+def _pool_plane_ops(text: str, plane) -> dict:
+    """{opcode: count} of the instructions whose result is the plane as
+    stored or its rows view (blocks and page merged)."""
+    layers, blocks, page, row = plane.shape
+    shapes = (f"[{layers},{blocks},{page},{row}]",
+              f"[{layers},{blocks * page},{row}]")
+    ops = {}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+(\[[0-9,]*\])\S* "
+                     r"([\w\-]+)\(", line)
+        if m and m.group(1) in shapes:
+            ops[m.group(2)] = ops.get(m.group(2), 0) + 1
+    return ops
+
+
+def _expansions(text: str, window: int, width: int = 128) -> list:
+    """Results shaped ``[.., window, heads, width]`` in a compiled text: a
+    window's latents made into a head's keys or values; [(heads, line)]."""
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[(?:\d+,)*"
+                     rf"{window},(\d+),{width}\]\S* convolution\(", line)
+        if m:
+            found.append((int(m.group(1)), line))
+    return found
+
+
+def test_latent_decode_step_moves_no_pool_plane(v5e):
+    """The latent family's decode step at the published widths (one dense
+    and one sparse layer, 4 slots, a pool of 256 blocks of 64, window
+    4096): nothing of a pool plane's size is computed but the in-place
+    scatters and the fusions that wrap them, neither for the latent plane
+    nor for the indexer's keys, and the step's temporaries stay under the
+    latent plane. With rows of 576 the compiler lays the plane out
+    blocks-minor to save the lanes' padding and converts the whole pool
+    on the way in and out (2 x 1.84 GB a step at the cell's pool, compiled
+    in PR 28): ``LatentConfig.stored_width`` pads the row to 640 instead.
+    The step gathers its selected rows and attends in the latent space:
+    no head's keys or values are made of the window (PR 34)."""
+    M, cfg, params, state, on_chip = _latent_shapes(v5e)
     compiled = jax.jit(
         M.latent_decode_step, static_argnums=(1, 5), donate_argnums=(2,)
     ).lower(params, cfg, state, on_chip(jnp.zeros((4,), jnp.int32)),
             on_chip(jnp.zeros((4,), bool)), 4096).compile()
     text = compiled.as_text()
     for plane in ("ckv", "ik"):
-        layers, blocks, page, row = state[plane].shape
-        # the plane as stored, or its rows view (blocks and page merged)
-        shapes = (f"[{layers},{blocks},{page},{row}]",
-                  f"[{layers},{blocks * page},{row}]")
-        ops = {}
-        for line in text.splitlines():
-            m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+(\[[0-9,]*\])\S* "
-                         r"([\w\-]+)\(", line)
-            if m and m.group(1) in shapes:
-                ops[m.group(2)] = ops.get(m.group(2), 0) + 1
+        ops = _pool_plane_ops(text, state[plane])
         assert set(ops) <= POOL_SIZED_OK, (plane, ops)
         assert ops["scatter"] == ops["fusion"] == cfg.n_layers, (plane, ops)
     latent_plane = math.prod(state["ckv"].shape) * 2
     assert compiled.memory_analysis().temp_size_in_bytes < latent_plane
+    assert not _expansions(text, 4096)
+
+
+def test_latent_chunk_expands_its_window_a_group_of_heads_at_a_time(v5e):
+    """A 512-token chunk at the published widths over the 32768 window
+    (the largest of the five programs `dsv32_longctx` warms; the same two
+    layers and pool as the step above): its attention runs in the
+    expanded form, eight heads a group.
+
+    - One pair of expansions a layer in the text, ``[32768, 8, 128]``
+      each (a group's keys and values, 67 MB), inside the loop over the
+      sixteen groups and in no loop over blocks of queries: never all
+      heads' at once (2.15 GB a layer).
+    - A group's scores ``[8, 512, 32768]`` leave no fusion in float32:
+      the first product keeps the row maxima alone, the second writes the
+      exponentials in bfloat16 (12 B a score through the chip's memory
+      would bound the chunk: PERF.md, section 6, PR 34).
+    - The temporaries stay under 1 GB (the absorbed form's were 854 MB at
+      the cell's size; the cell's peak has to stay under 15.5 GB beside
+      12 GB of weights and pool).
+    - Neither pool plane is copied or transposed."""
+    M, cfg, params, state, on_chip = _latent_shapes(v5e)
+    window, chunk = 32768, 512
+    scalar = on_chip(jnp.zeros((), jnp.int32))
+    compiled = jax.jit(
+        M.latent_prefill_chunk, static_argnums=(1, 7), donate_argnums=(2,)
+    ).lower(params, cfg, state, on_chip(jnp.zeros((1, chunk), jnp.int32)),
+            scalar, scalar, scalar, window,
+            on_chip(jnp.zeros((window // 64,), jnp.int32))).compile()
+    text = compiled.as_text()
+    made = _expansions(text, window)
+    assert [heads for heads, _ in made] == [8] * (2 * cfg.n_layers), made
+    # computations a fusion calls hold what never leaves the chip's cores
+    fused = set(re.findall(r"calls=%?([\w.\-]+)", text))
+    scores = f"f32[8,{chunk},{window}]"
+    name, written = None, []
+    for line in text.splitlines():
+        m = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if m:
+            name = m.group(1)
+        elif name not in fused and " = " in line and scores in line.split(
+                " = ", 1)[1].split("(", 1)[0]:
+            written.append(line.strip()[:160])
+    assert not written, written
+    assert f"bf16[8,{chunk},{window}]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    for plane in ("ckv", "ik"):
+        ops = _pool_plane_ops(text, state[plane])
+        assert set(ops) <= POOL_SIZED_OK, (plane, ops)
 
 
 # -- the hybrid family at its cell's sizes ----------------------------------

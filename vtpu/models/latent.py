@@ -12,11 +12,14 @@ What differs from ``transformer.py`` / ``moe.py``, by mechanism:
   by the one page table (``table`` / ``len`` as ``init_paged_kv_cache``
   lays them, so the engine's allocator serves them unchanged).
 - **Attention reads a selection.** The indexer scores every visible
-  position of the read window, the best ``index_topk`` are kept, and
-  attention runs in the latent space (the absorbed form: queries taken
-  through the key up-projection, the mix of latents through the value
-  up-projection) over those rows alone, gathered through the page table
-  (``vtpu/ops/latent.py``).
+  position of the read window and the best ``index_topk`` are kept. A
+  decode step attends in the latent space (the absorbed form: queries
+  taken through the key up-projection, the mix of latents through the
+  value up-projection) over those rows alone, gathered through the page
+  table; a prefill chunk's many queries share one window, so it takes the
+  window's latents through both up-projections once a layer (the expanded
+  form) and attends a head 192 and 128 wide under the selection's mask.
+  Which form runs is read off the shapes in ``vtpu/ops/latent.py``.
 - **Two stacks walked in order**: ``params["dense"]`` (the leading layers,
   a SwiGLU each) and ``params["sparse"]`` (a router ``n_experts`` wide, a
   shared expert, and the stacks of the experts *held here*, ``held =
@@ -241,8 +244,13 @@ def _attention(cfg: LatentConfig, lp, l: int, x, ckv, ik, rope, positions,
     """One layer's attention half over x [N, T, D]: the latent and indexer
     projections, their rows written into layer ``l`` of the two planes at
     (wblk, woff) (out-of-range block ids drop), the selection, attention
-    over the selected rows, the output projection and the residual.
-    Returns (x, ckv, ik, the selected window indices [N, T, K])."""
+    over the selected rows, the output projection and the residual. In
+    which form attention runs (absorbed in the latent space, as a decode
+    step's; or over the window expanded into a head's keys and values, as
+    a chunk's) is ``sparse_latent_attention``'s to choose from the shapes:
+    it takes the query's parts and both up-projections and returns a
+    head's values. Returns (x, ckv, ik, the selection: window indices
+    [N, T, K] or a mask [N, T, W])."""
     n_, t_, _ = x.shape
     h, dn, dr, dv = cfg.n_heads, cfg.nope_dim, cfg.rope_dim, cfg.v_dim
     rkv = cfg.kv_rank
@@ -257,8 +265,6 @@ def _attention(cfg: LatentConfig, lp, l: int, x, ckv, ik, rope, positions,
         latent = jnp.concatenate(
             [rms_norm(kv[..., :rkv], lp["kv_norm"], cfg.eps),
              _rope(kv[..., rkv:], cos, sin, positions)], axis=-1)
-        # the absorbed form: a head's query through its key up-projection
-        q_abs = jnp.einsum("nthd,hdr->nthr", q[..., :dn], lp["w_uk"])
     with jax.named_scope("kv_write"):
         ckv = write_rows(ckv, l, wblk, woff, jnp.pad(
             latent, ((0, 0), (0, 0), (0, cfg.stored_width - cfg.latent_width))))
@@ -274,11 +280,11 @@ def _attention(cfg: LatentConfig, lp, l: int, x, ckv, ik, rope, positions,
             w_i = (n @ lp["idx_w"]).astype(jnp.float32) * (
                 cfg.index_heads ** -0.5 * cfg.index_dim ** -0.5)
             ik = write_rows(ik, l, wblk, woff, k_i)
-        mixed, idx = sparse_latent_attention(
-            ckv, ik, l, tables, positions, q_abs, q_pe, q_i, w_i,
-            cfg.index_topk, cfg.attn_scale, given=given)
+        attn, idx = sparse_latent_attention(
+            ckv, ik, l, tables, positions, q[..., :dn], q_pe, lp["w_uk"],
+            lp["w_uv"], q_i, w_i, cfg.index_topk, cfg.attn_scale,
+            given=given)
     with jax.named_scope("o_proj"):
-        attn = jnp.einsum("nthr,hrv->nthv", mixed, lp["w_uv"])
         x = x + attn.reshape(n_, t_, h * dv) @ lp["wo"]
     return x, ckv, ik, idx
 

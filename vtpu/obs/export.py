@@ -93,6 +93,14 @@ COUNTERS = {
                              "Of those, the tokens attention read: the "
                              "smaller of a slot's length and the "
                              "selection's size"),
+    "chunk_attn_launches": ("chunk_attn_launches",
+                            "Prefill chunks dispatched for a model whose "
+                            "attention reads a selection"),
+    "chunk_attn_expanded": ("chunk_attn_expanded",
+                            "Of those, the chunks long enough to attend "
+                            "their window expanded into a head's keys and "
+                            "values (made once a layer) rather than in "
+                            "the latent space"),
     "ssm_rows_stepped": ("ssm_rows_stepped",
                          "Slot rows of recurrent state the decode ticks "
                          "updated: every slot's, the step's shape (models "

@@ -1,7 +1,10 @@
 """Latent attention over a learned sparse selection (DeepSeek-V3.2's block):
 the indexer's scores over a read window, the selection of the best cached
-tokens a query, and attention in the latent space over the selected rows of
-a paged pool.
+tokens a query, and attention over the selected: a decode step's in the
+latent space (the absorbed form) over rows gathered from a paged pool, a
+prefill chunk's over its whole window under the selection's mask, the
+window expanded into a head's keys and values once a layer
+(``sparse_latent_attention`` reads which off the shapes).
 
 Shapes, throughout: N sequences (decode: the slots; a prefill chunk: 1), T
 queries a sequence (decode: 1; a chunk: its tokens), a read window of W
@@ -191,43 +194,92 @@ def masked_latent_attention(q_abs: jax.Array, q_pe: jax.Array,
                       preferred_element_type=jnp.float32).astype(window.dtype)
 
 
+def expands_window(t: int, rank: int, dn: int, dv: int) -> bool:
+    """Whether ``t`` queries that share one window attend it in the expanded
+    form (the window's latents through ``w_uk`` and ``w_uv`` once, then a
+    head ``dn + Dr`` and ``dv`` wide) or in the absorbed one (every query
+    through ``w_uk``, a head ``rank + Dr`` and ``rank`` wide in the latent
+    space, its mix through ``w_uv``).
+
+    Multiply-adds of one window position and head under all ``t`` queries:
+    absorbed ``t * ((rank + Dr) + rank)``; expanded ``rank * (dn + dv)`` for
+    the position's key and value and ``t * ((dn + Dr) + dv)`` against them.
+    The expanded form is the smaller where ``t * (2 * rank - dn - dv) > rank
+    * (dn + dv)``: from 171 queries at the published widths (512, 128, 128).
+    What a query costs whatever the window's length (its trip through
+    ``w_uk`` and ``w_uv`` in the absorbed form) is left out: it would only
+    move the line towards fewer queries. Nor is the second time
+    ``_expanded`` makes its scores counted: that product (``t * (dn + Dr)``)
+    stands in for 8 B a score of memory traffic, which on a v5e (240 FLOPs
+    a byte) costs five times as much. A single query reads its selected
+    rows alone, not a window: never expanded."""
+    return t > 1 and t * (2 * rank - dn - dv) > rank * (dn + dv)
+
+
 def sparse_latent_attention(ckv: jax.Array, ik: jax.Array, l: int,
                             tables: jax.Array, positions: jax.Array,
-                            q_abs: jax.Array, q_pe: jax.Array,
+                            q_nope: jax.Array, q_pe: jax.Array,
+                            w_uk: jax.Array, w_uv: jax.Array,
                             q_idx: jax.Array, w_idx: jax.Array, topk: int,
                             scale: float, given=None) -> tuple:
     """Layer ``l``'s selection and attention for ``[N, T]`` queries at
     ``positions`` over the window that ``tables`` maps, both planes whole
-    (``[L, n_blocks, page, R]``), read as they are stored.
+    (``[L, n_blocks, page, R]``), read as they are stored. A head's query
+    comes in its two parts, ``q_nope [N, T, H, dn]`` and the rotated ``q_pe
+    [N, T, H, Dr]``, with the layer's up-projections ``w_uk [H, dn, rank]``
+    and ``w_uv [H, rank, dv]``: which side of the scores they are applied
+    to is this function's choice.
 
-    **A decode step (T = 1) gathers**: the indexer's scores against the
-    window's keys (``ik`` plane), ``lax.top_k``, and attention over the
-    selected rows of the ``ckv`` plane alone, read through the page table.
+    **A decode step (T = 1) gathers, absorbed**: the indexer's scores
+    against the window's keys (``ik`` plane), ``lax.top_k``, the query
+    through ``w_uk``, attention in the latent space over the selected rows
+    of the ``ckv`` plane alone, read through the page table, and the mix of
+    latents through ``w_uv``.
     **A chunk (T > 1) masks**: its queries share one window, so the
     window's latents are read once (whole pages), the selection is a mask
     from the exact threshold (``select_mask``) and attention runs over the
-    window under it, a block of queries at a time. Gathering a chunk's rows a query
-    (2048 x 1.25 KB each) and sorting a chunk's scores cost more on the
-    chip than the masked products at every window up to 32 k: PERF.md,
-    section 6, PR 28 has both.
+    window under it. Gathering a chunk's rows a query (2048 x 1.25 KB
+    each) and sorting a chunk's scores cost more on the chip than the
+    masked products at every window up to 32 k: PERF.md, section 6, PR 28
+    has both. **In the form its shapes give** (``expands_window``): few
+    queries attend absorbed, as the step does; the engine's chunks (512
+    queries; the whole-prompt bucket's 256) **expand** the window into a
+    head's keys and values once a layer and attend a head 192 and 128
+    wide, the scores made twice (``_expanded``): 71 % of the absorbed
+    form's products and a third of its bytes, 40 % less time at every
+    window from 4 k to 24 k (PERF.md, section 6, PR 34).
 
     ``given`` ([N, T, K] window indices; those past a query's position do
     not count) takes the selection's place (tests hold the two sides to
-    one selection with it). Returns (mixed latents [N, T, H, R], the
+    one selection with it). Returns (a head's values [N, T, H, dv], the
     selection: indices [N, T, K] from the gathering route, the mask
     [N, T, W] from the masking one)."""
-    width = q_abs.shape[-1] + q_pe.shape[-1]  # a stored row may be padded
+    t, rank = positions.shape[1], w_uk.shape[-1]
+    width = rank + q_pe.shape[-1]  # a stored row may be padded
+    expand = expands_window(t, rank, q_nope.shape[-1], w_uv.shape[-1])
+    if not expand:
+        with jax.named_scope("qkv"):  # where the step's trace has it
+            q_abs = jnp.einsum("nthd,hdr->nthr", q_nope, w_uk)
     keys = None
     if given is None:
         with jax.named_scope("indexer"):
             keys = window_rows(ik, l, tables)
-    queries = (positions, q_abs, q_pe, q_idx, w_idx)
-    if positions.shape[1] == 1:
-        return _gathering(ckv, l, tables, keys, queries, given, width, topk,
-                          scale)
-    with jax.named_scope("latent_attn"):
-        window = window_rows(ckv, l, tables)[..., :width]
-    return _masking(window, keys, queries, given, topk, scale)
+    if t == 1:
+        mixed, chosen = _gathering(
+            ckv, l, tables, keys, (positions, q_abs, q_pe, q_idx, w_idx),
+            given, width, topk, scale)
+    else:
+        with jax.named_scope("latent_attn"):
+            window = window_rows(ckv, l, tables)[..., :width]
+        chosen = _selection(keys, positions, q_idx, w_idx, given, topk,
+                            window.shape[1])
+        with jax.named_scope("latent_attn"):
+            if expand:
+                return _expanded(q_nope, q_pe, window, chosen, w_uk, w_uv,
+                                 scale), chosen
+            mixed = _absorbed(q_abs, q_pe, window, chosen, scale)
+    with jax.named_scope("o_proj"):
+        return jnp.einsum("nthr,hrv->nthv", mixed, w_uv), chosen
 
 
 def _gathering(ckv, l, tables, keys, queries, given, width, topk, scale):
@@ -250,34 +302,13 @@ def _gathering(ckv, l, tables, keys, queries, given, width, topk, scale):
             q_abs, q_pe, rows[..., :width], valid, scale), idx
 
 
-def _masking(window, keys, queries, given, topk, scale):
-    """A chunk's route: the threshold's mask and attention over the whole
-    window under it, as many queries a block as keep a block's scores
-    ``[N, qb, H, W]`` under 512 MB."""
-    n, t = queries[0].shape
-    w, heads = window.shape[1], queries[1].shape[2]
-    qb = t
-    while qb > 8 and qb % 2 == 0 and n * qb * heads * w * 4 > _BLOCK_BYTES:
-        qb //= 2
-
-    def attend(pos, qa, qp, qi, wi, idx=None):
-        if idx is None:
-            with jax.named_scope("indexer"):
-                scores = index_scores(qi, wi, keys)
-            with jax.named_scope("select"):
-                keep = select_mask(scores, pos, topk)
-        else:
-            with jax.named_scope("select"):
-                keep = jnp.zeros(pos.shape + (w,), bool).at[
-                    jnp.arange(n)[:, None, None],
-                    jnp.arange(pos.shape[1])[None, :, None], idx].set(True)
-                keep = keep & (jnp.arange(w) <= pos[..., None])
-        with jax.named_scope("latent_attn"):
-            return masked_latent_attention(qa, qp, window, keep, scale), keep
-
-    args = queries if given is None else queries + (given,)
+def _by_query_blocks(fn, qb: int, *xs):
+    """``fn`` over blocks of ``qb`` of the T queries that lead each of
+    ``xs [N, T, ...]``, a block after another, its result ``[N, qb, ...]``
+    joined to ``[N, T, ...]`` again; called once where one block is all."""
+    t = xs[0].shape[1]
     if qb == t:
-        return attend(*args)
+        return fn(*xs)
 
     def blocks_of(x):  # [N, T, ...] -> [T / qb, N, qb, ...]
         return jnp.moveaxis(
@@ -287,6 +318,125 @@ def _masking(window, keys, queries, given, topk, scale):
         x = jnp.moveaxis(x, 0, 1)
         return x.reshape(x.shape[0], t, *x.shape[3:])
 
-    mixed, keep = jax.lax.map(lambda xs: attend(*xs),
-                              tuple(map(blocks_of, args)))
-    return whole(mixed), whole(keep)
+    return whole(jax.lax.map(lambda b: fn(*b), tuple(map(blocks_of, xs))))
+
+
+def _halved(size: int, least: int, fits) -> int:
+    """``size`` halved while it is even, over ``least`` and ``fits(size)``
+    is false."""
+    while size > least and size % 2 == 0 and not fits(size):
+        size //= 2
+    return size
+
+
+def _selection(keys, positions, q_idx, w_idx, given, topk, w):
+    """A chunk's selection as a mask ``[N, T, W]``: the threshold's over
+    the indexer's scores, as many queries a block as keep the scores of
+    all its heads ``[N, qb, Hi, W]`` under 256 MB, so that ``index_scores``
+    takes a block in one pass; or ``given``'s positions."""
+    n, t = positions.shape
+
+    def mask(pos, qi, wi):
+        with jax.named_scope("indexer"):
+            scores = index_scores(qi, wi, keys)
+        with jax.named_scope("select"):
+            return select_mask(scores, pos, topk)
+
+    if given is None:
+        qb = _halved(t, 8, lambda qb: n * qb * q_idx.shape[2] * w * 4
+                     <= _SCORE_BYTES)
+        return _by_query_blocks(mask, qb, positions, q_idx, w_idx)
+    with jax.named_scope("select"):
+        keep = jnp.zeros((n, t, w), bool).at[
+            jnp.arange(n)[:, None, None], jnp.arange(t)[None, :, None],
+            given].set(True)
+        return keep & (jnp.arange(w) <= positions[..., None])
+
+
+def _absorbed(q_abs, q_pe, window, keep, scale):
+    """A chunk of few queries: ``masked_latent_attention`` over as many
+    queries a block as keep a block's scores ``[N, qb, H, W]`` under
+    512 MB."""
+    n, t, heads, _ = q_abs.shape
+    w = window.shape[1]
+    qb = _halved(t, 8, lambda qb: n * qb * heads * w * 4 <= _BLOCK_BYTES)
+    return _by_query_blocks(
+        lambda qa, qp, k: masked_latent_attention(qa, qp, window, k, scale),
+        qb, q_abs, q_pe, keep)
+
+
+def _expanded_tiles(n: int, t: int, heads: int, w: int) -> tuple[int, int]:
+    """(heads a group, queries a block) of the expanded form: the heads
+    halved first, the queries only once a single head's scores ``[N, 1,
+    T, W]`` are still over 512 MB as float32."""
+    g = _halved(heads, 1, lambda g: n * t * g * w * 4 <= _BLOCK_BYTES)
+    return g, _halved(t, 8, lambda qb: n * qb * g * w * 4 <= _BLOCK_BYTES)
+
+
+def _expanded(q_nope, q_pe, window, keep, w_uk, w_uv, scale):
+    """A chunk of many queries, in the expanded form: a head's keys
+    ``window[..., :rank] . w_uk`` and values ``. w_uv`` made once a layer
+    in the window's dtype (float32 sums), the rotated key ``window[...,
+    rank:]`` shared by the heads as it is stored; scores one product over
+    ``dn + Dr`` under ``masked_latent_attention``'s scale and mask, and the
+    exponentials against the values: ``[N, T, H, dv]``, with no ``w_uv``
+    product left to make.
+
+    **The scores are made twice.** Held in float32 between the products,
+    a block's scores cross the chip's memory three times (written, read
+    for the row sums, read for the values), which is what bounds a chunk
+    in either form (12 B a score against 640 FLOPs: PERF.md, section 6,
+    PR 34). At 192 wide the product is cheap enough to repeat: the first
+    pass keeps each row's maximum and nothing else; the second, behind a
+    barrier that keeps the compiler from making one of the two, has the
+    exponential fused onto the product and writes it once in the window's
+    dtype beside its float32 row sums. The quotient is taken on the
+    values' side (``[N, T, H, dv]``, not ``[N, H, T, W]``): the same
+    softmax with the division after the rounding instead of before it.
+
+    Every head has keys of its own here, so a head's product has only its
+    own queries for rows: the heads go a group at a time, as many as keep
+    a group's scores ``[N, g, T, W]`` under 512 MB as float32 with all T
+    queries in one block (8 heads at the 24 k and 32 k windows, 64 at
+    4 k; all heads over blocks of 32 queries took 2.8 times as long),
+    each group's keys and values made as its turn comes (67 MB each at
+    32 k, where all heads' at once would be 2.15 GB); only where one
+    head's scores are over that do the queries go in blocks as well."""
+    n, t, heads, dn = q_nope.shape
+    w, dtype, rank = window.shape[1], window.dtype, w_uk.shape[-1]
+    g, qb = _expanded_tiles(n, t, heads, w)
+    latents, k_pe = window[..., :rank], window[..., rank:]
+
+    def group(q, uk, uv):  # [N, T, g, dn + Dr], [g, dn, rank], [g, rank, dv]
+        k = jnp.einsum("nsr,gdr->nsgd", latents, uk,
+                       preferred_element_type=jnp.float32).astype(dtype)
+        v = jnp.einsum("nsr,grv->nsgv", latents, uv,
+                       preferred_element_type=jnp.float32).astype(dtype)
+        k = jnp.concatenate([k, jnp.broadcast_to(
+            k_pe[:, :, None], k.shape[:3] + k_pe.shape[-1:])], axis=-1)
+
+        def scores(qs, kp):  # [N, qb, g, dn + Dr], [N, qb, W] -> [N, g, qb, W]
+            s = jnp.einsum("ntgd,nsgd->ngts", qs, k,
+                           preferred_element_type=jnp.float32) * scale
+            return jnp.where(kp[:, None], s, _NEG)
+
+        def attend(qs, kp):
+            top = jnp.max(scores(qs, kp), axis=-1, keepdims=True)
+            qs, top = jax.lax.optimization_barrier((qs, top))
+            e = jnp.exp(scores(qs, kp) - top)
+            total = jnp.moveaxis(jnp.sum(e, axis=-1), 1, 2)  # [N, qb, g]
+            mixed = jnp.einsum("ngts,nsgv->ntgv", e.astype(dtype), v,
+                               preferred_element_type=jnp.float32)
+            return (mixed / total[..., None]).astype(dtype)
+
+        return _by_query_blocks(attend, qb, q, keep)
+
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    if g == heads:
+        return group(q, w_uk, w_uv)
+    groups = heads // g
+    out = jax.lax.map(lambda xs: group(*xs), (
+        jnp.moveaxis(q.reshape(n, t, groups, g, -1), 2, 0),
+        w_uk.reshape(groups, g, dn, rank),
+        w_uv.reshape(groups, g, rank, -1)))       # [G, N, T, g, dv]
+    return jnp.moveaxis(out, 0, 2).reshape(n, t, heads, -1)

@@ -67,6 +67,7 @@ from vtpu.models.transformer import (
     prefill,
 )
 from vtpu.ops.decode_attn import PAGED_ATTN_ROUTES
+from vtpu.ops.latent import expands_window
 from vtpu.parallel.sharding import (
     constrain_paged_kv,
     head_sharding,
@@ -556,10 +557,11 @@ class LatentSlotModel:
     32 k context needs read windows where no whole-prompt bucket exists),
     ``kv_bytes_per_token`` (a latent row and an indexer key a layer, not
     heads), ``attn_select_topk`` (what a decode tick reads of what it
-    sees) and ``pool_planes``. Paged only. Not supported, and refused by
-    name: a mesh, an int8 cache, speculation and the swap tier (a forced
-    ``ServingConfig.paged_attn`` the engine refuses itself: there is one
-    route, so ``paged_attn`` is None)."""
+    sees), ``chunk_attn_expands`` (the form a chunk's attention takes at a
+    given length) and ``pool_planes``. Paged only. Not supported, and
+    refused by name: a mesh, an int8 cache, speculation and the swap tier
+    (a forced ``ServingConfig.paged_attn`` the engine refuses itself: there
+    is one route, so ``paged_attn`` is None)."""
 
     supports_kv_buckets = True
     mesh = None
@@ -615,6 +617,13 @@ class LatentSlotModel:
     def prefill_into_slots(self, params, state, padded, slots, true_lens):
         return latent_prefill_rows(
             params, self.cfg, state, padded, slots, true_lens)
+
+    def chunk_attn_expands(self, queries: int) -> bool:
+        """Whether a chunk of ``queries`` tokens attends its window in the
+        expanded form: the rule the traced program applies to its shapes
+        (``vtpu.ops.latent.expands_window``), for the engine's counter."""
+        cfg = self.cfg
+        return expands_window(queries, cfg.kv_rank, cfg.nope_dim, cfg.v_dim)
 
     def decode_step(self, params, state, tokens, active, kv_bucket,
                     unroll=False):

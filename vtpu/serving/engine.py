@@ -1424,6 +1424,12 @@ class ServingEngine:
                        # of a slot's length and the selection's size); 0
                        # for every other model
                        "attn_visible_tokens": 0, "attn_selected_tokens": 0,
+                       # ... and the prefill chunks dispatched for such a
+                       # model, with those of them whose length put their
+                       # attention in the expanded form (the model's
+                       # ``chunk_attn_expands``: keys and values made from
+                       # the window's latents once a layer)
+                       "chunk_attn_launches": 0, "chunk_attn_expanded": 0,
                        # a slot model that keeps recurrent rows beside its
                        # pages (``recurrent_state_bytes``): slot rows a
                        # decode tick's state update touched (every slot's,
@@ -3381,6 +3387,10 @@ class ServingEngine:
                 budget -= c
                 self._stats["prefill_chunks"] += 1
                 self._stats["prefill_tokens"] += real
+                if self._select_topk:
+                    self._stats["chunk_attn_launches"] += 1
+                    self._stats["chunk_attn_expanded"] += int(
+                        self.model.chunk_attn_expands(c))
                 self.trace.record("prefill_chunk", req.rid, slot, c)
                 if adm["off"] >= adm["padded"].shape[1]:  # final chunk
                     del self._admitting[slot]
