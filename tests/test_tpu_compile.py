@@ -488,6 +488,72 @@ def test_latent_decode_step_moves_no_pool_plane(v5e):
     assert not _expansions(text, 4096)
 
 
+def _dense_latent_shapes(v5e, slots: int, blocks: int):
+    """DeepSeek-V2's block at the published widths (one dense and one
+    sparse layer, the 20 held experts, no indexer) as shapes on the
+    described chip: (module, cfg, params, state, on_chip)."""
+    from vtpu.models import latent as M
+
+    cfg = M.LatentConfig(
+        vocab=12800, d_model=5120, n_heads=128, n_dense_layers=1,
+        n_sparse_layers=1, d_ff=12288, d_ff_expert=1536, d_ff_shared=3072,
+        q_rank=1536, kv_rank=512, nope_dim=128, rope_dim=64, v_dim=128,
+        index_heads=0, index_dim=0, index_topk=0, n_experts=160,
+        held=(0, 20), top_k=6, n_group=8, topk_group=3, route_scale=16.0,
+        topk_method="group_limited_greedy", yarn_mscale=0.707,
+        yarn_mscale_all_dim=0.707, max_seq=32768)
+    assert cfg.attn_scale == pytest.approx(192 ** -0.5 * 1.5896, rel=1e-4)
+    chip = SingleDeviceSharding(v5e[0])
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: M.init_latent_params(jax.random.key(0), cfg)))
+    state = on_chip(jax.eval_shape(
+        lambda: M.init_latent_cache(cfg, slots, 64, blocks)))
+    return M, cfg, params, state, on_chip
+
+
+@pytest.mark.parametrize("window", [4096, 24576])
+def test_dense_latent_decode_step_walks_the_pool_in_place(
+        v5e, monkeypatch, window):
+    """The decode step of a latent model without an indexer (96 slots as
+    `dsv2_longgen` has them, a pool of 2048 blocks of 64): one Mosaic
+    kernel a layer walks the plane where it lies. Nothing of the plane's
+    size is computed but the in-place scatters and the fusions that wrap
+    them; nothing of a read window's size either, gathered, sliced or
+    copied a slot (96 slots x the window x 640 is what ``window_rows``
+    would make: 3.0 GB a layer at 24 k; an eighth of it is the line);
+    the temporaries stay under a hundredth of it; the state holds one
+    plane."""
+    slots = 96
+    M, cfg, params, state, on_chip = _dense_latent_shapes(v5e, slots, 2048)
+    assert sorted(state) == ["ckv", "len", "table"]
+    # trace-time routing asks the backend: compiled, not interpreted
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = jax.jit(
+        M.latent_decode_step, static_argnums=(1, 5), donate_argnums=(2,)
+    ).lower(params, cfg, state, on_chip(jnp.zeros((slots,), jnp.int32)),
+            on_chip(jnp.zeros((slots,), bool)), window).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == cfg.n_layers
+    ops = _pool_plane_ops(text, state["ckv"])
+    assert set(ops) <= POOL_SIZED_OK, ops
+    assert ops["scatter"] == ops["fusion"] == cfg.n_layers, ops
+    a_window = slots * window * cfg.stored_width
+    sized = count_pool_sized_ops(text, a_window // 8)
+    assert not {"gather", "dynamic-slice", "slice", "reshape", "transpose",
+                "concatenate"} & set(sized), sized
+    # what else is that large at the 4 k window is a weight on its way (a
+    # prefetch, or ``wq_b`` laid out for its product: PERF.md section 7)
+    for shape in re.findall(r" = \w+\[([0-9,]+)\]\S* copy\(", text):
+        assert slots not in [int(d) for d in shape.split(",")][:1] \
+            or math.prod(int(d) for d in shape.split(",")) < a_window // 8
+    assert compiled.memory_analysis().temp_size_in_bytes < a_window * 2 // 100
+
+
 def test_latent_chunk_expands_its_window_a_group_of_heads_at_a_time(v5e):
     """A 512-token chunk at the published widths over the 32768 window
     (the largest of the five programs `dsv32_longctx` warms; the same two

@@ -2,6 +2,7 @@
 step, the programs' names, the loop's phases as spans, the warm-up's clock
 and the prefill-token counter (ISSUE 25)."""
 
+import dataclasses
 import re
 import time
 
@@ -32,6 +33,11 @@ LATENT = LatentConfig(
     kv_rank=16, nope_dim=8, rope_dim=4, v_dim=8, index_heads=2, index_dim=8,
     index_topk=4, n_experts=8, held=(2, 4), top_k=2, n_group=2, topk_group=1,
     max_seq=32, dtype=jnp.float32)
+# the same family without an indexer (DeepSeek-V2's block): no ``indexer``
+# and no ``select`` in any of its programs, the walk under ``latent_attn``
+LATENT_DENSE = dataclasses.replace(
+    LATENT, index_heads=0, index_dim=0, index_topk=0, d_ff_shared=32,
+    topk_method="group_limited_greedy")
 HYBRID = HybridConfig(
     vocab=64, d_model=32, layer_types=("mamba", "attention", "mamba"),
     n_heads=4, n_kv_heads=2, head_dim=64, d_ff=64, ssm_heads=4,
@@ -45,6 +51,7 @@ SSM = {"attn", "ssm_conv", "ssm_scan", "ssm_gate", "mlp"}
 # three parts nested under ``attn`` (the name vbench/scopes.py knows)
 SPARSE_ATTN = {"attn", "indexer", "select", "latent_attn", "mlp", "route",
                "experts"}
+DENSE_ATTN = SPARSE_ATTN - {"indexer", "select"}
 ROUTE = {"kernel": {"pool_relayout", "paged_attn"}, "gather": {"gather_attn"},
          None: {"attn"}}
 TRUNK = {"embed", "qkv", "kv_write", "o_proj", "lm_head"}
@@ -58,9 +65,10 @@ def _engine(family: str, route, **serving):
                         **paged, **serving)
     if family == "dense":
         return ServingEngine(init_params(jax.random.key(0), DENSE), DENSE, cfg)
-    if family == "latent":
+    if family in ("latent", "latent_dense"):
+        mc = LATENT if family == "latent" else LATENT_DENSE
         model = LatentSlotModel(
-            init_latent_params(jax.random.key(0), LATENT), LATENT,
+            init_latent_params(jax.random.key(0), mc), mc,
             kv_page=cfg.kv_page)
         return ServingEngine(serving=cfg, model=model)
     if family == "hybrid":
@@ -115,6 +123,9 @@ CASES = [
     ("latent", "paged", "decode", TRUNK | SPARSE_ATTN | {"sample"}),
     ("latent", "paged", "admit", TRUNK | SPARSE_ATTN | {"sample"}),
     ("latent", "paged", "chunk", TRUNK | SPARSE_ATTN),
+    ("latent_dense", "paged", "decode", TRUNK | DENSE_ATTN | {"sample"}),
+    ("latent_dense", "paged", "admit", TRUNK | DENSE_ATTN | {"sample"}),
+    ("latent_dense", "paged", "chunk", TRUNK | DENSE_ATTN),
     ("hybrid", "kernel", "decode", TRUNK | SSM | ROUTE["kernel"]
      | {"sample"}),
     ("hybrid", "gather", "decode", TRUNK | SSM | ROUTE["gather"]
@@ -165,6 +176,13 @@ def test_kernels_are_named():
         q, pool[0, :2].reshape(2, PAGE, 2, 16),
         pool[0, :2].reshape(2, PAGE, 2, 16), lens))()
     assert "paged_attn" in str(paged) and "decode_attn" in str(dense)
+    from vtpu.ops.decode_attn import latent_decode_attention
+
+    walk = jax.make_jaxpr(lambda: latent_decode_attention(
+        jnp.zeros((2, 2, 128), jnp.float32),
+        jnp.zeros((2, 5, PAGE, 128), jnp.float32), table, lens[:, 0], 0,
+        64, 1.0))()
+    assert "latent_walk" in str(walk)
 
 
 def test_phase_notes_what_note_noted():

@@ -1,5 +1,6 @@
-"""Latent attention under a learned sparse selection, over two stacks of
-layers: the third model family with a paged cache (DeepSeek-V3.2's block).
+"""Latent attention, under a learned sparse selection or over all that is
+cached, over two stacks of layers: the third model family with a paged
+cache (DeepSeek-V3.2's block, and DeepSeek-V2's).
 
 What differs from ``transformer.py`` / ``moe.py``, by mechanism:
 
@@ -20,11 +21,19 @@ What differs from ``transformer.py`` / ``moe.py``, by mechanism:
   window's latents through both up-projections once a layer (the expanded
   form) and attends a head 192 and 128 wide under the selection's mask.
   Which form runs is read off the shapes in ``vtpu/ops/latent.py``.
+- **A model without an indexer attends all it has cached**
+  (``index_heads`` 0: DeepSeek-V2). It has no ``idx_*`` leaf, no ``ik``
+  plane and no indexer scope; a decode step walks its slots' live pages
+  in the latent plane (``ops/decode_attn.latent_decode_attention``), so
+  its attention grows with the context; a chunk masks causally.
 - **Two stacks walked in order**: ``params["dense"]`` (the leading layers,
   a SwiGLU each) and ``params["sparse"]`` (a router ``n_experts`` wide, a
   shared expert, and the stacks of the experts *held here*, ``held =
   (first, count)``: this chip's share of a layer that a deployment divides
-  over several; what the absent experts would add is left out).
+  over several; what the absent experts would add is left out). The
+  router is the model's own (``topk_method``: V3's ``noaux_tc``,
+  ``moe.grouped_route``; V2's ``group_limited_greedy``,
+  ``moe.group_limited_route``).
 - An untied output head, an epsilon that is the configuration's, YaRN's
   frequencies. Two projections are held as a decode step's products read
   them, so that no step lays a weight out anew: ``wq_b [heads * (nope +
@@ -50,7 +59,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from vtpu.models.moe import grouped_route, held_experts_ffn
+from vtpu.models.moe import (
+    group_limited_route, grouped_route, held_experts_ffn)
 from vtpu.models.transformer import _embed
 from vtpu.ops import rms_norm, scaled_normal, yarn_rope_angles
 from vtpu.ops.latent import sparse_latent_attention, write_rows
@@ -68,13 +78,15 @@ class LatentConfig:
     n_dense_layers: int = 1
     n_sparse_layers: int = 2
     d_ff: int = 128           # the dense layers' SwiGLU width
-    d_ff_expert: int = 32     # a routed expert's, and the shared expert's
+    d_ff_expert: int = 32     # a routed expert's
+    d_ff_shared: int | None = None  # the shared experts' as one SwiGLU
+    #                           (None: one routed expert's, V3.2; V2: 3072)
     q_rank: int = 48
     kv_rank: int = 32
     nope_dim: int = 16        # a head's part without position
     rope_dim: int = 8         # ... and its rotated part (one key head)
     v_dim: int = 16
-    index_heads: int = 4
+    index_heads: int = 4      # 0: no indexer, every cached latent attended
     index_dim: int = 16
     index_topk: int = 16
     n_experts: int = 16       # the router's width: the whole layer's experts
@@ -83,6 +95,7 @@ class LatentConfig:
     n_group: int = 4
     topk_group: int = 2
     route_scale: float = 2.5
+    topk_method: str = "noaux_tc"  # or "group_limited_greedy" (V2's router)
     max_seq: int = 256
     rope_theta: float = 10000.0
     yarn_factor: float = 40.0
@@ -101,6 +114,12 @@ class LatentConfig:
     @property
     def latent_width(self) -> int:
         return self.kv_rank + self.rope_dim
+
+    @property
+    def has_indexer(self) -> bool:
+        """Whether attention reads a selection (an indexer's best
+        ``index_topk``) or, with no indexer, all that is cached."""
+        return self.index_heads > 0
 
     def _mscale(self, m: float) -> float:
         return 0.1 * m * math.log(self.yarn_factor) + 1.0 \
@@ -126,8 +145,8 @@ class LatentConfig:
     @property
     def kv_bytes_per_token(self) -> int:
         """Pool bytes one cached token costs across all layers."""
-        return (self.n_layers * (self.stored_width + self.index_dim)
-                * jnp.dtype(self.dtype).itemsize)
+        row = self.stored_width + (self.index_dim if self.has_indexer else 0)
+        return self.n_layers * row * jnp.dtype(self.dtype).itemsize
 
     def rope_tables(self) -> tuple[jax.Array, jax.Array]:
         return yarn_rope_angles(
@@ -142,13 +161,14 @@ def init_latent_params(rng: jax.Array, cfg: LatentConfig) -> Params:
     d, h, rq, rkv = cfg.d_model, cfg.n_heads, cfg.q_rank, cfg.kv_rank
     dn, dr, dv = cfg.nope_dim, cfg.rope_dim, cfg.v_dim
     hi, di, f, e = cfg.index_heads, cfg.index_dim, cfg.d_ff_expert, cfg.n_experts
+    fs = cfg.d_ff_shared or f
     keys = iter(jax.random.split(rng, 64))
 
     def w(shape, fan_in, dtype=None):
         return scaled_normal(next(keys), shape, fan_in, dtype or cfg.dtype)
 
     def attn(l):
-        return {
+        leaves = {
             "attn_norm": jnp.ones((l, d), cfg.dtype),
             "wq_a": w((l, d, rq), d),
             "q_norm": jnp.ones((l, rq), cfg.dtype),
@@ -158,13 +178,16 @@ def init_latent_params(rng: jax.Array, cfg: LatentConfig) -> Params:
             "w_uk": w((l, h, dn, rkv), rkv),
             "w_uv": w((l, h, rkv, dv), rkv),
             "wo": w((l, h * dv, d), h * dv),
-            "idx_wq": w((l, hi * di, rq), rq),
-            "idx_wk": w((l, d, di), d),
-            "idx_k_gain": jnp.ones((l, di), cfg.dtype),
-            "idx_k_bias": w((l, di), 16.0),
-            "idx_w": w((l, d, hi), d),
-            "mlp_norm": jnp.ones((l, d), cfg.dtype),
         }
+        if cfg.has_indexer:
+            leaves.update({
+                "idx_wq": w((l, hi * di, rq), rq),
+                "idx_wk": w((l, d, di), d),
+                "idx_k_gain": jnp.ones((l, di), cfg.dtype),
+                "idx_k_bias": w((l, di), 16.0),
+                "idx_w": w((l, d, hi), d),
+            })
+        return {**leaves, "mlp_norm": jnp.ones((l, d), cfg.dtype)}
 
     ld, ls, held = cfg.n_dense_layers, cfg.n_sparse_layers, cfg.held[1]
     return {
@@ -177,31 +200,35 @@ def init_latent_params(rng: jax.Array, cfg: LatentConfig) -> Params:
                   "w_down": w((ld, cfg.d_ff, d), cfg.d_ff)},
         "sparse": {**attn(ls),
                    "router": w((ls, d, e), d, jnp.float32),
-                   "route_bias": w((ls, e), 400.0, jnp.float32),
+                   **({"route_bias": w((ls, e), 400.0, jnp.float32)}
+                      if cfg.topk_method == "noaux_tc" else {}),
                    "w_gate": w((ls, held, d, f), d),
                    "w_up": w((ls, held, d, f), d),
                    "w_down": w((ls, held, f, d), f),
-                   "ws_gate": w((ls, d, f), d),
-                   "ws_up": w((ls, d, f), d),
-                   "ws_down": w((ls, f, d), f)},
+                   "ws_gate": w((ls, d, fs), d),
+                   "ws_up": w((ls, d, fs), d),
+                   "ws_down": w((ls, fs, d), fs)},
     }
 
 
 def init_latent_cache(cfg: LatentConfig, slots: int, page: int,
                       n_blocks: int) -> dict[str, jax.Array]:
     """The paged state: the latent plane, the indexer's key plane beside
-    it, and ``table`` / ``len`` as ``init_paged_kv_cache`` lays them. Block
-    0 is the null block (never handed out; unmapped entries point at it)."""
+    it where the model has an indexer, and ``table`` / ``len`` as
+    ``init_paged_kv_cache`` lays them. Block 0 is the null block (never
+    handed out; unmapped entries point at it)."""
     if cfg.max_seq % page:
         raise ValueError(f"kv page {page} must divide max_seq {cfg.max_seq}")
-    return {
+    state = {
         "table": jnp.zeros((slots, cfg.max_seq // page), jnp.int32),
         "len": jnp.zeros((slots,), jnp.int32),
         "ckv": jnp.zeros((cfg.n_layers, n_blocks, page, cfg.stored_width),
                          cfg.dtype),
-        "ik": jnp.zeros((cfg.n_layers, n_blocks, page, cfg.index_dim),
-                        cfg.dtype),
     }
+    if cfg.has_indexer:
+        state["ik"] = jnp.zeros(
+            (cfg.n_layers, n_blocks, page, cfg.index_dim), cfg.dtype)
+    return state
 
 
 # ------------------------------------------------------------- the block
@@ -240,14 +267,17 @@ def _swiglu(x, w_gate, w_up, w_down):
 
 
 def _attention(cfg: LatentConfig, lp, l: int, x, ckv, ik, rope, positions,
-               tables, wblk, woff, given):
+               tables, wblk, woff, given, lens):
     """One layer's attention half over x [N, T, D]: the latent and indexer
     projections, their rows written into layer ``l`` of the two planes at
     (wblk, woff) (out-of-range block ids drop), the selection, attention
-    over the selected rows, the output projection and the residual. In
-    which form attention runs (absorbed in the latent space, as a decode
-    step's; or over the window expanded into a head's keys and values, as
-    a chunk's) is ``sparse_latent_attention``'s to choose from the shapes:
+    over the selected rows, the output projection and the residual (a
+    model without an indexer: no second plane, no selection, attention
+    over all that is cached; ``lens`` is what a decode step's slots read).
+    In which form attention runs (absorbed in the latent space, as a
+    decode step's; or over the window expanded into a head's keys and
+    values, as a chunk's) is ``sparse_latent_attention``'s to choose from
+    the shapes:
     it takes the query's parts and both up-projections and returns a
     head's values. Returns (x, ckv, ik, the selection: window indices
     [N, T, K] or a mask [N, T, W])."""
@@ -269,21 +299,25 @@ def _attention(cfg: LatentConfig, lp, l: int, x, ckv, ik, rope, positions,
         ckv = write_rows(ckv, l, wblk, woff, jnp.pad(
             latent, ((0, 0), (0, 0), (0, cfg.stored_width - cfg.latent_width))))
     with jax.named_scope("attn"):  # vbench/scopes.py's name for all of it
-        with jax.named_scope("indexer"):
-            q_i = _rope_head(
-                jnp.einsum("ntr,qr->ntq", c_q, lp["idx_wq"]).reshape(
-                    n_, t_, cfg.index_heads, cfg.index_dim),
-                cos, sin, positions, dr)
-            k_i = _rope_head(_layer_norm(
-                n @ lp["idx_wk"], lp["idx_k_gain"], lp["idx_k_bias"], cfg.eps),
-                cos, sin, positions, dr)
-            w_i = (n @ lp["idx_w"]).astype(jnp.float32) * (
-                cfg.index_heads ** -0.5 * cfg.index_dim ** -0.5)
-            ik = write_rows(ik, l, wblk, woff, k_i)
+        if cfg.has_indexer:
+            with jax.named_scope("indexer"):
+                q_i = _rope_head(
+                    jnp.einsum("ntr,qr->ntq", c_q, lp["idx_wq"]).reshape(
+                        n_, t_, cfg.index_heads, cfg.index_dim),
+                    cos, sin, positions, dr)
+                k_i = _rope_head(_layer_norm(
+                    n @ lp["idx_wk"], lp["idx_k_gain"], lp["idx_k_bias"],
+                    cfg.eps), cos, sin, positions, dr)
+                w_i = (n @ lp["idx_w"]).astype(jnp.float32) * (
+                    cfg.index_heads ** -0.5 * cfg.index_dim ** -0.5)
+                ik = write_rows(ik, l, wblk, woff, k_i)
+            topk = cfg.index_topk
+        else:
+            q_i = w_i = topk = None
         attn, idx = sparse_latent_attention(
             ckv, ik, l, tables, positions, q[..., :dn], q_pe, lp["w_uk"],
-            lp["w_uv"], q_i, w_i, cfg.index_topk, cfg.attn_scale,
-            given=given)
+            lp["w_uv"], q_i, w_i, topk, cfg.attn_scale, given=given,
+            lens=lens)
     with jax.named_scope("o_proj"):
         x = x + attn.reshape(n_, t_, h * dv) @ lp["wo"]
     return x, ckv, ik, idx
@@ -301,9 +335,15 @@ def _sparse_ffn(cfg: LatentConfig, lp, x):
     first, count = cfg.held
     with jax.named_scope("route"):
         n = rms_norm(x, lp["mlp_norm"], cfg.eps).reshape(-1, shape[-1])
-        gates = grouped_route(
-            lp["router"], lp["route_bias"], n, cfg.top_k, cfg.n_group,
-            cfg.topk_group, cfg.route_scale)[:, first:first + count]
+        if cfg.topk_method == "noaux_tc":
+            gates = grouped_route(
+                lp["router"], lp["route_bias"], n, cfg.top_k, cfg.n_group,
+                cfg.topk_group, cfg.route_scale)
+        else:
+            gates = group_limited_route(
+                lp["router"], n, cfg.top_k, cfg.n_group, cfg.topk_group,
+                cfg.route_scale)
+        gates = gates[:, first:first + count]
     with jax.named_scope("experts"):
         y = held_experts_ffn(lp, n, gates) + _swiglu(
             n, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
@@ -323,9 +363,11 @@ class _Layer:
 
 
 def _walk(params: Params, cfg: LatentConfig, ckv, ik, tokens, positions,
-          tables, wblk, woff, given=None):
+          tables, wblk, woff, given=None, lens=None):
     """Both stacks in order over tokens [N, T]: (hidden [N, T, D], ckv, ik,
-    [the selection of each layer])."""
+    [the selection of each layer]); ``ik`` is None, and stays so, for a
+    model without an indexer, whose decode step says with ``lens [N]``
+    how many rows each slot's attention reads."""
     x = _embed(params, cfg, tokens)
     with jax.named_scope("qkv"):
         rope = cfg.rope_tables()
@@ -336,7 +378,7 @@ def _walk(params: Params, cfg: LatentConfig, ckv, ik, tokens, positions,
             lp = _Layer(stack, i)
             x, ckv, ik, idx = _attention(
                 cfg, lp, l, x, ckv, ik, rope, positions, tables, wblk, woff,
-                None if given is None else given[l])
+                None if given is None else given[l], lens)
             x = ffn(cfg, lp, x)
             selected.append(idx)
             l += 1
@@ -353,6 +395,12 @@ def _head(params: Params, cfg: LatentConfig, x: jax.Array) -> jax.Array:
 # -------------------------------------------------------- the entry points
 
 
+def _planes(ckv, ik) -> dict:
+    """The pool planes of a state: the latent plane, and the indexer's
+    keys where there are any."""
+    return {"ckv": ckv} if ik is None else {"ckv": ckv, "ik": ik}
+
+
 def latent_forward(params: Params, cfg: LatentConfig, tokens: jax.Array,
                    given=None, page: int = 8):
     """Full-sequence forward over a scratch pool of its own: tokens [B, S]
@@ -367,8 +415,8 @@ def latent_forward(params: Params, cfg: LatentConfig, tokens: jax.Array,
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     wblk = jnp.take_along_axis(tables, positions // page, axis=1)
     x, _, _, selected = _walk(
-        params, cfg, cache["ckv"], cache["ik"], tokens, positions, tables,
-        wblk, positions % page, given)
+        params, cfg, cache["ckv"], cache.get("ik"), tokens, positions,
+        tables, wblk, positions % page, given)
     return _head(params, cfg, x), selected
 
 
@@ -385,10 +433,10 @@ def latent_prefill_rows(params: Params, cfg: LatentConfig, state, tokens,
     tables = state["table"][slots, :-(-s // page)]
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (n, s))
     wblk = jnp.take_along_axis(tables, positions // page, axis=1)
-    x, ckv, ik, _ = _walk(params, cfg, state["ckv"], state["ik"], tokens,
+    x, ckv, ik, _ = _walk(params, cfg, state["ckv"], state.get("ik"), tokens,
                           positions, tables, wblk, positions % page)
     last = x[jnp.arange(n), true_lens - 1]
-    new = {**state, "ckv": ckv, "ik": ik,
+    new = {**state, **_planes(ckv, ik),
            "len": state["len"].at[slots].set(true_lens)}
     return _head(params, cfg, last), new
 
@@ -410,9 +458,9 @@ def latent_prefill_chunk(params: Params, cfg: LatentConfig, state, chunk,
     wblk = jnp.take(block_ids, positions // page, mode="fill",
                     fill_value=n_blocks)
     x, ckv, ik, _ = _walk(
-        params, cfg, state["ckv"], state["ik"], chunk, positions,
+        params, cfg, state["ckv"], state.get("ik"), chunk, positions,
         block_ids[None, :window // page], wblk, positions % page, given)
-    new = {**state, "ckv": ckv, "ik": ik,
+    new = {**state, **_planes(ckv, ik),
            "len": state["len"].at[slot].set(new_len, mode="drop")}
     return _head(params, cfg, x), new
 
@@ -422,9 +470,11 @@ def latent_decode_step(params: Params, cfg: LatentConfig, state, tokens,
     """One decode tick for the whole slot pool: tokens [B], active [B] ->
     (logits [B, V], state). Each slot writes its new rows at its own
     length and reads the first ``window`` positions of its table row: the
-    indexer's keys of the whole window, the latents of the selected only.
-    An inactive slot (its table row may be stale) writes nowhere: its
-    block id is out of range and the scatter drops it."""
+    indexer's keys of the whole window, the latents of the selected only;
+    or, where nothing selects, its own ``len + 1`` rows page by page. An
+    inactive slot (its table row may be stale) writes nowhere: its block
+    id is out of range and the scatter drops it; nor does it read, where
+    the walk is told lengths."""
     page, n_blocks = state["ckv"].shape[2], state["ckv"].shape[1]
     lens = state["len"]
     rows = jnp.arange(tokens.shape[0])
@@ -432,9 +482,10 @@ def latent_decode_step(params: Params, cfg: LatentConfig, state, tokens,
     wblk = jnp.where(active & (lens < cfg.max_seq),
                      state["table"][rows, here], n_blocks)
     x, ckv, ik, _ = _walk(
-        params, cfg, state["ckv"], state["ik"], tokens[:, None],
+        params, cfg, state["ckv"], state.get("ik"), tokens[:, None],
         lens[:, None], state["table"][:, :window // page], wblk[:, None],
-        (lens % page)[:, None], given)
-    new = {**state, "ckv": ckv, "ik": ik,
+        (lens % page)[:, None], given,
+        None if cfg.has_indexer else jnp.where(active, lens + 1, 0))
+    new = {**state, **_planes(ckv, ik),
            "len": jnp.where(active, lens + 1, lens)}
     return _head(params, cfg, x[:, 0]), new

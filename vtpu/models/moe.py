@@ -156,6 +156,36 @@ def grouped_route(
     return weights / jnp.sum(weights, axis=-1, keepdims=True) * scale
 
 
+def group_limited_route(
+    router_w: jax.Array, x: jax.Array, top_k: int, n_group: int,
+    topk_group: int, scale: float,
+) -> jax.Array:
+    """Group-limited greedy routing with softmax scores (DeepSeek-V2's
+    router, ``topk_method`` ``group_limited_greedy``) over flat tokens x:
+    [T, D], router_w [D, E] in float32.
+
+    A token's affinity to an expert is ``s = softmax(x . w)`` over all E.
+    The experts lie in ``n_group`` equal groups (the devices that hold
+    them), a group scores its **best** ``s`` (V3's ``grouped_route`` sums
+    the two best of ``sigmoid + bias``), the ``topk_group`` best groups are
+    kept and the ``top_k`` largest ``s`` among the kept groups are chosen.
+    A chosen expert weighs ``scale * s``: no bias, and no division by the
+    chosen scores' sum (``norm_topk_prob`` false). Returns gates [T, E]
+    float32 in ``grouped_route``'s form, zero at every expert not chosen,
+    so ``held_experts_ffn`` reads its own columns. Nothing is dropped."""
+    t, e = x.shape[0], router_w.shape[1]
+    s = jax.nn.softmax(jnp.matmul(
+        x.astype(jnp.float32), router_w,
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    groups = s.reshape(t, n_group, e // n_group)
+    _, kept = jax.lax.top_k(jnp.max(groups, axis=-1), topk_group)  # [T, g]
+    keep = jnp.sum(jax.nn.one_hot(kept, n_group, dtype=jnp.float32), axis=1)
+    choice = jnp.where(keep[:, :, None] > 0, groups, -jnp.inf).reshape(t, e)
+    _, chosen = jax.lax.top_k(choice, top_k)  # [T, k]
+    hot = jnp.sum(jax.nn.one_hot(chosen, e, dtype=jnp.float32), axis=1)
+    return s * hot * scale
+
+
 def held_experts_ffn(lp_e: dict[str, jax.Array], x: jax.Array,
                      gates: jax.Array) -> jax.Array:
     """This holder's part of an expert layer's result: the experts whose

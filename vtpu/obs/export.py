@@ -101,6 +101,13 @@ COUNTERS = {
                             "their window expanded into a head's keys and "
                             "values (made once a layer) rather than in "
                             "the latent space"),
+    "latent_rows_live": ("latent_rows_live",
+                         "Cached tokens the dispatched slots could see, "
+                         "summed over decode ticks (models whose decode "
+                         "step walks a latent plane)"),
+    "latent_rows_walked": ("latent_rows_walked",
+                           "Rows that walk copied: every slot's live "
+                           "pages whole, one page for an idle slot"),
     "ssm_rows_stepped": ("ssm_rows_stepped",
                          "Slot rows of recurrent state the decode ticks "
                          "updated: every slot's, the step's shape (models "

@@ -970,6 +970,149 @@ def _check_pool(q: jax.Array, pool: jax.Array, table: jax.Array) -> None:
 
 
 # --------------------------------------------------------------------------
+# The walk over a latent plane (DeepSeek-V2's decode step: every cached
+# latent attended, no selection). A cached token is ONE row of the plane
+# ``[L, n_blocks, page, stored]``, shared by all heads: key (its first
+# ``width`` columns, the normed latent and the rotated part) and value (its
+# first ``rank`` columns) at once. The walk is ``_grouped_kernel``'s: one
+# grid step a slot, the slot's live pages copied out of the plane in HBM a
+# group at a time, double-buffered; a slot's H absorbed queries are one
+# (H, stored) tile, so a group's rows are read once for both products, both
+# on the MXU. Nothing of a window's or the plane's size is made around it.
+
+_LATENT_GROUP_TOKENS = 1024
+
+
+def _latent_kernel(lay_ref, tbl_ref, len_ref, q_ref, pool_hbm, o_ref,
+                   buf, sems, turn_ref, m_ref, d_ref, acc_ref, *,
+                   scale: float, page: int, group: int, rank: int):
+    """One slot a grid step: its H queries (H, stored) against its live
+    pages' rows, ``group`` pages a wait into one of two VMEM buffers
+    (group * page, stored), the next group (or the next slot's first) in
+    flight meanwhile. Scores (H, group * page) in float32 under the running
+    maximum and sum; the probabilities against the rows' first ``rank``
+    columns. The buffers are zeroed once: a group's unfilled pages are
+    masked, and what is masked must still be finite. A slot with nothing
+    to read (length 0: idle, or still admitting) visits one page and
+    gives a finite row that nobody reads."""
+    b, nb = pl.program_id(0), pl.num_programs(0)
+    width = group * page
+    lay = lay_ref[0]
+    live = functools.partial(_live_pages, len_ref, t=1, page=page)
+
+    def copy_group(row, g, slot, wait: bool):
+        n = jnp.minimum(live(row) - g * group, group)
+
+        def one(i, _):
+            blk = tbl_ref[row, g * group + i]
+            at = pl.ds(pl.multiple_of(i * page, page), page)
+            dma = pltpu.make_async_copy(
+                pool_hbm.at[lay, blk], buf.at[slot, at], sems.at[slot])
+            if wait:
+                dma.wait()
+            else:
+                dma.start()
+            return _
+
+        jax.lax.fori_loop(0, n, one, 0)
+
+    @pl.when(b == 0)
+    def _first():
+        turn_ref[0] = 0
+        buf[...] = jnp.zeros(buf.shape, buf.dtype)
+        copy_group(0, 0, 0, wait=False)
+
+    m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, m_ref.dtype)
+    d_ref[...] = jnp.zeros(d_ref.shape, d_ref.dtype)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
+    n_groups = pl.cdiv(live(b), group)
+    turn = turn_ref[0]
+    q = q_ref[0]                                          # (H, stored)
+    length = len_ref[b, 0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+
+    def attend_group(g, _):
+        slot = (turn + g) % 2
+        last = g + 1 == n_groups
+
+        @pl.when(jnp.logical_not(last) | (b + 1 < nb))
+        def _prefetch():
+            copy_group(jnp.where(last, jnp.minimum(b + 1, nb - 1), b),
+                       jnp.where(last, 0, g + 1), 1 - slot, wait=False)
+
+        copy_group(b, g, slot, wait=True)
+        s = jax.lax.dot_general(
+            q, buf[slot], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale    # (H, width)
+        s = jnp.where(g * width + lane < length, s, _NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        d_ref[...] = d_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[...] = m_new
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p.astype(buf.dtype), buf[slot, :, :rank],
+            preferred_element_type=jnp.float32)
+        return _
+
+    jax.lax.fori_loop(0, n_groups, attend_group, 0)
+    turn_ref[0] = (turn + n_groups) % 2
+    o_ref[0] = (acc_ref[...] / d_ref[...]).astype(o_ref.dtype)
+
+
+def latent_decode_attention(q: jax.Array, pool: jax.Array, table: jax.Array,
+                            lens: jax.Array, layer, rank: int, scale: float,
+                            interpret: bool | None = None) -> jax.Array:
+    """A decode step's attention over a latent plane, walked in place.
+
+    q ``[B, H, stored]``: a slot's absorbed queries, a head's query through
+    its key up-projection beside its rotated part, zeros in the columns the
+    plane's rows are padded with. pool: the WHOLE plane ``[L, n_blocks,
+    page, stored]``, never a layer's slice. table ``[B, Wp]`` and ``layer``
+    as ``paged_decode_attention`` takes them; lens ``[B]``: the rows a slot
+    reads (its first ``lens`` positions; 0 reads nothing). Returns the
+    probabilities' mix of the rows' first ``rank`` columns, ``[B, H,
+    rank]``, for the caller's value up-projection. The kernel is
+    ``latent_walk`` in a trace, under the caller's scope."""
+    b, h, stored = q.shape
+    n_layers, n_blocks, page, _ = pool.shape
+    if pool.shape[3] != stored or table.shape[0] != b:
+        raise ValueError(
+            f"queries {q.shape} and table {table.shape} do not fit the "
+            f"plane {pool.shape}")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    wp = table.shape[1]
+    lens = jnp.minimum(lens.astype(jnp.int32), wp * page)[:, None]
+    group = max(1, min(_LATENT_GROUP_TOKENS // page, wp))
+    q_spec = pl.BlockSpec((1, h, stored), lambda i, *_: (i, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, scale=scale, page=page,
+                          group=group, rank=rank),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,  # layer index, table, lengths
+            grid=(b,),
+            in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, h, rank), lambda i, *_: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, group * page, stored), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),  # a buffer slot each
+                pltpu.SMEM((1,), jnp.int32),    # which buffer slot is next
+                pltpu.VMEM((h, 1), jnp.float32),     # maximum
+                pltpu.VMEM((h, 1), jnp.float32),     # denominator
+                pltpu.VMEM((h, rank), jnp.float32),  # accumulator
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, h, rank), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_GROUP_VMEM_BYTES + (24 << 20)),
+        interpret=interpret,
+        name="latent_walk",
+    )(_layer_arr(layer), table, lens, q, pool)
+
+
+# --------------------------------------------------------------------------
 # HLO audits: prove the pool gather, and every other pool-sized result,
 # disappeared from a compiled step.
 

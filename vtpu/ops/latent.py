@@ -4,21 +4,27 @@ tokens a query, and attention over the selected: a decode step's in the
 latent space (the absorbed form) over rows gathered from a paged pool, a
 prefill chunk's over its whole window under the selection's mask, the
 window expanded into a head's keys and values once a layer
-(``sparse_latent_attention`` reads which off the shapes).
+(``sparse_latent_attention`` reads which off the shapes). And the same
+attention with no selection (DeepSeek-V2's block: every query attends
+every cached latent): a decode step walks its slots' live pages in the
+pool (``vtpu.ops.decode_attn.latent_decode_attention``), a chunk attends
+its window under the causal mask alone.
 
 Shapes, throughout: N sequences (decode: the slots; a prefill chunk: 1), T
 queries a sequence (decode: 1; a chunk: its tokens), a read window of W
 cached positions walked through ``tables [N, W // page]`` of pool block
 ids. A pool plane is ``[L, n_blocks, page, R]``: R = latent rank + rotary
 width for the latent plane, the indexer's head width for its keys.
-Everything is plain XLA; each part runs under the scope that
-``vtpu.ops.SCOPES`` names for it.
+Everything but that walk is plain XLA; each part runs under the scope
+that ``vtpu.ops.SCOPES`` names for it.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from vtpu.ops.decode_attn import latent_decode_attention
 
 _NEG = float("-inf")
 
@@ -220,8 +226,8 @@ def sparse_latent_attention(ckv: jax.Array, ik: jax.Array, l: int,
                             tables: jax.Array, positions: jax.Array,
                             q_nope: jax.Array, q_pe: jax.Array,
                             w_uk: jax.Array, w_uv: jax.Array,
-                            q_idx: jax.Array, w_idx: jax.Array, topk: int,
-                            scale: float, given=None) -> tuple:
+                            q_idx: jax.Array, w_idx: jax.Array, topk,
+                            scale: float, given=None, lens=None) -> tuple:
     """Layer ``l``'s selection and attention for ``[N, T]`` queries at
     ``positions`` over the window that ``tables`` maps, both planes whole
     (``[L, n_blocks, page, R]``), read as they are stored. A head's query
@@ -249,11 +255,19 @@ def sparse_latent_attention(ckv: jax.Array, ik: jax.Array, l: int,
     form's products and a third of its bytes, 40 % less time at every
     window from 4 k to 24 k (PERF.md, section 6, PR 34).
 
+    **No selection** (``topk`` None: a model without an indexer, whose
+    queries attend every cached latent; ``ik``, ``q_idx``, ``w_idx`` None
+    with it): a decode step walks each slot's first ``lens [N]`` rows (all
+    up to its position unless told: 0 skips a slot) page by page in the
+    pool, absorbed, a row read once for both products
+    (``latent_decode_attention``: no window is gathered); a chunk attends
+    its window in the same two forms under the causal mask alone.
+
     ``given`` ([N, T, K] window indices; those past a query's position do
     not count) takes the selection's place (tests hold the two sides to
     one selection with it). Returns (a head's values [N, T, H, dv], the
     selection: indices [N, T, K] from the gathering route, the mask
-    [N, T, W] from the masking one)."""
+    [N, T, W] from the masking one, None where nothing selects)."""
     t, rank = positions.shape[1], w_uk.shape[-1]
     width = rank + q_pe.shape[-1]  # a stored row may be padded
     expand = expands_window(t, rank, q_nope.shape[-1], w_uv.shape[-1])
@@ -261,25 +275,49 @@ def sparse_latent_attention(ckv: jax.Array, ik: jax.Array, l: int,
         with jax.named_scope("qkv"):  # where the step's trace has it
             q_abs = jnp.einsum("nthd,hdr->nthr", q_nope, w_uk)
     keys = None
-    if given is None:
+    if given is None and topk is not None:
         with jax.named_scope("indexer"):
             keys = window_rows(ik, l, tables)
-    if t == 1:
+    if t == 1 and topk is None:
+        with jax.named_scope("latent_attn"):
+            if lens is None:
+                lens = positions[:, 0] + 1
+            mixed, chosen = _walking(ckv, l, tables, lens, q_abs, q_pe,
+                                     scale), None
+    elif t == 1:
         mixed, chosen = _gathering(
             ckv, l, tables, keys, (positions, q_abs, q_pe, q_idx, w_idx),
             given, width, topk, scale)
     else:
         with jax.named_scope("latent_attn"):
             window = window_rows(ckv, l, tables)[..., :width]
-        chosen = _selection(keys, positions, q_idx, w_idx, given, topk,
-                            window.shape[1])
+        if topk is None:
+            with jax.named_scope("latent_attn"):
+                keep = (jnp.arange(window.shape[1], dtype=jnp.int32)
+                        <= positions[..., None])
+        else:
+            keep = _selection(keys, positions, q_idx, w_idx, given, topk,
+                              window.shape[1])
+        chosen = None if topk is None else keep
         with jax.named_scope("latent_attn"):
             if expand:
-                return _expanded(q_nope, q_pe, window, chosen, w_uk, w_uv,
+                return _expanded(q_nope, q_pe, window, keep, w_uk, w_uv,
                                  scale), chosen
-            mixed = _absorbed(q_abs, q_pe, window, chosen, scale)
+            mixed = _absorbed(q_abs, q_pe, window, keep, scale)
     with jax.named_scope("o_proj"):
         return jnp.einsum("nthr,hrv->nthv", mixed, w_uv), chosen
+
+
+def _walking(ckv, l, tables, lens, q_abs, q_pe, scale):
+    """The decode step's route where nothing selects: a slot's queries as
+    one tile ``[H, stored]`` (absorbed part, rotated part, zeros against a
+    row's padding) and the walk of its live pages in the plane."""
+    n, _, heads, rank = q_abs.shape
+    pad = ckv.shape[-1] - rank - q_pe.shape[-1]
+    q = jnp.concatenate(
+        [q_abs, q_pe, jnp.zeros((n, 1, heads, pad), q_abs.dtype)], axis=-1)
+    return latent_decode_attention(
+        q[:, 0], ckv, tables, lens, l, rank, scale)[:, None]
 
 
 def _gathering(ckv, l, tables, keys, queries, given, width, topk, scale):

@@ -547,18 +547,21 @@ class SsmSlotModel:
 
 
 class LatentSlotModel:
-    """Latent attention under a learned sparse selection, over two stacks
-    of layers (vtpu/models/latent): a paged pool of latents with the
-    indexer's key pool beside it, both walked by the one page table, so the
-    engine's allocator, chunked admission and sampler serve it as they
-    serve the other two families.
+    """Latent attention, under a learned sparse selection or over all that
+    is cached, over two stacks of layers (vtpu/models/latent): a paged
+    pool of latents, with the indexer's key pool beside it where the model
+    has an indexer, walked by the one page table, so the engine's
+    allocator, chunked admission and sampler serve it as they serve the
+    other families.
 
     It states what the engine cannot know of it: ``read_windows`` (a
     32 k context needs read windows where no whole-prompt bucket exists),
-    ``kv_bytes_per_token`` (a latent row and an indexer key a layer, not
-    heads), ``attn_select_topk`` (what a decode tick reads of what it
-    sees), ``chunk_attn_expands`` (the form a chunk's attention takes at a
-    given length) and ``pool_planes``. Paged only. Not supported, and
+    ``kv_bytes_per_token`` (a latent row, and an indexer key where there
+    is one, a layer, not heads), ``attn_select_topk`` (what a decode tick
+    reads of what it sees; None: all of it, walked page by page:
+    ``walks_latent_plane``), ``chunk_attn_expands`` (the form
+    a chunk's attention takes at a given length) and ``pool_planes``. All
+    read off the model's configuration. Paged only. Not supported, and
     refused by name: a mesh, an int8 cache, speculation and the swap tier
     (a forced ``ServingConfig.paged_attn`` the engine refuses itself: there
     is one route, so ``paged_attn`` is None)."""
@@ -566,7 +569,6 @@ class LatentSlotModel:
     supports_kv_buckets = True
     mesh = None
     paged_attn = None
-    pool_planes = ("ckv", "ik")
 
     def __init__(self, params: Any, cfg: Any, kv_page: Optional[int] = None,
                  kv_pool_blocks: Optional[int] = None,
@@ -590,17 +592,21 @@ class LatentSlotModel:
         self.n_kv_blocks = None
         self.read_windows = tuple(sorted(read_windows)) if read_windows else None
         self.kv_bytes_per_token = cfg.kv_bytes_per_token
-        self.attn_select_topk = cfg.index_topk
+        selects = cfg.has_indexer
+        self.pool_planes = ("ckv", "ik") if selects else ("ckv",)
+        self.attn_select_topk = cfg.index_topk if selects else None
+        self.walks_latent_plane = not selects
 
     def check_serving(self, serving) -> None:
         """Refuse the ServingConfig options this family cannot serve."""
         if serving.spec_tokens:
             raise ValueError(
                 "LatentSlotModel has no spec_step (a verify chunk would "
-                "need a selection a draft position): set spec_tokens=0")
+                "need a selection a draft position, and the latent walk "
+                "one query a slot): set spec_tokens=0")
         if serving.kv_swap is not None:
             raise ValueError(
-                "LatentSlotModel's two pool planes have no swap staging: "
+                "LatentSlotModel's pool planes have no swap staging: "
                 "set kv_swap=None")
 
     def init_state(self, slots: int):

@@ -876,6 +876,8 @@ class ServingEngine:
         # per-tick route counters must read the same value)
         self._paged_attn = getattr(model, "paged_attn", None)
         self._select_topk = getattr(model, "attn_select_topk", None)
+        self._latent_walk = getattr(model, "walks_latent_plane", False)
+        self._chunk_expands = getattr(model, "chunk_attn_expands", None)
         # bytes of recurrent rows the state holds beside its pages (a
         # family with state-space layers says; 0 for every other)
         self._recurrent_bytes = (
@@ -1430,6 +1432,13 @@ class ServingEngine:
                        # ``chunk_attn_expands``: keys and values made from
                        # the window's latents once a layer)
                        "chunk_attn_launches": 0, "chunk_attn_expanded": 0,
+                       # a slot model whose decode step walks a latent
+                       # plane (``walks_latent_plane``): cached tokens the
+                       # dispatched slots could see, summed over decode
+                       # ticks, and the rows the walk copied for them (a
+                       # slot's live pages whole; one page for a slot that
+                       # was not dispatched); 0 for every other model
+                       "latent_rows_live": 0, "latent_rows_walked": 0,
                        # a slot model that keeps recurrent rows beside its
                        # pages (``recurrent_state_bytes``): slot rows a
                        # decode tick's state update touched (every slot's,
@@ -3387,10 +3396,10 @@ class ServingEngine:
                 budget -= c
                 self._stats["prefill_chunks"] += 1
                 self._stats["prefill_tokens"] += real
-                if self._select_topk:
+                if self._chunk_expands is not None:
                     self._stats["chunk_attn_launches"] += 1
                     self._stats["chunk_attn_expanded"] += int(
-                        self.model.chunk_attn_expands(c))
+                        self._chunk_expands(c))
                 self.trace.record("prefill_chunk", req.rid, slot, c)
                 if adm["off"] >= adm["padded"].shape[1]:  # final chunk
                     del self._admitting[slot]
@@ -3524,6 +3533,11 @@ class ServingEngine:
             page = self._page
             live = sum(-(-(ln + 1) // page) for ln in lens)
             self._stats["read_pages_live"] += live * ticks
+            if self._latent_walk:
+                self._stats["latent_rows_live"] += (
+                    sum(lens) + len(lens)) * ticks
+                self._stats["latent_rows_walked"] += page * ticks * (
+                    live + self.serving.slots - len(lens))
             self._stats["read_pages_window"] += (key // page) * len(lens) * ticks
             rh = self._stats["read_pages_hist"]
             rh[live] = rh.get(live, 0) + ticks
