@@ -30,6 +30,7 @@ from vbench.reference import hybrid as ref
 from vbench.sut import hybrid as sut
 from vtpu.models import hybrid as M
 from vtpu.models.transformer import cached_attention, init_paged_kv_cache
+from vtpu.ops import ssm_step
 from vtpu.serving import ServingConfig, ServingEngine
 from vtpu.serving.adapters import HybridSlotModel
 
@@ -172,6 +173,56 @@ def test_one_token_step_equals_the_recurrence():
         jnp.asarray(b)[None], jnp.asarray(c)[None], jnp.asarray(h0)[None])
     np.testing.assert_allclose(got_y[0], want_y, atol=1e-6)
     np.testing.assert_allclose(got_h[0], want_h, atol=1e-6)
+
+
+@pytest.fixture(params=["xla", "kernel"])
+def route(request, monkeypatch):
+    """Both routes of a step's state update: XLA's code, as every CPU run
+    takes it (None), and the kernel forced for the programs traced from here
+    on, which off a TPU runs interpreted (the list of its traced calls'
+    ``interpret``)."""
+    if request.param == "xla":
+        return None
+    calls = []
+
+    def counted(*a, **kw):
+        calls.append(kw.get("interpret"))
+        return ssm_step.ssm_state_step(*a, **kw)
+
+    monkeypatch.setattr(M, "step_in_kernel", lambda t: t == 1)
+    monkeypatch.setattr(M, "ssm_state_step", counted)
+    return calls
+
+
+@pytest.mark.parametrize("layer", [0, 2, 4])
+@pytest.mark.parametrize("tile", [2, 4])
+@pytest.mark.parametrize("slots", [3, 8])
+def test_state_kernel_equals_the_xla_step(monkeypatch, slots, tile, layer):
+    """One layer of a stack of five through the kernel (interpreted), in
+    tiles of fewer heads than the 8 there are: ``y`` and that layer of ``h``
+    equal ``_ssd_step``'s to float32 rounding, every other layer of the
+    stack and every row with ``dt == 0`` is bit-equal."""
+    monkeypatch.setattr(ssm_step, "_TILE_HEADS", tile)
+    rng = np.random.default_rng(100 * slots + 10 * tile + layer)
+    lm, h, p, n = 5, 8, 32, 16
+    x, b, c = (jnp.asarray(rng.normal(size=s).astype(np.float32))
+               for s in ((slots, 1, h, p), (slots, 1, n), (slots, 1, n)))
+    dt = rng.uniform(0.004, 0.03, (slots, 1, h)).astype(np.float32)
+    dt[1] = 0  # an inactive slot
+    dt = jnp.asarray(dt)
+    a = jnp.asarray(-rng.uniform(1, 16, h).astype(np.float32))
+    stack = jnp.asarray(rng.normal(size=(lm, slots, h, p, n)).astype(np.float32))
+    want_y, want_h = M._ssd_step(x, dt, a, b, c, stack[layer])
+    got_y, got = jax.jit(
+        lambda st, l: ssm_step.ssm_state_step(
+            st, l, *M._step_operands(x, dt, a, b, c), interpret=True)
+    )(stack, jnp.int32(layer))
+    np.testing.assert_allclose(got_y, want_y[:, 0], atol=1e-5, rtol=1e-6)
+    np.testing.assert_allclose(got[layer], want_h, atol=1e-6, rtol=1e-6)
+    for other in set(range(lm)) - {layer}:
+        np.testing.assert_array_equal(got[other], stack[other])
+    np.testing.assert_array_equal(got[layer, 1], stack[layer, 1])
+    assert not np.array_equal(got[layer, 0], stack[layer, 0])
 
 
 # ------------------------------------------- the whole model, in logits
@@ -330,7 +381,8 @@ def test_padded_rows_take_the_state_at_each_true_len(program, prompt):
         assert int(both["len"][slot]) == n
 
 
-def test_inactive_slots_rows_are_bit_equal_after_a_step(program, prompt):
+def test_inactive_slots_rows_are_bit_equal_after_a_step(
+        program, prompt, route):
     mc, params = program
     _, state = _chunked(mc, params, _fresh_state(mc), prompt, 21)
     state["h"] = state["h"].at[:, 2].set(0.625)
@@ -348,6 +400,7 @@ def test_inactive_slots_rows_are_bit_equal_after_a_step(program, prompt):
             np.asarray(after[key]) != before[key], axis=(0, 2, 3, 4)))
         assert set(changed) <= {int(BLOCKS[21 // PAGE])}
     np.testing.assert_array_equal(after["len"], before["len"] + [0, 1, 0])
+    assert route is None or (route and all(route))  # traced, interpreted
 
 
 # -------------------------------------------------- through the engine
@@ -394,10 +447,24 @@ def served_alone(program, requests):
 
 
 def test_engine_serves_the_reference_greedy_tokens(
-        program, requests, served_alone):
+        program, requests, served_alone, route):
     """Through ``submit()`` on the pipelined loop: the first token of each
-    stream is the reference's argmax at the prompt's last position."""
-    for p, got in zip(requests[:3], served_alone[:3]):
+    stream is the reference's argmax at the prompt's last position. With the
+    state kernel forced (interpreted) every decode tick counts as its, and
+    the streams are the XLA route's token for token."""
+    served = served_alone[:3]
+    if route is not None:
+        eng = _engine(program)
+        eng.start()
+        try:
+            served = [_serve(eng, [p])[0] for p in requests[:3]]
+            stats = eng.stats()
+        finally:
+            eng.stop()
+        assert route and all(route)
+        assert stats["ssm_kernel_ticks"] == stats["decode_ticks"] > 0
+        assert served == served_alone[:3]
+    for p, got in zip(requests[:3], served):
         want = _reference(p)[-1]
         assert got[0] == int(np.argmax(want))
         assert len(got) == 8
@@ -421,6 +488,7 @@ def test_eight_concurrent_requests_equal_each_served_alone(
     assert stats["recurrent_state_bytes"] == 4 * mc.recurrent_bytes_per_slot
     assert 0 < stats["ssm_rows_live"] < stats["ssm_rows_stepped"]
     assert stats["ssm_rows_stepped"] == 4 * stats["decode_ticks"]
+    assert stats["ssm_kernel_ticks"] == 0  # off a TPU a step is XLA's code
 
 
 def test_a_slot_given_to_a_new_session_equals_a_fresh_engines(

@@ -641,7 +641,10 @@ def test_hybrid_programs_compile_at_the_cells_sizes(
     whole-prompt admission of 512 compile for a v5e and fit the chip
     beside the state. The step updates the recurrent state in place: its
     temporaries stay under ONE layer's state (134 MB of the 4.83 GB), so
-    there is no second copy of ``h``."""
+    there is no second copy of ``h``; and it visits a layer's state once,
+    inside the state kernel (PR 36): the kernel takes the stack as the
+    loop carries it, so outside the custom calls nothing computes, copies,
+    slices or updates an array of a layer's or the stack's shape."""
     # trace-time routing asks the backend: the kernel, compiled, as on a chip
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     M, cfg, params, state, on_chip = _hybrid_shapes(v5e)
@@ -673,7 +676,45 @@ def test_hybrid_programs_compile_at_the_cells_sizes(
     big = count_pool_sized_ops(compiled.as_text(), math.prod(state["k"].shape))
     assert "copy" not in big and "transpose" not in big, big
     if program == "step":
-        assert compiled.as_text().count("tpu_custom_call") == 4
+        text = compiled.as_text()
+        # the paged kernel an attention layer (4) and the state kernel a
+        # run of Mamba layers (one loop body each: 5)
+        runs = [kind for kind, _, _ in M.layer_runs(cfg.layer_types)]
+        assert (runs.count("attention"), runs.count("mamba")) == (4, 5)
+        assert text.count("tpu_custom_call") == 4 + 5
         one_layer = recurrent // cfg.n_ssm_layers
         assert one_layer == 64 * 64 * 64 * 128 * 4
         assert mem.temp_size_in_bytes < one_layer, mem.temp_size_in_bytes
+        assert _state_shaped_ops(text, state["h"].shape) == []
+
+
+def _state_shaped_ops(text: str, stack: tuple) -> list:
+    """The fusions, copies, dynamic slices and dynamic updates of a compiled
+    program's text, outside its custom calls, with an operand or a result
+    of one layer's state's shape or of the stack's."""
+    shapes = tuple("f32[" + ",".join(map(str, dims)) + "]"
+                   for dims in (stack, stack[1:]))
+    visits = re.compile(
+        r" (fusion|copy|dynamic-slice|dynamic-update-slice)\(")
+    return [line.strip()[:200] for line in text.splitlines()
+            if " = " in line and visits.search(line)
+            and any(shape in line for shape in shapes)]
+
+
+def test_state_shaped_ops_finds_the_parents_three_visits():
+    """The reader of the property above, on lines as the v5e compiler wrote
+    them for the step before the kernel (a layer sliced out of the stack,
+    updated by a fusion, put back) and for the step with it."""
+    stack = (36, 64, 64, 64, 128)
+    before = """
+  %fusion.9 = f32[64,64,64,128]{3,2,1,0:T(8,128)} fusion(f32[36,64,64,64,128]{4,3,2,1,0:T(8,128)} %gte.1, s32[] %i), kind=kLoop
+  %dynamic-update-slice.4 = f32[36,64,64,64,128]{4,3,2,1,0:T(8,128)} dynamic-update-slice(%gte.1, %fusion.9, %i, %c, %c, %c, %c)
+  %copy.2 = f32[36,64,64,64,128]{4,3,2,1,0:T(8,128)} copy(%gte.1)
+"""
+    after = """
+  %get-tuple-element.3 = f32[36,64,64,64,128]{4,3,2,1,0:T(8,128)} get-tuple-element(%arg), index=3
+  %ssm_state_step.35 = (f32[64,64,64]{2,1,0:T(8,128)}, f32[36,64,64,64,128]{4,3,2,1,0:T(8,128)}) custom-call(%a, %b), custom_call_target="tpu_custom_call"
+  %fusion.1 = f32[64,64,64]{2,1,0:T(8,128)} fusion(%x), kind=kLoop
+"""
+    assert len(_state_shaped_ops(before, stack)) == 3
+    assert _state_shaped_ops(after, stack) == []

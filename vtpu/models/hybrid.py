@@ -19,7 +19,8 @@ What differs from ``transformer.py``, by mechanism:
   depthwise convolution with bias, then SiLU; ``h_t = exp(dt_t A) h_{t-1} +
   dt_t x_t (outer) B_t``, ``y_t = h_t C_t + D x_t`` a head; a gated RMSNorm
   over all inner channels; the output projection. A decode step is the
-  recurrence itself (``_ssd_step``); a prefill chunk computes the same in
+  recurrence itself (``_ssd_step``; on a TPU ``ops/ssm_step``'s kernel, one
+  visit of a layer's state in the stack); a prefill chunk computes the same in
   the chunked matrix form (``_ssd_chunked``: inside a chunk of
   ``ssd_chunk`` tokens a masked ``C B^T`` product weighted by the
   cumulative decay, between chunks the carried state).
@@ -36,7 +37,8 @@ What differs from ``transformer.py``, by mechanism:
 One walk (``_walk``) serves every entry point, as in ``latent.py``: the
 attention layers unrolled (a static layer index), each run of Mamba layers
 between them a ``fori_loop`` over the stacked leaves and the stacked state
-(one compiled body a run, the state's row of a layer updated in place).
+(one compiled body a run, the state's row of a layer updated in place; the
+kernel's route hands it the stack and the layer's index).
 """
 
 from __future__ import annotations
@@ -55,6 +57,7 @@ from vtpu.models.transformer import (
     kv_plane_shape,
 )
 from vtpu.ops import rms_norm, scaled_normal
+from vtpu.ops.ssm_step import ssm_state_step
 
 Params = dict[str, Any]
 KV_KEYS = ("k", "v")
@@ -214,16 +217,33 @@ def _empty_rows(cfg: HybridConfig, n: int):
 # ------------------------------------------------------------- the mixer
 
 
+def step_in_kernel(t: int) -> bool:
+    """Whether a program of ``t`` tokens a row updates the recurrent state
+    in the Pallas kernel (``ops/ssm_step``: one visit of a layer's state,
+    over the stack as stored) and not in ``_ssd_step``'s XLA code: a decode
+    step on a TPU. Resolved when a program is traced, from what it can
+    observe, as ``cached_attention`` asks for the paged kernel; the engine
+    counts its ticks by the same call (``stats()["ssm_kernel_ticks"]``)."""
+    return t == 1 and jax.default_backend() == "tpu"
+
+
+def _step_operands(xs, dt, a, bm, cm):
+    """A one-token step's operands in float32: (decay [B, H], dt * x
+    [B, H, P], B [B, N], C [B, N]) from xs [B, 1, H, P], dt [B, 1, H], a
+    [H], bm, cm [B, 1, N]."""
+    f32 = jnp.float32
+    dt = dt[:, 0]
+    return (jnp.exp(dt * a), dt[..., None] * xs[:, 0].astype(f32),
+            bm[:, 0].astype(f32), cm[:, 0].astype(f32))
+
+
 def _ssd_step(xs, dt, a, bm, cm, h):
     """The recurrence, one token a row. xs [B, 1, H, P]; dt [B, 1, H]
     float32 (0: the state passes through); a [H]; bm, cm [B, 1, N]; h
     [B, H, P, N] float32 -> (y [B, 1, H, P] float32, h)."""
-    dt = dt[:, 0]
-    decay = jnp.exp(dt * a)  # [B, H]
-    dx = dt[..., None] * xs[:, 0].astype(jnp.float32)  # [B, H, P]
-    h = (h * decay[..., None, None]
-         + dx[..., None] * bm[:, 0].astype(jnp.float32)[:, None, None, :])
-    y = jnp.einsum("bhpn,bn->bhp", h, cm[:, 0].astype(jnp.float32))
+    decay, dx, bm, cm = _step_operands(xs, dt, a, bm, cm)
+    h = h * decay[..., None, None] + dx[..., None] * bm[:, None, None, :]
+    y = jnp.einsum("bhpn,bn->bhp", h, cm)
     return y[:, None], h
 
 
@@ -283,10 +303,12 @@ def _residual(cfg: HybridConfig, x, branch):
             ).astype(x.dtype)
 
 
-def _mamba_mixer(cfg: HybridConfig, lp, x, conv, h, n_valid):
+def _mamba_mixer(cfg: HybridConfig, lp, x, conv, h, n_valid, layer=None):
     """One Mamba-2 mixer over x [B, T, D] from the carried rows ``conv``
     [B, K - 1, Dc] and ``h`` [B, H, P, N]; ``n_valid`` [B] of each row's T
-    tokens are real (the first ones). Returns (x + r * mixer(x), conv, h)."""
+    tokens are real (the first ones). Returns (x + r * mixer(x), conv, h).
+    With ``layer`` (a step in the kernel: ``step_in_kernel``), ``h`` is the
+    whole stack [Lm, B, H, P, N], taken and returned."""
     b, t, _ = x.shape
     nh, p, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
     di, dc, k = cfg.d_inner, cfg.conv_dim, cfg.conv_width
@@ -302,15 +324,23 @@ def _mamba_mixer(cfg: HybridConfig, lp, x, conv, h, n_valid):
                 acc = acc + seq[:, j:j + t].astype(f32) * lp["conv_w"][j].astype(f32)
             xbc = jax.nn.silu(acc).astype(x.dtype)
             # the window after the row's last real token
-            at = n_valid[:, None] + jnp.arange(k - 1)[None, :]
-            conv = jnp.take_along_axis(seq, at[:, :, None], axis=1)
+            if t == 1:  # one token on, or where it stood: no gather
+                conv = jnp.where((n_valid > 0)[:, None, None], seq[:, 1:], conv)
+            else:
+                at = n_valid[:, None] + jnp.arange(k - 1)[None, :]
+                conv = jnp.take_along_axis(seq, at[:, :, None], axis=1)
         with jax.named_scope("ssm_scan"):
             xs = xbc[..., :di].reshape(b, t, nh, p)
             bm, cm = xbc[..., di:di + n], xbc[..., di + n:]
             real = jnp.arange(t)[None, :] < n_valid[:, None]
             dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"]) * real[..., None]
             a = -jnp.exp(lp["a_log"].astype(f32))
-            if t == 1:
+            if layer is not None:  # the stack, this layer of it in place
+                y, h = ssm_state_step(
+                    h, layer, *_step_operands(xs, dt, a, bm, cm),
+                    interpret=jax.default_backend() != "tpu")
+                y = y[:, None]
+            elif t == 1:
                 y, h = _ssd_step(xs, dt, a, bm, cm, h)
             else:
                 y, h = _ssd_chunked(xs, dt, a, bm, cm, h, cfg.ssd_chunk)
@@ -353,20 +383,25 @@ def _walk(params: Params, cfg: HybridConfig, tokens, n_valid, kv, conv, h,
     x = _embed(params, cfg, tokens)
     mamba, attention = params["mamba"], params["attention"]
 
+    in_kernel = step_in_kernel(tokens.shape[1])
+
     def mamba_layer(l, carry):
         x, conv, h = carry
         lp = jax.tree_util.tree_map(
             lambda a: jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False),
             mamba)
         # a layer's rows read from and written to the state: the scan's
+        # (the kernel visits its layer of ``h`` in the stack itself)
         with jax.named_scope("attn"), jax.named_scope("ssm_scan"):
             c = jax.lax.dynamic_index_in_dim(conv, l, 0, False)
-            s = jax.lax.dynamic_index_in_dim(h, l, 0, False)
-        x, c, s = _mamba_mixer(cfg, lp, x, c, s, n_valid)
+            s = h if in_kernel else jax.lax.dynamic_index_in_dim(h, l, 0, False)
+        x, c, s = _mamba_mixer(
+            cfg, lp, x, c, s, n_valid, l if in_kernel else None)
         x = _mlp(cfg, lp, x)
         with jax.named_scope("attn"), jax.named_scope("ssm_scan"):
             return (x, jax.lax.dynamic_update_index_in_dim(conv, c, l, 0),
-                    jax.lax.dynamic_update_index_in_dim(h, s, l, 0))
+                    s if in_kernel
+                    else jax.lax.dynamic_update_index_in_dim(h, s, l, 0))
 
     for kind, first, end in layer_runs(cfg.layer_types):
         if kind == "mamba":

@@ -114,6 +114,9 @@ COUNTERS = {
                          "with state-space layers)"),
     "ssm_rows_live": ("ssm_rows_live",
                       "Of those, the rows of dispatched slots"),
+    "ssm_kernel_ticks": ("ssm_kernel_ticks",
+                         "Decode ticks whose step updated the recurrent "
+                         "state in the one-visit kernel"),
     "paged_attn_kernel_ticks": ("paged_attn_kernel_ticks",
                                 "Ticks routed to the fused paged-attention "
                                 "kernel (table walked in place)"),

@@ -41,7 +41,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from vtpu.models import slots as slot_steps, transformer
+from vtpu.models import hybrid, slots as slot_steps, transformer
 from vtpu.models.hybrid import (
     hybrid_decode_step,
     hybrid_prefill_chunk,
@@ -743,6 +743,11 @@ class HybridSlotModel:
 
     def recurrent_state_bytes(self, slots: int) -> int:
         return slots * self.cfg.recurrent_bytes_per_slot
+
+    def ssm_step_in_kernel(self) -> bool:
+        """Whether a decode step traced now updates the recurrent state in
+        the Pallas kernel: the question the trace itself asks."""
+        return hybrid.step_in_kernel(1)
 
     def init_state(self, slots: int):
         self.n_kv_blocks = _pool_blocks(self, slots)

@@ -883,6 +883,7 @@ class ServingEngine:
         self._recurrent_bytes = (
             model.recurrent_state_bytes(b)
             if hasattr(model, "recurrent_state_bytes") else 0)
+        self._ssm_in_kernel = getattr(model, "ssm_step_in_kernel", None)
         if (serving.paged_attn is not None
                 and self._paged_attn != serving.paged_attn):
             raise ValueError(
@@ -1443,8 +1444,13 @@ class ServingEngine:
                        # pages (``recurrent_state_bytes``): slot rows a
                        # decode tick's state update touched (every slot's,
                        # the step's shape), and those of them that belonged
-                       # to a dispatched slot; 0 for every other model
+                       # to a dispatched slot; 0 for every other model.
+                       # ssm_kernel_ticks: the decode ticks whose step was
+                       # traced with the state kernel (the model's
+                       # ``ssm_step_in_kernel``, the call the trace makes:
+                       # every one on a TPU, none elsewhere)
                        "ssm_rows_stepped": 0, "ssm_rows_live": 0,
+                       "ssm_kernel_ticks": 0,
                        # KV overcommit: parks/resumes are lifecycle events;
                        # evicted_blocks counts pool blocks reclaimed from
                        # parked sessions; swap_out/in_bytes are the D2H/H2D
@@ -3529,6 +3535,8 @@ class ServingEngine:
         if self._recurrent_bytes:
             self._stats["ssm_rows_stepped"] += self.serving.slots * ticks
             self._stats["ssm_rows_live"] += len(lens) * ticks
+            if self._ssm_in_kernel is not None and self._ssm_in_kernel():
+                self._stats["ssm_kernel_ticks"] += ticks
         if self._paged and lens:
             page = self._page
             live = sum(-(-(ln + 1) // page) for ln in lens)
