@@ -9,14 +9,22 @@ Fast tier. Three layers:
   swap-out -> swap-in -> resume session (and a parallel drop ->
   recompute-on-fault one) whose JSONL events reconstruct the exact span
   sequence and whose Chrome dump is valid ``trace_event`` JSON;
+- lost time: the process's pause watch (a made clock for the rule; a held
+  interpreter and a full collection for the classes; the thread and the
+  collector's listener come and go with the engines) and the tick
+  profiler's long samples (``long_ms`` / ``long_count``, the plain rule on
+  a stalled fetch, ``tick_long``'s tick id);
 - exporter: the coverage static check (every stats() key maps to a
   ``vtpu_serving_*`` family or is explicitly allowlisted — new engine
   counters cannot silently drift out of the exporter) and the merged
   MonitorCollector exposition staying duplicate-free.
 """
 
+import contextlib
+import gc
 import io
 import json
+import threading
 import time
 
 import jax
@@ -32,6 +40,7 @@ from vtpu.obs.export import (
     SPECIAL,
     ServingCollector,
 )
+from vtpu.obs import pauses
 from vtpu.obs.tickprof import BoundedHistogram, TickProfiler
 from vtpu.obs.trace import (
     DROP_RESTORE_SEQUENCE,
@@ -40,6 +49,7 @@ from vtpu.obs.trace import (
     subsequence,
 )
 from vtpu.serving import ServingConfig, ServingEngine
+from vtpu.serving.faults import FaultPlan, FaultSpec
 
 CFG = ModelConfig(
     vocab=64, d_model=32, n_heads=2, n_layers=1, d_ff=64,
@@ -485,6 +495,371 @@ def test_shed_and_fault_events_attribute_stream_ends(params):
     assert json.loads(json.dumps(chrome)) == chrome
     names = {e["name"] for e in chrome["traceEvents"] if e["ph"] == "i"}
     assert {"shed", "fault"} <= names
+
+
+# ------------------------------------------------------------- lost time
+
+
+class _MadeClock:
+    """A clock the watcher's sleeps advance: each takes the period and
+    what ``late_ms`` plans for it; ``cpu`` and the collector follow the
+    plan too. The sleep after the last planned one says stop."""
+
+    def __init__(self, late_ms, cpu_ms=(), gc_ms=()):
+        self.ns, self.cpu_s, self.i = 10 ** 12, 0.0, 0
+        self.late_ms, self.cpu_ms, self.gc_ms = late_ms, cpu_ms, gc_ms
+        self.watch = None
+
+    def now(self):
+        return self.ns
+
+    def cpu(self):
+        return self.cpu_s
+
+    def sleep(self, seconds):
+        if self.i == len(self.late_ms):
+            return True
+        i, self.i = self.i, self.i + 1
+        gc_ms = self.gc_ms[i] if i < len(self.gc_ms) else 0.0
+        if gc_ms:  # a full collection that starts 2 ms into the sleep
+            self.ns += 2_000_000
+            self.watch._on_gc("start", {"generation": 2})
+            self.ns += int(gc_ms * 1e6)
+            self.watch._on_gc("stop", {"generation": 2, "collected": 7})
+            self.ns -= 2_000_000 + int(gc_ms * 1e6)
+        self.ns += int(seconds * 1e9) + int(self.late_ms[i] * 1e6)
+        self.cpu_s += (self.cpu_ms[i] if i < len(self.cpu_ms) else 0.0) / 1e3
+        return False
+
+
+def _made_watch(**plan):
+    clock = _MadeClock(**plan)
+    watch = pauses.PauseWatch(clock=clock.now, cpu=clock.cpu)
+    clock.watch = watch
+    watch.run(clock.sleep)
+    return watch.snapshot()
+
+
+def test_pause_watch_counts_nothing_in_a_quiet_second():
+    """200 sleeps that wake on time or up to 20 ms late: no pause."""
+    snap = _made_watch(late_ms=[0.0, 0.3, 4.0, 19.9, 20.0] * 40)
+    assert snap["host"]["count"] == 0 and snap["host"]["total_ms"] == 0.0
+    assert snap["recent"] == {"host": [], "gc": []}
+    assert (snap["period_ms"], snap["late_ms"]) == (5, 20)
+
+
+def test_pause_watch_notes_a_late_wake_with_what_classes_it():
+    """Three pauses of 108 ms: the machine's (nothing of the process
+    ran), a held interpreter (the process's CPU time grew by as much) and
+    a full collection (timed by the listener inside the sleep)."""
+    snap = _made_watch(late_ms=[0, 0, 108, 0, 108, 0, 108, 20.5],
+                       cpu_ms=[5, 5, 0.4, 5, 112, 5, 109, 1],
+                       gc_ms=[0, 0, 0, 0, 0, 0, 104])
+    rows = snap["recent"]["host"]
+    assert [r[1:] for r in rows] == [
+        [108.0, 0.4, 0.0], [108.0, 112.0, 0.0], [108.0, 109.0, 104.0],
+        [20.5, 1.0, 0.0]]
+    # each begins where its sleep began: after the sleeps before it
+    assert rows[0][0] == 10 ** 12 + 2 * 5_000_000
+    assert rows[1][0] == rows[0][0] + 113_000_000 + 5_000_000
+    assert snap["host"]["count"] == 4
+    assert snap["host"]["total_ms"] == pytest.approx(3 * 108 + 20.5)
+    assert snap["host"]["max_ms"] == 108.0
+    assert snap["gc"]["2"] == {"count": 1, "total_ms": 104.0,
+                               "max_ms": 104.0}
+    assert snap["gc"]["0"]["count"] == 0
+    [[start, ms, generation, collected]] = snap["recent"]["gc"]
+    assert (ms, generation, collected) == (104.0, 2, 7)
+    assert start == rows[2][0] + 2_000_000
+
+
+def test_pause_watch_rings_hold_the_last_64():
+    snap = _made_watch(late_ms=[30.0 + i for i in range(70)])
+    assert snap["host"]["count"] == 70
+    assert [r[1] for r in snap["recent"]["host"]] == [
+        30.0 + i for i in range(6, 70)]
+
+
+def _planted_pause(watch, plant, at_least_ms):
+    """Run ``plant`` with the watch on; the longest pause it noted."""
+    before = watch.host.count
+    deadline = time.monotonic() + 10.0
+    while True:
+        plant()
+        time.sleep(0.05)  # the watcher's wake after the hold
+        rows = [r for r in watch.snapshot()["recent"]["host"]
+                if r[1] >= at_least_ms]
+        if watch.host.count > before and rows:
+            return max(rows, key=lambda r: r[1])
+        assert time.monotonic() < deadline, "the hold was never seen"
+
+
+def test_pause_watch_sees_a_held_interpreter():
+    """Another thread in one C call that keeps the interpreter (a sum
+    over a range sized to 0.1 s): the watcher wakes late, and the process's
+    CPU time grew across the pause: ``cpu_ms`` near ``ms``, no collection."""
+    t0 = time.perf_counter()
+    sum(range(2_000_000))
+    n = int(2_000_000 * 0.1 / (time.perf_counter() - t0))
+    watch = pauses.PauseWatch()
+    watch.acquire()
+    try:
+        def hold():
+            t = threading.Thread(target=lambda: sum(range(n)))
+            t.start()
+            t.join(timeout=30)
+
+        start, ms, cpu_ms, gc_ms = _planted_pause(watch, hold, 50.0)
+    finally:
+        watch.release()
+    assert cpu_ms >= 0.5 * min(ms, 100.0)
+    assert gc_ms <= 0.2 * ms
+    assert start <= time.monotonic_ns()
+
+
+def test_pause_watch_sees_a_collection_of_a_large_heap():
+    """A full collection over a large heap holds the interpreter as long
+    as a stop of the machine; the listener's time inside the pause says
+    whose it was: ``gc_ms`` near ``ms``."""
+    shared = [[] for _ in range(64)]
+    heap = [shared * 1 for _ in range(180_000)]  # 11 M references to visit
+    watch = pauses.PauseWatch()
+    watch.acquire()
+    try:
+        start, ms, cpu_ms, gc_ms = _planted_pause(watch, gc.collect, 25.0)
+        snap = watch.snapshot()
+    finally:
+        watch.release()
+        del heap
+    assert gc_ms >= 0.7 * ms
+    full = [r for r in snap["recent"]["gc"] if r[2] == 2]
+    assert full and max(r[1] for r in full) >= 0.7 * ms
+    assert snap["gc"]["2"]["count"] >= 1
+    assert snap["gc"]["2"]["total_ms"] >= snap["gc"]["2"]["max_ms"] >= gc_ms
+
+
+def test_pause_watch_comes_and_goes_with_its_engines(params, monkeypatch):
+    """Two engines of a process share the one watch; the last to stop
+    takes the listener off ``gc.callbacks`` and ends the thread, and a
+    later start brings both back (twice in one process)."""
+    # a watch of the test's own in the process's place: an engine that an
+    # earlier test of this process left running holds the real one
+    watch = pauses.PauseWatch()
+    monkeypatch.setattr(pauses, "WATCH", watch)
+
+    def listening():
+        return [c for c in gc.callbacks
+                if getattr(c, "__self__", None) is watch]
+
+    threads = []
+    for _ in range(2):
+        a, b = (ServingEngine(params, CFG, ServingConfig(
+            slots=2, prefill_buckets=(8,), max_new_tokens=4))
+            for _ in range(2))
+        assert not watch.running and not listening()
+        a.start()
+        b.start()
+        try:
+            assert watch.running and len(listening()) == 1
+            threads.append(watch._thread)
+            assert threads[-1].name == "vtpu-pause-watch"
+            assert a.stats()["pauses"]["late_ms"] == 20
+        finally:
+            a.stop()
+            assert watch.running and len(listening()) == 1
+            b.stop()
+        b.stop()  # idempotent: no second release
+        assert not watch.running and not listening()
+        assert not threads[-1].is_alive()
+    assert threads[0] is not threads[1]
+    # the counters outlive the thread: monotonic for the process
+    assert a.stats()["pauses"]["host"]["count"] == watch.host.count
+
+
+def test_long_counters_are_monotonic_and_a_windows_growth_is_the_excess():
+    prof = TickProfiler(tick=lambda: 41)
+    prof.note("deliver", 0.004)
+    prof.note("deliver", 0.0499)  # under the rule
+    prof.note("dispatch", 0.060)  # set-up's, before the window
+    before = prof.snapshot()
+    assert before["deliver"]["long_count"] == 0
+    assert before["dispatch"]["long_ms"] == pytest.approx(60.0)
+    t0 = time.monotonic_ns()
+    prof.note("deliver", 0.120)
+    prof.note("dispatch", 0.055)
+    prof.note("swap_drain", 0.050)
+    prof.note("idle_wait", 0.9)  # never long: the loop chose to wait
+    prof.note("admission", 0.010)
+    after = prof.snapshot()
+    grown = {p: (after[p]["long_count"] - before[p]["long_count"],
+                 after[p]["long_ms"] - before[p]["long_ms"])
+             for p in after}
+    assert grown == {
+        "deliver": (1, pytest.approx(120.0)),
+        "dispatch": (1, pytest.approx(55.0)),
+        "swap_drain": (1, pytest.approx(50.0)),
+        "idle_wait": (0, 0.0), "admission": (0, 0.0), "fetch": (0, 0.0)}
+    assert after["deliver"]["max_ms"] == pytest.approx(120.0)
+    rows = prof.long_snapshot()
+    assert [r[0] for r in rows] == ["dispatch", "deliver", "dispatch",
+                                    "swap_drain"]
+    phase, tick, start_ns, ms, excess = rows[1]
+    assert (tick, ms, excess) == (41, 120.0, 120.0)
+    # a sample that ends now began its length ago
+    assert abs(start_ns - (t0 - 120_000_000)) < 50_000_000
+    for i in range(100):
+        prof.note("deliver", 0.051)
+    assert len(prof.long_snapshot()) == 64
+    assert prof.snapshot()["deliver"]["long_count"] == 101
+
+
+@pytest.mark.parametrize("k", [1, 4])
+def test_plain_rule_judges_only_fetches_of_decode_steps_alone(k):
+    """The rule on a made timeline, per inner tick (a k-tick flush is one
+    sample of k ticks): no judgement before 16 plain samples; a fetch that
+    carries chunks, or follows one that did, is never long under 1 s; a
+    plain one over twice the mean plus 10 ms is, by what lies over the
+    mean, and stays out of the mean."""
+    launched = [0]
+    prof = TickProfiler(tick=lambda: 7, prefill=lambda: launched[0])
+
+    def fetch(ms, chunk=0):
+        launched[0] += chunk
+        prof.note("fetch", k * ms / 1e3, ticks=k)
+        return prof.snapshot()["fetch"]
+
+    for _ in range(15):
+        fetch(10.0)
+    assert fetch(200.0)["long_count"] == 0  # the 16th: no mean yet
+    for _ in range(640):  # the mean forgets it: its memory is 64 samples
+        fetch(10.0)
+    assert fetch(29.0)["long_count"] == 0  # under 2 x 10 + 10
+    # chunks of unequal length, each pass slower than any plain one
+    for chunk, ms in ((512, 140.0), (512, 150.0), (131, 60.0)):
+        assert fetch(ms, chunk)["long_count"] == 0
+    # the loop is one tick deep: the fetch after a launch's own waits for
+    # the launch
+    assert fetch(150.0)["long_count"] == 0
+    # a host phase beside or after a launch may block on the device's
+    # queue: not judged under a second either
+    launched[0] += 512
+    prof.note("dispatch", 0.198)
+    prof.note("admission", 0.130)
+    assert fetch(150.0)["long_count"] == 0
+    prof.note("dispatch", 0.080)
+    assert fetch(150.0)["long_count"] == 0
+    host = prof.snapshot()
+    assert host["dispatch"]["long_count"] == host["admission"][
+        "long_count"] == 0
+    prof.note("deliver", 0.110)  # a plain pass again: a stop in deliver
+    assert prof.snapshot()["deliver"]["long_ms"] == pytest.approx(110.0)
+    got = fetch(118.0)  # and a stop of 108 ms in a plain fetch
+    assert got["long_count"] == 1
+    assert got["long_ms"] == pytest.approx(k * 108.0, rel=0.05)
+    assert fetch(10.0)["long_count"] == 1
+    # whatever the tick held, a second is long, whole
+    got = fetch(1200.0 / k, chunk=512)
+    assert got["long_count"] == 2
+    assert got["long_ms"] == pytest.approx(k * 108.0 + 1200.0, rel=0.05)
+    launched[0] += 512
+    prof.note("admission", 1.5)
+    assert prof.snapshot()["admission"]["long_ms"] == pytest.approx(1500.0)
+    assert [r[:2] for r in prof.long_snapshot()] == [
+        ["deliver", 7], ["fetch", 7], ["fetch", 7], ["admission", 7]]
+
+
+class _Spans:
+    """In the profiler spans' place: every span's name, tick and length."""
+
+    def __init__(self):
+        self.seen = []
+
+    @contextlib.contextmanager
+    def __call__(self, name, **ids):
+        t0 = time.perf_counter()
+        yield
+        self.seen.append((name, ids.get("tick"), time.perf_counter() - t0))
+
+
+def test_plain_rule_counts_a_delayed_fetch_on_decode_only_passes(params):
+    """A stall of 0.3 s planted inside one fetch of a toy engine that is
+    only decoding: ``long_ms`` grows by it to within 10 %, and the row of
+    ``tick_long`` carries the tick id that pass's span carries."""
+    plan = FaultPlan([FaultSpec("delayed_fetch", at=30, arg=0.3)])
+    eng = ServingEngine(params, CFG, ServingConfig(
+        slots=2, prefill_buckets=(8,), max_new_tokens=56, faults=plan))
+    spans = eng.tick_profile._span = _Spans()
+    eng.start()
+    try:
+        r = eng.submit(_prompt(3, 5), max_new_tokens=56)
+        assert len(list(r.stream())) == 56
+        stats = eng.stats()
+    finally:
+        eng.stop()
+    fetch = stats["tick_phase_ms"]["fetch"]
+    rows = [r for r in stats["tick_long"] if r[0] == "fetch"
+            and r[3] >= 290.0]
+    assert len(rows) == 1 and fetch["long_count"] >= 1
+    phase, tick, start_ns, ms, excess = rows[0]
+    assert 270.0 <= excess <= ms <= 345.0
+    assert fetch["long_ms"] >= excess - 0.01
+    stalled = [(name, t) for name, t, s in spans.seen
+               if name == "vtpu.tick.fetch" and s >= 0.29]
+    assert stalled == [("vtpu.tick.fetch", tick)]
+    # the stall did not enter the mean a plain pass is held against
+    assert eng.tick_profile._plain_mean < 100.0
+
+
+class _SlowChunks(FaultPlan):
+    """A device that takes 4 ms a prompt token: the fetch of a pass that
+    launched a chunk, and the one after it (the loop is one tick deep),
+    stall by the chunk's length."""
+
+    def __init__(self):
+        super().__init__([])
+        self.engine, self.seen, self.owed, self.ticks = None, 0, 0.0, []
+
+    def fire(self, seam):
+        if seam != "delayed_fetch":
+            return super().fire(seam)
+        now = self.engine._stats["prefill_tokens"]
+        stall, self.owed = self.owed + 0.004 * (now - self.seen), \
+            0.004 * (now - self.seen)
+        self.seen = now
+        if not stall:
+            return None
+        self.ticks.append(self.engine._tick_count())
+        return FaultSpec("delayed_fetch", arg=stall)
+
+
+def test_plain_rule_counts_nothing_on_passes_that_carry_chunks(params):
+    """A stream decoding alone, then beside a prompt that arrives in
+    chunks of 16, 16 and 8: every pass with a chunk is far over twice the
+    plain passes' mean plus 10 ms, and none is counted."""
+    plan = _SlowChunks()
+    eng = ServingEngine(params, CFG, ServingConfig(
+        slots=2, prefill_buckets=(16,), prefill_chunk=16, max_new_tokens=56,
+        faults=plan))
+    plan.engine = eng
+    eng.start()
+    try:
+        a = eng.submit(_prompt(4, 5), max_new_tokens=56)
+        stream = a.stream()
+        head = [next(stream) for _ in range(30)]  # 30 passes decoding alone
+        b = eng.submit(_prompt(5, 40), max_new_tokens=4)
+        assert len(list(b.stream())) == 4
+        assert len(head + list(stream)) == 56
+        stats = eng.stats()
+    finally:
+        eng.stop()
+    assert stats["prefill_chunks"] >= 3
+    # B's three chunks stalled their fetches and the ones after them
+    assert len(plan.ticks) >= 4
+    assert stats["tick_phase_ms"]["fetch"]["max_ms"] >= 60.0
+    assert eng.tick_profile._plain_n >= 16
+    counted = [r for r in stats["tick_long"]
+               if r[0] == "fetch" and r[1] in plan.ticks]
+    assert counted == []
 
 
 # ---------------------------------------------------------------- exporter

@@ -26,7 +26,15 @@ or adds one), and by the programs' names: ``jit_step`` decodes,
                record of the host's share of a tick: the EMA keys
                (``host_ms_per_tick``, ``host_ms_per_token``,
                ``admission_stall_ms``) are gone; ``host_ms_per_tick()``
-               here reads the totals.
+               here reads the totals. Each histogram also counts the
+               samples judged long and their excess (``long_count``,
+               ``long_ms``; the last 64 in ``stats()["tick_long"]``): what
+               a window lost in a phase.
+- pauses.py:   the process's pause watch (``stats()["pauses"]``): a thread
+               whose late wakes are the pauses of the whole process, each
+               classed by the CPU time and the collector's time inside it,
+               and every collection by generation; one watch a process,
+               acquired by ``ServingEngine.start()``.
 - warmup.py:   ``stats()["warmup_s"]``: the warm-up's seconds by kind
                (trace_lower, compile, cache_load, run) from JAX's own
                monitoring events.

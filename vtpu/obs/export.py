@@ -27,6 +27,8 @@ from prometheus_client.core import (
 )
 from prometheus_client.registry import Collector
 
+from vtpu.obs import pauses
+
 PREFIX = "vtpu_serving_"
 
 # stats() key -> (family suffix, help). Monotonic counters.
@@ -302,6 +304,12 @@ SPECIAL = {
     "kv_hbm_bytes",            # -> vtpu_serving_kv_hbm_bytes{layout=...}
     "kv_hbm_bytes_per_chip",   # -> ..._per_chip{layout=...}
     "tick_phase_ms",           # -> vtpu_serving_tick_phase_seconds{phase=...}
+                               #    and, from its long_ms / long_count,
+                               #    vtpu_serving_tick_phase_long_seconds
+                               #    and ..._tick_phase_long {phase=...}
+    "pauses",                  # -> vtpu_serving_host_pause_seconds,
+                               #    vtpu_serving_gc_pause_seconds and
+                               #    vtpu_serving_gc_collections{generation}
     "warmup_s",                # -> vtpu_serving_warmup_seconds{kind=...}
                                #    and vtpu_serving_warmup_programs
 }
@@ -313,6 +321,9 @@ ALLOWLIST: set = {
     "loop_policy",           # policy class name (string) — config echo
     "loop_error",            # repr of the exception that killed the loop
                              # (None on a live engine); submit() raises it
+    "tick_long",             # ring of the last 64 long samples, each with
+                             # its tick and start: stats()'s, not a series
+                             # (their sums are tick_phase_ms' long_ms)
 }
 
 # ------------------------------------------------------------------- fleet
@@ -642,6 +653,51 @@ def serving_families(sources: dict[str, object]) -> Iterable:
                 buckets, total = hist.prom_buckets()
                 fam.add_metric((name, phase), buckets, total)
     yield fam
+    # the time lost whole (tickprof.py, pauses.py): rate() of the long
+    # seconds over wall seconds is the share of serving time lost
+    long_s = CounterMetricFamily(
+        PREFIX + "tick_phase_long_seconds",
+        "Excess seconds of the loop's samples judged long, by phase (1 s "
+        "or more; on a pass of decode steps alone a host phase of 50 ms "
+        "or more, a fetch over twice such fetches' mean)",
+        labels=("engine", "phase"))
+    long_n = CounterMetricFamily(
+        PREFIX + "tick_phase_long",
+        "Samples of the loop judged long, by phase",
+        labels=("engine", "phase"))
+    for name, s in snaps.items():
+        for phase, snap in (s.get("tick_phase_ms") or {}).items():
+            if "long_ms" in snap:
+                long_s.add_metric((name, phase), snap["long_ms"] / 1e3)
+                long_n.add_metric((name, phase), float(snap["long_count"]))
+    yield long_s
+    yield long_n
+    # the process's pauses: one watch a process, so the engines of one
+    # process carry the same series (take max by process, never a sum)
+    fam = HistogramMetricFamily(
+        PREFIX + "host_pause_seconds",
+        "Lateness of the pause watch's wakes that came more than 20 ms "
+        "late: time in which nothing of the process's Python ran",
+        labels=("engine",))
+    gc_s = CounterMetricFamily(
+        PREFIX + "gc_pause_seconds",
+        "Seconds in the interpreter's collections, by generation",
+        labels=("engine", "generation"))
+    gc_n = CounterMetricFamily(
+        PREFIX + "gc_collections",
+        "Collections of the interpreter's collector, by generation",
+        labels=("engine", "generation"))
+    buckets, total = pauses.WATCH.host.prom_buckets()
+    for name, s in snaps.items():
+        if s.get("pauses") is None:
+            continue
+        fam.add_metric((name,), buckets, total)
+        for gen, row in s["pauses"]["gc"].items():
+            gc_s.add_metric((name, gen), row["total_ms"] / 1e3)
+            gc_n.add_metric((name, gen), float(row["count"]))
+    yield fam
+    yield gc_s
+    yield gc_n
 
 
 class ServingCollector(Collector):

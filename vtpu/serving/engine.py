@@ -37,6 +37,7 @@ from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from vtpu.models import transformer
 from vtpu.models.transformer import ModelConfig, Params, kv_bytes_per_token
+from vtpu.obs import pauses
 from vtpu.obs.tickprof import TickProfiler
 from vtpu.obs.warmup import WarmupClock
 from vtpu.obs.trace import RequestTrace, TERMINAL_CODES, pct
@@ -1513,7 +1514,12 @@ class ServingEngine:
             # one-time event (val = the requested draft length): the trace
             # dump shows WHY the configured speculation never ran
             self.trace.record("spec_disabled", -1, -1, serving.spec_tokens)
-        self._prof = TickProfiler(tick=self._tick_count)
+        self._prof = TickProfiler(
+            tick=self._tick_count,
+            prefill=lambda: self._stats["prefill_tokens"])
+        # whether this engine holds the process's pause watch
+        # (vtpu/obs/pauses.py: start() acquires it, stop() releases it)
+        self._watching = False
         self._warmup = WarmupClock()
         self._req_ctr = itertools.count()
         # registered prompt prefixes: id -> {tokens, buffers, len, pad,
@@ -2182,6 +2188,9 @@ class ServingEngine:
         return drain_engine(self, dst, timeout=timeout)
 
     def start(self) -> None:
+        if not self._watching:
+            self._watching = True
+            pauses.WATCH.acquire()
         self._thread = threading.Thread(target=self._loop, daemon=True)
         self._thread.start()
         if self._disagg is not None:
@@ -2193,6 +2202,9 @@ class ServingEngine:
     def stop(self) -> None:
         self._stop.set()
         self._wake.set()  # an idle loop notices the stop immediately
+        if self._watching:
+            self._watching = False
+            pauses.WATCH.release()
         if self._thread:
             self._thread.join(timeout=10)
             # _loop's finally owns the slot/queue cleanup; touching its state
@@ -3894,6 +3906,12 @@ class ServingEngine:
         # (admission head / dispatch / fetch / deliver / swap drain / idle
         # wait), and the warm-up's seconds by kind
         s["tick_phase_ms"] = self._prof.snapshot()
+        # the time lost whole: the loop's samples judged long (the last 64;
+        # their sums are tick_phase_ms' long_ms) and the process's pauses
+        # and collections (one watch a process: engines of one process
+        # report the same)
+        s["tick_long"] = self._prof.long_snapshot()
+        s["pauses"] = pauses.WATCH.snapshot()
         s["warmup_s"] = self._warmup.snapshot()
         s["device_sampling"] = self._device_sampling
         s["pipelined"] = self._pipeline
