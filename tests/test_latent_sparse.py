@@ -280,8 +280,9 @@ def test_a_chunks_expanded_form_equals_its_absorbed_form(
 
 def test_the_chunk_attention_bench_runs_at_a_cut_down_shape(tmp_path):
     """``benchmarks/latent_chunk_attn_bench.py --tiny`` (the table PERF.md's
-    PR 34 entry chose the form with) runs on the CPU and holds its two
-    forms to each other in float32; its times there are no speeds."""
+    PR 34 entry chose the form with, and PR 38's the kernel) runs on the
+    CPU and holds its two forms to each other in float32; its times there
+    are no speeds."""
     import json
     import pathlib
     import subprocess
@@ -298,6 +299,13 @@ def test_the_chunk_attention_bench_runs_at_a_cut_down_shape(tmp_path):
     assert got["device"]["platform"] == "cpu"
     assert got["float32_highest_max_abs_diff"] < 1e-4
     assert got["rows"][0]["expanded_tiles"] == [8, 64]
+    # the kernel's rows (interpreted): both masks, the chunk at the window's
+    # end and at three quarters of it, each against the expanded form
+    kernel = [r for r in got["rows"] if "mask" in r]
+    assert [(r["mask"], r["chunk_end"]) for r in kernel] == [
+        ("selection", 256), ("causal", 256), ("selection", 192),
+        ("causal", 192)]
+    assert all(r["kernel_64x2"]["max_abs_diff"] < 0.02 for r in kernel)
 
 
 @pytest.mark.parametrize("window,heads", [

@@ -103,6 +103,18 @@ COUNTERS = {
                             "their window expanded into a head's keys and "
                             "values (made once a layer) rather than in "
                             "the latent space"),
+    "chunk_attn_kernel": ("chunk_attn_kernel",
+                          "Of those, the chunks whose program attends in "
+                          "the chunk kernel (a block's scores kept on the "
+                          "chip) rather than in XLA's code"),
+    "chunk_keys_live": ("chunk_keys_live",
+                        "Window positions at or before a chunk's last "
+                        "position, summed over those chunks"),
+    "chunk_keys_attended": ("chunk_keys_attended",
+                            "Window positions their programs multiplied: "
+                            "up to the chunk's end rounded up to a block "
+                            "in the kernel, the whole read window in "
+                            "XLA's code"),
     "latent_rows_live": ("latent_rows_live",
                          "Cached tokens the dispatched slots could see, "
                          "summed over decode ticks (models whose decode "

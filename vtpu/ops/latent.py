@@ -2,9 +2,11 @@
 the indexer's scores over a read window, the selection of the best cached
 tokens a query, and attention over the selected: a decode step's in the
 latent space (the absorbed form) over rows gathered from a paged pool, a
-prefill chunk's over its whole window under the selection's mask, the
-window expanded into a head's keys and values once a layer
-(``sparse_latent_attention`` reads which off the shapes). And the same
+prefill chunk's over its window under the selection's mask, the window
+expanded into a head's keys and values, on a TPU inside a kernel that
+keeps a block's scores on the chip and stops at the chunk's last position
+(``sparse_latent_attention`` reads which off the shapes and the backend;
+``vtpu.ops.latent_chunk``). And the same
 attention with no selection (DeepSeek-V2's block: every query attends
 every cached latent): a decode step walks its slots' live pages in the
 pool (``vtpu.ops.decode_attn.latent_decode_attention``), a chunk attends
@@ -15,8 +17,8 @@ queries a sequence (decode: 1; a chunk: its tokens), a read window of W
 cached positions walked through ``tables [N, W // page]`` of pool block
 ids. A pool plane is ``[L, n_blocks, page, R]``: R = latent rank + rotary
 width for the latent plane, the indexer's head width for its keys.
-Everything but that walk is plain XLA; each part runs under the scope
-that ``vtpu.ops.SCOPES`` names for it.
+Everything but that walk and the chunk's kernel is plain XLA; each part
+runs under the scope that ``vtpu.ops.SCOPES`` names for it.
 """
 
 from __future__ import annotations
@@ -25,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from vtpu.ops.decode_attn import latent_decode_attention
+from vtpu.ops.latent_chunk import chunk_attention, keys_attended
 
 _NEG = float("-inf")
 
@@ -215,11 +218,34 @@ def expands_window(t: int, rank: int, dn: int, dv: int) -> bool:
     What a query costs whatever the window's length (its trip through
     ``w_uk`` and ``w_uv`` in the absorbed form) is left out: it would only
     move the line towards fewer queries. Nor is the second time
-    ``_expanded`` makes its scores counted: that product (``t * (dn + Dr)``)
-    stands in for 8 B a score of memory traffic, which on a v5e (240 FLOPs
-    a byte) costs five times as much. A single query reads its selected
-    rows alone, not a window: never expanded."""
+    ``_expanded`` makes its scores counted (off the chip; the kernel makes
+    them once): that product (``t * (dn + Dr)``) stands in for 8 B a score
+    of memory traffic, which on a v5e (240 FLOPs a byte) costs five times
+    as much. A single query reads its selected rows alone, not a window:
+    never expanded."""
     return t > 1 and t * (2 * rank - dn - dv) > rank * (dn + dv)
+
+
+def attends_in_kernel(t: int, rank: int, dn: int, dv: int) -> bool:
+    """Whether ``t`` queries that share one window attend it in
+    ``latent_chunk.chunk_attention`` (the expanded form with a block's
+    scores kept in VMEM, the key blocks past the queries' last position
+    left out) and not in ``_expanded``'s XLA code: the shapes that expand,
+    on a TPU. Resolved when a program is traced, from what it can observe,
+    as ``models.hybrid.step_in_kernel``; the engine counts its chunks by
+    the same call (``stats()["chunk_attn_kernel"]``)."""
+    return expands_window(t, rank, dn, dv) and jax.default_backend() == "tpu"
+
+
+def chunk_keys_attended(t: int, rank: int, dn: int, dv: int, end: int,
+                        window: int) -> tuple[bool, int]:
+    """(whether the program of ``t`` queries over a read window of
+    ``window`` holds the kernel, the window positions it multiplies when
+    its last query is at ``end - 1``): the kernel stops at ``end`` rounded
+    up to its key block, XLA's code attends the whole window. Host
+    integers, for the engine's counters."""
+    kernel = attends_in_kernel(t, rank, dn, dv)
+    return kernel, keys_attended(end, window) if kernel else window
 
 
 def sparse_latent_attention(ckv: jax.Array, ik: jax.Array, l: int,
@@ -250,10 +276,14 @@ def sparse_latent_attention(ckv: jax.Array, ik: jax.Array, l: int,
     has both. **In the form its shapes give** (``expands_window``): few
     queries attend absorbed, as the step does; the engine's chunks (512
     queries; the whole-prompt bucket's 256) **expand** the window into a
-    head's keys and values once a layer and attend a head 192 and 128
-    wide, the scores made twice (``_expanded``): 71 % of the absorbed
-    form's products and a third of its bytes, 40 % less time at every
-    window from 4 k to 24 k (PERF.md, section 6, PR 34).
+    head's keys and values and attend a head 192 and 128 wide: 71 % of the
+    absorbed form's products (PERF.md, section 6, PR 34). On a TPU
+    (``attends_in_kernel``) in one kernel, a block of the window at a
+    time, the block's keys, values and scores never leaving the chip, and
+    only as far as the chunk's last position (``latent_chunk``; PERF.md,
+    section 6, PR 38); elsewhere in XLA's code over the whole window, the
+    scores made twice (``_expanded``), which is also the kernel's
+    reference.
 
     **No selection** (``topk`` None: a model without an indexer, whose
     queries attend every cached latent; ``ik``, ``q_idx``, ``w_idx`` None
@@ -271,6 +301,7 @@ def sparse_latent_attention(ckv: jax.Array, ik: jax.Array, l: int,
     t, rank = positions.shape[1], w_uk.shape[-1]
     width = rank + q_pe.shape[-1]  # a stored row may be padded
     expand = expands_window(t, rank, q_nope.shape[-1], w_uv.shape[-1])
+    kernel = attends_in_kernel(t, rank, q_nope.shape[-1], w_uv.shape[-1])
     if not expand:
         with jax.named_scope("qkv"):  # where the step's trace has it
             q_abs = jnp.einsum("nthd,hdr->nthr", q_nope, w_uk)
@@ -291,15 +322,20 @@ def sparse_latent_attention(ckv: jax.Array, ik: jax.Array, l: int,
     else:
         with jax.named_scope("latent_attn"):
             window = window_rows(ckv, l, tables)[..., :width]
-        if topk is None:
+        if topk is not None:
+            keep = _selection(keys, positions, q_idx, w_idx, given, topk,
+                              window.shape[1])
+        elif kernel:
+            keep = None
+        else:
             with jax.named_scope("latent_attn"):
                 keep = (jnp.arange(window.shape[1], dtype=jnp.int32)
                         <= positions[..., None])
-        else:
-            keep = _selection(keys, positions, q_idx, w_idx, given, topk,
-                              window.shape[1])
         chosen = None if topk is None else keep
         with jax.named_scope("latent_attn"):
+            if kernel:
+                return chunk_attention(q_nope, q_pe, window, keep, positions,
+                                       w_uk, w_uv, scale), chosen
             if expand:
                 return _expanded(q_nope, q_pe, window, keep, w_uk, w_uv,
                                  scale), chosen
@@ -412,7 +448,9 @@ def _expanded_tiles(n: int, t: int, heads: int, w: int) -> tuple[int, int]:
 
 
 def _expanded(q_nope, q_pe, window, keep, w_uk, w_uv, scale):
-    """A chunk of many queries, in the expanded form: a head's keys
+    """A chunk of many queries, in the expanded form, as XLA code (off the
+    chip; on it ``latent_chunk.chunk_attention`` computes the same, and is
+    tested against this): a head's keys
     ``window[..., :rank] . w_uk`` and values ``. w_uv`` made once a layer
     in the window's dtype (float32 sums), the rotated key ``window[...,
     rank:]`` shared by the heads as it is stored; scores one product over

@@ -67,7 +67,7 @@ from vtpu.models.transformer import (
     prefill,
 )
 from vtpu.ops.decode_attn import PAGED_ATTN_ROUTES
-from vtpu.ops.latent import expands_window
+from vtpu.ops.latent import chunk_keys_attended, expands_window
 from vtpu.parallel.sharding import (
     constrain_paged_kv,
     head_sharding,
@@ -560,8 +560,10 @@ class LatentSlotModel:
     is one, a layer, not heads), ``attn_select_topk`` (what a decode tick
     reads of what it sees; None: all of it, walked page by page:
     ``walks_latent_plane``), ``chunk_attn_expands`` (the form
-    a chunk's attention takes at a given length) and ``pool_planes``. All
-    read off the model's configuration. Paged only. Not supported, and
+    a chunk's attention takes at a given length), ``chunk_keys_attended``
+    (whether that form runs in the kernel, and the window positions it
+    then multiplies) and ``pool_planes``. All read off the model's
+    configuration and the backend. Paged only. Not supported, and
     refused by name: a mesh, an int8 cache, speculation and the swap tier
     (a forced ``ServingConfig.paged_attn`` the engine refuses itself: there
     is one route, so ``paged_attn`` is None)."""
@@ -630,6 +632,17 @@ class LatentSlotModel:
         (``vtpu.ops.latent.expands_window``), for the engine's counter."""
         cfg = self.cfg
         return expands_window(queries, cfg.kv_rank, cfg.nope_dim, cfg.v_dim)
+
+    def chunk_keys_attended(self, queries: int, end: int,
+                            window: int) -> tuple[bool, int]:
+        """(whether the program of a chunk of ``queries`` tokens holds the
+        chunk kernel, the window positions it multiplies for a chunk whose
+        last position is ``end - 1``): the rule the traced program applies
+        (``vtpu.ops.latent.chunk_keys_attended``), for the engine's
+        counters."""
+        cfg = self.cfg
+        return chunk_keys_attended(
+            queries, cfg.kv_rank, cfg.nope_dim, cfg.v_dim, end, window)
 
     def decode_step(self, params, state, tokens, active, kv_bucket,
                     unroll=False):

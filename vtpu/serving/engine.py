@@ -879,6 +879,7 @@ class ServingEngine:
         self._select_topk = getattr(model, "attn_select_topk", None)
         self._latent_walk = getattr(model, "walks_latent_plane", False)
         self._chunk_expands = getattr(model, "chunk_attn_expands", None)
+        self._chunk_keys = getattr(model, "chunk_keys_attended", None)
         # bytes of recurrent rows the state holds beside its pages (a
         # family with state-space layers says; 0 for every other)
         self._recurrent_bytes = (
@@ -1434,6 +1435,14 @@ class ServingEngine:
                        # ``chunk_attn_expands``: keys and values made from
                        # the window's latents once a layer)
                        "chunk_attn_launches": 0, "chunk_attn_expanded": 0,
+                       # ... those whose program holds the chunk kernel (the
+                       # model's ``chunk_keys_attended``), and over all of
+                       # them the window positions at or before a chunk's
+                       # last, and the positions its program multiplies
+                       # (the kernel: up to the chunk's end rounded up to a
+                       # block; XLA's code: the whole read window)
+                       "chunk_attn_kernel": 0, "chunk_keys_live": 0,
+                       "chunk_keys_attended": 0,
                        # a slot model whose decode step walks a latent
                        # plane (``walks_latent_plane``): cached tokens the
                        # dispatched slots could see, summed over decode
@@ -3418,6 +3427,10 @@ class ServingEngine:
                     self._stats["chunk_attn_launches"] += 1
                     self._stats["chunk_attn_expanded"] += int(
                         self._chunk_expands(c))
+                    kernel, attended = self._chunk_keys(c, need, kv_bucket)
+                    self._stats["chunk_attn_kernel"] += int(kernel)
+                    self._stats["chunk_keys_live"] += need
+                    self._stats["chunk_keys_attended"] += attended
                 self.trace.record("prefill_chunk", req.rid, slot, c)
                 if adm["off"] >= adm["padded"].shape[1]:  # final chunk
                     del self._admitting[slot]
