@@ -731,3 +731,96 @@ def test_state_shaped_ops_finds_the_parents_three_visits():
 """
     assert len(_state_shaped_ops(before, stack)) == 3
     assert _state_shaped_ops(after, stack) == []
+
+
+# -- the window family at its cell's sizes -----------------------------------
+
+SWA_SLOTS, SWA_BLOCKS = 96, 14001
+
+
+def _swa_shapes(v5e):
+    """MiMo-V2.5's ``SwaConfig`` at the published widths in its first
+    seven layers (0 1 1 1 1 0 1; the 16 held experts), with the adapter's
+    params and the engine state of `mimo_mixedqueue` (96 slots, 14000
+    blocks of 64) as shapes on the described chip."""
+    from vtpu.models import swa as M
+
+    cfg = M.SwaConfig(
+        vocab=19072, d_model=4096, n_heads=64, head_dim=192, v_head_dim=128,
+        rope_dim=64,
+        layer_types=("full",) + ("window",) * 4 + ("full", "window"),
+        ffn_types=("dense",) + ("moe",) * 6, n_kv_heads=4,
+        n_kv_heads_window=8, window=128, d_ff=16384, d_ff_expert=2048,
+        n_experts=256, held=(0, 16), top_k=8, max_seq=36864)
+    chip = SingleDeviceSharding(v5e[0])
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: M.init_swa_params(jax.random.key(0), cfg)))
+    params["layers"] = M.hold_projections(params["layers"], cfg)
+    state = on_chip(jax.eval_shape(lambda: M.init_swa_state(
+        cfg, SWA_SLOTS, 64, SWA_BLOCKS)))
+    return M, cfg, params, state, on_chip
+
+
+@pytest.mark.parametrize("program,window", [
+    ("step", 4096), ("step", 32768), ("chunk", 4096), ("chunk", 32768)])
+def test_window_family_programs_compile_at_the_cells_sizes(
+        v5e, monkeypatch, program, window):
+    """The decode step and a 512-token chunk of `mimo_mixedqueue`, at its
+    smallest and its largest read window, compile for a v5e and fit the
+    chip beside the state. A cached token's row as stored is 4 x 192 = 768
+    and 4 x 128 = 512 columns a full layer (2560 B, whole 128-lane tiles,
+    no padding) and a ring row 8 x 320 columns (5120 B). The step walks
+    the full layers' pool in place, one Mosaic kernel a full layer:
+    nothing of a pool plane's size is computed but the in-place scatters
+    and the fusions that wrap them, no window is gathered
+    (``count_pool_gathers`` 0 at half of one slot-window's keys; the
+    embedding's 96 rows are an eighth of it at the 4 k window), and
+    the rings are updated in place. Neither program lays a pool plane or a
+    stack of projections out anew."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    M, cfg, params, state, on_chip = _swa_shapes(v5e)
+    i32 = lambda *shape: on_chip(jnp.zeros(shape, jnp.int32))  # noqa: E731
+    assert cfg.kv_bytes_per_token == 2 * 2560
+    assert cfg.ring_bytes_per_position == 5 * 5120
+    assert state["k"].shape[2:] == (64, 768) and state["v"].shape[3] == 512
+    assert state["wk"].shape == (5, 96, 128, 1536)
+    if program == "step":
+        compiled = jax.jit(
+            M.swa_decode_step, static_argnums=(1, 5), donate_argnums=(2,)
+        ).lower(params, cfg, state, i32(SWA_SLOTS),
+                on_chip(jnp.zeros((SWA_SLOTS,), bool)), window).compile()
+    else:
+        compiled = jax.jit(
+            M.swa_prefill_chunk, static_argnums=(1, 7), donate_argnums=(2,)
+        ).lower(params, cfg, state, i32(1, 512), i32(), i32(), i32(), window,
+                i32(window // 64)).compile()
+    mem = compiled.memory_analysis()
+    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert peak < 0.95 * 16 * 2**30, peak
+    pool = sum(math.prod(state[key].shape) * 2 for key in ("k", "v"))
+    rings = sum(math.prod(state[key].shape) * 2 for key in ("wk", "wv"))
+    assert rings == 96 * 5 * 128 * 5120
+    assert mem.alias_size_in_bytes > pool + rings   # updated in place
+    text = compiled.as_text()
+    for plane in ("k", "v"):
+        ops = _pool_plane_ops(text, state[plane])
+        assert set(ops) <= POOL_SIZED_OK, (plane, ops)
+    big = count_pool_sized_ops(text, math.prod(state["v"].shape))
+    assert "copy" not in big and "transpose" not in big, big
+    # no stack of projections is laid out anew
+    wq = math.prod(params["layers"]["window_moe"]["wq"].shape)
+    for shape in re.findall(r" = bf16\[([0-9,]+)\]\S* (?:copy|transpose)\(",
+                            text):
+        assert math.prod(int(d) for d in shape.split(",")) < wq // 5, shape
+    if program == "step":
+        assert text.count("tpu_custom_call") == 2    # a full layer each
+        a_window = window * 768                      # one slot's keys
+        assert decode_attn.count_pool_gathers(text, a_window // 2) == 0
+        ring_layer = rings // 5
+        assert mem.temp_size_in_bytes < ring_layer, mem.temp_size_in_bytes

@@ -15,11 +15,12 @@ from vtpu.models import ModelConfig, init_params
 from vtpu.models.hybrid import HybridConfig, init_hybrid_params
 from vtpu.models.latent import LatentConfig, init_latent_params
 from vtpu.models.moe import MoEConfig, init_moe_params
+from vtpu.models.swa import SwaConfig, init_swa_params
 from vtpu.obs.tickprof import HOST_PHASES, TickProfiler, host_ms_per_tick
 from vtpu.ops import SCOPES
 from vtpu.serving import ServingConfig, ServingEngine
 from vtpu.serving.adapters import (
-    HybridSlotModel, LatentSlotModel, MoeSlotModel)
+    HybridSlotModel, LatentSlotModel, MoeSlotModel, WindowSlotModel)
 
 PAGE, CHUNK, BUCKET = 8, 8, 16
 DENSE = ModelConfig(
@@ -42,7 +43,16 @@ HYBRID = HybridConfig(
     vocab=64, d_model=32, layer_types=("mamba", "attention", "mamba"),
     n_heads=4, n_kv_heads=2, head_dim=64, d_ff=64, ssm_heads=4,
     ssm_head_dim=16, ssm_state=8, ssd_chunk=4, max_seq=32, dtype=jnp.float32)
+SWA = SwaConfig(
+    vocab=64, d_model=32, n_heads=4, head_dim=24, v_head_dim=16, rope_dim=8,
+    layer_types=("full", "window", "full"), ffn_types=("dense", "moe", "moe"),
+    n_kv_heads=1, n_kv_heads_window=2, window=4, d_ff=64, d_ff_expert=16,
+    n_experts=8, held=(2, 4), top_k=2, max_seq=32, dtype=jnp.float32)
 BLOCK = {"dense": {"mlp"}, "moe": {"route", "experts"}}
+# the window family: a window layer's ring read and write nested under
+# ``attn`` as the latent and Mamba parts are, a dense layer then expert
+# ones, and the paged pool's routes for its full layers
+WINDOW = {"attn", "window_attn", "ring_write", "mlp", "route", "experts"}
 # the hybrid family: its Mamba layers' three parts nested under ``attn``
 # as the latent family's are, the SwiGLU of every layer, and the paged
 # pool's routes for its attention layers
@@ -70,6 +80,11 @@ def _engine(family: str, route, **serving):
         model = LatentSlotModel(
             init_latent_params(jax.random.key(0), mc), mc,
             kv_page=cfg.kv_page)
+        return ServingEngine(serving=cfg, model=model)
+    if family == "swa":
+        model = WindowSlotModel(
+            init_swa_params(jax.random.key(0), SWA), SWA,
+            kv_page=cfg.kv_page, paged_attn=cfg.paged_attn)
         return ServingEngine(serving=cfg, model=model)
     if family == "hybrid":
         model = HybridSlotModel(
@@ -132,6 +147,12 @@ CASES = [
      | {"sample"}),
     ("hybrid", "kernel", "admit", TRUNK | SSM | {"sample"}),
     ("hybrid", "kernel", "chunk", TRUNK | SSM | {"gather_attn"}),
+    ("swa", "kernel", "decode", TRUNK | WINDOW | ROUTE["kernel"]
+     | {"sample"}),
+    ("swa", "gather", "decode", TRUNK | WINDOW | ROUTE["gather"]
+     | {"sample"}),
+    ("swa", "kernel", "admit", TRUNK | WINDOW | {"gather_attn", "sample"}),
+    ("swa", "kernel", "chunk", TRUNK | WINDOW | {"gather_attn"}),
 ]
 
 

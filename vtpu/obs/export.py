@@ -131,6 +131,10 @@ COUNTERS = {
     "ssm_kernel_ticks": ("ssm_kernel_ticks",
                          "Decode ticks whose step updated the recurrent "
                          "state in the one-visit kernel"),
+    "window_rows_read": ("window_rows_read",
+                         "Ring positions the dispatched slots' window "
+                         "attention read (a layer), summed over decode "
+                         "ticks (models whose window layers keep a ring)"),
     "paged_attn_kernel_ticks": ("paged_attn_kernel_ticks",
                                 "Ticks routed to the fused paged-attention "
                                 "kernel (table walked in place)"),
@@ -274,6 +278,12 @@ GAUGES = {
                               "Bytes of recurrent rows every slot holds "
                               "beside the pool, whatever a session's "
                               "length (0: no state-space layers)", 1),
+    "window_ring": ("window_ring_rows",
+                    "Rows of the ring a window layer keeps a slot (None: "
+                    "no window layers)", 1),
+    "ring_bytes_per_position": ("ring_bytes_per_position",
+                                "Bytes a cached token would cost the window "
+                                "layers were they paged", 1),
     "kv_pool_free": ("kv_pool_free_blocks", "Free pool blocks", 1),
     "kv_pool_used": ("kv_pool_used_blocks", "Allocated pool blocks", 1),
     "kv_pool_used_hwm": ("kv_pool_used_blocks_hwm",

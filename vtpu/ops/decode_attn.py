@@ -1113,6 +1113,153 @@ def latent_decode_attention(q: jax.Array, pool: jax.Array, table: jax.Array,
 
 
 # --------------------------------------------------------------------------
+# The walk over keys wider than values (a decoder whose heads are 192 wide
+# for keys and 128 for values, few key/value heads under many query heads).
+# A cached token is one row of each of two planes, ``k [L, n_blocks, page,
+# Hk * Dk]`` and ``v [L, n_blocks, page, Hk * Dv]``: its key/value heads side
+# by side, whole rows of 128 lanes whatever a head's width, so a page is
+# copied as it is stored. The walk is ``_latent_kernel``'s with the values in
+# a plane of their own: a slot's Hq queries come spread over the key row
+# (``vtpu.ops.window_attn.spread_queries``: a query head's Dk columns at its
+# key/value head's place, zeros elsewhere), one product against a group's key
+# rows scores every query head against its own key head, and the
+# probabilities against the value rows give every head's mix in its own
+# key/value head's columns, which the caller keeps.
+
+
+def _wide_kernel(lay_ref, tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                 k_buf, v_buf, sems, turn_ref, m_ref, d_ref, acc_ref, *,
+                 scale: float, page: int, group: int):
+    """One slot a grid step: its Hq spread queries (Hq, Hk * Dk) against
+    its live pages' key rows, ``group`` pages a wait into one of two VMEM
+    buffers a plane, the next group (or the next slot's first) in flight
+    meanwhile; scores (Hq, group * page) in float32 under the running
+    maximum and sum, the probabilities against the value rows. The buffers
+    are zeroed once (what is masked must still be finite); a slot with
+    nothing to read visits one page and gives a finite row nobody reads."""
+    planes = ((k_hbm, k_buf), (v_hbm, v_buf))
+    b, nb = pl.program_id(0), pl.num_programs(0)
+    width = group * page
+    lay = lay_ref[0]
+    live = functools.partial(_live_pages, len_ref, t=1, page=page)
+
+    def copy_group(row, g, slot, wait: bool):
+        n = jnp.minimum(live(row) - g * group, group)
+
+        def one(i, _):
+            blk = tbl_ref[row, g * group + i]
+            at = pl.ds(pl.multiple_of(i * page, page), page)
+            for p, (pool, buf) in enumerate(planes):
+                dma = pltpu.make_async_copy(
+                    pool.at[lay, blk], buf.at[slot, at], sems.at[p, slot])
+                if wait:
+                    dma.wait()
+                else:
+                    dma.start()
+            return _
+
+        jax.lax.fori_loop(0, n, one, 0)
+
+    @pl.when(b == 0)
+    def _first():
+        turn_ref[0] = 0
+        k_buf[...] = jnp.zeros(k_buf.shape, k_buf.dtype)
+        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+        copy_group(0, 0, 0, wait=False)
+
+    _init_accumulators(m_ref, d_ref, acc_ref)
+    n_groups = pl.cdiv(live(b), group)
+    turn = turn_ref[0]
+    q = q_ref[0]                                          # (Hq, Hk * Dk)
+    length = len_ref[b, 0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+
+    def attend_group(g, _):
+        slot = (turn + g) % 2
+        last = g + 1 == n_groups
+
+        @pl.when(jnp.logical_not(last) | (b + 1 < nb))
+        def _prefetch():
+            copy_group(jnp.where(last, jnp.minimum(b + 1, nb - 1), b),
+                       jnp.where(last, 0, g + 1), 1 - slot, wait=False)
+
+        copy_group(b, g, slot, wait=True)
+        s = jax.lax.dot_general(
+            q, k_buf[slot], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale    # (Hq, width)
+        s = jnp.where(g * width + lane < length, s, _NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        d_ref[...] = d_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[...] = m_new
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p.astype(v_buf.dtype), v_buf[slot],
+            preferred_element_type=jnp.float32)
+        return _
+
+    jax.lax.fori_loop(0, n_groups, attend_group, 0)
+    turn_ref[0] = (turn + n_groups) % 2
+    o_ref[0] = (acc_ref[...] / d_ref[...]).astype(o_ref.dtype)
+
+
+def wide_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
+                          table: jax.Array, lens: jax.Array, layer,
+                          scale: float,
+                          interpret: bool | None = None) -> jax.Array:
+    """A decode step's attention over key and value planes of unequal
+    width, walked in place.
+
+    q ``[B, Hq, Hk * Dk]``: a slot's query heads spread over the key row.
+    k_pool ``[L, n_blocks, page, Hk * Dk]`` and v_pool ``[L, n_blocks,
+    page, Hk * Dv]``: the WHOLE planes, never a layer's slice. table
+    ``[B, Wp]`` and ``layer`` as ``paged_decode_attention`` takes them;
+    lens ``[B]``: the rows a slot reads (its first ``lens`` positions; 0
+    reads nothing). Returns ``[B, Hq, Hk * Dv]``: each query head's mix
+    under its own weights of every key/value head's values, of which its
+    own head's columns are the attention's result. The kernel is
+    ``wide_walk`` in a trace, under the caller's scope."""
+    b, hq, ck = q.shape
+    page, cv = k_pool.shape[2], v_pool.shape[3]
+    if (k_pool.shape[3] != ck or v_pool.shape[:3] != k_pool.shape[:3]
+            or table.shape[0] != b):
+        raise ValueError(
+            f"queries {q.shape} and table {table.shape} do not fit the "
+            f"planes {k_pool.shape}, {v_pool.shape}")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    wp = table.shape[1]
+    lens = jnp.minimum(lens.astype(jnp.int32), wp * page)[:, None]
+    group = max(1, min(_LATENT_GROUP_TOKENS // page, wp))
+    q_spec = pl.BlockSpec((1, hq, ck), lambda i, *_: (i, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        functools.partial(_wide_kernel, scale=scale, page=page, group=group),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,  # layer index, table, lengths
+            grid=(b,),
+            in_specs=[q_spec, hbm, hbm],
+            out_specs=pl.BlockSpec((1, hq, cv), lambda i, *_: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, group * page, ck), k_pool.dtype),
+                pltpu.VMEM((2, group * page, cv), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),  # plane x buffer slot
+                pltpu.SMEM((1,), jnp.int32),      # which buffer slot is next
+                pltpu.VMEM((hq, 1), jnp.float32),   # maximum
+                pltpu.VMEM((hq, 1), jnp.float32),   # denominator
+                pltpu.VMEM((hq, cv), jnp.float32),  # accumulator
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, hq, cv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_GROUP_VMEM_BYTES + (24 << 20)),
+        interpret=interpret,
+        name="wide_walk",
+    )(_layer_arr(layer), table, lens, q, k_pool, v_pool)
+
+
+# --------------------------------------------------------------------------
 # HLO audits: prove the pool gather, and every other pool-sized result,
 # disappeared from a compiled step.
 

@@ -6,7 +6,8 @@ serving engine is model-agnostic so every family it schedules can also be
 served: the dense transformer (KV-cache decode, bounded read windows), the
 selective SSM (O(1) recurrent state — no cache growth with context, the
 profile attention can't offer), and models that keep both kinds of state a
-session (``HybridSlotModel``: recurrent rows beside a paged pool). An
+session (``HybridSlotModel``: recurrent rows beside a paged pool;
+``WindowSlotModel``: a ring of the last positions beside a paged pool). An
 adapter owns the per-slot device state; the engine owns slots, admission,
 and streaming.
 
@@ -41,7 +42,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from vtpu.models import hybrid, slots as slot_steps, transformer
+from vtpu.models import hybrid, slots as slot_steps, swa, transformer
 from vtpu.models.hybrid import (
     hybrid_decode_step,
     hybrid_prefill_chunk,
@@ -792,5 +793,125 @@ class HybridSlotModel:
         if block_ids is None:  # the slot's own table row
             block_ids = state["table"][slot, :window // self.kv_page]
         return hybrid_prefill_chunk(
+            params, self.cfg, state, chunk, slot, offset, new_len, window,
+            block_ids)
+
+
+class WindowSlotModel:
+    """Window layers among full layers (vtpu/models/swa): a session's cache
+    is of two kinds, pages of the paged pool for the full layers and a
+    slot-indexed ring of ``window`` rows for the window layers, in one
+    engine state, so the allocator, batched and chunked admission, the read
+    windows and the sampler serve it as they serve the other families.
+
+    It states what the engine cannot know of it: ``read_windows``,
+    ``kv_bytes_per_token`` (the full layers alone: pages are charged for
+    them and for nothing else), ``recurrent_state_bytes`` (the rings,
+    whatever a session's length), ``window_ring`` (the rows a ring holds)
+    and ``ring_bytes_per_position`` (what a cached token would cost the
+    window layers were they paged). Paged only; ``paged_attn`` forces the
+    full layers' decode route as the dense family's does. What a session
+    with rings cannot do yet is refused by name, each with the mechanism
+    that is missing (``check_serving``, ``refuses``)."""
+
+    supports_kv_buckets = True
+    mesh = None
+    refuses = {
+        "register_prefix": (
+            "a shared prefix is pages; a session that starts from one also "
+            "needs the window layers' rings as they stood at the prefix's "
+            "last token, and no snapshot of them is kept at a boundary"),
+        "drain": (
+            "migration ships a session as pool pages; the window layers' "
+            "rings have no staging"),
+    }
+
+    def __init__(self, params: Any, cfg: Any, kv_page: Optional[int] = None,
+                 kv_pool_blocks: Optional[int] = None,
+                 read_windows: Optional[tuple] = None,
+                 paged_attn: Optional[str] = None,
+                 mesh: Optional[Any] = None):
+        if mesh is not None:
+            raise ValueError(
+                "WindowSlotModel has no sharding rule for the rings (rows "
+                "of key/value heads side by side beside a pool of the "
+                "same): pass no mesh")
+        if kv_page is None:
+            raise ValueError(
+                "WindowSlotModel has a paged cache only: set kv_page")
+        if getattr(cfg, "kv_int8", False):
+            raise ValueError(
+                "WindowSlotModel has no int8 cache: a row holds a token's "
+                "heads side by side, which the per-head scales' planes do "
+                "not follow")
+        if paged_attn is not None and paged_attn not in PAGED_ATTN_ROUTES:
+            raise ValueError(
+                f"paged_attn must be one of {PAGED_ATTN_ROUTES} or None "
+                f"(auto), got {paged_attn!r}")
+        _check_read_windows(read_windows, kv_page, cfg.max_seq)
+        self.cfg = cfg
+        self.max_context = cfg.max_seq
+        self.kv_page = kv_page
+        self.kv_pool_blocks = kv_pool_blocks
+        self.n_kv_blocks = None
+        self.paged_attn = paged_attn
+        self.read_windows = tuple(sorted(read_windows)) if read_windows else None
+        self.kv_bytes_per_token = cfg.kv_bytes_per_token
+        self.window_ring = cfg.window
+        self.ring_bytes_per_position = cfg.ring_bytes_per_position
+        self.params = {**params, "layers": swa.hold_projections(
+            params["layers"], cfg)}
+
+    def check_serving(self, serving) -> None:
+        """Refuse the ServingConfig options this family cannot serve."""
+        if serving.spec_tokens:
+            raise ValueError(
+                "WindowSlotModel has no spec_step: a rejected draft would "
+                "need the rings' overwritten rows back, and a step keeps no "
+                "earlier copy (spec_tokens=0)")
+        if serving.kv_swap is not None:
+            raise ValueError(
+                "WindowSlotModel cannot park or swap a session: its pages "
+                "could be staged, its rings have no snapshot at the "
+                "boundary (kv_swap=None; park, resume and migrate need it)")
+        if serving.disagg is not None:
+            raise ValueError(
+                "WindowSlotModel has no slot-less prefill: a prefill worker "
+                "fills pool blocks, and the rings have no home outside a "
+                "slot (disagg=None)")
+
+    def recurrent_state_bytes(self, slots: int) -> int:
+        return slots * self.cfg.ring_bytes_per_slot
+
+    def init_state(self, slots: int):
+        self.n_kv_blocks = _pool_blocks(self, slots)
+        return swa.init_swa_state(
+            self.cfg, slots, self.kv_page, self.n_kv_blocks)
+
+    def prefill_into_slot(self, params, state, padded, slot, true_len):
+        logits, new = self.prefill_into_slots(
+            params, state, padded, jnp.asarray(slot)[None],
+            jnp.asarray(true_len)[None])
+        return logits[0], new
+
+    def prefill_into_slots(self, params, state, padded, slots, true_lens):
+        return swa.swa_prefill_rows(
+            params, self.cfg, state, padded, slots, true_lens)
+
+    def decode_step(self, params, state, tokens, active, kv_bucket,
+                    unroll=False):
+        del unroll  # seven layers of three kinds: always walked unrolled
+        return swa.swa_decode_step(
+            params, self.cfg, state, tokens, active,
+            kv_bucket or self.max_context, paged_attn=self.paged_attn)
+
+    def prefill_chunk_into_slot(self, params, state, chunk, slot, offset,
+                                new_len, kv_bucket=0, unroll=False,
+                                block_ids=None):
+        del unroll
+        window = kv_bucket or self.max_context
+        if block_ids is None:  # the slot's own table row
+            block_ids = state["table"][slot, :window // self.kv_page]
+        return swa.swa_prefill_chunk(
             params, self.cfg, state, chunk, slot, offset, new_len, window,
             block_ids)

@@ -886,6 +886,9 @@ class ServingEngine:
             model.recurrent_state_bytes(b)
             if hasattr(model, "recurrent_state_bytes") else 0)
         self._ssm_in_kernel = getattr(model, "ssm_step_in_kernel", None)
+        # rows of the ring a window layer keeps a slot (a family with
+        # window layers says; None for every other)
+        self._window_ring = getattr(model, "window_ring", None)
         if (serving.paged_attn is not None
                 and self._paged_attn != serving.paged_attn):
             raise ValueError(
@@ -1461,6 +1464,15 @@ class ServingEngine:
                        # every one on a TPU, none elsewhere)
                        "ssm_rows_stepped": 0, "ssm_rows_live": 0,
                        "ssm_kernel_ticks": 0,
+                       # a slot model whose window layers keep a ring of
+                       # the last positions a slot (``window_ring``): ring
+                       # positions the dispatched slots' window attention
+                       # read (a layer), summed over decode ticks (the
+                       # smaller of a slot's length and the ring), beside
+                       # attn_visible_tokens above, which for such a model
+                       # is what its full layers' walk visits; 0 for every
+                       # other model
+                       "window_rows_read": 0,
                        # KV overcommit: parks/resumes are lifecycle events;
                        # evicted_blocks counts pool blocks reclaimed from
                        # parked sessions; swap_out/in_bytes are the D2H/H2D
@@ -3552,12 +3564,16 @@ class ServingEngine:
         hist = self._stats["kv_bucket_hist"]
         key = int(kv_bucket) or int(self.model.max_context or 0)
         hist[key] = hist.get(key, 0) + ticks
-        if self._select_topk:
+        if self._select_topk or self._window_ring:
             # + 1: a step sees the token it writes
             self._stats["attn_visible_tokens"] += (sum(lens) + len(lens)) * ticks
+        if self._select_topk:
             self._stats["attn_selected_tokens"] += sum(
                 min(ln + 1, self._select_topk) for ln in lens) * ticks
-        if self._recurrent_bytes:
+        if self._window_ring:
+            self._stats["window_rows_read"] += sum(
+                min(ln + 1, self._window_ring) for ln in lens) * ticks
+        elif self._recurrent_bytes:
             self._stats["ssm_rows_stepped"] += self.serving.slots * ticks
             self._stats["ssm_rows_live"] += len(lens) * ticks
             if self._ssm_in_kernel is not None and self._ssm_in_kernel():
@@ -3962,6 +3978,12 @@ class ServingEngine:
         # a session's memory that does not grow with its length: the
         # recurrent rows of every slot, beside the pool's bytes above
         s["recurrent_state_bytes"] = self._recurrent_bytes
+        # ... of which a family with window layers holds rings: the rows a
+        # ring has, and what a cached token would cost those layers were
+        # they paged as the full layers are (None: no window layers)
+        s["window_ring"] = self._window_ring
+        s["ring_bytes_per_position"] = getattr(
+            self.model, "ring_bytes_per_position", None)
         if self._paged:
             usable = self._n_blocks - 1  # minus the reserved null block
             free = self._alloc.free_blocks
