@@ -61,6 +61,19 @@ def _custom_calls(fn, *avals) -> int:
         "tpu_custom_call")
 
 
+_EXPERT_KERNEL = re.compile(
+    r'op_name="[^"]*experts/jit\(grouped_experts_ffn\)/'
+    r'experts_(?:gate_up|down)/')
+
+
+def _expert_kernels(text: str) -> int:
+    """Custom calls of a compiled text that are the held experts' grouped
+    kernels (vtpu/ops/grouped_ffn.py, PR 41: ``experts_gate_up`` and
+    ``experts_down`` under the ``experts`` scope), two a sparse layer."""
+    return sum(1 for line in text.splitlines()
+               if "tpu_custom_call" in line and _EXPERT_KERNEL.search(line))
+
+
 def _on(sharding):
     def aval(shape, dtype, spec=None):
         sh = sharding if spec is None else NamedSharding(sharding.mesh, spec)
@@ -527,8 +540,9 @@ def test_dense_latent_decode_step_walks_the_pool_in_place(
     them; nothing of a read window's size either, gathered, sliced or
     copied a slot (96 slots x the window x 640 is what ``window_rows``
     would make: 3.0 GB a layer at 24 k; an eighth of it is the line);
-    the temporaries stay under a hundredth of it; the state holds one
-    plane."""
+    the temporaries stay under a hundredth of it beside the held experts'
+    weighed activations (since PR 41: the worst routing's 20 tiles of 96
+    row slots, 5.9 MB whatever the window); the state holds one plane."""
     slots = 96
     M, cfg, params, state, on_chip = _dense_latent_shapes(v5e, slots, 2048)
     assert sorted(state) == ["ckv", "len", "table"]
@@ -539,7 +553,8 @@ def test_dense_latent_decode_step_walks_the_pool_in_place(
     ).lower(params, cfg, state, on_chip(jnp.zeros((slots,), jnp.int32)),
             on_chip(jnp.zeros((slots,), bool)), window).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == cfg.n_layers
+    assert _expert_kernels(text) == 2 * cfg.n_sparse_layers
+    assert text.count("tpu_custom_call") == cfg.n_layers + 2
     ops = _pool_plane_ops(text, state["ckv"])
     assert set(ops) <= POOL_SIZED_OK, ops
     assert ops["scatter"] == ops["fusion"] == cfg.n_layers, ops
@@ -552,7 +567,12 @@ def test_dense_latent_decode_step_walks_the_pool_in_place(
     for shape in re.findall(r" = \w+\[([0-9,]+)\]\S* copy\(", text):
         assert slots not in [int(d) for d in shape.split(",")][:1] \
             or math.prod(int(d) for d in shape.split(",")) < a_window // 8
-    assert compiled.memory_analysis().temp_size_in_bytes < a_window * 2 // 100
+    from vtpu.ops import grouped_ffn
+
+    _, tm, tiles = grouped_ffn.plan(slots, cfg.held[1], cfg.top_k)
+    assert (tm, tiles) == (96, 20)
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        a_window * 2 // 100 + tiles * tm * cfg.d_ff_expert * 2)
 
 
 @pytest.mark.parametrize("family", ["selects", "dense"])
@@ -592,6 +612,8 @@ def test_latent_chunk_expands_its_window_a_group_of_heads_at_a_time(
             on_chip(jnp.zeros((window // 64,), jnp.int32))).compile()
     text = compiled.as_text()
     kernels = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert _expert_kernels(text) == 2 * cfg.n_sparse_layers
+    kernels = [line for line in kernels if not _EXPERT_KERNEL.search(line)]
     assert len(kernels) == cfg.n_layers
     assert all(re.search(r'op_name="[^"]*latent_attn/latent_chunk', line)
                for line in kernels), [k[-300:] for k in kernels]
@@ -613,6 +635,44 @@ def test_latent_chunk_expands_its_window_a_group_of_heads_at_a_time(
     for plane in sorted(set(state) & {"ckv", "ik"}):
         ops = _pool_plane_ops(text, state[plane])
         assert set(ops) <= POOL_SIZED_OK, (plane, ops)
+
+
+# (H, D, F, top_k) of the three configurations that hold experts, and the
+# rows of their decode step; the admission bucket and a chunk are 256 and 512
+HELD_EXPERTS = {"mimo-v2.5-7l-ep16": (16, 4096, 2048, 8, 96),
+                "deepseek-v3.2-5l-ep16": (16, 7168, 2048, 8, 16),
+                "deepseek-v2-5l-ep8": (20, 5120, 1536, 6, 96)}
+
+
+@pytest.mark.parametrize("program",
+                         ["step", "admission", "chunk", "most_rows"])
+@pytest.mark.parametrize("config", sorted(HELD_EXPERTS))
+def test_held_experts_kernels_compile(v5e, config, program):
+    """The grouped kernels of ``vtpu/ops/grouped_ffn.py`` (PR 41) at the
+    published widths and the rows of each program, and at the most rows
+    ``takes`` lets a launch have (several prompts admitted together, a
+    longer chunk: a launch it takes must compile, for nothing falls back
+    from Mosaic's refusal), over a stack of two layers read in place: two
+    Mosaic kernels, and beside the worst routing's tiles of weighed
+    activations no temporary of a layer's stack (a layer sliced out for a
+    kernel would be copied)."""
+    from vtpu.ops.grouped_ffn import (
+        _MOST_ROWS, grouped_experts_ffn, plan, takes)
+
+    h, d, f, top_k, slots = HELD_EXPERTS[config]
+    t = {"step": slots, "admission": 256, "chunk": 512,
+         "most_rows": _MOST_ROWS}[program]
+    assert takes(t, d, f) and not takes(_MOST_ROWS + 1, d, f)
+    _, tm, tiles = plan(t, h, top_k)
+    aval = _on(SingleDeviceSharding(v5e[0]))
+    compiled = jax.jit(
+        lambda x, g, a, b, c: grouped_experts_ffn(x, g, a, b, c, 1, top_k)
+    ).lower(aval((t, d), jnp.bfloat16), aval((t, h), jnp.float32),
+            aval((2, h, d, f), jnp.bfloat16), aval((2, h, d, f), jnp.bfloat16),
+            aval((2, h, f, d), jnp.bfloat16)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        tiles * tm * f * 2 + h * d * f * 2 // 8)
 
 
 # -- the hybrid family at its cell's sizes ----------------------------------
@@ -818,8 +878,13 @@ def test_window_family_programs_compile_at_the_cells_sizes(
     for shape in re.findall(r" = bf16\[([0-9,]+)\]\S* (?:copy|transpose)\(",
                             text):
         assert math.prod(int(d) for d in shape.split(",")) < wq // 5, shape
+    # the six expert layers' two kernels each, the stacks read in place
+    # (a layer sliced out of one for a kernel would be a copy of 268 MB)
+    assert _expert_kernels(text) == 2 * cfg.ffn_types.count("moe")
+    assert mem.temp_size_in_bytes < 3 * wq * 2, mem.temp_size_in_bytes
     if program == "step":
-        assert text.count("tpu_custom_call") == 2    # a full layer each
+        # beside them a full layer each
+        assert text.count("tpu_custom_call") - _expert_kernels(text) == 2
         a_window = window * 768                      # one slot's keys
         assert decode_attn.count_pool_gathers(text, a_window // 2) == 0
         ring_layer = rings // 5

@@ -345,7 +345,7 @@ def _sparse_ffn(cfg: LatentConfig, lp, x):
                 cfg.route_scale)
         gates = gates[:, first:first + count]
     with jax.named_scope("experts"):
-        y = held_experts_ffn(lp, n, gates) + _swiglu(
+        y = held_experts_ffn(lp, n, gates, cfg.top_k) + _swiglu(
             n, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
     return x + y.reshape(shape)
 
@@ -360,6 +360,11 @@ class _Layer:
 
     def __getitem__(self, name: str) -> jax.Array:
         return self.stack[name][self.i]
+
+    def stacked(self, name: str) -> tuple[jax.Array, int]:
+        """(the leaf whole, this layer's index), for a kernel that reads
+        the stack in place."""
+        return self.stack[name], self.i
 
 
 def _walk(params: Params, cfg: LatentConfig, ckv, ik, tokens, positions,

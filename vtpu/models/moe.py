@@ -27,6 +27,7 @@ from vtpu.models.transformer import (
     _embed, _lm_head, _o_proj, _prefill_cache, _qkv,
 )
 from vtpu.ops import scaled_normal, rms_norm, rope_angles, causal_attention
+from vtpu.ops.grouped_ffn import grouped_experts_ffn, takes
 
 Params = dict[str, Any]
 
@@ -186,20 +187,65 @@ def group_limited_route(
     return s * hot * scale
 
 
-def held_experts_ffn(lp_e: dict[str, jax.Array], x: jax.Array,
-                     gates: jax.Array) -> jax.Array:
-    """This holder's part of an expert layer's result: the experts whose
-    stacks it is given ([H, D, F] / [H, F, D]: a share of the layer's,
-    told which by the ``gates [T, H]`` it is handed, the router's columns
-    for exactly these experts), each a SwiGLU over x [T, D], weighed by its
-    gate and summed. Every held expert computes over all T rows and the
-    gate zeroes the rows not routed to it (exact, static shapes, H times
-    the rows' products: PERF.md says what that costs)."""
+def experts_grouped(t: int, d: int, f: int) -> bool:
+    """Whether ``held_experts_ffn`` runs a launch of ``t`` rows ``d`` wide
+    through experts ``f`` wide in the grouped kernels: the shapes they
+    take (``ops.grouped_ffn.takes``: up to the most rows they were compiled
+    and timed at, and what fits the chip's VMEM), on a TPU. At every
+    number of rows the cells' programs have (a step's 16 and 96, the
+    admission bucket's 256, a chunk's 512) the kernels are the faster on a
+    v5e: PERF.md section 3 has the rule, section 6 the table. Resolved
+    when a program is traced, from what it can observe, as ``ops.latent
+    .attends_in_kernel``; the engine counts its launches' rows by the same
+    call (``stats()["expert_rows_grouped"]``)."""
+    return takes(t, d, f) and jax.default_backend() == "tpu"
+
+
+def held_experts_all_rows(lp_e: dict[str, jax.Array], x: jax.Array,
+                          gates: jax.Array) -> jax.Array:
+    """``held_experts_ffn`` with every held expert computing over all T
+    rows, the gate zeroing the rows not routed to it: H times the rows'
+    products (32 times what a chunk's routing needs; PERF.md section 6,
+    PR 41). What a launch the kernels do not take runs, the CPU's route,
+    and the kernels' reference: the same arithmetic a pair."""
     gate = jnp.einsum("td,hdf->htf", x, lp_e["w_gate"])
     up = jnp.einsum("td,hdf->htf", x, lp_e["w_up"])
     act = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
     act = (act * gates.T[:, :, None]).astype(x.dtype)  # weighed, then summed
     return jnp.einsum("htf,hfd->td", act, lp_e["w_down"])
+
+
+def _stacked(lp_e, name: str) -> tuple[jax.Array, Any]:
+    """(a leaf with its layers' axis, this layer's index): the stack as
+    stored where ``lp_e`` is a layer of one (``latent._Layer.stacked``), a
+    dict's leaf under a leading axis of one."""
+    if isinstance(lp_e, dict):
+        return lp_e[name][None], 0
+    return lp_e.stacked(name)
+
+
+def held_experts_ffn(lp_e: dict[str, jax.Array], x: jax.Array,
+                     gates: jax.Array, top_k: int | None = None) -> jax.Array:
+    """This holder's part of an expert layer's result: the experts whose
+    stacks it is given ([H, D, F] / [H, F, D]: a share of the layer's,
+    told which by the ``gates [T, H]`` it is handed, the router's columns
+    for exactly these experts), each a SwiGLU over x [T, D], weighed by its
+    gate and summed. Exact and dropless on static shapes.
+
+    On the launches ``experts_grouped`` names each held expert multiplies
+    only the rows with a gate other than zero (``ops.grouped_ffn``: the
+    pairs laid out expert by expert for the worst routing, the work done
+    following the routed rows, an expert without a row not read), and a
+    row's pairs are summed in float32; every other launch runs
+    ``held_experts_all_rows``. ``top_k`` is the most gates other than zero
+    a row can have, the router's (None: one for every expert held); it
+    sizes the worst routing's buffers and chooses nothing."""
+    w_down, layer = _stacked(lp_e, "w_down")
+    if not experts_grouped(*x.shape, w_down.shape[-2]):
+        return held_experts_all_rows(lp_e, x, gates)
+    return grouped_experts_ffn(
+        x, gates, _stacked(lp_e, "w_gate")[0], _stacked(lp_e, "w_up")[0],
+        w_down, layer, top_k or gates.shape[1]).astype(x.dtype)
 
 
 def expert_ffn(lp_e: dict[str, jax.Array], slots: jax.Array) -> jax.Array:

@@ -340,7 +340,7 @@ def _expert_ffn(cfg: SwaConfig, lp, x):
         gates = grouped_route(lp["router"], lp["route_bias"], n, cfg.top_k,
                               1, 1, 1.0)[:, first:first + count]
     with jax.named_scope("experts"):
-        y = held_experts_ffn(lp, n, gates)
+        y = held_experts_ffn(lp, n, gates, cfg.top_k)
     return x + y.reshape(shape)
 
 

@@ -115,6 +115,13 @@ COUNTERS = {
                             "up to the chunk's end rounded up to a block "
                             "in the kernel, the whole read window in "
                             "XLA's code"),
+    "expert_rows": ("expert_rows",
+                    "Rows of every launch (step, admission, chunk) of a "
+                    "model that holds experts"),
+    "expert_rows_grouped": ("expert_rows_grouped",
+                            "Of those, the rows of launches whose shape "
+                            "put each held expert over the rows routed to "
+                            "it alone rather than over all rows"),
     "latent_rows_live": ("latent_rows_live",
                          "Cached tokens the dispatched slots could see, "
                          "summed over decode ticks (models whose decode "

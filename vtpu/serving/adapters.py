@@ -42,7 +42,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from vtpu.models import hybrid, slots as slot_steps, swa, transformer
+from vtpu.models import hybrid, moe, slots as slot_steps, swa, transformer
 from vtpu.models.hybrid import (
     hybrid_decode_step,
     hybrid_prefill_chunk,
@@ -547,6 +547,16 @@ class SsmSlotModel:
         }
 
 
+def _experts_grouped(model, rows: int) -> bool:
+    """Whether a launch of ``rows`` rows has each expert the model holds
+    multiply the rows routed to it alone: the rule the traced program
+    applies to its shapes (``vtpu.models.moe.experts_grouped``), for the
+    engine's counters. ``LatentSlotModel``'s and ``WindowSlotModel``'s
+    ``experts_grouped``."""
+    cfg = model.cfg
+    return moe.experts_grouped(rows, cfg.d_model, cfg.d_ff_expert)
+
+
 class LatentSlotModel:
     """Latent attention, under a learned sparse selection or over all that
     is cached, over two stacks of layers (vtpu/models/latent): a paged
@@ -563,8 +573,9 @@ class LatentSlotModel:
     ``walks_latent_plane``), ``chunk_attn_expands`` (the form
     a chunk's attention takes at a given length), ``chunk_keys_attended``
     (whether that form runs in the kernel, and the window positions it
-    then multiplies) and ``pool_planes``. All read off the model's
-    configuration and the backend. Paged only. Not supported, and
+    then multiplies), ``experts_grouped`` (whether a launch of so many rows
+    has each held expert multiply its own rows alone) and ``pool_planes``.
+    All read off the model's configuration and the backend. Paged only. Not supported, and
     refused by name: a mesh, an int8 cache, speculation and the swap tier
     (a forced ``ServingConfig.paged_attn`` the engine refuses itself: there
     is one route, so ``paged_attn`` is None)."""
@@ -644,6 +655,8 @@ class LatentSlotModel:
         cfg = self.cfg
         return chunk_keys_attended(
             queries, cfg.kv_rank, cfg.nope_dim, cfg.v_dim, end, window)
+
+    experts_grouped = _experts_grouped
 
     def decode_step(self, params, state, tokens, active, kv_bucket,
                     unroll=False):
@@ -807,9 +820,11 @@ class WindowSlotModel:
     It states what the engine cannot know of it: ``read_windows``,
     ``kv_bytes_per_token`` (the full layers alone: pages are charged for
     them and for nothing else), ``recurrent_state_bytes`` (the rings,
-    whatever a session's length), ``window_ring`` (the rows a ring holds)
-    and ``ring_bytes_per_position`` (what a cached token would cost the
-    window layers were they paged). Paged only; ``paged_attn`` forces the
+    whatever a session's length), ``window_ring`` (the rows a ring holds),
+    ``ring_bytes_per_position`` (what a cached token would cost the
+    window layers were they paged) and ``experts_grouped`` (whether a
+    launch of so many rows has each held expert multiply its own rows
+    alone). Paged only; ``paged_attn`` forces the
     full layers' decode route as the dense family's does. What a session
     with rings cannot do yet is refused by name, each with the mechanism
     that is missing (``check_serving``, ``refuses``)."""
@@ -882,6 +897,8 @@ class WindowSlotModel:
 
     def recurrent_state_bytes(self, slots: int) -> int:
         return slots * self.cfg.ring_bytes_per_slot
+
+    experts_grouped = _experts_grouped
 
     def init_state(self, slots: int):
         self.n_kv_blocks = _pool_blocks(self, slots)

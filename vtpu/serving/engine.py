@@ -880,6 +880,7 @@ class ServingEngine:
         self._latent_walk = getattr(model, "walks_latent_plane", False)
         self._chunk_expands = getattr(model, "chunk_attn_expands", None)
         self._chunk_keys = getattr(model, "chunk_keys_attended", None)
+        self._experts_grouped = getattr(model, "experts_grouped", None)
         # bytes of recurrent rows the state holds beside its pages (a
         # family with state-space layers says; 0 for every other)
         self._recurrent_bytes = (
@@ -1446,6 +1447,14 @@ class ServingEngine:
                        # block; XLA's code: the whole read window)
                        "chunk_attn_kernel": 0, "chunk_keys_live": 0,
                        "chunk_keys_attended": 0,
+                       # a slot model that holds experts
+                       # (``experts_grouped``): the rows of every launch
+                       # (a step's slots, an admission's padded batch, a
+                       # chunk), and those of launches whose shape put
+                       # each held expert over its own rows alone (the
+                       # model's rule, read off the launch's static shape);
+                       # 0 for every other model
+                       "expert_rows": 0, "expert_rows_grouped": 0,
                        # a slot model whose decode step walks a latent
                        # plane (``walks_latent_plane``): cached tokens the
                        # dispatched slots could see, summed over decode
@@ -3163,6 +3172,7 @@ class ServingEngine:
             self.params, self.state, padded, jnp.int32(slot), jnp.int32(n)
         )
         self._stats["prefill_tokens"] += n
+        self._note_expert_rows(bucket)
         self._stats["prefill_batch_hist"][1] += 1
         self._finish_admit(slot, req, self._sample_first(logits), n)
 
@@ -3197,6 +3207,7 @@ class ServingEngine:
                 batch_keys,
             )
         self._stats["prefill_tokens"] += sum(lens)
+        self._note_expert_rows(n * bucket)
         rows = []
         for i, (slot, req) in enumerate(zip(slots, reqs)):
             self._begin_slot(slot, req, lens[i])
@@ -3443,6 +3454,7 @@ class ServingEngine:
                     self._stats["chunk_attn_kernel"] += int(kernel)
                     self._stats["chunk_keys_live"] += need
                     self._stats["chunk_keys_attended"] += attended
+                self._note_expert_rows(c)
                 self.trace.record("prefill_chunk", req.rid, slot, c)
                 if adm["off"] >= adm["padded"].shape[1]:  # final chunk
                     del self._admitting[slot]
@@ -3546,6 +3558,15 @@ class ServingEngine:
         """The ``tick`` id of the loop's profiler spans."""
         return self._stats["decode_ticks"] + self._stats["spec_ticks"]
 
+    def _note_expert_rows(self, rows: int, launches: int = 1) -> None:
+        """``launches`` launches of ``rows`` rows each through a model that
+        holds experts: host integers, no fetch."""
+        if self._experts_grouped is None:
+            return
+        self._stats["expert_rows"] += rows * launches
+        if self._experts_grouped(rows):
+            self._stats["expert_rows_grouped"] += rows * launches
+
     def _note_kv_window(self, kv_bucket: int, lens: list[int],
                         t: int = 1, ticks: int = 1) -> None:
         """Per-dispatch read-window telemetry. kv_bucket_hist surfaces the
@@ -3564,6 +3585,7 @@ class ServingEngine:
         hist = self._stats["kv_bucket_hist"]
         key = int(kv_bucket) or int(self.model.max_context or 0)
         hist[key] = hist.get(key, 0) + ticks
+        self._note_expert_rows(self.serving.slots * t, ticks)
         if self._select_topk or self._window_ring:
             # + 1: a step sees the token it writes
             self._stats["attn_visible_tokens"] += (sum(lens) + len(lens)) * ticks
