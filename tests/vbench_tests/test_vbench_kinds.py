@@ -281,7 +281,8 @@ def test_reference_walks_a_leading_dense_layer_then_sparse_ones(toy):
     rng = np.random.default_rng(3)
     pairs = [(rng.integers(0, 384, 21), list(rng.integers(0, 384, 9))),
              (rng.integers(0, 384, 5), list(rng.integers(0, 384, 30)))]
-    got = check.reference_logits(cfg, SEED, pairs)
+    got = check.reference_logits(
+        cfg, SEED, [check.replay(cfg, p, s) for p, s in pairs])
     for (prompt, served), logits in zip(pairs, got):
         tokens = np.concatenate([prompt, served[:-1]])
         want = _straight_line(cfg, w, tokens)[len(prompt) - 1:]

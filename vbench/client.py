@@ -95,8 +95,10 @@ class Client:
 
         def worker():
             try:
-                while not self._closing.is_set():
-                    with self._order:
+                while True:
+                    with self._order:  # close() waits for a submit begun
+                        if self._closing.is_set():
+                            return
                         plan = backlog.take()
                         rec, req = self._send(plan, _now())
                     rec.due_s = rec.sent_s
@@ -112,8 +114,10 @@ class Client:
     # ------------------------------------------------------------ ending
 
     def close(self) -> None:
-        """Send nothing more (streams in flight go on)."""
-        self._closing.set()
+        """Send nothing more (streams in flight go on). Once this returns
+        no saturated worker submits again: the engine may be stopped."""
+        with self._order:
+            self._closing.set()
 
     def join(self, timeout_s: float) -> bool:
         """Wait for every reader; call after the engine is stopped (its
@@ -132,11 +136,15 @@ class Client:
 
     def records(self) -> list[Record]:
         """Every record, times rebased to the window's start, with the
-        engine's queue-departure stamp read from its Request."""
+        engine's queue-departure stamp and, where the program keeps one,
+        its commit trail read from its Request."""
         out = []
         with self._lock:
             for r in self._records:
-                depart_ns, r.request = r.request.t_depart_ns, None
+                depart_ns, trail = (r.request.t_depart_ns,
+                                    getattr(r.request, "trail", None))
+                r.request = None
+                r.trail = None if trail is None else [int(p) for p in trail]
                 r.depart_s = (depart_ns / 1e9 - self.t0 if depart_ns
                               else float("nan"))
                 r.due_s -= self.t0

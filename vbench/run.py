@@ -303,7 +303,8 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
                                   len(r.tokens), r.status,
                                   round(r.due_s, 4), round(r.sent_s, 4),
                                   (round(r.stamps[0], 4) if r.stamps else None),
-                                  round(r.ended_s, 4)] for r in records]})
+                                  round(r.ended_s, 4), r.trail]
+                                 for r in records]})
     return result
 
 

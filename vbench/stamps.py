@@ -25,6 +25,8 @@ class Record:
     ended_s: float = math.nan
     prompt: object = None        # the token ids sent
     request: object = None       # the engine's Request (for t_depart_ns)
+    trail: Optional[list] = None  # Request.trail, where the program has
+    # one: the pass, within the request, that committed each token
 
 
 def percentile(values, q: float) -> Optional[float]:
