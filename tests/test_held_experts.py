@@ -217,17 +217,17 @@ def test_shapes_the_kernels_do_not_take(monkeypatch, t, d, f):
 def test_a_layer_of_a_stack_is_read_in_place(monkeypatch, given, top_k):
     """``held_experts_ffn`` hands the kernels the stacks whole with the
     layer's index when it is given a layer of a stack (the models'
-    ``_Layer``), and layer 1 of two answers as its own slice does, which a
+    ``LayerOfStack``), and layer 1 of two answers as its own slice does, which a
     dict of the layer's leaves goes in as under an axis of one; without the
     router's ``top_k`` the buffers are sized for a gate in every column
     and the answer is the same."""
-    from vtpu.models.latent import _Layer
+    from vtpu.models.latent import LayerOfStack
     h, e, t = 16, 256, 32
     both = [_stacks(s, h) for s in (1, 2)]
     stack = {k: jnp.stack([lp[k] for lp in both]) for k in both[0]}
     x = jax.random.normal(jax.random.key(1), (t, D), jnp.float32)
     gates = _routed(1, t, h, 8, e // 4)
-    leaf, layer = _Layer(stack, 1).stacked("w_up")
+    leaf, layer = LayerOfStack(stack, 1).stacked("w_up")
     assert leaf is stack["w_up"] and layer == 1
     handed = []
 
@@ -238,7 +238,7 @@ def test_a_layer_of_a_stack_is_read_in_place(monkeypatch, given, top_k):
 
     monkeypatch.setattr(moe, "experts_grouped", lambda t, d, f: True)
     monkeypatch.setattr(moe, "grouped_experts_ffn", interpreted)
-    lp = _Layer(stack, 1) if given == "layer_of_a_stack" else both[1]
+    lp = LayerOfStack(stack, 1) if given == "layer_of_a_stack" else both[1]
     got = moe.held_experts_ffn(lp, x, gates, top_k)
     _close(got, moe.held_experts_all_rows(both[1], x, gates))
     (w_gate, w_up, w_down, layer, k), = handed

@@ -889,3 +889,100 @@ def test_window_family_programs_compile_at_the_cells_sizes(
         assert decode_attn.count_pool_gathers(text, a_window // 2) == 0
         ring_layer = rings // 5
         assert mem.temp_size_in_bytes < ring_layer, mem.temp_size_in_bytes
+
+
+# -- generation by blocks at its cell's widths --------------------------------
+
+SDAR_SLOTS, SDAR_BLOCKS, SDAR_LAYERS = 96, 10241, 2
+
+
+def _blockdiff_shapes(v5e):
+    """SDAR's ``BlockDiffConfig`` at the published widths (32 query heads
+    on 4 key/value heads of 128, 16 of 128 experts 768 wide held, an eighth
+    of the vocabulary) in two of the cell's 24 layers (a whole program of 24
+    unrolled layers compiles in 40-60 s here: ``python -m vbench.rehearse
+    sdar-30b-a3b-24l-ep8`` is that rehearsal), with the adapter's params
+    and the engine state of `sdar_blockgen` (96 slots, 10240 blocks of 16)
+    as shapes on the described chip."""
+    from vtpu.models import blockdiff as M
+    from vtpu.models.transformer import hold_projections
+
+    cfg = M.BlockDiffConfig(
+        vocab=18992, d_model=2048, n_heads=32, n_kv_heads=4, head_dim=128,
+        n_layers=SDAR_LAYERS, d_ff=768, n_experts=128, held=(0, 16), top_k=8,
+        max_seq=6144, mask_token_id=18991, denoising_steps=2,
+        confidence_threshold=None)
+    chip = SingleDeviceSharding(v5e[0])
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_moe_params(jax.random.key(0), cfg)))
+    params["layers"] = hold_projections(params["layers"], cfg)
+    state = on_chip(jax.eval_shape(lambda: M.init_block_state(
+        cfg, SDAR_SLOTS, 16, SDAR_BLOCKS)))
+    return M, cfg, params, state, on_chip
+
+
+@pytest.mark.parametrize("program", ["pass", "chunk"])
+def test_block_generation_programs_compile_at_the_cells_widths(
+        v5e, monkeypatch, program):
+    """A pass of 4 rows a slot and a 512-token chunk of `sdar_blockgen`
+    compile for a v5e. A cached token's row as stored is 4 heads x 128 a
+    plane (2048 B a token a layer both planes). The pass walks the pool in
+    place, one Mosaic kernel a layer (``paged_attn`` under the scope
+    ``block_attn``: the grouped walk with its log-sum-exp out, named in
+    the compiled operation's path as both readers of a trace need it)
+    beside the held experts' two: nothing of a pool
+    plane's size is computed but the in-place scatters and what wraps them,
+    no window is gathered, the pool is updated in place, and no stack of
+    projections or experts is laid out anew (a layer sliced out of the
+    experts' stack for a kernel would be a copy of 151 MB)."""
+    from vtpu.models import slots as slot_steps
+    from vtpu.models.latent import LayerOfStack
+    from vtpu.models.moe import held_moe_ffn
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    M, cfg, params, state, on_chip = _blockdiff_shapes(v5e)
+    i32 = lambda *shape: on_chip(jnp.zeros(shape, jnp.int32))  # noqa: E731
+    assert state["k"].shape == (SDAR_LAYERS, SDAR_BLOCKS, 16, 4, 128)
+    window = 4096
+    if program == "pass":
+        compiled = jax.jit(
+            M.block_pass, static_argnums=(1, 4), donate_argnums=(2,)
+        ).lower(params, cfg, state,
+                on_chip(jnp.zeros((SDAR_SLOTS,), bool)), window).compile()
+    else:
+        def chunk(params, state, tokens, slot, offset, new_len, block_ids):
+            return slot_steps.chunked_prefill_into_slot(
+                params, cfg, state, tokens, slot, offset, new_len,
+                kv_bucket=window, unroll=True, ffn_fn=held_moe_ffn(cfg),
+                block_ids=block_ids, layer_of=LayerOfStack)
+
+        compiled = jax.jit(chunk, donate_argnums=(1,)).lower(
+            params, state, i32(1, 512), i32(), i32(), i32(),
+            i32(window // 16)).compile()
+    mem = compiled.memory_analysis()
+    pool = sum(math.prod(state[key].shape) * 2 for key in ("k", "v"))
+    assert mem.alias_size_in_bytes > pool               # updated in place
+    text = compiled.as_text()
+    big = count_pool_sized_ops(text, math.prod(state["k"].shape))
+    assert set(big) <= POOL_SIZED_OK, big
+    assert _expert_kernels(text) == 2 * SDAR_LAYERS
+    experts = math.prod(params["layers"]["w_gate"].shape[1:])
+    wq = math.prod(params["layers"]["wq"].shape[1:])
+    for shape in re.findall(r" = bf16\[([0-9,]+)\]\S* (?:copy|transpose)\(",
+                            text):
+        assert math.prod(int(d) for d in shape.split(",")) < wq, shape
+    if program == "pass":
+        assert text.count("tpu_custom_call") == 3 * SDAR_LAYERS
+        walks = re.findall(
+            r'custom_call_target="tpu_custom_call"[^\n]*'
+            r'op_name="([^"]*/paged_attn/pallas_call)"', text)
+        assert len(walks) == SDAR_LAYERS, walks[:3]
+        assert all("/attn/block_attn/paged_attn/" in w for w in walks)
+        a_window = window * 4 * 128                  # one slot's keys
+        assert decode_attn.count_pool_gathers(text, a_window // 2) == 0
+        assert mem.temp_size_in_bytes < experts * 2, mem.temp_size_in_bytes

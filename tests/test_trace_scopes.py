@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from vtpu.models import ModelConfig, init_params
+from vtpu.models.blockdiff import BlockDiffConfig
 from vtpu.models.hybrid import HybridConfig, init_hybrid_params
 from vtpu.models.latent import LatentConfig, init_latent_params
 from vtpu.models.moe import MoEConfig, init_moe_params
@@ -20,7 +21,8 @@ from vtpu.obs.tickprof import HOST_PHASES, TickProfiler, host_ms_per_tick
 from vtpu.ops import SCOPES
 from vtpu.serving import ServingConfig, ServingEngine
 from vtpu.serving.adapters import (
-    HybridSlotModel, LatentSlotModel, MoeSlotModel, WindowSlotModel)
+    BlockDiffSlotModel, HybridSlotModel, LatentSlotModel, MoeSlotModel,
+    WindowSlotModel)
 
 PAGE, CHUNK, BUCKET = 8, 8, 16
 DENSE = ModelConfig(
@@ -48,7 +50,16 @@ SWA = SwaConfig(
     layer_types=("full", "window", "full"), ffn_types=("dense", "moe", "moe"),
     n_kv_heads=1, n_kv_heads_window=2, window=4, d_ff=64, d_ff_expert=16,
     n_experts=8, held=(2, 4), top_k=2, max_seq=32, dtype=jnp.float32)
+BLOCKDIFF = BlockDiffConfig(
+    vocab=64, d_model=32, n_heads=4, n_kv_heads=2, n_layers=2, d_ff=16,
+    n_experts=8, held=(2, 4), top_k=2, max_seq=32, head_dim=16,
+    dtype=jnp.float32, mask_token_id=63)
 BLOCK = {"dense": {"mlp"}, "moe": {"route", "experts"}}
+# generation by blocks: a pass's attention nested under ``attn`` as the
+# other families' own parts are; on the kernel's route the walk of the pool
+# (the kernel ``paged_attn``) and its preparation of its queries lie inside
+# it; its chunk is the expert family's
+BLOCK_PASS = {"attn", "block_attn", "route", "experts", "sample"}
 # the window family: a window layer's ring read and write nested under
 # ``attn`` as the latent and Mamba parts are, a dense layer then expert
 # ones, and the paged pool's routes for its full layers
@@ -86,6 +97,11 @@ def _engine(family: str, route, **serving):
             init_swa_params(jax.random.key(0), SWA), SWA,
             kv_page=cfg.kv_page, paged_attn=cfg.paged_attn)
         return ServingEngine(serving=cfg, model=model)
+    if family == "blockdiff":
+        model = BlockDiffSlotModel(
+            init_moe_params(jax.random.key(0), BLOCKDIFF), BLOCKDIFF,
+            kv_page=cfg.kv_page, paged_attn=cfg.paged_attn)
+        return ServingEngine(serving=cfg, model=model)
     if family == "hybrid":
         model = HybridSlotModel(
             init_hybrid_params(jax.random.key(0), HYBRID), HYBRID,
@@ -100,6 +116,9 @@ def _engine(family: str, route, **serving):
 def _lowered(eng, step: str):
     """The step lowered as the engine's warm-up calls it."""
     b = eng.serving.slots
+    if step == "pass":
+        return eng._decode_sampled.lower(
+            eng.params, eng.state, jnp.zeros((b,), bool), BUCKET)
     if step == "decode":
         return eng._decode_sampled.lower(
             eng.params, eng.state, jnp.zeros((b,), jnp.int32),
@@ -153,6 +172,11 @@ CASES = [
      | {"sample"}),
     ("swa", "kernel", "admit", TRUNK | WINDOW | {"gather_attn", "sample"}),
     ("swa", "kernel", "chunk", TRUNK | WINDOW | {"gather_attn"}),
+    ("blockdiff", "kernel", "pass", TRUNK | BLOCK_PASS
+     | {"pool_relayout", "paged_attn"}),
+    ("blockdiff", "gather", "pass", TRUNK | BLOCK_PASS),
+    ("blockdiff", "kernel", "chunk", TRUNK | BLOCK["moe"]
+     | {"gather_attn", "attn"}),
 ]
 
 

@@ -350,7 +350,7 @@ def _sparse_ffn(cfg: LatentConfig, lp, x):
     return x + y.reshape(shape)
 
 
-class _Layer:
+class LayerOfStack:
     """Layer ``i`` of a stack of leaves ``[L, ...]``, each leaf sliced where
     it is used: a copy the compiler makes of one (a projection laid out
     for its product) then lies under that part's scope in a trace."""
@@ -380,7 +380,7 @@ def _walk(params: Params, cfg: LatentConfig, ckv, ik, tokens, positions,
     for kind, ffn in (("dense", _dense_ffn), ("sparse", _sparse_ffn)):
         stack = params[kind]
         for i in range(jax.tree_util.tree_leaves(stack)[0].shape[0]):
-            lp = _Layer(stack, i)
+            lp = LayerOfStack(stack, i)
             x, ckv, ik, idx = _attention(
                 cfg, lp, l, x, ckv, ik, rope, positions, tables, wblk, woff,
                 None if given is None else given[l], lens)

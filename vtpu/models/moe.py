@@ -18,13 +18,13 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 
 from vtpu.models.transformer import (
-    _embed, _lm_head, _o_proj, _prefill_cache, _qkv,
+    _embed, _lm_head, _o_proj, _prefill_cache, _qkv, kv_heads,
 )
 from vtpu.ops import scaled_normal, rms_norm, rope_angles, causal_attention
 from vtpu.ops.grouped_ffn import grouped_experts_ffn, takes
@@ -46,9 +46,31 @@ class MoEConfig:
     head_dim: int = 128
     dtype: Any = jnp.bfloat16
     kv_int8: bool = False  # int8 KV cache (see ModelConfig.kv_int8)
+    # What a published block may state otherwise; the defaults are what
+    # OLMoE's cell runs (vbench/configs/olmoe-1b-7b-8l.json records its
+    # QK-norm and its untied head as departures: each is one field here).
+    # The shared trunk reads them (transformer._qkv, _lm_head,
+    # cached_attention).
+    n_kv_heads: Optional[int] = None  # grouped key/value heads (None: n_heads)
+    qk_norm: bool = False      # an RMS norm a head on q and k before rope
+    tied_head: bool = True     # False: params["head"] [V, D] of its own
+    rope_theta: float = 10000.0
+    eps: float = 1e-6
+    # (first, count) of the experts whose stacks this holder has, of the
+    # n_experts the router scores (None: all of them; ``held_moe_ffn``)
+    held: Optional[tuple] = None
+
     @property
     def qkv_dim(self) -> int:
         return self.n_heads * self.head_dim
+
+    @property
+    def d_ff_expert(self) -> int:
+        return self.d_ff
+
+    @property
+    def n_held(self) -> int:
+        return self.held[1] if self.held else self.n_experts
 
     def capacity(self, tokens: int) -> int:
         """Static per-expert slot count for a `tokens`-token batch."""
@@ -59,28 +81,36 @@ def init_moe_params(rng: jax.Array, cfg: MoEConfig) -> Params:
     """Stacked [L, ...] tensors; experts stacked on their own axis [L, E, ...]."""
     keys = jax.random.split(rng, 9)
     d, f, l, e, qd = cfg.d_model, cfg.d_ff, cfg.n_layers, cfg.n_experts, cfg.qkv_dim
+    kvd = kv_heads(cfg) * cfg.head_dim
+    h = cfg.n_held  # expert stacks held here; the router scores all e
 
     def w(key, shape, fan_in):
         return scaled_normal(key, shape, fan_in, cfg.dtype)
 
-    return {
+    params = {
         "embed": w(keys[0], (cfg.vocab, d), d),
         "layers": {
             "wq": w(keys[1], (l, d, qd), d),
-            "wk": w(keys[2], (l, d, qd), d),
-            "wv": w(keys[3], (l, d, qd), d),
+            "wk": w(keys[2], (l, d, kvd), d),
+            "wv": w(keys[3], (l, d, kvd), d),
             "wo": w(keys[4], (l, qd, d), qd),
             # router stays f32: tiny matmul, and softmax over experts is
             # numerically load-bearing for balanced routing
             "router": (jax.random.normal(keys[5], (l, d, e), jnp.float32) / math.sqrt(d)),
-            "w_gate": w(keys[6], (l, e, d, f), d),
-            "w_up": w(keys[7], (l, e, d, f), d),
-            "w_down": w(keys[8], (l, e, f, d), f),
+            "w_gate": w(keys[6], (l, h, d, f), d),
+            "w_up": w(keys[7], (l, h, d, f), d),
+            "w_down": w(keys[8], (l, h, f, d), f),
             "attn_norm": jnp.ones((l, d), cfg.dtype),
             "mlp_norm": jnp.ones((l, d), cfg.dtype),
         },
         "final_norm": jnp.ones((d,), cfg.dtype),
     }
+    if cfg.qk_norm:
+        params["layers"]["q_norm"] = jnp.ones((l, cfg.head_dim), cfg.dtype)
+        params["layers"]["k_norm"] = jnp.ones((l, cfg.head_dim), cfg.dtype)
+    if not cfg.tied_head:
+        params["head"] = w(jax.random.fold_in(rng, 9), (cfg.vocab, d), d)
+    return params
 
 
 def route(
@@ -187,6 +217,41 @@ def group_limited_route(
     return s * hot * scale
 
 
+def topk_softmax_gates(router_w: jax.Array, x: jax.Array,
+                       top_k: int) -> jax.Array:
+    """``route``'s arithmetic (softmax over all E experts, the ``top_k``
+    largest a token, their weights divided by their sum) over flat tokens
+    x: [T, D], router_w [D, E] in float32, as gates [T, E] float32 in
+    ``grouped_route``'s form: zero at every expert not chosen, so the
+    holder of a share of the experts reads its own columns
+    (``held_experts_ffn``). No capacity: nothing is dropped."""
+    gates = group_limited_route(router_w, x, top_k, 1, 1, 1.0)
+    return gates / jnp.maximum(jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
+
+
+def held_moe_ffn(cfg: MoEConfig):
+    """The post-attention block, for the shared decode trunk
+    (``transformer.spec_verify_loop``'s ``ffn_fn``), of a holder of
+    ``cfg.held`` of the layer's experts: the router scores all
+    ``n_experts``, chooses ``top_k`` of them a row (softmax, renormalised),
+    and the held experts add their part (``held_experts_ffn``: the grouped
+    kernels on a TPU); what the absent ones would add is left out. Exact
+    and dropless at any number of rows."""
+    first, count = cfg.held or (0, cfg.n_experts)
+
+    def ffn(lp, x):
+        shape = x.shape
+        with jax.named_scope("route"):  # the norm rides with the router
+            n = rms_norm(x, lp["mlp_norm"], cfg.eps).reshape(-1, shape[-1])
+            gates = topk_softmax_gates(
+                lp["router"], n, cfg.top_k)[:, first:first + count]
+        with jax.named_scope("experts"):
+            y = held_experts_ffn(lp, n, gates, cfg.top_k)
+        return y.reshape(shape)
+
+    return ffn
+
+
 def experts_grouped(t: int, d: int, f: int) -> bool:
     """Whether ``held_experts_ffn`` runs a launch of ``t`` rows ``d`` wide
     through experts ``f`` wide in the grouped kernels: the shapes they
@@ -217,7 +282,7 @@ def held_experts_all_rows(lp_e: dict[str, jax.Array], x: jax.Array,
 
 def _stacked(lp_e, name: str) -> tuple[jax.Array, Any]:
     """(a leaf with its layers' axis, this layer's index): the stack as
-    stored where ``lp_e`` is a layer of one (``latent._Layer.stacked``), a
+    stored where ``lp_e`` is a layer of one (``latent.LayerOfStack.stacked``), a
     dict's leaf under a leading axis of one."""
     if isinstance(lp_e, dict):
         return lp_e[name][None], 0

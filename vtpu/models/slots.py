@@ -252,6 +252,7 @@ def chunked_prefill_into_slot(
     unroll: bool = False,
     block_ids: Optional[jax.Array] = None,
     mesh=None,
+    layer_of=None,
 ) -> tuple[jax.Array, dict[str, jax.Array]]:
     """One [1, C] prompt chunk written into *slot* at positions
     offset..offset+C-1: prefill as a sequence of fixed-size chunk forwards
@@ -300,6 +301,12 @@ def chunked_prefill_into_slot(
     scatters back to the pool after the trunk), so gathering it first costs
     nothing extra — the kernel's payoff is exclusive to the decode/verify
     ticks, where the gather was pure read-side overhead.
+
+    A family whose blocks of positions see each other both ways
+    (``cfg.attn_block``) takes the same path: the chunk's rows are in the
+    view before attention reads it, and ``cached_attention`` lets a query
+    read to the end of its own block (chunks a multiple of the block).
+    ``layer_of`` is ``spec_verify_loop``'s.
     """
     c = chunk.shape[1]
     bucket = kv_bucket or cfg.max_seq
@@ -326,7 +333,7 @@ def chunked_prefill_into_slot(
 
     logits, new_view = spec_verify_loop(
         params, cfg, view, chunk, bucket, write_kv, ffn_fn=ffn_fn,
-        unroll=unroll, mesh=mesh,
+        unroll=unroll, mesh=mesh, layer_of=layer_of,
     )
     return logits, _chunk_write_back(
         cache, new_view, kv_keys, bucket, c, slot, offset, new_len, block_ids)

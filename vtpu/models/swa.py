@@ -39,7 +39,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from vtpu.models.latent import _Layer, _rope_head, _swiglu
+from vtpu.models.latent import LayerOfStack, _rope_head, _swiglu
 from vtpu.models.moe import grouped_route, held_experts_ffn
 from vtpu.models.transformer import _held_projection
 from vtpu.ops import rms_norm, rope_angles, scaled_normal
@@ -365,7 +365,7 @@ def _walk(params: Params, cfg: SwaConfig, state, tokens, positions, at,
     of_kind: dict = {}
     for attn_kind, ffn_kind, kind in zip(cfg.layer_types, cfg.ffn_types,
                                          cfg.layer_kinds):
-        lp = _Layer(params["layers"][kind], of_kind.get(kind, 0))
+        lp = LayerOfStack(params["layers"][kind], of_kind.get(kind, 0))
         of_kind[kind] = of_kind.get(kind, 0) + 1
         l = seen[attn_kind]
         seen[attn_kind] = l + 1

@@ -533,7 +533,9 @@ def _qkv(cfg, lp, x, cos, sin, positions):
     pipeline) or a serving adapter's held [H, Dh, d] (hold_projections):
     the leaf's rank says which, and either way an output is the same dot
     product over d. k and v have ``kv_heads(cfg)`` heads; a family without
-    rotary positions (``cfg.rotary`` False) gets q and k as projected."""
+    rotary positions (``cfg.rotary`` False) gets q and k as projected; one
+    with ``cfg.qk_norm`` norms each head of q and k first (gains
+    ``q_norm`` / ``k_norm`` [Dh] a layer)."""
     normed = rms_norm(x, lp["attn_norm"], getattr(cfg, "eps", 1e-6))
     if lp["wq"].ndim == 3:
         q, k, v = (jnp.einsum("bsd,hed->bshe", normed, lp[name])
@@ -541,6 +543,11 @@ def _qkv(cfg, lp, x, cos, sin, positions):
     else:
         q, k, v = ((normed @ lp[name]).reshape(
             x.shape[:2] + (-1, cfg.head_dim)) for name in PROJECTIONS)
+    if getattr(cfg, "qk_norm", False):
+        # an RMS norm a head (gain [Dh]) on queries and keys, before the
+        # rotary positions
+        eps = getattr(cfg, "eps", 1e-6)
+        q, k = rms_norm(q, lp["q_norm"], eps), rms_norm(k, lp["k_norm"], eps)
     if not getattr(cfg, "rotary", True):
         return q, k, v
     return apply_rope(q, cos, sin, positions), apply_rope(k, cos, sin, positions), v
@@ -566,12 +573,14 @@ def _embed(params, cfg, tokens):
 
 @jax.named_scope("lm_head")
 def _lm_head(params, x, logits_at=None):
-    """Final norm and the tied output head; ``logits_at`` ([B] positions)
-    gathers one row a sequence before the vocabulary projection."""
+    """Final norm and the output head, the embedding's transpose unless the
+    parameters hold a ``head`` of their own ([V, D]: a model whose
+    configuration unties it); ``logits_at`` ([B] positions) gathers one row
+    a sequence before the vocabulary projection."""
     x = rms_norm(x, params["final_norm"])
     if logits_at is not None:
         x = x[jnp.arange(x.shape[0]), logits_at]  # [B, D]
-    return (x @ params["embed"].T).astype(jnp.float32)
+    return (x @ params.get("head", params["embed"]).T).astype(jnp.float32)
 
 
 def transformer_layer(
@@ -739,6 +748,8 @@ def spec_verify_loop(
     unroll: bool = False,
     mesh=None,
     paged_attn=None,
+    attend=None,
+    layer_of=None,
 ) -> tuple[jax.Array, dict[str, jax.Array]]:
     """Verify pass for speculative decoding: one forward over a [B, T] draft
     chunk whose row-i query sits at cache position len[b] + i.
@@ -786,11 +797,20 @@ def spec_verify_loop(
     engages only where it beat the gather path on this hardware). Both
     routes share the kv_len masking and null-block contracts verbatim, so
     streams stay token-equal across the routing decision.
+
+    ``attend(l, lp, x, kv) -> (attn, kv)`` replaces the attention half
+    (None: ``cached_attention`` over this cache, as described above; a
+    family whose rows of one slot see each other both ways brings its own:
+    vtpu/models/blockdiff.py). ``layer_of(stack, l)`` (unrolled walks only)
+    replaces the slice of every stacked leaf as a layer's parameters: a
+    family whose kernels read a stack in place hands a view that slices a
+    leaf where it is used (``latent.LayerOfStack``).
     """
     ffn = ffn_fn or _mlp_block
-    attend = cached_attention(
-        cfg, cache, draft.shape[1], kv_bucket, write_kv, unroll=unroll,
-        mesh=mesh, paged_attn=paged_attn)
+    if attend is None:
+        attend = cached_attention(
+            cfg, cache, draft.shape[1], kv_bucket, write_kv, unroll=unroll,
+            mesh=mesh, paged_attn=paged_attn)
     x = _embed(params, cfg, draft)
 
     def layer(l, carry, lp=None):
@@ -806,7 +826,10 @@ def spec_verify_loop(
     if unroll:
         carry = (x, kv0)
         for l in range(cfg.n_layers):
-            lp = jax.tree_util.tree_map(lambda a: a[l], params["layers"])
+            if layer_of is not None:
+                lp = layer_of(params["layers"], l)
+            else:
+                lp = jax.tree_util.tree_map(lambda a: a[l], params["layers"])
             carry = layer(l, carry, lp=lp)
         x, new_kv = carry
     else:
@@ -841,7 +864,8 @@ def cached_attention(cfg, cache, t: int, kv_bucket: int, write_kv,
     bucket = kv_bucket or cfg.max_seq
     quant = "k_scale" in cache
     scale = getattr(cfg, "attn_scale", None)
-    cos, sin = (rope_angles(cfg.max_seq, cfg.head_dim)
+    cos, sin = (rope_angles(cfg.max_seq, cfg.head_dim,
+                            getattr(cfg, "rope_theta", 10000.0))
                 if getattr(cfg, "rotary", True) else (None, None))
     lens = cache["len"]
     # Paged pool ("table" present): reads gather each slot's live pages
@@ -869,6 +893,14 @@ def cached_attention(cfg, cache, t: int, kv_bucket: int, write_kv,
     ragged_len = jnp.minimum(
         lens[:, None] + 1 + jnp.arange(t)[None, :], cfg.max_seq
     )
+    block = getattr(cfg, "attn_block", 0)
+    if block:
+        # causal between blocks of ``block`` positions, two-sided inside
+        # one: a query reads up to the end of its own block (the chunk's
+        # rows are in the cache before attention reads it)
+        ragged_len = jnp.minimum(
+            ((lens[:, None] + jnp.arange(t)[None, :]) // block + 1) * block,
+            cfg.max_seq)
     kv_keys = kv_planes(cache)
     plane = cache["k"].shape[-2:]  # kv_plane_shape: may pack heads a row
 

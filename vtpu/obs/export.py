@@ -142,6 +142,19 @@ COUNTERS = {
                          "Ring positions the dispatched slots' window "
                          "attention read (a layer), summed over decode "
                          "ticks (models whose window layers keep a ring)"),
+    "block_slot_passes": ("block_slot_passes",
+                          "Passes slots took, denoising and writing "
+                          "(models that generate by blocks)"),
+    "block_write_passes": ("block_write_passes",
+                           "Of those, the passes that wrote a clean "
+                           "block's keys and values into the pool"),
+    "block_rows_dispatched": ("block_rows_dispatched",
+                              "Rows of the slots that took a pass"),
+    "block_rows_masked": ("block_rows_masked",
+                          "Of those, the rows that were masked and could "
+                          "answer"),
+    "block_tokens_committed": ("block_tokens_committed",
+                               "Rows the passes committed"),
     "paged_attn_kernel_ticks": ("paged_attn_kernel_ticks",
                                 "Ticks routed to the fused paged-attention "
                                 "kernel (table walked in place)"),
@@ -288,6 +301,9 @@ GAUGES = {
     "window_ring": ("window_ring_rows",
                     "Rows of the ring a window layer keeps a slot (None: "
                     "no window layers)", 1),
+    "block_length": ("block_length_rows",
+                     "Rows of a block, for a model that generates by "
+                     "blocks (0: a token a step)", 1),
     "ring_bytes_per_position": ("ring_bytes_per_position",
                                 "Bytes a cached token would cost the window "
                                 "layers were they paged", 1),

@@ -527,9 +527,14 @@ def _paged_kernel(lay_ref, tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 def _grouped_kernel(lay_ref, tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
                     k_buf, v_buf, sems, turn_ref, *,
-                    scale: float, page: int, group: int, rows: int):
+                    scale: float, page: int, group: int, rows: int,
+                    lse_ref=None):
     """``_paged_kernel``'s walk for grouped queries, with both products on
-    the MXU (``_grouped_call`` has the operands' layout).
+    the MXU (``_grouped_call`` has the operands' layout). ``lse_ref``
+    (``_grouped_lse_kernel``) also takes each query row's log of its
+    softmax's denominator, maximum included, along every lane: what a
+    caller needs to join this walk's result with keys that are not in the
+    pool.
 
     A slot's nq queries (``_pack_queries``; a multiple of 8) come as a
     block (rows * nq, lanes), pool row r of query j at r * nq + j, and a
@@ -615,9 +620,18 @@ def _grouped_kernel(lay_ref, tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     init = (jnp.full((rows * nq, 1), _NEG_INF, jnp.float32),
             jnp.zeros((rows * nq, 1), jnp.float32),
             jnp.zeros(q.shape, jnp.float32))
-    _, d, acc = jax.lax.fori_loop(0, n_groups, attend_group, init)
+    m, d, acc = jax.lax.fori_loop(0, n_groups, attend_group, init)
     turn_ref[0] = (turn + n_groups) % 2
     o_ref[0] = (acc / d).astype(o_ref.dtype)
+    if lse_ref is not None:
+        lse_ref[0] = jnp.broadcast_to(m + jnp.log(d), lse_ref.shape[1:])
+
+
+def _grouped_lse_kernel(lay_ref, tbl_ref, len_ref, q_ref, k_hbm, v_hbm,
+                        o_ref, lse_ref, k_buf, v_buf, sems, turn_ref, **kw):
+    """``_grouped_kernel`` with its second output."""
+    _grouped_kernel(lay_ref, tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                    k_buf, v_buf, sems, turn_ref, lse_ref=lse_ref, **kw)
 
 
 def _window_block(i, j, lay_ref, tbl_ref, len_ref, *, t: int, page: int):
@@ -752,13 +766,15 @@ def _paged_call(q, k_pool, v_pool, k_scale_pool, v_scale_pool, table,
 
 
 def _grouped_call(packed, k_pool, v_pool, table, kv_len, lay,
-                  interpret: bool, scale: float):
+                  interpret: bool, scale: float, lse: bool = False):
     """The walk for grouped queries (``_grouped_kernel``). packed [B, nq,
     rows, lanes] (``_pack_queries``), kv_len [B, nq]; the pools go in
     viewed [L, n_blocks, page * rows, lanes], a token's rows under one
     another, which is the bytes as stored (no plane is laid out anew:
     tests/test_tpu_compile.py holds it), so a group of pages is one
-    matrix of whole tiles."""
+    matrix of whole tiles. ``lse``: also return [B, nq, rows] float32,
+    each query row's log-sum-exp over what it read (about -1e30 where it
+    read nothing)."""
     b, nq, rows, lanes = packed.shape
     n_layers, n_blocks, page = k_pool.shape[:3]
     wp = table.shape[1]
@@ -772,27 +788,38 @@ def _grouped_call(packed, k_pool, v_pool, table, kv_len, lay,
     group = _pages_per_group(page, rows, lanes, k_pool.dtype.itemsize, wp)
     q_spec = pl.BlockSpec((1,) + q.shape[1:], lambda i, *_: (i, 0, 0))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
+    out_specs, out_shape = q_spec, jax.ShapeDtypeStruct(q.shape, q.dtype)
+    if lse:  # a second output, a row's number along a whole tile of lanes
+        wide = q.shape[:2] + (128,)
+        out_specs = [q_spec, pl.BlockSpec((1,) + wide[1:],
+                                          lambda i, *_: (i, 0, 0))]
+        out_shape = [out_shape, jax.ShapeDtypeStruct(wide, jnp.float32)]
     out = pl.pallas_call(
-        functools.partial(_grouped_kernel, scale=scale, page=page,
-                          group=group, rows=rows),
+        functools.partial(_grouped_lse_kernel if lse else _grouped_kernel,
+                          scale=scale, page=page, group=group, rows=rows),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,  # layer index, table, lengths
-            grid=(b,), in_specs=[q_spec, hbm, hbm], out_specs=q_spec,
+            grid=(b,), in_specs=[q_spec, hbm, hbm], out_specs=out_specs,
             scratch_shapes=[
                 pltpu.VMEM((2, group * page * rows, lanes), k_pool.dtype),
                 pltpu.VMEM((2, group * page * rows, lanes), v_pool.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),  # plane x buffer slot
                 pltpu.SMEM((1,), jnp.int32),  # which buffer slot is next
             ]),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_GROUP_VMEM_BYTES + (24 << 20)),
         interpret=interpret,
         name="paged_attn",  # the kernel's name, and its scope in a trace
     )(lay, table, lens, q, k_pool.reshape(merged), v_pool.reshape(merged))
-    return out.reshape(b, rows, nq + pad, lanes).transpose(
+    if lse:
+        out, sums = out
+        sums = sums[..., 0].reshape(b, rows, nq + pad).transpose(
+            0, 2, 1)[:, :nq]
+    out = out.reshape(b, rows, nq + pad, lanes).transpose(
         0, 2, 1, 3)[:, :nq]
+    return (out, sums) if lse else out
 
 
 def paged_decode_attention(
@@ -805,6 +832,7 @@ def paged_decode_attention(
     mesh=None,
     interpret: bool | None = None,
     scale: Optional[float] = None,
+    lse: bool = False,
 ) -> jax.Array:
     """Fused paged decode/verify attention: walk the page table IN PLACE
     over the block pool — no gather_kv_pages, no dense window.
@@ -839,7 +867,11 @@ def paged_decode_attention(
     heads than query heads, stored several a row of whole lanes
     (``transformer.kv_plane_shape``), walk the same table as more queries
     a slot (``_pack_queries``) with both products on the MXU
-    (``_grouped_kernel``); one chip only."""
+    (``_grouped_kernel``); one chip only. For grouped queries alone:
+    ``lse`` also returns each query head's log-sum-exp over what it read
+    ([B, T, H] float32, about -1e30 where that was nothing), with which a
+    caller joins this result to keys that are not in the pool (a pass of a
+    block's rows that see each other: vtpu/models/blockdiff.py)."""
     t = q.shape[1]
     kv_len = _norm_kv_len(kv_len, t)
     _check_pool(q, k_pool, table)
@@ -853,8 +885,17 @@ def paged_decode_attention(
             scale = 1.0 / math.sqrt(q.shape[3])
         packed, unpack = _pack_queries(q, k_pool.shape[3], k_pool.shape[4])
         lens = jnp.repeat(kv_len, packed.shape[1] // t, axis=1)
-        return unpack(_grouped_call(packed, k_pool, v_pool, table, lens,
-                                    lay, interpret, scale))
+        out = _grouped_call(packed, k_pool, v_pool, table, lens, lay,
+                            interpret, scale, lse=lse)
+        if not lse:
+            return unpack(out)
+        out, sums = out
+        b, rows = q.shape[0], k_pool.shape[3]
+        # query (t, p, g) of pool row r is head (r * pack + p) * G + g
+        sums = sums.reshape(b, t, -1, rows).transpose(0, 1, 3, 2)
+        return unpack(out), sums.reshape(b, t, q.shape[2])
+    if lse:
+        raise ValueError("only the grouped walk returns its log-sum-exp")
     if mesh is None:
         return _paged_call(q, k_pool, v_pool, None, None, table, kv_len,
                            lay, interpret, scale)

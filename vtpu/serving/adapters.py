@@ -42,7 +42,9 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from vtpu.models import hybrid, moe, slots as slot_steps, swa, transformer
+from vtpu.models import (
+    blockdiff, hybrid, moe, slots as slot_steps, swa, transformer,
+)
 from vtpu.models.hybrid import (
     hybrid_decode_step,
     hybrid_prefill_chunk,
@@ -50,6 +52,7 @@ from vtpu.models.hybrid import (
     init_hybrid_state,
 )
 from vtpu.models.latent import (
+    LayerOfStack,
     init_latent_cache,
     latent_decode_step,
     latent_prefill_chunk,
@@ -175,6 +178,24 @@ def fused_spec_decode_step(model: Any, k: int, spec_tokens: int,
         return multi_tick_spec_decode(
             spec, k, spec_tokens, ngram, eos_token, state, tokens, active,
             cap, hist, hist_len, k_dyn)
+
+    return step
+
+
+def block_pass_step(model: Any):
+    """A block-generating slot model's pass as ONE jit-able step:
+
+        (params, state, active[B], kv_bucket) -> (result[B, 5 + 2 * bl]
+                                                  int32, state)
+
+    The slots' blocks, their phases and their commits live in the state
+    (vtpu/models/blockdiff.py), so the step takes no tokens and returns
+    none to feed back: the pipelined loop dispatches pass t + 1 with the
+    state pass t returned and fetches pass t's result meanwhile. The
+    closure's name is the program's in a profiler trace (``jit_step``), as
+    the decode closures' above: a pass is this family's decode step."""
+    def step(params, state, active, kv_bucket):
+        return model.block_pass(params, state, active, kv_bucket)
 
     return step
 
@@ -932,3 +953,148 @@ class WindowSlotModel:
         return swa.swa_prefill_chunk(
             params, self.cfg, state, chunk, slot, offset, new_len, window,
             block_ids)
+
+
+class BlockDiffSlotModel:
+    """Generation by diffusion over blocks (vtpu/models/blockdiff.py): the
+    expert decoder on the shared trunk, its cache the paged pool walked by
+    the one page table, so the engine's allocator, chunked admission and
+    read windows serve it as they serve the other families. What it yields
+    is not a token a step: ``block_length`` says so, and the engine then
+    runs its loop of passes (``ServingEngine._loop_blocks``), admits
+    without a first token and hands a block's tokens over when the device
+    reports it clean.
+
+    It states what the engine cannot know of it: ``block_length``,
+    ``read_windows``, ``kv_bytes_per_token``, ``experts_grouped`` and
+    ``block_attn_route`` (the route a pass traced now takes, for the
+    engine's counters), and keeps the layout of its state and of a pass's
+    result to itself: the engine opens a block through ``open_block`` and
+    reads a pass through ``read_pass``. Paged only. What cannot serve this family yet is
+    refused by name, each with the mechanism that is missing
+    (``check_serving``, ``refuses``)."""
+
+    supports_kv_buckets = True
+    mesh = None
+    refuses = {
+        "register_prefix": (
+            "a shared prefix's pages could be mapped, but an admission from "
+            "one opens its first block at the prefix's end, and the prefix "
+            "build has no pass that ends on a block boundary with nothing "
+            "sampled"),
+        "drain": (
+            "migration ships a session as pool pages and a next token; a "
+            "session here is also a block on the device (ids, flags, the "
+            "passes that committed them), which has no staging"),
+    }
+
+    def __init__(self, params: Any, cfg: Any, kv_page: Optional[int] = None,
+                 kv_pool_blocks: Optional[int] = None,
+                 read_windows: Optional[tuple] = None,
+                 paged_attn: Optional[str] = None,
+                 mesh: Optional[Any] = None):
+        if mesh is not None:
+            raise ValueError(
+                "BlockDiffSlotModel serves one chip's share of each layer "
+                "(its held experts) and has no sharding rule: pass no mesh")
+        if kv_page is None:
+            raise ValueError(
+                "BlockDiffSlotModel has a paged cache only: set kv_page")
+        if cfg.kv_int8:
+            raise ValueError(
+                "BlockDiffSlotModel has no int8 cache: the walk that joins "
+                "the pool with a pass's own keys reads bfloat16 pages")
+        blockdiff.block_attn_route(paged_attn)  # a bad name raises here
+        _check_read_windows(read_windows, kv_page, cfg.max_seq)
+        self.cfg = cfg
+        self.max_context = cfg.max_seq
+        self.kv_page = kv_page
+        self.kv_pool_blocks = kv_pool_blocks
+        self.n_kv_blocks = None
+        self.paged_attn = paged_attn
+        self.block_length = cfg.block_length
+        self.read_windows = tuple(sorted(read_windows)) if read_windows else None
+        self.kv_bytes_per_token = kv_bytes_per_token(cfg)
+        self.params = {**params, "layers": hold_projections(
+            params["layers"], cfg)}
+
+    def check_serving(self, serving) -> None:
+        """Refuse the ServingConfig options this family cannot serve."""
+        if not serving.prefill_chunk:
+            raise ValueError(
+                "BlockDiffSlotModel admits a prompt in chunks under the "
+                "block mask and has no whole-prompt program: set "
+                "prefill_chunk (a multiple of block_length)")
+        if serving.prefill_chunk % self.block_length:
+            raise ValueError(
+                f"prefill_chunk {serving.prefill_chunk} must be a multiple "
+                f"of block_length {self.block_length}: a chunk's edge may "
+                "not cut a block, whose rows see each other")
+        if serving.spec_tokens:
+            raise ValueError(
+                "BlockDiffSlotModel has no spec_step: a pass already "
+                "commits several rows of a block, and a draft beyond the "
+                "block has no rows to verify it (spec_tokens=0)")
+        if serving.decode_loop_k and serving.decode_loop_k > 1:
+            raise ValueError(
+                "BlockDiffSlotModel has no device loop or fused loop: a "
+                "flush of k passes would hand blocks over inside the loop, "
+                "and the loop's carry is a token a slot (decode_loop_k=None)")
+        if serving.kv_swap is not None:
+            raise ValueError(
+                "BlockDiffSlotModel cannot park or swap a session: its "
+                "pages could be staged, its block on the device has no "
+                "snapshot (kv_swap=None; park, resume and migrate need it)")
+        if serving.disagg is not None:
+            raise ValueError(
+                "BlockDiffSlotModel has no slot-less prefill: a prefill "
+                "worker hands over a first token, and an admission here "
+                "yields none (disagg=None)")
+        if serving.temperature > 0.0 or serving.logprobs:
+            raise ValueError(
+                "BlockDiffSlotModel commits a row's best token by its "
+                "confidence on the device: no temperature, no logprobs")
+        if serving.pipeline_decode is False:
+            raise ValueError(
+                "BlockDiffSlotModel has the pipelined loop alone "
+                "(pipeline_decode=None)")
+
+    experts_grouped = _experts_grouped
+
+    def block_attn_route(self) -> str:
+        """The route a pass traced now takes: the question the trace asks."""
+        return blockdiff.block_attn_route(self.paged_attn)
+
+    def init_state(self, slots: int):
+        self.n_kv_blocks = _pool_blocks(self, slots)
+        return blockdiff.init_block_state(
+            self.cfg, slots, self.kv_page, self.n_kv_blocks)
+
+    def block_pass(self, params, state, active, kv_bucket):
+        return blockdiff.block_pass(
+            params, self.cfg, state, active, kv_bucket or self.max_context,
+            paged_attn=self.paged_attn)
+
+    def open_block(self, state, slot, ids, masked, end):
+        """An admission's last act: ``slot``'s first generated block opened
+        at its cached length, ``ids [block_length]`` committed where
+        ``masked`` is False, its generation ending at position ``end``."""
+        return blockdiff.open_block(state, slot, ids, masked, end)
+
+    def read_pass(self, result):
+        """``block_pass``'s fetched result by name, an entry a slot
+        (``blockdiff.PassResult``): what the engine delivers and counts."""
+        return blockdiff.read_pass(result)
+
+    def prefill_chunk_into_slot(self, params, state, chunk, slot, offset,
+                                new_len, kv_bucket=0, unroll=False,
+                                block_ids=None):
+        del unroll  # the held experts' kernels read a static layer's stack
+        window = kv_bucket or self.max_context
+        if block_ids is None:  # the slot's own table row
+            block_ids = state["table"][slot, :window // self.kv_page]
+        return slot_steps.chunked_prefill_into_slot(
+            params, self.cfg, state, chunk, slot, offset, new_len,
+            kv_bucket=kv_bucket, unroll=True,
+            ffn_fn=moe.held_moe_ffn(self.cfg), block_ids=block_ids,
+            layer_of=LayerOfStack)

@@ -46,6 +46,7 @@ from vtpu.parallel.sharding import head_sharding
 from vtpu.serving.adapters import (
     TransformerSlotModel,
     batched_admission_step,
+    block_pass_step,
     fused_spec_decode_step,
     multi_tick_decode_step,
     sampled_decode_step,
@@ -619,6 +620,11 @@ class Request:
     # (must FAULT typed — an unstarted rebuild would replay tokens the
     # client already has) from a genuinely unstarted one (safe re-queue)
     delivered: int = 0
+    # for a model that generates by blocks (several tokens of a stream
+    # committed in one pass, not in their order): one integer a delivered
+    # token, in the stream's order, the number of the request's pass whose
+    # logits committed it; None for every other model
+    trail: Optional[list] = None
     # the REQUESTED terminal (cancel()/shed set it; the engine applies it
     # at the next safe boundary) — what the `cancelled` property reads
     _abort: Optional[str] = dataclasses.field(default=None, repr=False)
@@ -833,6 +839,15 @@ class ServingEngine:
         # tokens without per-token logprobs (the verify step returns ids
         # only, so logprobs streaming forces plain ticks), and a model
         # without spec_step can't speculate at all
+        # a model that generates by blocks (``block_length``): a pass of so
+        # many rows a slot under a mask two-sided inside the block, the
+        # slots' blocks and their commits on the device (_loop_blocks)
+        self._blocks = int(getattr(model, "block_length", 0) or 0)
+        if self._blocks and sample is not None:
+            raise ValueError(
+                f"{type(model).__name__} commits a block's rows by their "
+                "confidence on the device: a custom sample= callable has no "
+                "row of logits to be handed")
         self._spec_tokens = (
             serving.spec_tokens
             if sample is None and serving.temperature <= 0.0
@@ -917,7 +932,19 @@ class ServingEngine:
         # the state is donated through every step jit: the engine is its
         # only holder and reassigns self.state from the result, so XLA can
         # alias input to output instead of copying the pool state per call
-        if self._device_sampling:
+        if self._blocks:
+            # the pass is this family's decode step, under the same name
+            # (the program ``jit_step`` of a trace; what a rehearsal of
+            # the decode step compiles); the block's opening rides an
+            # admission's end as the table row rides its start
+            self._decode = None
+            self._decode_sampled = jax.jit(
+                block_pass_step(model), static_argnames=("kv_bucket",),
+                donate_argnums=(1,))
+            self._open_block = jax.jit(
+                model.open_block, donate_argnums=(0,))
+            self._rng = None
+        elif self._device_sampling:
             self._decode = None
             self._decode_sampled = jax.jit(
                 sampled_decode_step(
@@ -1046,14 +1073,16 @@ class ServingEngine:
             model.spec_step, static_argnames=("kv_bucket", "unroll"),
             donate_argnums=(1,),
         ) if self._spec_tokens and not self._fused_spec else None
-        self._prefill = jax.jit(model.prefill_into_slot, donate_argnums=(1,))
+        # a model that generates by blocks admits in chunks alone
+        self._prefill = None if self._blocks else jax.jit(
+            model.prefill_into_slot, donate_argnums=(1,))
         # batched async admission: device sampling supplies the fused first-
         # token sampler, and speculation needs the first token ON THE HOST
         # (draft history) — same gating shape as pipelining
         async_adm = serving.async_admission
         can_async = (
             self._device_sampling and not self._spec_tokens
-            and hasattr(model, "prefill_into_slots"))
+            and not self._blocks and hasattr(model, "prefill_into_slots"))
         if async_adm and not can_async:
             raise ValueError(
                 "async_admission=True requires device sampling (no custom "
@@ -1128,10 +1157,10 @@ class ServingEngine:
         self._unroll = model.supports_kv_buckets
         # prefill buckets past the context cap are unusable (out-of-range
         # positions); sanitize once so every consumer agrees
-        self._prefill_buckets = tuple(
+        self._prefill_buckets = () if self._blocks else tuple(
             bkt for bkt in serving.prefill_buckets if ctx is None or bkt <= ctx
         )
-        if not self._prefill_buckets:
+        if not self._prefill_buckets and not self._blocks:
             raise ValueError(
                 f"no prefill bucket fits max_context={ctx}: "
                 f"{serving.prefill_buckets}"
@@ -1143,7 +1172,7 @@ class ServingEngine:
             # bucket — a prompt it can never afford would head-of-line
             # block the queue until the engine drained fully idle) and a
             # prefill chunk
-            floor = max(self._prefill_buckets)
+            floor = max(self._prefill_buckets, default=0)
             if self._chunk:
                 floor = max(floor, self._chunk)
             if budget < floor:
@@ -1482,6 +1511,17 @@ class ServingEngine:
                        # is what its full layers' walk visits; 0 for every
                        # other model
                        "window_rows_read": 0,
+                       # a slot model that generates by blocks
+                       # (``block_length``), read off each pass's fetched
+                       # result: passes a slot took (denoising and
+                       # writing), those of them that wrote the clean
+                       # block's keys and values, the rows of the slots
+                       # that took a pass, those of them that were masked
+                       # and could answer, and the rows committed; 0 for
+                       # every other model
+                       "block_slot_passes": 0, "block_write_passes": 0,
+                       "block_rows_dispatched": 0, "block_rows_masked": 0,
+                       "block_tokens_committed": 0,
                        # KV overcommit: parks/resumes are lifecycle events;
                        # evicted_blocks counts pool blocks reclaimed from
                        # parked sessions; swap_out/in_bytes are the D2H/H2D
@@ -2312,7 +2352,9 @@ class ServingEngine:
     def _bucket(self, n: int) -> Optional[int]:
         """Smallest prefill bucket covering *n*, or None when the prompt
         goes through chunked prefill instead (longer than every bucket,
-        chunking configured). Raises for prompts nothing can admit."""
+        chunking configured). Raises for prompts nothing can admit. A
+        model that generates by blocks has no bucket: it has no whole-prompt
+        program, and every prompt goes in chunks."""
         for b in self._prefill_buckets:
             if n <= b:
                 return b
@@ -3155,6 +3197,16 @@ class ServingEngine:
                 "n": base + n, "off": 0, "base": base}
             return
         bucket = self._bucket(n)
+        if self._blocks:
+            # the prompt's whole blocks go in chunks; its other tokens open
+            # the first generated block as rows already committed
+            # (_open_first_block), at once where no whole block precedes
+            prompt = np.asarray(prompt)  # one fetch an admission
+            n -= n % self._blocks
+            prompt, tail = prompt[:n], prompt[n:]
+            if n == 0:
+                self._open_first_block(slot, req, tail)
+                return
         if bucket is None:
             # Chunked prefill is INCREMENTAL: park the request and let the
             # serving loop advance one [1, C] chunk per iteration, so live
@@ -3165,6 +3217,8 @@ class ServingEngine:
             self._admitting[slot] = {
                 "req": req, "padded": pad_to_chunks(prompt, n, self._chunk),
                 "n": n, "off": 0, "base": 0}
+            if self._blocks:
+                self._admitting[slot]["tail"] = tail
             return
         padded = np.zeros((1, bucket), np.int32)  # host-built, as above
         padded[0, :n] = np.asarray(prompt)
@@ -3216,16 +3270,18 @@ class ServingEngine:
         self._pending_firsts.append({"tokens": tok, "rows": rows})
         self._stats["prefill_batch_hist"][n] += 1
 
-    def _begin_slot(self, slot: int, req: Request, n: int) -> None:
+    def _begin_slot(self, slot: int, req: Request, n: int,
+                    firsts: int = 1) -> None:
         """Async-admission slot bookkeeping: everything _finish_admit does
         EXCEPT consuming the first token's value, which is still device-
         resident (delivered later by _emit_first through a batched fetch).
         The first token's budget slice is reserved here so the dispatch
-        predicates see the same numbers as the legacy path."""
+        predicates see the same numbers as the legacy path (``firsts`` 0:
+        an admission that yields no token, _open_first_block)."""
         self._slot_req[slot] = req
         ctx = self.model.max_context
         budget = min(req.max_new_tokens, ctx - n) if ctx else req.max_new_tokens
-        self._slot_budget[slot] = budget - 1
+        self._slot_budget[slot] = budget - firsts
         self._slot_len[slot] = n
         self._itl_last[slot] = None
         if self._track_history:
@@ -3464,6 +3520,9 @@ class ServingEngine:
                         # park — restore the slot, sample and emit nothing
                         self._restore_slot(slot, adm["resume"])
                         continue
+                    if self._blocks:  # no first token: the passes' own
+                        self._open_first_block(slot, req, adm["tail"])
+                        continue
                     pad = adm["padded"].shape[1]
                     last_row = logits[0, (n - base - 1) - (pad - c)]
                     if self._async_admission:
@@ -3568,15 +3627,18 @@ class ServingEngine:
             self._stats["expert_rows_grouped"] += rows * launches
 
     def _note_kv_window(self, kv_bucket: int, lens: list[int],
-                        t: int = 1, ticks: int = 1) -> None:
+                        t: int = 1, ticks: int = 1, wrote: int = 1) -> None:
         """Per-dispatch read-window telemetry. kv_bucket_hist surfaces the
         global read tax: every dispatched tick's window, set by the LONGEST
         live sequence — on the dense path that window is streamed verbatim
         for every slot. ``lens`` carries each dispatched slot's device-side
-        length THIS tick will read up to (exclusive of the +1 applied
-        here); under paging the live-page counters quantify how much of
-        the window each slot actually maps (the rest dedupes onto the null
-        block instead of streaming distinct lines). ``ticks`` (> 1 for a
+        length THIS tick will read up to (exclusive of the ``wrote`` = 1
+        position a step writes to the cache before it reads; 0 for a pass
+        of a model that generates by blocks, whose rows read the block's
+        own keys beside the cache and not from it); under paging the
+        live-page counters quantify how much of the window each slot
+        actually maps (the rest dedupes onto the null block instead of
+        streaming distinct lines). ``ticks`` (> 1 for a
         k-tick device-loop flush) scales every per-tick counter so the
         window/route accounting stays denominated in INNER ticks; the
         live-page figures use the dispatch-time lengths for all k (a
@@ -3586,9 +3648,10 @@ class ServingEngine:
         key = int(kv_bucket) or int(self.model.max_context or 0)
         hist[key] = hist.get(key, 0) + ticks
         self._note_expert_rows(self.serving.slots * t, ticks)
-        if self._select_topk or self._window_ring:
-            # + 1: a step sees the token it writes
-            self._stats["attn_visible_tokens"] += (sum(lens) + len(lens)) * ticks
+        if self._select_topk or self._window_ring or self._blocks:
+            # + wrote: a step sees the token it writes
+            self._stats["attn_visible_tokens"] += (
+                sum(lens) + wrote * len(lens)) * ticks
         if self._select_topk:
             self._stats["attn_selected_tokens"] += sum(
                 min(ln + 1, self._select_topk) for ln in lens) * ticks
@@ -3602,7 +3665,7 @@ class ServingEngine:
                 self._stats["ssm_kernel_ticks"] += ticks
         if self._paged and lens:
             page = self._page
-            live = sum(-(-(ln + 1) // page) for ln in lens)
+            live = sum(-(-(ln + wrote) // page) for ln in lens)
             self._stats["read_pages_live"] += live * ticks
             if self._latent_walk:
                 self._stats["latent_rows_live"] += (
@@ -3616,8 +3679,10 @@ class ServingEngine:
             # route statically from the same (override, window, chunk
             # width, quantization) inputs, so this host-side count IS what
             # the dispatched executable did
-            route = paged_attn_route(
-                self._paged_attn, key, t=t, quant="k_scale" in self.state)
+            # (a model that generates by blocks states its own rule)
+            route = self.model.block_attn_route() if self._blocks else \
+                paged_attn_route(self._paged_attn, key, t=t,
+                                 quant="k_scale" in self.state)
             self._stats["paged_attn_kernel_ticks" if route == "kernel"
                         else "paged_attn_gather_ticks"] += ticks
 
@@ -3748,24 +3813,34 @@ class ServingEngine:
                     self._contain_fault(slot)
 
     def _emit(self, slot: int, tok: int, lp: Optional[float] = None,
-              now: Optional[float] = None) -> None:
+              now: Optional[float] = None,
+              when: Optional[int] = None) -> None:
         """Per-slot bookkeeping for ONE delivered decode token — the single
-        implementation behind both the device-sampled delivery (_deliver)
-        and the host-sampler fallback, so budget/eos/retire semantics cannot
-        fork between the two paths. Mirrors the device first: its cache
-        length advanced for this slot at dispatch, unconditionally of what
-        eos does below."""
+        implementation behind the device-sampled delivery (_deliver), the
+        host-sampler fallback and a clean block's tokens (_deliver_blocks),
+        so budget/eos/retire semantics cannot fork between the paths.
+        Mirrors the device first: its cache length advanced for this slot
+        at dispatch, unconditionally of what eos does below. ``when`` marks
+        a token of a model that generates by blocks, the request's pass
+        that committed it (``Request.trail``): the writing pass, not the
+        token, advances such a slot's length, and its admission delivered
+        no first token, so the first one is stamped here."""
         self._maybe_inject_dispatch()
         req = self._slot_req[slot]
         self._tokens[slot] = tok
-        self._slot_len[slot] += 1
+        if when is None:
+            self._slot_len[slot] += 1
+        elif req.delivered == 0:
+            self._note_first_token(req, slot)
         self._note_itl(slot, now if now is not None else time.perf_counter())
         self.trace.record("token", req.rid, slot)
-        # logprob BEFORE the queue put: the put unblocks the client thread,
-        # which may immediately read logprobs[-1] expecting this token's
-        # entry to exist
+        # logprob (and the trail's entry) BEFORE the queue put: the put
+        # unblocks the client thread, which may immediately read
+        # logprobs[-1] expecting this token's entry to exist
         if lp is not None:
             req.logprobs.append(lp)
+        if when is not None:
+            req.trail.append(when)
         req.delivered += 1
         req.out.put(tok)
         self._stats["generated_tokens"] += 1
@@ -3800,6 +3875,67 @@ class ServingEngine:
         req.out.put(first)
         if self._slot_budget[slot] <= 0 or first == self.serving.eos_token:
             self._retire(slot)
+
+    def _open_first_block(self, slot: int, req: Request,
+                          tail: np.ndarray) -> None:
+        """The end of an admission into a model that generates by blocks:
+        the prompt's whole blocks are cached (the slot's length), its other
+        tokens (``tail``, on the host) open the first generated block on
+        the device as rows already committed, and the slot joins the
+        passes. No token is delivered: the first come from the block's own
+        passes."""
+        bl = self._blocks
+        n = int(req.tokens.shape[0])
+        self._begin_slot(slot, req, n, firsts=0)
+        self._slot_len[slot] = n - len(tail)
+        req.trail = []
+        ids = np.zeros((bl,), np.int32)
+        ids[:len(tail)] = tail
+        self.state = self._open_block(
+            self.state, jnp.int32(slot), ids, np.arange(bl) >= len(tail),
+            jnp.int32(n + self._slot_budget[slot]))
+        if self._slot_budget[slot] <= 0:
+            self._retire(slot)
+
+    def _deliver_blocks(self, tick: dict) -> None:
+        """Deliver one pass of a model that generates by blocks: ONE fetch
+        of the pass's result, read by the model (``read_pass``: a row a
+        slot), the counters of what the device did, and, for every slot
+        whose block the pass left clean, its tokens in position order with
+        the request's pass that committed each (``Request.trail``), cut at
+        the request's budget. The request-identity check of _deliver guards
+        the lookahead here too: a slot retired or given to another request
+        since the dispatch drops its row."""
+        (rows,) = self._fetch((tick["result"],))
+        if self._died:
+            return  # fleet fencing, post-fetch (see _deliver)
+        bl = self._blocks
+        got = self.model.read_pass(rows)
+        took = int(got.took.sum())
+        self._stats["block_slot_passes"] += took
+        self._stats["block_write_passes"] += int(got.wrote.sum())
+        self._stats["block_rows_dispatched"] += took * bl
+        self._stats["block_rows_masked"] += int(got.eligible[got.took].sum())
+        self._stats["block_tokens_committed"] += int(
+            got.committed[got.took].sum())
+        with self._prof.phase("deliver"):
+            now = time.perf_counter()
+            for slot, req in enumerate(tick["reqs"]):
+                if req is None or req is not self._slot_req[slot]:
+                    continue
+                if got.wrote[slot]:
+                    self._slot_len[slot] = int(got.first[slot]) + bl
+                if not got.clean[slot]:
+                    continue
+                try:
+                    for tok, when in zip(got.ids[slot], got.when[slot]):
+                        if when < 0:  # a row of the prompt, or past the end
+                            continue
+                        self._emit(slot, int(tok), now=now, when=int(when))
+                        if self._slot_req[slot] is not req:
+                            break  # retired: budget spent, or eos
+                except Exception:
+                    self._contain_fault(slot)
 
     def _spec_probe_ema(self) -> float:
         """EMA value for a fresh probe: slightly above breakeven, so a
@@ -4004,6 +4140,8 @@ class ServingEngine:
         # ring has, and what a cached token would cost those layers were
         # they paged as the full layers are (None: no window layers)
         s["window_ring"] = self._window_ring
+        # rows of a block, for a model that generates by blocks (0: none)
+        s["block_length"] = self._blocks
         s["ring_bytes_per_position"] = getattr(
             self.model, "ring_bytes_per_position", None)
         if self._paged:
@@ -4139,7 +4277,12 @@ class ServingEngine:
                 # and costs one masked tick; where they do not (a mesh whose
                 # compiler picked another output sharding) the second
                 # executable is compiled HERE instead of mid-stream.
-                if self._loop_k:
+                if self._blocks:
+                    # a pass over no active slot: nothing commits, nothing
+                    # is written
+                    _, self.state = self._decode_sampled(
+                        self.params, self.state, inactive, bucket)
+                elif self._loop_k:
                     # the k-tick flush executable replaces the single-tick
                     # sampled step as the loop's only decode dispatch; warm it
                     # per read bucket (all-inactive, zero caps: k masked ticks
@@ -4203,6 +4346,13 @@ class ServingEngine:
             for n in self._admit_sizes:
                 keys = jax.random.split(jax.random.key(0), n + 1)
                 _, _ = keys[0], keys[1:]
+        elif self._blocks:
+            # no whole-prompt program; the block's opening, as the table
+            # row's install below (slot 0 holds no request: its block ends
+            # at 0 and does nothing)
+            self.state = self._open_block(
+                self.state, jnp.int32(0), np.zeros((self._blocks,), np.int32),
+                np.ones((self._blocks,), bool), jnp.int32(0))
         else:
             for bucket in self._prefill_buckets:
                 with span(program="prefill_into_slot", bucket=bucket):
@@ -4211,7 +4361,7 @@ class ServingEngine:
                         jnp.zeros((1, bucket), jnp.int32),
                         jnp.int32(0), jnp.int32(1),
                     )
-        if self._device_sampling:
+        if self._device_sampling and not self._blocks:
             # the [B] token merge serves both the pipelined fed-merge and
             # the admission override — warm its one executable
             # (both token operands committed, as every serving call's are)
@@ -4288,7 +4438,9 @@ class ServingEngine:
                     self._warm_executables()
                 if self._disagg is not None:
                     self._disagg.started.set()
-                if self._fused_spec:
+                if self._blocks:
+                    self._loop_blocks()
+                elif self._fused_spec:
                     self._loop_fused()
                 elif self._loop_k:
                     self._loop_device()
@@ -4700,6 +4852,66 @@ class ServingEngine:
             # now the sessions may be rebuilt on survivors, and a late
             # delivery here would duplicate their tokens).
             self._deliver(inflight)
+
+    def _loop_blocks(self) -> None:
+        """_loop_pipelined for a model that generates by blocks
+        (vtpu/models/blockdiff.py): one pass a tick for every slot that
+        holds a request, pass t + 1 dispatched before pass t's result is
+        fetched. What each slot does next (a denoising pass, the writing
+        pass, nothing more because its generation has reached its end) is
+        decided on the device from the slot's block, which lives in the
+        state, so nothing is fed back from the host and nothing is
+        predicted: a slot whose last block is already clean on the device
+        takes a pass that does nothing until the host has delivered it and
+        retired the slot. A slot admitted after t's dispatch joins at
+        t + 1 with its first block opened by the admission."""
+        b = self.serving.slots
+        inflight: Optional[dict] = None
+        active = None
+        active_key: Optional[tuple] = None
+        while not self._stop.is_set():
+            admitted = self._tick_head()
+            dispatch = [i for i in range(b) if self._slot_req[i] is not None]
+            new_inflight = None
+            if dispatch:
+                with self._prof.phase("dispatch"):
+                    live = set(dispatch)
+                    if active_key != tuple(dispatch):
+                        active = jnp.asarray(
+                            [i in live for i in range(b)], bool)
+                        active_key = tuple(dispatch)
+                    # the host's mirror of a slot's length lags the passes
+                    # in flight and not yet fetched: two, of which at most
+                    # one advances it by a block; the window must hold the
+                    # block after that one too
+                    need = max(self._slot_len[i] for i in dispatch) \
+                        + 2 * self._blocks
+                    kv_bucket = next(
+                        (bkt for bkt in self._kv_buckets if bkt >= need),
+                        self.model.max_context)
+                    self._note_kv_window(
+                        kv_bucket, [self._slot_len[i] for i in dispatch],
+                        t=self._blocks, wrote=0)
+                    result, self.state = self._decode_sampled(
+                        self.params, self.state, active, kv_bucket)
+                    self._stats["decode_ticks"] += 1
+                    if inflight is not None:
+                        self._stats["pipelined_ticks"] += 1
+                    new_inflight = {
+                        "result": result,
+                        "reqs": [self._slot_req[i] if i in live else None
+                                 for i in range(b)]}
+            if not dispatch and inflight is None:
+                self._idle_wait(admitted)
+                continue
+            if inflight is not None:
+                self._deliver_blocks(inflight)
+            inflight = new_inflight
+            self._inflight_slots = (
+                {i for i in range(b) if inflight["reqs"][i] is not None}
+                if inflight is not None else set())
+        if inflight is not None and not self._died:
+            self._deliver_blocks(inflight)  # as _loop_pipelined's last
 
     def _loop_device(self) -> None:
         """Multi-tick device-resident decode loop (decode_loop_k = k > 1):
