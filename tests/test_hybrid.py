@@ -296,38 +296,192 @@ def test_attention_layer_alone_against_the_reference(program):
         np.testing.assert_allclose(jnp.concatenate(outs), want, atol=2e-5)
 
 
-@pytest.mark.parametrize("rows,heads,dtype,tol", [
-    (4, 32, jnp.float32, 1e-6), (1, 4, jnp.float32, 1e-6),
-    (2, 8, jnp.float32, 1e-6), (4, 8, jnp.float32, 1e-6),
-    (4, 32, jnp.bfloat16, 0.01)])
-def test_grouped_kernel_equals_the_gather_route(rows, heads, dtype, tol):
-    """``paged_decode_attention`` over pools of ``rows`` rows of 128 lanes
-    a token (2 heads of 64 a row) against ``paged_causal_attention`` on the
-    same operands: 1, 2 and 4 rows; 8 queries a slot and fewer (padded to
-    a sublane tile); slots that read one token, a page and a bit, two
-    groups of pages and a bit, and nothing yet; a layer that is not the
-    first; scattered blocks."""
-    from vtpu.ops.attention import paged_causal_attention
-    from vtpu.ops.decode_attn import paged_decode_attention
-
-    slots, page, wp = 5, 16, 20
+def _grouped_operands(rows, heads, dh, lens, dtype):
+    """Pools of ``rows`` rows of 128 lanes a token over two layers, the
+    slots' blocks scattered, queries of ``heads`` heads of ``dh`` for
+    lengths ``lens`` [slots, T]."""
+    slots, page, wp = len(lens), 16, 136
     keys = jax.random.split(jax.random.PRNGKey(11), 3)
     shape = (2, 1 + slots * wp, page, rows, 128)
     k_pool = jax.random.normal(keys[0], shape, dtype)
     v_pool = jax.random.normal(keys[1], shape, dtype)
-    q = jax.random.normal(keys[2], (slots, 1, heads, 64), dtype)
-    lens = jnp.asarray([1, 21, 130, 300, 0], jnp.int32)
+    q = jax.random.normal(keys[2], (slots, lens.shape[1], heads, dh), dtype)
     table = jnp.asarray(1 + np.random.default_rng(3).permutation(
         slots * wp).reshape(slots, wp).astype(np.int32))
+    return q, k_pool, v_pool, table
+
+
+# a group is 1024 tokens (64 pages); a slot that reads one token, a page and
+# a bit, an eighth of a group and a bit, two groups and a bit, and nothing
+ONE_QUERY = [[1], [21], [130], [2100], [0]]
+# the block pass's shape (every row of a slot reads the slot's cache):
+# exactly a group, a group and a token, several groups, nothing, a page
+BLOCK_ROWS = [[1024] * 4, [1025] * 4, [2100] * 4, [0] * 4, [16] * 4]
+# the verify shape (a slot's rows read one token more each): over a group's
+# edge, inside the last of several groups, from nothing, inside a page
+RISING = [[1022, 1023, 1024, 1025], [2047, 2048, 2049, 2050], [0, 1, 2, 3],
+          [5, 6, 7, 8], [2173, 2174, 2175, 2176]]
+GROUPED_CASES = [
+    pytest.param(4, 32, 64, ONE_QUERY, jnp.float32, 1e-6, id="4-32-f32"),
+    pytest.param(1, 4, 64, ONE_QUERY, jnp.float32, 1e-6, id="1-4-f32"),
+    pytest.param(2, 8, 64, ONE_QUERY, jnp.float32, 1e-6, id="2-8-f32"),
+    pytest.param(4, 8, 64, ONE_QUERY, jnp.float32, 1e-6, id="4-8-f32"),
+    pytest.param(4, 32, 64, ONE_QUERY, jnp.bfloat16, 0.01, id="4-32-bf16"),
+    pytest.param(4, 32, 128, BLOCK_ROWS, jnp.float32, 2e-6,
+                 id="sdar-block-f32"),
+    pytest.param(4, 32, 128, BLOCK_ROWS, jnp.bfloat16, 0.01,
+                 id="sdar-block-bf16"),
+    pytest.param(4, 32, 128, RISING, jnp.float32, 2e-6,
+                 id="sdar-rising-f32"),
+    pytest.param(4, 8, 64, RISING, jnp.float32, 1e-6, id="4-8-rising-f32"),
+    pytest.param(2, 8, 64, RISING, jnp.float32, 1e-6, id="2-8-rising-f32"),
+    pytest.param(1, 4, 64, RISING, jnp.float32, 1e-6, id="1-4-rising-f32"),
+    pytest.param(1, 4, 64, BLOCK_ROWS, jnp.bfloat16, 0.01,
+                 id="1-4-block-bf16"),
+    pytest.param(2, 8, 64, RISING, jnp.bfloat16, 0.02, id="2-8-rising-bf16"),
+]
+
+
+@pytest.mark.parametrize("rows,heads,dh,lens,dtype,tol", GROUPED_CASES)
+def test_grouped_kernel_equals_the_gather_route(
+        rows, heads, dh, lens, dtype, tol):
+    """``paged_decode_attention`` over pools of ``rows`` rows of 128 lanes
+    a token (2 heads of 64 a row, or 1 of 128: SDAR's) against
+    ``paged_causal_attention`` on the same operands: 1, 2 and 4 rows; 8
+    queries a slot, fewer (padded to a sublane tile) and SDAR's 32 a pool
+    row; one query a slot, a block's four at one length and a verify
+    chunk's four at rising lengths; slots that read nothing, a token, a
+    page, exactly a group, a group and a token, several groups and a bit;
+    float32 pools (read with a stride as they are) and bfloat16 ones (read
+    through their 32-bit view); a layer that is not the first; scattered
+    blocks."""
+    from vtpu.ops.attention import paged_causal_attention
+    from vtpu.ops.decode_attn import paged_decode_attention
+
+    lens = np.asarray(lens, np.int32)
+    q, k_pool, v_pool, table = _grouped_operands(rows, heads, dh, lens, dtype)
+    scale = dh ** -0.5
     want = paged_causal_attention(
-        q, k_pool[1], v_pool[1], table, lens[:, None], scale=0.125)
+        q, k_pool[1], v_pool[1], table, jnp.asarray(lens), scale=scale)
     got = paged_decode_attention(
-        q, k_pool, v_pool, table, lens, layer=1, scale=0.125)
-    live = np.asarray(lens) > 0  # a slot that reads nothing has no answer
+        q, k_pool, v_pool, table, jnp.asarray(lens), layer=1, scale=scale)
+    live = lens > 0  # a query that reads nothing has no answer
     np.testing.assert_allclose(
         np.asarray(got, np.float32)[live], np.asarray(want, np.float32)[live],
         atol=tol)
     assert np.isfinite(np.asarray(got, np.float32)).all()
+
+
+@pytest.mark.parametrize("rows,heads,dh,lens,dtype,tol", [
+    pytest.param(4, 32, 128, BLOCK_ROWS, jnp.float32, 1e-5,
+                 id="sdar-block-f32"),
+    pytest.param(4, 32, 128, BLOCK_ROWS, jnp.bfloat16, 0.01,
+                 id="sdar-block-bf16"),
+    pytest.param(4, 32, 128, RISING, jnp.float32, 1e-5,
+                 id="sdar-rising-f32"),
+    pytest.param(4, 8, 64, ONE_QUERY, jnp.float32, 1e-5, id="4-8-f32"),
+    pytest.param(2, 8, 64, RISING, jnp.float32, 1e-5, id="2-8-rising-f32"),
+    pytest.param(1, 4, 64, ONE_QUERY, jnp.float32, 1e-5, id="1-4-f32"),
+])
+def test_grouped_kernels_log_sum_exp_is_the_gathered_windows(
+        rows, heads, dh, lens, dtype, tol):
+    """The walk's second output (``lse=True``: what ``blockdiff._joined``
+    joins the own block's softmax by) against ``log(sum(exp(scores)))``
+    made straight from the gathered window in float32, a query head at a
+    time; about -1e30 where a query read nothing; the first output
+    unchanged by asking for the second."""
+    from vtpu.ops.attention import gather_kv_pages
+    from vtpu.ops.decode_attn import paged_decode_attention
+
+    lens = np.asarray(lens, np.int32)
+    q, k_pool, v_pool, table = _grouped_operands(rows, heads, dh, lens, dtype)
+    scale = dh ** -0.5
+    got, sums = paged_decode_attention(
+        q, k_pool, v_pool, table, jnp.asarray(lens), layer=1, scale=scale,
+        lse=True)
+    alone = paged_decode_attention(
+        q, k_pool, v_pool, table, jnp.asarray(lens), layer=1, scale=scale)
+    assert sums.shape == q.shape[:3] and sums.dtype == jnp.float32
+    assert np.array_equal(np.asarray(got, np.float32),
+                          np.asarray(alone, np.float32))
+    slots, t = lens.shape
+    window = np.asarray(gather_kv_pages(k_pool[1], table), np.float64)
+    window = window.reshape(slots, window.shape[1], -1, dh)  # [B, S, Hk, Dh]
+    grouped = np.asarray(q, np.float64).reshape(
+        slots, t, window.shape[2], -1, dh)
+    scores = np.einsum("btkgd,bskd->btkgs", grouped, window) * scale
+    read = np.arange(window.shape[1]) < lens[:, :, None, None, None]
+    with np.errstate(divide="ignore"):
+        want = np.log(np.sum(np.exp(np.where(read, scores, -np.inf)), -1))
+    live = lens > 0
+    np.testing.assert_allclose(
+        np.asarray(sums)[live], want.reshape(slots, t, heads)[live], atol=tol)
+    assert (np.asarray(sums)[~live] < -1e29).all()
+
+
+@pytest.mark.parametrize("rows,heads,dtype", [
+    pytest.param(3, 12, jnp.bfloat16, id="3-rows-of-16-bit"),
+    pytest.param(4, 8, jnp.float8_e4m3fn, id="4-rows-of-8-bit"),
+])
+def test_grouped_walk_refuses_rows_that_fill_no_whole_words(
+        rows, heads, dtype):
+    """A pool row's tokens are read with a stride, which Mosaic gives for
+    32-bit data alone: a 16-bit pool goes through its 32-bit view, two pool
+    rows a word, so an odd number of rows above one has no such read, and
+    no 8-bit pool has one (four rows a word are not unpacked)."""
+    from vtpu.ops.decode_attn import paged_decode_attention
+
+    lens = np.asarray([[5], [600]], np.int32)
+    q, k_pool, v_pool, table = _grouped_operands(
+        rows, heads, 64, lens, jnp.float32)
+    with pytest.raises(ValueError, match="whole words"):
+        paged_decode_attention(
+            q.astype(dtype), k_pool.astype(dtype), v_pool.astype(dtype),
+            table, jnp.asarray(lens), layer=1, scale=0.125)
+
+
+def test_grouped_walk_reads_any_number_of_32_bit_rows():
+    """A 32-bit pool is read with a stride as it is: three rows a token."""
+    from vtpu.ops.attention import paged_causal_attention
+    from vtpu.ops.decode_attn import paged_decode_attention
+
+    lens = np.asarray([[5], [600]], np.int32)
+    q, k_pool, v_pool, table = _grouped_operands(3, 12, 64, lens, jnp.float32)
+    want = paged_causal_attention(
+        q, k_pool[1], v_pool[1], table, jnp.asarray(lens), scale=0.125)
+    got = paged_decode_attention(q, k_pool, v_pool, table, jnp.asarray(lens),
+                                 layer=1, scale=0.125)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+
+
+def test_the_walk_bench_runs_at_cut_down_shapes(tmp_path):
+    """``benchmarks/paged_attn_walk_bench.py --tiny`` at the two shapes of
+    the grouped walk (the table PERF.md's PR 44 entry chose the kernel's
+    form with) runs on the CPU, the kernel interpreted, at a group length
+    of its own asking, and holds its rows to the gather route's and its
+    log-sum-exp to the gathered window's; its times there are no speeds."""
+    import json
+    import pathlib
+    import subprocess
+    import sys
+
+    root = pathlib.Path(__file__).resolve().parent.parent
+    out = tmp_path / "bench.json"
+    done = subprocess.run(
+        [sys.executable, str(root / "benchmarks/paged_attn_walk_bench.py"),
+         "--tiny", "--shapes", "granite4096,sdar4096", "--groups", "64",
+         "--out", str(out)],
+        capture_output=True, text=True, timeout=300)
+    assert done.returncode == 0, done.stderr[-2000:]
+    granite, sdar = json.loads(out.read_text())
+    assert (granite["shape"], sdar["shape"]) == ("granite4096", "sdar4096")
+    assert granite["device"] == sdar["device"] == "cpu"
+    assert "error" not in granite and "error" not in sdar
+    assert (granite["queries_a_slot"], sdar["queries_a_slot"]) == (1, 4)
+    assert granite["group_tokens"] == sdar["group_tokens"] == 64
+    assert granite["max_abs_err"] < 1e-5 and sdar["max_abs_err"] < 1e-5
+    assert sdar["max_abs_err_lse"] < 1e-5
+    assert "max_abs_err_lse" not in granite
 
 
 # ---------------------------------------------------------- admission

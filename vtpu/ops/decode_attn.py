@@ -58,10 +58,18 @@ softmax denominator).
 Grouped queries (fewer key/value heads than query heads, the heads stored
 several a row of whole lanes: ``transformer.kv_plane_shape``) make the same
 walk with their arithmetic on the MXU (``_grouped_kernel``): a slot's
-queries of a pool row are a tile of 8 against a group's 128 tokens, where
-the vector unit's form paid a vreg a token a query (PERF.md, PR 32: 22.7 ms
+queries of a pool row are a tile of 8 (the hybrid cell's) or 32 (a block
+pass's four rows of 8 heads) against a group's tokens, where the
+vector unit's form paid a vreg a token a query (PERF.md, PR 32: 22.7 ms
 for four layers of the hybrid cell at any page size, its copies not the
-bound).
+bound). A pool row is key/value heads of its own, so the kernel makes a
+product a pool row over that row's tokens, read out of the group's buffer
+with a stride (``pool_row`` says how, and why not plainly), and a softmax
+a pool row over scores that are all wanted. What a group costs is the
+latency of its chain (wait, product, softmax, product, carry) more than
+its work, so this walk's groups are ``_GROUPED_GROUP_TOKENS`` long where
+the others' are 128 (PERF.md, PR 44, has the timings, and those of the
+forms not taken).
 
 Both kernels equal their XLA references on the same operands
 (tests/test_ops.py drives the dense study; tests/test_paged_attn_kernel.py
@@ -380,13 +388,19 @@ def decode_attention(
 # never more than the read window has.
 _GROUP_TOKENS = 128
 _GROUP_VMEM_BYTES = 8 << 20
+# the grouped walk's (``_grouped_kernel``). A group there is one dependent
+# chain (wait, product, softmax, product) and its time is the chain's
+# latency more than its work: a block pass's 24 layers read 27.4 / 22.4 /
+# 17.3 / 16.0 / 16.5 ms at 128 / 256 / 512 / 1024 / 2048 tokens, the hybrid
+# step's four 6.2 / 6.2 / 5.0 / 4.5 / 4.3 (PERF.md, PR 44)
+_GROUPED_GROUP_TOKENS = 1024
 
 
 def _pages_per_group(page: int, h: int, dh: int, itemsize: int,
-                     wp: int) -> int:
+                     wp: int, tokens: int) -> int:
     per_page = 2 * page * h * dh * itemsize  # K and V
     fit = _GROUP_VMEM_BYTES // (2 * per_page)
-    return max(1, min(fit, _GROUP_TOKENS // page, wp))
+    return max(1, min(fit, tokens // page, wp))
 
 
 def _copies_cut(h: int, itemsize: int) -> bool:
@@ -541,24 +555,27 @@ def _grouped_kernel(lay_ref, tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     group of pages as (tokens * rows, lanes), row r of a token at
     token * rows + r: the pool's bytes as they are stored. A pool row is
     key/value heads of its own, so query row (r, j) attends over the
-    cached rows r alone: one product against the whole group gives every
-    query row against every cached row, the mask keeps the lanes of the
-    same pool row (and of positions under the query's length), and the
-    running softmax is one (rows * nq, tokens * rows) tile a group for all
-    queries; the probabilities, zero at every other lane, go into one
-    product with the values as stored. The buffers are zeroed once: a
-    group's unfilled pages are masked, and what is masked must still be
-    finite."""
+    cached rows r alone: ``pool_row`` reads those out of the group's
+    buffer, every ``rows``-th row from the r-th, and a pool row is a
+    running softmax of its own, (nq, lanes) x (lanes, tokens) scores in
+    float32 and the probabilities against the same rows of the values.
+    At ``rows`` 1 nothing is strided and the kernel is the plain walk of
+    a (nq, lanes) tile.
+
+    The buffers are zeroed once: a group's unfilled pages are masked, and
+    what is masked must still be finite."""
     planes = ((k_hbm, k_buf), (v_hbm, v_buf))
     b, nb = pl.program_id(0), pl.num_programs(0)
     nq = q_ref.shape[1] // rows
     pr = page * rows                    # pool rows a page
-    width = group * pr                  # score lanes a group
+    tokens = group * page
+    per_word = 4 // k_buf.dtype.itemsize   # pool rows a 32-bit word
     lay = lay_ref[0]
     live = functools.partial(_live_pages, len_ref, t=nq, page=page)
+    live_b = live(b)
 
-    def copy_group(row, g, slot, wait: bool):
-        n = jnp.minimum(live(row) - g * group, group)
+    def copy_group(row, g, slot, n_live, wait: bool):
+        n = jnp.minimum(n_live - g * group, group)
 
         def one(i, _):
             blk = tbl_ref[row, g * group + i]
@@ -579,52 +596,80 @@ def _grouped_kernel(lay_ref, tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         turn_ref[0] = 0
         k_buf[...] = jnp.zeros(k_buf.shape, k_buf.dtype)
         v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
-        copy_group(0, 0, 0, wait=False)
+        copy_group(0, 0, 0, live_b, wait=False)
 
-    n_groups = pl.cdiv(live(b), group)
+    n_groups = pl.cdiv(live_b, group)
     turn = turn_ref[0]
     q = q_ref[0]                                         # (rows * nq, lanes)
-    sub = jax.lax.broadcasted_iota(jnp.int32, (rows * nq, 1), 0)
-    lens = jnp.zeros((rows * nq, 1), jnp.int32)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (nq, 1), 0)
+    lens = jnp.zeros((nq, 1), jnp.int32)
     for j in range(nq):
-        lens = jnp.where(sub % nq == j, len_ref[b, j], lens)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
-    same_row = sub // nq == lane % rows                  # (rows * nq, width)
+        lens = jnp.where(sub == j, len_ref[b, j], lens)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, tokens), 1)
+
+    def pool_row(buf, slot, r):
+        """Pool row r of the group's tokens, (tokens, lanes). Mosaic
+        reads with a stride only 32-bit data (of a bfloat16 buffer,
+        ``buf[slot, pl.ds(r, tokens, stride=rows)]``: "not implemented:
+        Strided load with non 32-bit data"), so a 16-bit buffer is read
+        through its 32-bit view, a word holding pool rows 2i (its low
+        half) and 2i + 1, and the half wanted is shifted or masked into a
+        float32 that is the 16-bit value exactly."""
+        if rows == 1:
+            return buf[slot]
+        if per_word == 1:
+            return buf[slot, pl.ds(r, tokens, stride=rows)]
+        words = buf.bitcast(jnp.uint32)[
+            slot, pl.ds(r // 2, tokens, stride=rows // 2)]
+        bits = (words << 16) if r % 2 == 0 else (
+            words & jnp.uint32(0xFFFF0000))
+        return pltpu.bitcast(bits, jnp.float32).astype(buf.dtype)
 
     def attend_group(g, carry):
-        m_prev, d_prev, acc = carry
         slot = (turn + g) % 2
         last = g + 1 == n_groups
 
-        @pl.when(jnp.logical_not(last) | (b + 1 < nb))
+        @pl.when(jnp.logical_not(last))
         def _prefetch():
-            copy_group(jnp.where(last, jnp.minimum(b + 1, nb - 1), b),
-                       jnp.where(last, 0, g + 1), 1 - slot, wait=False)
+            copy_group(b, g + 1, 1 - slot, live_b, wait=False)
 
-        copy_group(b, g, slot, wait=True)
-        s = jax.lax.dot_general(
-            q, k_buf[slot], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        valid = same_row & (g * group * page + lane // rows < lens)
-        s = jnp.where(valid, s, _NEG_INF)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        # a row with nothing to read yet (an idle slot, a padded query)
-        # would weigh every lane by exp(0): keep the other rows' lanes out
-        p = jnp.where(same_row, jnp.exp(s - m_new), 0.0)
-        d_new = d_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        pv = jnp.dot(p.astype(v_buf.dtype), v_buf[slot],
-                     preferred_element_type=jnp.float32)
-        return m_new, d_new, acc * alpha + pv
+        @pl.when(last & (b + 1 < nb))
+        def _prefetch_next_slot():
+            copy_group(b + 1, 0, 1 - slot, live(b + 1), wait=False)
 
-    init = (jnp.full((rows * nq, 1), _NEG_INF, jnp.float32),
-            jnp.zeros((rows * nq, 1), jnp.float32),
-            jnp.zeros(q.shape, jnp.float32))
-    m, d, acc = jax.lax.fori_loop(0, n_groups, attend_group, init)
+        copy_group(b, g, slot, live_b, wait=True)
+        out = []
+        for r, (m_prev, d_prev, acc) in enumerate(carry):
+            s = jax.lax.dot_general(
+                q[r * nq:(r + 1) * nq], pool_row(k_buf, slot, r),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # (nq, tokens)
+            s = jnp.where(g * tokens + lane < lens, s, _NEG_INF)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            # a row with nothing to read yet (an idle slot, a padded
+            # query) weighs every token by exp(0): finite, and nobody
+            # reads it
+            p = jnp.exp(s - m_new)
+            out.append((m_new,
+                        d_prev * alpha + jnp.sum(p, axis=1, keepdims=True),
+                        acc * alpha + jnp.dot(
+                            p.astype(v_buf.dtype), pool_row(v_buf, slot, r),
+                            preferred_element_type=jnp.float32)))
+        return tuple(out)
+
+    init = tuple((jnp.full((nq, 1), _NEG_INF, jnp.float32),
+                  jnp.zeros((nq, 1), jnp.float32),
+                  jnp.zeros((nq, q.shape[1]), jnp.float32))
+                 for _ in range(rows))
+    state = jax.lax.fori_loop(0, n_groups, attend_group, init)
     turn_ref[0] = (turn + n_groups) % 2
-    o_ref[0] = (acc / d).astype(o_ref.dtype)
-    if lse_ref is not None:
-        lse_ref[0] = jnp.broadcast_to(m + jnp.log(d), lse_ref.shape[1:])
+    for r, (m, d, acc) in enumerate(state):
+        at = pl.ds(r * nq, nq)
+        o_ref[0, at] = (acc / d).astype(o_ref.dtype)
+        if lse_ref is not None:
+            lse_ref[0, at] = jnp.broadcast_to(
+                m + jnp.log(d), (nq, lse_ref.shape[2]))
 
 
 def _grouped_lse_kernel(lay_ref, tbl_ref, len_ref, q_ref, k_hbm, v_hbm,
@@ -733,7 +778,8 @@ def _paged_call(q, k_pool, v_pool, k_scale_pool, v_scale_pool, table,
         params = pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"))
     else:
-        group = _pages_per_group(page, h, dh, k_pool.dtype.itemsize, wp)
+        group = _pages_per_group(page, h, dh, k_pool.dtype.itemsize, wp,
+                                 _GROUP_TOKENS)
         hbm = pl.BlockSpec(memory_space=pl.ANY)
         operands, in_specs = [q, k_pool, v_pool], [q_spec, hbm, hbm]
         kernel = functools.partial(_paged_kernel, group=group)
@@ -778,6 +824,12 @@ def _grouped_call(packed, k_pool, v_pool, table, kv_len, lay,
     b, nq, rows, lanes = packed.shape
     n_layers, n_blocks, page = k_pool.shape[:3]
     wp = table.shape[1]
+    if rows > 1 and (k_pool.dtype.itemsize not in (2, 4)
+                     or rows % (4 // k_pool.dtype.itemsize)):
+        raise ValueError(
+            f"the grouped walk reads a pool row's tokens with a stride, "
+            f"32-bit words of one or two rows only: {rows} rows of "
+            f"{k_pool.dtype} a token do not fill whole words")
     pad = -nq % 8  # whole sublane tiles of queries; a padded one reads nothing
     with jax.named_scope("pool_relayout"):
         lens = jnp.pad(jnp.minimum(kv_len.astype(jnp.int32), wp * page),
@@ -785,7 +837,8 @@ def _grouped_call(packed, k_pool, v_pool, table, kv_len, lay,
         q = jnp.pad(packed, ((0, 0), (0, pad), (0, 0), (0, 0)))
         q = q.transpose(0, 2, 1, 3).reshape(b, rows * (nq + pad), lanes)
     merged = (n_layers, n_blocks, page * rows, lanes)
-    group = _pages_per_group(page, rows, lanes, k_pool.dtype.itemsize, wp)
+    group = _pages_per_group(page, rows, lanes, k_pool.dtype.itemsize, wp,
+                             _GROUPED_GROUP_TOKENS)
     q_spec = pl.BlockSpec((1,) + q.shape[1:], lambda i, *_: (i, 0, 0))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     out_specs, out_shape = q_spec, jax.ShapeDtypeStruct(q.shape, q.dtype)
