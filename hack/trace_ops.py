@@ -11,7 +11,8 @@ mean over all launches hides when two traces hold different mixes.
 
 Reads the newest ``.xplane.pb`` under the directory with vbench/scopes.py's
 own reader; a scope path keeps the names of the vocabulary, the hybrid
-family's ``ssm_*`` and the latent family's (``vbench.latent_scopes.NAMES``);
+family's ``ssm_*``, the latent family's (``vbench.latent_scopes.NAMES``) and
+a chunk's attention over its window (``vbench.chunk_scopes.NAMES``);
 an operation's kind is its name without the number
 (``fusion``, ``ssm_state_step``, ``reshape``).
 """
@@ -24,7 +25,7 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-from vbench import latent_scopes, scopes  # noqa: E402
+from vbench import chunk_scopes, latent_scopes, scopes  # noqa: E402
 
 
 def main(argv) -> int:
@@ -43,7 +44,8 @@ def main(argv) -> int:
                 continue
             names = [part for part in op[3].rstrip(":").split("/")
                      if part in scopes.VOCAB or part.startswith("ssm_")
-                     or part in latent_scopes.NAMES]
+                     or part in latent_scopes.NAMES
+                     or part in chunk_scopes.NAMES]
             key = ("/".join(names), scopes.short_name(op[0]).split(".")[0])
             ms[key] += own / 1e9
             count[key] += 1

@@ -467,8 +467,8 @@ def test_the_engine_counts_its_chunks_by_the_form_of_their_attention(
         family, chunk, expanded):
     """``chunk_attn_launches`` is every chunk dispatched for a model that
     selects, ``chunk_attn_expanded`` those whose length the shape rule
-    expands (toy widths: more than 32 queries); a dense model counts
-    neither."""
+    expands (toy widths: more than 32 queries); a dense model counts its
+    chunks too since PR 45 (its window holds heads: none expands)."""
     if family == "latent":
         mc, params = _both_sides(TOY)
         eng = _engine(mc, params, chunk)
@@ -496,12 +496,8 @@ def test_the_engine_counts_its_chunks_by_the_form_of_their_attention(
     assert stats["loop_error"] is None
     chunks = sum(-(-len(p) // chunk) for p in prompts)
     assert stats["prefill_chunks"] == chunks
-    if family == "dense":
-        assert stats["chunk_attn_launches"] == 0
-        assert stats["chunk_attn_expanded"] == 0
-    else:
-        assert stats["chunk_attn_launches"] == chunks
-        assert stats["chunk_attn_expanded"] == (chunks if expanded else 0)
+    assert stats["chunk_attn_launches"] == chunks
+    assert stats["chunk_attn_expanded"] == (chunks if expanded else 0)
 
 
 @pytest.mark.parametrize("what,match", [

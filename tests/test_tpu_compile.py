@@ -728,10 +728,7 @@ def test_hybrid_programs_compile_at_the_cells_sizes(
         ).lower(params, cfg, state, i32(HYBRID_SLOTS),
                 on_chip(jnp.zeros((HYBRID_SLOTS,), bool)), 8192).compile()
     elif program == "chunk":
-        compiled = jax.jit(
-            M.hybrid_prefill_chunk, static_argnums=(1, 7), donate_argnums=(2,)
-        ).lower(params, cfg, state, i32(1, 512), i32(), i32(), i32(), 8192,
-                i32(8192 // 16)).compile()
+        compiled = _hybrid_chunk(v5e, 8192)[0]
     else:
         compiled = jax.jit(
             M.hybrid_prefill_rows, static_argnums=(1,), donate_argnums=(2,)
@@ -855,10 +852,7 @@ def test_window_family_programs_compile_at_the_cells_sizes(
         ).lower(params, cfg, state, i32(SWA_SLOTS),
                 on_chip(jnp.zeros((SWA_SLOTS,), bool)), window).compile()
     else:
-        compiled = jax.jit(
-            M.swa_prefill_chunk, static_argnums=(1, 7), donate_argnums=(2,)
-        ).lower(params, cfg, state, i32(1, 512), i32(), i32(), i32(), window,
-                i32(window // 64)).compile()
+        compiled = _swa_chunk(v5e, window)[0]
     mem = compiled.memory_analysis()
     peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
@@ -940,10 +934,6 @@ def test_block_generation_programs_compile_at_the_cells_widths(
     no window is gathered, the pool is updated in place, and no stack of
     projections or experts is laid out anew (a layer sliced out of the
     experts' stack for a kernel would be a copy of 151 MB)."""
-    from vtpu.models import slots as slot_steps
-    from vtpu.models.latent import LayerOfStack
-    from vtpu.models.moe import held_moe_ffn
-
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     M, cfg, params, state, on_chip = _blockdiff_shapes(v5e)
     i32 = lambda *shape: on_chip(jnp.zeros(shape, jnp.int32))  # noqa: E731
@@ -955,15 +945,7 @@ def test_block_generation_programs_compile_at_the_cells_widths(
         ).lower(params, cfg, state,
                 on_chip(jnp.zeros((SDAR_SLOTS,), bool)), window).compile()
     else:
-        def chunk(params, state, tokens, slot, offset, new_len, block_ids):
-            return slot_steps.chunked_prefill_into_slot(
-                params, cfg, state, tokens, slot, offset, new_len,
-                kv_bucket=window, unroll=True, ffn_fn=held_moe_ffn(cfg),
-                block_ids=block_ids, layer_of=LayerOfStack)
-
-        compiled = jax.jit(chunk, donate_argnums=(1,)).lower(
-            params, state, i32(1, 512), i32(), i32(), i32(),
-            i32(window // 16)).compile()
+        compiled = _blockdiff_chunk(v5e, window)[0]
     mem = compiled.memory_analysis()
     pool = sum(math.prod(state[key].shape) * 2 for key in ("k", "v"))
     assert mem.alias_size_in_bytes > pool               # updated in place
@@ -986,3 +968,133 @@ def test_block_generation_programs_compile_at_the_cells_widths(
         a_window = window * 4 * 128                  # one slot's keys
         assert decode_attn.count_pool_gathers(text, a_window // 2) == 0
         assert mem.temp_size_in_bytes < experts * 2, mem.temp_size_in_bytes
+
+
+# -- a chunk's attention over its gathered window (PR 45) ---------------------
+
+_CHUNK_KERNEL = re.compile(
+    r'custom_call_target="tpu_custom_call"[^\n]*'
+    r'op_name="[^"]*/(?:attn|gather_attn)/chunk_attn/jit\(chunk_attention\)/'
+    r'chunk_attn/pallas_call"')
+
+
+def _dense_chunk(v5e, window):
+    """A 512-token chunk of `dsllm7b_longprompt` (32 heads of 128, hidden
+    4096) in two of its 15 layers over its pool (898 blocks of 16)."""
+    from vtpu.models import slots as slot_steps
+    from vtpu.models.transformer import hold_projections, init_paged_kv_cache
+
+    cfg = ModelConfig(
+        vocab=102400, d_model=4096, n_heads=32, n_layers=2, d_ff=11008,
+        max_seq=4096, head_dim=128, dtype=jnp.bfloat16, use_pallas=False)
+    aval = _on(SingleDeviceSharding(v5e[0]))
+    on_chip = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: aval(x.shape, x.dtype), tree)
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(jax.random.key(0), cfg)))
+    params["layers"] = hold_projections(params["layers"], cfg)
+    state = on_chip(jax.eval_shape(
+        lambda: init_paged_kv_cache(cfg, 16, 16, 898)))
+
+    def chunk(params, state, tokens, slot, offset, new_len, block_ids):
+        return slot_steps.chunked_prefill_into_slot(
+            params, cfg, state, tokens, slot, offset, new_len,
+            kv_bucket=window, unroll=True, block_ids=block_ids)
+
+    i32 = lambda *shape: aval(shape, jnp.int32)  # noqa: E731
+    return jax.jit(chunk, donate_argnums=(1,)).lower(
+        params, state, i32(1, 512), i32(), i32(), i32(),
+        i32(window // 16)).compile(), cfg.n_layers, 32 * 128
+
+
+def _hybrid_chunk(v5e, window):
+    M, cfg, params, state, on_chip = _hybrid_shapes(v5e)
+    i32 = lambda *shape: on_chip(jnp.zeros(shape, jnp.int32))  # noqa: E731
+    return jax.jit(
+        M.hybrid_prefill_chunk, static_argnums=(1, 7), donate_argnums=(2,)
+    ).lower(params, cfg, state, i32(1, 512), i32(), i32(), i32(), window,
+            i32(window // 16)).compile(), cfg.n_attn_layers, 8 * 64
+
+
+def _swa_chunk(v5e, window):
+    M, cfg, params, state, on_chip = _swa_shapes(v5e)
+    i32 = lambda *shape: on_chip(jnp.zeros(shape, jnp.int32))  # noqa: E731
+    return jax.jit(
+        M.swa_prefill_chunk, static_argnums=(1, 7), donate_argnums=(2,)
+    ).lower(params, cfg, state, i32(1, 512), i32(), i32(), i32(), window,
+            i32(window // 64)).compile(), cfg.layer_types.count("full"), 4 * 192
+
+
+def _blockdiff_chunk(v5e, window):
+    from vtpu.models import slots as slot_steps
+    from vtpu.models.latent import LayerOfStack
+    from vtpu.models.moe import held_moe_ffn
+
+    M, cfg, params, state, on_chip = _blockdiff_shapes(v5e)
+    i32 = lambda *shape: on_chip(jnp.zeros(shape, jnp.int32))  # noqa: E731
+
+    def chunk(params, state, tokens, slot, offset, new_len, block_ids):
+        return slot_steps.chunked_prefill_into_slot(
+            params, cfg, state, tokens, slot, offset, new_len,
+            kv_bucket=window, unroll=True, ffn_fn=held_moe_ffn(cfg),
+            block_ids=block_ids, layer_of=LayerOfStack)
+
+    return jax.jit(chunk, donate_argnums=(1,)).lower(
+        params, state, i32(1, 512), i32(), i32(), i32(),
+        i32(window // 16)).compile(), SDAR_LAYERS, 4 * 128
+
+
+# the four wired configurations' chunk programs at a read window of their
+# cell's (the dense one's 4096 is also its hidden width: a float32 ``[1, 512,
+# 4096]`` of the norms is a quarter of the line the scores are held to)
+CHUNK_PROGRAMS = {
+    "dsllm7b_longprompt": (_dense_chunk, 4096),
+    "granite4h_sessions": (_hybrid_chunk, 16384),
+    "mimo_mixedqueue": (_swa_chunk, 24576),
+    "sdar_blockgen": (_blockdiff_chunk, 4096),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CHUNK_PROGRAMS))
+def test_chunk_programs_attend_their_window_in_the_chunk_kernel(
+        v5e, monkeypatch, cell):
+    """The chunk program of each wired configuration, compiled for a v5e at
+    its cell's widths: one ``chunk_attn`` kernel an attention layer, under
+    the scope the trace's reader looks for; no float32 tensor of the
+    chunk's scores (``[heads, T x G, window]``, nor four heads' worth of
+    it: XLA's form wrote and read it three times a layer); and the
+    gathered window goes into the kernel as it lies: nothing copies or
+    transposes an array of a layer's window."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    build, window = CHUNK_PROGRAMS[cell]
+    compiled, layers, row_width = build(v5e, window)
+    text = compiled.as_text()
+    assert len(_CHUNK_KERNEL.findall(text)) == layers
+    for dims in re.findall(r" = f32\[([0-9,]+)\]", text):
+        dims = [int(d) for d in dims.split(",")]
+        assert not (dims[-1] == window
+                    and math.prod(dims) >= 4 * 512 * window), dims
+    # an array of a layer's window that is copied or transposed is the
+    # gather's own or the write-back's (slots._chunk_window and
+    # _chunk_write_back, swa's window_rows: PERF.md section 7), as in the
+    # parent; what the kernel's call prepares is the queries' fold and back,
+    # arrays of the chunk's 512 rows (the parent also copied the window a
+    # layer under ``attn``: the einsums' transposes)
+    for line in text.splitlines():
+        moved = re.search(r" = bf16\[([0-9,]+)\]\S* (?:copy|transpose)\(", line)
+        if not moved:
+            continue
+        dims = [int(d) for d in moved.group(1).split(",")]
+        if math.prod(dims) < window * row_width or 512 in dims:
+            continue
+        assert re.search(r'op_name="[^"]*/(gather_attn/gather|kv_write/)',
+                         line), line[:300]
+    # nor is a layer's window sliced out of the stacked view for the
+    # kernel's operand: the kernel takes the stack and the layer's index
+    for line in text.splitlines():
+        cut = re.search(r" = bf16\[([0-9,]+)\]\S* slice\(", line)
+        if cut and math.prod(int(d) for d in cut.group(1).split(",")) \
+                >= window * row_width:
+            assert not re.search(
+                r'op_name="[^"]*/(attn|gather_attn)/(chunk_attn/)?slice"',
+                line), line[:300]

@@ -152,8 +152,9 @@ CASES = [
     ("dense", "kernel", "admit", TRUNK | BLOCK["dense"] | {"attn", "sample"}),
     ("moe", "kernel", "admit", TRUNK | BLOCK["moe"] | {"attn", "sample"}),
     ("dense", "kernel", "chunk", TRUNK | BLOCK["dense"]
-     | {"gather_attn", "attn"}),
-    ("moe", "kernel", "chunk", TRUNK | BLOCK["moe"] | {"gather_attn", "attn"}),
+     | {"gather_attn", "attn", "chunk_attn"}),
+    ("moe", "kernel", "chunk", TRUNK | BLOCK["moe"]
+     | {"gather_attn", "attn", "chunk_attn"}),
     ("latent", "paged", "decode", TRUNK | SPARSE_ATTN | {"sample"}),
     ("latent", "paged", "admit", TRUNK | SPARSE_ATTN | {"sample"}),
     ("latent", "paged", "chunk", TRUNK | SPARSE_ATTN),
@@ -164,19 +165,22 @@ CASES = [
      | {"sample"}),
     ("hybrid", "gather", "decode", TRUNK | SSM | ROUTE["gather"]
      | {"sample"}),
-    ("hybrid", "kernel", "admit", TRUNK | SSM | {"sample"}),
-    ("hybrid", "kernel", "chunk", TRUNK | SSM | {"gather_attn"}),
+    ("hybrid", "kernel", "admit", TRUNK | SSM | {"chunk_attn", "sample"}),
+    ("hybrid", "kernel", "chunk", TRUNK | SSM
+     | {"gather_attn", "chunk_attn"}),
     ("swa", "kernel", "decode", TRUNK | WINDOW | ROUTE["kernel"]
      | {"sample"}),
     ("swa", "gather", "decode", TRUNK | WINDOW | ROUTE["gather"]
      | {"sample"}),
-    ("swa", "kernel", "admit", TRUNK | WINDOW | {"gather_attn", "sample"}),
-    ("swa", "kernel", "chunk", TRUNK | WINDOW | {"gather_attn"}),
+    ("swa", "kernel", "admit", TRUNK | WINDOW
+     | {"gather_attn", "chunk_attn", "sample"}),
+    ("swa", "kernel", "chunk", TRUNK | WINDOW
+     | {"gather_attn", "chunk_attn"}),
     ("blockdiff", "kernel", "pass", TRUNK | BLOCK_PASS
      | {"pool_relayout", "paged_attn"}),
     ("blockdiff", "gather", "pass", TRUNK | BLOCK_PASS),
     ("blockdiff", "kernel", "chunk", TRUNK | BLOCK["moe"]
-     | {"gather_attn", "attn"}),
+     | {"gather_attn", "attn", "chunk_attn"}),
 ]
 
 

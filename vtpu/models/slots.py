@@ -297,10 +297,19 @@ def chunked_prefill_into_slot(
     the per-chunk pool traffic stays chip-local exactly like decode's.
 
     The paged decode KERNEL route deliberately does not apply here: a chunk
-    needs the materialized dense window regardless (the whole window
-    scatters back to the pool after the trunk), so gathering it first costs
-    nothing extra — the kernel's payoff is exclusive to the decode/verify
-    ticks, where the gather was pure read-side overhead.
+    gathers its window into a dense view, writes its own rows into it and
+    scatters the written pages back, so the table-walker (one query row a
+    slot over pages in place) has nothing to walk. The chunk's attention
+    over that view has a kernel of its own since PR 45:
+    ``transformer.chunk_window_attention`` asks ``ops.chunk_attn.takes``
+    and, on a TPU with bfloat16 planes, 128 query rows or more a key/value
+    head and a read window of 2048 positions or more, runs
+    ``chunk_attn.chunk_attention`` over the stacked view as it lies (the
+    layer picked by the kernel's index maps, a block's scores kept in VMEM,
+    the key blocks past the chunk's end neither copied nor multiplied);
+    elsewhere, and for int8 planes or a
+    head-sharded pool, ``causal_attention``'s ragged form as before. The
+    gather and the write-back are as they were (PERF.md section 7).
 
     A family whose blocks of positions see each other both ways
     (``cfg.attn_block``) takes the same path: the chunk's rows are in the

@@ -42,7 +42,7 @@ import jax.numpy as jnp
 from vtpu.models.latent import LayerOfStack, _rope_head, _swiglu
 from vtpu.models.moe import grouped_route, held_experts_ffn
 from vtpu.models.transformer import _held_projection
-from vtpu.ops import rms_norm, rope_angles, scaled_normal
+from vtpu.ops import chunk_attn, rms_norm, rope_angles, scaled_normal
 from vtpu.ops.decode_attn import paged_attn_route, wide_decode_attention
 from vtpu.ops.latent import window_rows, write_rows
 from vtpu.ops.window_attn import (
@@ -248,6 +248,26 @@ def _flat(x: jax.Array) -> jax.Array:
     return x.reshape(x.shape[:2] + (-1,))
 
 
+def _gathered_attention(cfg: SwaConfig, q, keys, values, positions):
+    """A full layer's attention over its gathered window, rows of heads
+    side by side ``[N, W, Hk * D]``: a decode step's single query in
+    ``full_attention``; a chunk's or a prompt's queries in the chunk
+    kernel over the rows as they lie where ``chunk_attn.takes`` the
+    shapes, else in ``full_attention`` too (``chunk_attn.attend_window``)."""
+    n, hk = q.shape[0], cfg.n_kv_heads
+
+    def in_xla():
+        return full_attention(
+            q, keys.reshape(n, -1, hk, cfg.head_dim),
+            values.reshape(n, -1, hk, cfg.v_head_dim), positions,
+            cfg.attn_scale)
+
+    if q.shape[1] == 1:
+        return in_xla()
+    return chunk_attn.attend_window(
+        q, keys, values, positions + 1, cfg.attn_scale, in_xla)
+
+
 def _full_layer(cfg: SwaConfig, lp, l: int, x, pool, rope, positions, at,
                 route):
     """A full layer's attention half over x [N, T, D]: the token's key and
@@ -273,13 +293,9 @@ def _full_layer(cfg: SwaConfig, lp, l: int, x, pool, rope, positions, at,
             attn = own_values(mixed, hk)[:, None]
     else:
         with jax.named_scope("gather_attn"):
-            n_ = x.shape[0]
             keys = window_rows(pool["k"], l, at["tables"])
             values = window_rows(pool["v"], l, at["tables"])
-            attn = full_attention(
-                q, keys.reshape(n_, -1, hk, cfg.head_dim),
-                values.reshape(n_, -1, hk, cfg.v_head_dim), positions,
-                cfg.attn_scale)
+            attn = _gathered_attention(cfg, q, keys, values, positions)
     return attn, pool
 
 

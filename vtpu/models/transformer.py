@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Optional
 
 import jax
@@ -23,6 +24,7 @@ from vtpu.ops import (
     causal_attention_int8kv, flash_attention, paged_causal_attention,
     paged_causal_attention_int8kv,
 )
+from vtpu.ops import chunk_attn
 from vtpu.ops.attention import FLASH_MIN_SEQ
 from vtpu.ops.decode_attn import (
     paged_attn_route, paged_decode_attention, paged_decode_attention_int8kv,
@@ -958,11 +960,31 @@ def cached_attention(cfg, cache, t: int, kv_bucket: int, write_kv,
                 q, view["k"][:, :bucket], view["k_scale"][:, :bucket],
                 view["v"][:, :bucket], view["v_scale"][:, :bucket],
                 kv_len=ragged_len)
-        return causal_attention(
-            q, view["k"][:, :bucket], view["v"][:, :bucket],
-            kv_len=ragged_len, scale=scale)
+        k, v = view["k"][:, :bucket], view["v"][:, :bucket]
+        if t == 1:  # a decode step over a dense cache
+            return causal_attention(q, k, v, kv_len=ragged_len, scale=scale)
+        return chunk_window_attention(
+            q, k, v, ragged_len, scale, mesh, stack=(kv["k"], kv["v"], l))
 
     return attend
+
+
+def chunk_window_attention(q, k, v, reach, scale, mesh=None, stack=None):
+    """The T queries of a chunk over the dense window they share, query i
+    reading its first ``reach[b, i]`` rows: in the chunk kernel where
+    ``chunk_attn.takes`` the shapes (a TPU, bfloat16, rows enough a
+    key/value head), else ``causal_attention``'s ragged form, which is the
+    same attention as XLA code (``chunk_attn.attend_window``: either runs
+    under the scope ``chunk_attn``). ``stack`` ``(keys, values, layer)``:
+    the layers' stacked planes ``[L, B, S, ...]`` that ``k`` and ``v`` are
+    layer ``layer``'s first positions of; the kernel reads them where they
+    lie, so no layer is sliced out for its operand."""
+    keys, values, layer = stack or (k, v, None)
+    return chunk_attn.attend_window(
+        q, keys, values, reach,
+        1.0 / math.sqrt(q.shape[-1]) if scale is None else scale,
+        lambda: causal_attention(q, k, v, kv_len=reach, scale=scale), mesh,
+        layer=layer, window=k.shape[1] if stack else None)
 
 
 def greedy_generate(

@@ -16,7 +16,7 @@ SCOPES = (
     "embed", "qkv", "kv_write", "pool_relayout", "paged_attn", "gather_attn",
     "attn", "o_proj", "mlp", "route", "experts", "lm_head", "sample",
     "indexer", "select", "latent_attn", "ssm_conv", "ssm_scan", "ssm_gate",
-    "window_attn", "ring_write", "block_attn",
+    "window_attn", "ring_write", "block_attn", "chunk_attn",
 )
 
 from vtpu.ops.init import scaled_normal  # noqa: E402

@@ -65,11 +65,13 @@ from vtpu.models.transformer import (
     init_kv_cache,
     init_paged_kv_cache,
     kv_bytes_per_token,
+    kv_plane_shape,
     kv_quantized,
     multi_tick_decode,
     multi_tick_spec_decode,
     prefill,
 )
+from vtpu.ops import chunk_attn
 from vtpu.ops.decode_attn import PAGED_ATTN_ROUTES
 from vtpu.ops.latent import chunk_keys_attended, expands_window
 from vtpu.parallel.sharding import (
@@ -276,7 +278,44 @@ def swap_page_scatter(model: Any):
     return scatter
 
 
-class _CachedAttentionSlotModel:
+class _CountsHeadChunks:
+    """What the engine asks of a family that caches heads to count its
+    prefill chunks by the form of their attention (``stats()``'s five
+    ``chunk_*`` counters): its window holds keys and values as they are
+    read, so no chunk expands one, and whether a chunk's program holds the
+    chunk kernel is the rule the traced program applies to the same shapes
+    (``vtpu.ops.chunk_attn.takes``). A family whose chunk hands the
+    attention other shapes than ``cfg``'s stacked heads says so
+    (``_chunk_attn_shapes``)."""
+
+    def chunk_attn_expands(self, queries: int) -> bool:
+        return False
+
+    def chunk_keys_attended(self, queries: int, end: int,
+                            window: int) -> tuple[bool, int]:
+        """(whether the program of a chunk of ``queries`` tokens over a
+        read window of ``window`` holds the chunk kernel, the window
+        positions it multiplies for a chunk whose last position is
+        ``end - 1``)."""
+        return chunk_attn.chunk_keys_attended(
+            *self._chunk_attn_shapes(queries, window), end, self.mesh)
+
+    def _chunk_attn_shapes(self, queries: int, window: int):
+        return _stacked_chunk_shapes(self.cfg, queries, window)
+
+
+def _stacked_chunk_shapes(cfg, queries: int, window: int):
+    """(q, keys, values) of a chunk of ``queries`` tokens over a window of
+    ``window`` as ``transformer.cached_attention`` hands them to the
+    attention, for a cache of ``cfg``'s heads (``kv_plane_shape``)."""
+    dtype = jnp.int8 if kv_quantized(cfg) else cfg.dtype
+    plane = jax.ShapeDtypeStruct(
+        (1, window) + kv_plane_shape(cfg), dtype)
+    return (jax.ShapeDtypeStruct(
+        (1, queries, cfg.n_heads, cfg.head_dim), cfg.dtype), plane, plane)
+
+
+class _CachedAttentionSlotModel(_CountsHeadChunks):
     """The slot model of a family that attends over a per-slot KV cache
     (vtpu/models/slots): the state's allocation (dense rows or a paged
     block pool, on one chip or head-sharded over a ('tp',) mesh) and the
@@ -698,7 +737,7 @@ class LatentSlotModel:
             block_ids)
 
 
-class HybridSlotModel:
+class HybridSlotModel(_CountsHeadChunks):
     """Mamba-2 layers among grouped-query attention layers
     (vtpu/models/hybrid): a session's state is of two kinds, pages of the
     paged pool for the attention layers and a slot-indexed row of
@@ -792,6 +831,9 @@ class HybridSlotModel:
     def recurrent_state_bytes(self, slots: int) -> int:
         return slots * self.cfg.recurrent_bytes_per_slot
 
+    def _chunk_attn_shapes(self, queries: int, window: int):
+        return _stacked_chunk_shapes(self.cfg.attention, queries, window)
+
     def ssm_step_in_kernel(self) -> bool:
         """Whether a decode step traced now updates the recurrent state in
         the Pallas kernel: the question the trace itself asks."""
@@ -831,7 +873,7 @@ class HybridSlotModel:
             block_ids)
 
 
-class WindowSlotModel:
+class WindowSlotModel(_CountsHeadChunks):
     """Window layers among full layers (vtpu/models/swa): a session's cache
     is of two kinds, pages of the paged pool for the full layers and a
     slot-indexed ring of ``window`` rows for the window layers, in one
@@ -921,6 +963,17 @@ class WindowSlotModel:
 
     experts_grouped = _experts_grouped
 
+    def _chunk_attn_shapes(self, queries: int, window: int):
+        """A full layer's chunk: rows of key/value heads side by side."""
+        cfg = self.cfg
+
+        def of(*shape):
+            return jax.ShapeDtypeStruct(shape, cfg.dtype)
+
+        return (of(1, queries, cfg.n_heads, cfg.head_dim),
+                of(1, window, cfg.n_kv_heads * cfg.head_dim),
+                of(1, window, cfg.n_kv_heads * cfg.v_head_dim))
+
     def init_state(self, slots: int):
         self.n_kv_blocks = _pool_blocks(self, slots)
         return swa.init_swa_state(
@@ -955,7 +1008,7 @@ class WindowSlotModel:
             block_ids)
 
 
-class BlockDiffSlotModel:
+class BlockDiffSlotModel(_CountsHeadChunks):
     """Generation by diffusion over blocks (vtpu/models/blockdiff.py): the
     expert decoder on the shared trunk, its cache the paged pool walked by
     the one page table, so the engine's allocator, chunked admission and
