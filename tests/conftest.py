@@ -23,8 +23,8 @@ jax.config.update("jax_platforms", "cpu")
 # the difference between fitting that budget and flaking on box weather.
 # Keyed by exact HLO + flags, so nothing about what is tested changes.
 # The directory follows vtpu.util.jaxcache's rule (an ambient
-# JAX_COMPILATION_CACHE_DIR wins, else <checkout>/.jax_cache);
-# test_bench_smoke threads the same dir into its bench subprocesses.
+# JAX_COMPILATION_CACHE_DIR wins, else <checkout>/.jax_cache); a test
+# that spawns an engine host (test_crosshost) hands its child the same.
 from vtpu.util.jaxcache import place_compile_cache  # noqa: E402
 
 place_compile_cache()

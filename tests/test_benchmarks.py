@@ -81,14 +81,3 @@ def test_deployment_manifests_parse():
         docs = list(yaml.safe_load_all((ROOT / "benchmarks" / "deployments" / name).read_text()))
         assert docs and all(d.get("kind") for d in docs)
 
-
-def test_mfu_bench_cpu_smoke():
-    """MFU harness runs end to end on the CPU with --cpu (a smoke of the
-    harness: no peak, no utilization, nothing written)."""
-    r = subprocess.run(
-        [sys.executable, str(ROOT / "benchmarks" / "mfu_bench.py"), "--cpu"],
-        capture_output=True, text=True, timeout=300,
-    )
-    assert r.returncode == 0, r.stderr
-    assert "prefill" in r.stdout and "attention" in r.stdout
-    assert "decode" in r.stdout

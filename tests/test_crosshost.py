@@ -215,7 +215,8 @@ def test_partition_suspect_heal_no_failover(params, refs, remote_member):
     failovers — and a mid-stream partition is survived token-exact: the
     host keeps generating into its outbox, the client detects the seq
     gap on heal and the resend replays it, duplicates dropped by seq."""
-    t = remote_member()
+    plan = FaultPlan()
+    t = remote_member(eng_faults=plan)
     fleet, _ = _member_fleet(params, t, FC_HEAL)
     fleet.start()
     try:
@@ -234,7 +235,11 @@ def test_partition_suspect_heal_no_failover(params, refs, remote_member):
 
         # mid-stream partition: wait until the HOST has demonstrably
         # produced tokens into the blackout (their sends were dropped),
-        # so the heal MUST exercise the gap-detect + resend path
+        # so the heal MUST exercise the gap-detect + resend path. The
+        # host is throttled (~10 ms a token): unthrottled, on a loaded
+        # machine, it ends the whole stream inside the partition, and a
+        # heal that finds no live stream has no gap to detect
+        plan.arm("delayed_fetch", count=100000, arg=0.01)
         req = fleet.submit(P2, max_new_tokens=STEPS)
         it = iter(req.stream())
         got = [next(it)]
@@ -340,6 +345,51 @@ def test_dead_engine_behind_live_link_fails_over(params, refs,
         assert j["hops"][0]["host"] == "h0"
         assert j["hops"][1]["host"] == "local"
         assert j["conserved"] is True
+    finally:
+        fleet.stop()
+
+
+def test_dead_local_engine_fails_over_onto_a_remote_survivor(
+        params, refs, remote_member):
+    """The rebuild crosses the wire the other way: the engine that dies is
+    local and the only survivor is remote, so the session's history goes
+    out as a ``migrate_in`` ask and its stream comes back through the
+    proxy — token-equal, with the failover hop tagged by the survivor's
+    host and the rebuild counted on the engine behind the link."""
+    plan = FaultPlan()
+    # throttled (~10 ms a token), as every kill here: the death has to
+    # land while the stream runs
+    plan.arm("delayed_fetch", count=100000, arg=0.01)
+    t = remote_member(host="h1", name="r1")
+    engines = {"a": ServingEngine(params, CFG, ServingConfig(
+                   **BASE, faults=plan)),
+               "r1": t.rem}
+    fleet = EngineFleet(engines, FleetConfig(
+        **FC_KILL, route_policy=PinPolicy("a")))
+    fleet.start()
+    try:
+        _wait(lambda: t.rem._beat_ns != 0, 60, "remote warm-up beat")
+        req = fleet.submit(P1, max_new_tokens=STEPS)
+        it = iter(req.stream())
+        got = [next(it), next(it)]
+        plan.arm("engine_death")
+        got += list(it)
+        assert got == refs[0]
+        assert req.status == Status.OK
+        _wait(lambda: fleet.stats(
+            include_engines=False)["journeys_ended"] >= 1, 10,
+            "journey close")
+        st = fleet.stats(include_engines=False)
+        assert st["failovers"] == 1 and st["failover_sessions"] == 1
+        assert st["failover_faulted"] == 0
+        assert st["engine_states"]["a"] == "DEAD"
+        j = fleet.trace.journeys()[req.jid]
+        assert [h["kind"] for h in j["hops"]] == ["route", "failover"]
+        assert [h["host"] for h in j["hops"]] == ["local", "h1"]
+        assert j["conserved"] is True
+        assert t.eng.stats()["migrations_in"] == 1
+        _wait(lambda: t.eng.stats()["active_slots"] == 0, 15,
+              "host-side slot reclaimed")
     finally:
         fleet.stop()
 
@@ -547,13 +597,21 @@ def test_tcp_sigkill_child_failover_token_equal(params, refs, monkeypatch):
             include_engines=False)["journeys_ended"] >= 1, 10,
             "journey close")
         st = fleet.stats(include_engines=False)
-        assert st["failovers"] == 1
+        assert st["failovers"] == 1 and st["failover_sessions"] == 1
+        assert st["failover_faulted"] == 0
+        assert st["engine_states"]["r0"] == "DEAD"
         assert st["failover_blackout_p99_ms"] is not None
+        # the fabric's counters account for the traffic: messages both
+        # ways, and more bytes in than out, since the tokens flow back
+        assert st["remote_engines"] == 1
+        assert st["fabric_msgs_sent"] > 0 and st["fabric_msgs_recv"] > 0
+        assert st["fabric_bytes_recv"] > st["fabric_bytes_sent"] > 0
         # journey host tags survive the hop across processes
         j = fleet.trace.journeys()[req.jid]
         assert [h["kind"] for h in j["hops"]] == ["route", "failover"]
         assert j["hops"][0]["host"] == "h0"
         assert j["hops"][1]["host"] == "local"
+        assert j["conserved"] is True
         # survivors hold nothing (leak_check re-audits at teardown)
         for n in ("e1", "e2"):
             assert fleet.engines[n].stats()["active_slots"] == 0

@@ -1,6 +1,6 @@
 """Hermeticity lock for the driver's multi-chip dryrun.
 
-MULTICHIP_r01 failed because eager ops inside ``dryrun_multichip`` dispatched
+Round 1's run failed because eager ops inside ``dryrun_multichip`` dispatched
 to the ambient default platform — a wedged TPU client in the driver env whose
 first executed op raised. The fix pins ``jax_default_device`` to the resolved
 dryrun mesh for the whole body. These tests lock the property in: the second
@@ -31,7 +31,7 @@ def test_dryrun_multichip_cpu_mesh():
 
 
 def test_dryrun_hermetic_to_wedged_default_platform(monkeypatch):
-    """Simulate the MULTICHIP_r01 driver env: any eager primitive that runs
+    """Simulate round 1's driver env: any eager primitive that runs
     while jax_default_device is unpinned explodes (as the wedged TPU client
     did). The dryrun must pin every eager op to its own mesh and pass."""
     from jax._src import core as jcore

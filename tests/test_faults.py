@@ -11,6 +11,7 @@ ends with, and — via the conftest ``leak_check`` fixture riding every
 engine-constructing test — that nothing the failure touched leaked.
 """
 
+import dataclasses
 import queue as _queue
 import time
 
@@ -611,11 +612,33 @@ def test_watchdog_recovers_device_loop_after_grace_window(params):
     assert "loop_k1" in eng._degrade_rungs     # re-armed for a relapse
 
 
-def test_watchdog_recovery_restores_paged_attn_route(params):
+def _drive_watchdog_clock(monkeypatch, plan, stall_s=0.05, step_s=0.0005):
+    """Put the fetch watchdog on a clock the test drives: every reading is
+    ``step_s`` after the last, and the first one after ``plan`` fired its
+    delayed_fetch is ``stall_s`` later still. What the watchdog then sees
+    is the plan's stall and nothing else: not how long this machine takes
+    over an interpreted kernel's tick."""
+    from vtpu.serving import engine as engine_mod
+
+    now, seen = 0.0, 0
+
+    def clock():
+        nonlocal now, seen
+        fired = plan.snapshot()["injected"]["delayed_fetch"]
+        now += step_s + stall_s * (fired - seen)
+        seen = fired
+        return now
+
+    monkeypatch.setattr(engine_mod, "_watchdog_clock", clock)
+
+
+def test_watchdog_recovery_restores_paged_attn_route(params, monkeypatch):
     """The rung-2 recovery: a forced-kernel paged engine degraded to the
     gather route re-lowers BACK to the kernel once latency recovers —
     kernel ticks resume after the recovery, streams token-equal across
-    both re-lowers."""
+    both re-lowers. The watchdog reads a driven clock: back on the kernel
+    route a tick is interpreted here, and by the machine's own clock one
+    of 11 ms trips a watchdog of 10 a second time."""
     prompts = [_prompt(87, 5), _prompt(88, 5)]
     serving_kw = dict(kv_page=8, max_new_tokens=12)
     ref_eng = ServingEngine(params, CFG, _serving(
@@ -626,7 +649,8 @@ def test_watchdog_recovery_restores_paged_attn_route(params):
                for p in prompts]
     finally:
         ref_eng.stop()
-    plan = FaultPlan([FaultSpec("delayed_fetch", at=1, arg=0.05)])
+    plan = FaultPlan([FaultSpec("delayed_fetch", at=1, arg=0.001)])
+    _drive_watchdog_clock(monkeypatch, plan)
     eng = ServingEngine(params, CFG, _serving(
         paged_attn="kernel", fetch_watchdog_ms=10.0,
         fetch_watchdog_recover_ms=1.0, faults=plan, **serving_kw))
@@ -770,6 +794,350 @@ def test_legacy_two_arg_shed_policy_still_works(params):
         eng.stop()
     assert drop.status == Status.SHED_OVERLOAD
     assert keep.status == Status.CANCELLED  # survived to the stop
+
+
+# ---------------------------------- a schedule of faults in one engine life
+#
+# Every test above pairs one seam with its recovery. These run a whole
+# schedule, several seams over traffic that parks, evicts, sheds, hands
+# off, migrates and fails over within one life of the engines, and hold
+# the run to what must be true after any of it: every request ends typed,
+# a stream no fault touched says what its fault-free run said, the
+# allocator, the host tier and the slots are back where they began, every
+# seam the schedule configured fired, and no recovery added a fetch.
+
+SOAK_NEW = 24   # a budget a park or a kill lands inside (8 + 24 < max_seq)
+SOAK_PAGE = 8
+# ~5 ms a tick: the engine decodes whether or not the client reads, and a
+# stream of 24 unthrottled ticks can end between a test's two head reads
+# and its park, leaving nothing to evict and a seam that never fires
+THROTTLE = FaultSpec("delayed_fetch", at=0, count=100000, arg=0.005)
+
+
+def _take(req, n):
+    """Up to n tokens off the raw queue, fewer where a fault or a shed
+    ended the stream first."""
+    got = []
+    while len(got) < n:
+        item = req.out.get(timeout=120)
+        if item is None or isinstance(item, Terminal):
+            break
+        got.append(item)
+    return got
+
+
+def _drain_typed(req, timeout=120.0):
+    """The rest of the stream. By status and not by stream(): _take may
+    have consumed the one Terminal already, and a second blocking get
+    would wait for ever."""
+    got = []
+    t0 = time.perf_counter()
+    while req.status is None:
+        try:
+            item = req.out.get(timeout=0.05)
+        except _queue.Empty:
+            # a leak that starves admission shows here, as a failure
+            assert time.perf_counter() - t0 < timeout, "stream never ended"
+            continue
+        if item is None or isinstance(item, Terminal):
+            break
+        got.append(item)
+    while True:  # tokens precede finish(): nothing arrives after this
+        try:
+            item = req.out.get_nowait()
+        except _queue.Empty:
+            return got
+        if item is not None and not isinstance(item, Terminal):
+            got.append(item)
+
+
+def _settled(eng, timeout=60.0):
+    """stats() once nothing is active, parked, queued or admitting."""
+    t0 = time.perf_counter()
+    while True:
+        s = eng.stats()
+        if (s["active_slots"] == 0 and s["parked_sessions"] == 0
+                and s["queued"] == 0 and s["admitting_slots"] == 0):
+            return s
+        assert time.perf_counter() - t0 < timeout, "engine never settled"
+        time.sleep(0.01)
+
+
+def _wait_stat(eng, key, want, timeout=60.0):
+    t0 = time.perf_counter()
+    while eng.stats()[key] < want:
+        assert time.perf_counter() - t0 < timeout, f"{key} never reached {want}"
+        time.sleep(0.002)
+
+
+def _serve(params, serving, prompts):
+    """One engine's life over ``prompts``, each to its typed end:
+    (requests, streams, the settled stats)."""
+    eng = ServingEngine(params, CFG, serving)
+    eng.start()
+    try:
+        reqs = [eng.submit(p, max_new_tokens=SOAK_NEW) for p in prompts]
+        streams = [_drain_typed(r) for r in reqs]
+        return reqs, streams, _settled(eng)
+    finally:
+        eng.stop()
+
+
+def _soak_core(params):
+    """A paged int8 pool with a host tier half the size of what parks:
+    deadlines, an overload burst, parks, evictions whose spill is lost,
+    a blocked reservation and a fault in one request's delivery."""
+    cfg = dataclasses.replace(CFG, kv_int8=True)
+    waves, prompt_len = 2, 8
+    pages_per = -(-(prompt_len + SOAK_NEW) // SOAK_PAGE)
+
+    def serving(faults, shed):
+        return _serving(
+            slots=waves, max_new_tokens=SOAK_NEW, prefill_chunk=16,
+            kv_page=SOAK_PAGE, kv_pool_blocks=waves * pages_per + 1,
+            kv_swap=max(waves * pages_per // 2, 1),
+            shed_queue_depth=2 if shed else 0, faults=faults)
+
+    def traffic(eng, chaos):
+        """The same submits in the same order in both arms; the chaos arm
+        has two requests past their deadline before them."""
+        reqs, streams = [], []
+
+        def submit(seed, **kw):
+            reqs.append(eng.submit(_prompt(seed, prompt_len),
+                                   max_new_tokens=SOAK_NEW, **kw))
+            streams.append([])
+            return len(reqs) - 1
+
+        late = [submit(500 + j, deadline_ms=0) for j in range(2 * chaos)]
+        wave1 = [submit(100 + j, priority=5) for j in range(waves)]
+        for i in wave1:
+            streams[i] += _take(reqs[i], 2)
+        # the burst goes in while every slot is busy: with a bounded line
+        # the lowest priorities are shed at the next tick head, before
+        # the parks below free a slot
+        for j in range(2 + waves):
+            submit(600 + j, priority=0)
+        if chaos:
+            _wait_stat(eng, "shed_overload", 1)
+        live = [i for i in wave1 if reqs[i].status is None]
+        for i in live:
+            eng.park(reqs[i])
+        _wait_stat(eng, "parked_sessions", len(live))
+        # a second wave and what is left of the burst press the parked
+        # pages out: spilled, or dropped where the spill is lost
+        for j in range(waves):
+            submit(200 + j, priority=5)
+        for i in live:
+            eng.resume(reqs[i])
+        for i, req in enumerate(reqs):
+            streams[i] += _drain_typed(req)
+        return reqs, streams, late
+
+    ref_eng = ServingEngine(params, cfg, serving(FaultPlan([THROTTLE]), False))
+    ref_eng.start()
+    try:
+        _, want, _ = traffic(ref_eng, chaos=0)
+    finally:
+        ref_eng.stop()
+    # the seams the run is held to are pinned to arrivals that exist at
+    # any load; the seeded part lays reproducible chaos over them, and
+    # whatever it hits has to pass the same checks
+    plan = FaultPlan(
+        [THROTTLE,
+         FaultSpec("alloc_exhaust", at=0),   # the first reservation blocks
+         FaultSpec("swap_d2h_loss", at=0),   # the first eviction's spill
+         FaultSpec("dispatch_exc", at=9)]    # one delivery in mid-wave
+        + list(FaultPlan.seeded(0, rates={
+            "alloc_exhaust": 0.05, "swap_d2h_loss": 0.3,
+            "swap_h2d_loss": 0.5}).specs))
+    eng = ServingEngine(params, cfg, serving(plan, True))
+    eng.start()
+    try:
+        reqs, streams, late = traffic(eng, chaos=1)
+        settled = _settled(eng)
+    finally:
+        eng.stop()
+    assert all(reqs[i].status == Status.SHED_DEADLINE for i in late)
+    assert settled["shed_overload"] >= 1
+    assert settled["fault_recomputes"] >= 1
+    return dict(
+        reqs=reqs, streams=streams, want=[None] * len(late) + want,
+        settled={"engine": settled}, plans=[plan],
+        seams=("alloc_exhaust", "swap_d2h_loss", "dispatch_exc"),
+        gets_per_tick={"engine": (1.0,)})
+
+
+def _soak_disagg(params):
+    """The one prefill worker dies with a request claimed."""
+    n = 2
+    prompts = [_prompt(300 + j, 8) for j in range(n)]
+
+    def run(faults):
+        return _serve(params, _disagg_serving(
+            max_new_tokens=SOAK_NEW, faults=faults), prompts)
+
+    _, want, _ = run(None)
+    plan = FaultPlan([FaultSpec("worker_death", at=0)])
+    reqs, streams, settled = run(plan)
+    assert all(r.status == Status.OK for r in reqs)
+    assert settled["worker_restarts"] == 1
+    assert settled["faulted_requests"] == 0
+    assert settled["handoffs"] == n and settled["handoff_copies"] == 0
+    return dict(reqs=reqs, streams=streams, want=want,
+                settled={"engine": settled}, plans=[plan],
+                seams=("worker_death",), gets_per_tick={"engine": (1.0,)})
+
+
+def _soak_device_loop(params):
+    """Under the two-tick device loop a fetch stalls past the watchdog
+    and, flushes later, one request's delivery faults."""
+    k, n = 2, 2
+    prompts = [_prompt(400 + j, 8) for j in range(n)]
+
+    def run(faults, wd):
+        return _serve(params, _serving(
+            max_new_tokens=SOAK_NEW, decode_loop_k=k, fetch_watchdog_ms=wd,
+            faults=faults), prompts)
+
+    _, want, _ = run(None, 0.0)
+    plan = FaultPlan([FaultSpec("delayed_fetch", at=2, arg=0.03),
+                      FaultSpec("dispatch_exc", at=5)])
+    reqs, streams, settled = run(plan, 8.0)
+    assert sorted(r.status for r in reqs) == sorted(
+        [Status.OK] * (n - 1) + [Status.FAULTED])
+    assert settled["watchdog_degrades"] >= 1
+    # decode_ticks counts inner ticks after the degrade clamps a flush
+    # too: the contract stays one fetch for k of them
+    return dict(reqs=reqs, streams=streams, want=want,
+                settled={"engine": settled}, plans=[plan],
+                seams=("delayed_fetch", "dispatch_exc"),
+                gets_per_tick={"engine": (round(1 / k, 4),)})
+
+
+def _soak_migrate(params):
+    """The first of three migrations loses its source after the metadata
+    handshake; the destination rebuilds that session from its history."""
+    from vtpu.serving import migrate
+
+    n = 3
+    prompts = [_prompt(700 + j, 8) for j in range(n)]
+
+    def serving(faults=None):
+        return _serving(slots=n, max_new_tokens=SOAK_NEW, prefill_chunk=16,
+                        kv_page=SOAK_PAGE, kv_swap=8, faults=faults)
+
+    _, want, _ = _serve(params, serving(), prompts)
+    plan = FaultPlan([THROTTLE, FaultSpec("migrate_src_death", at=0)])
+    src = ServingEngine(params, CFG, serving(plan))
+    dst = ServingEngine(params, CFG, serving())
+    src.start()
+    dst.start()
+    try:
+        reqs = [src.submit(p, max_new_tokens=SOAK_NEW) for p in prompts]
+        streams = [_take(r, 2) for r in reqs]
+        # parked first: a parked session cannot finish, so the order of
+        # extraction, and which session the seam hits, is the list's
+        for r in reqs:
+            src.park(r)
+        _wait_stat(src, "parked_sessions", n)
+        paths = [migrate(r, src, dst)["path"] for r in reqs]
+        for j, r in enumerate(reqs):
+            streams[j] += _drain_typed(r)
+        settled = {"src": _settled(src), "dst": _settled(dst)}
+    finally:
+        src.stop()
+        dst.stop()
+    assert all(r.status == Status.OK for r in reqs)
+    assert paths == ["recompute"] + ["resident"] * (n - 1)
+    assert settled["dst"]["migrate_recomputes"] >= 1
+    assert settled["src"]["migration_copies"] == 0
+    assert settled["dst"]["migration_copies"] == 0
+    return dict(reqs=reqs, streams=streams, want=want, settled=settled,
+                plans=[plan], seams=("migrate_src_death",),
+                gets_per_tick={"src": (None, 1.0), "dst": (1.0,)})
+
+
+def _soak_fleet(params):
+    """One engine of three dies with every session of the fleet on it."""
+    from vtpu.obs.fleettrace import validate_bundle
+    from vtpu.serving import EngineFleet, FleetConfig, RoutePolicy
+
+    class PinA(RoutePolicy):
+        def score(self, name, signals):
+            if signals.draining:
+                return None
+            return 1.0 if name == "a" else 0.0
+
+    n = 2
+    prompts = [_prompt(800 + j, 8) for j in range(n)]
+
+    def serving(faults=None):
+        return _serving(slots=n, max_new_tokens=SOAK_NEW, prefill_chunk=16,
+                        kv_page=SOAK_PAGE, kv_swap=8, faults=faults)
+
+    _, want, _ = _serve(params, serving(), prompts)
+    plan = FaultPlan([THROTTLE])
+    engines = {"a": ServingEngine(params, CFG, serving(plan)),
+               "b": ServingEngine(params, CFG, serving()),
+               "c": ServingEngine(params, CFG, serving())}
+    # a wide window for a miss: a live loop that a loaded machine starves
+    # for a second must not be declared dead
+    fleet = EngineFleet(engines, FleetConfig(
+        probe_interval_ms=5.0, miss_ms=2000.0, suspect_misses=2,
+        dead_misses=4, route_policy=PinA))
+    fleet.start()
+    try:
+        reqs = [fleet.submit(p, max_new_tokens=SOAK_NEW) for p in prompts]
+        streams = [_take(r, 2) for r in reqs]
+        plan.arm("engine_death")  # the next flush boundary kills 'a'
+        for j, r in enumerate(reqs):
+            streams[j] += _drain_typed(r)
+        fs = fleet.stats()
+        # the corpse too: the fleet's reap gave back what it held
+        settled = {name: _settled(eng) for name, eng in engines.items()}
+    finally:
+        fleet.stop()
+    assert all(r.status == Status.OK for r in reqs)
+    assert fs["failovers"] == 1 and fs["failover_sessions"] == n
+    assert fs["failover_faulted"] == 0
+    assert fs["engine_states"]["a"] == "DEAD"
+    journeys = fleet.trace.journeys()
+    assert all(journeys[r.jid]["conserved"] is True
+               and journeys[r.jid]["n_hops"] == 2 for r in reqs)
+    assert validate_bundle(fleet.trace.bundles().get("a"))
+    # the survivors only: the corpse died with a tick dispatched and never
+    # fetched, which is what a crash loses, so its own ratio reads short
+    return dict(reqs=reqs, streams=streams, want=want, settled=settled,
+                plans=[plan], seams=("engine_death",),
+                gets_per_tick={"b": (None, 1.0), "c": (None, 1.0)})
+
+
+@pytest.mark.parametrize("soak", [
+    _soak_core, _soak_disagg, _soak_device_loop, _soak_migrate, _soak_fleet,
+], ids=["core", "disagg", "device_loop", "migrate", "fleet"])
+def test_seeded_schedule_across_seams_leaves_nothing_behind(params, soak):
+    run = soak(params)
+    # every request ends with a type, never with a silent close
+    assert all(r.status in Status.ALL for r in run["reqs"])
+    # a fault changes when and who, never what an untouched stream says
+    untouched = [i for i, r in enumerate(run["reqs"])
+                 if r.status == Status.OK and run["want"][i] is not None]
+    assert untouched
+    for i in untouched:
+        assert run["streams"][i] == run["want"][i], f"stream {i} diverged"
+    # the allocator, the host tier and the slots are back where they began
+    for name, s in run["settled"].items():
+        assert s["kv_pool_free"] == s["kv_pool_blocks"], name
+        assert s["swap_host_free"] == s["swap_host_blocks"], name
+        assert s["active_slots"] == 0 and s["parked_sessions"] == 0, name
+    # every seam the schedule configured fired
+    for seam in run["seams"]:
+        assert sum(p.snapshot()["injected"].get(seam, 0)
+                   for p in run["plans"]) >= 1, seam
+    # and no recovery path added a fetch
+    for name, allowed in run["gets_per_tick"].items():
+        assert run["settled"][name]["device_gets_per_tick"] in allowed, name
 
 
 # ------------------------------------------------------- FaultPlan unit

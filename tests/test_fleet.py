@@ -23,6 +23,7 @@ Layered:
   rides every engine these tests build — dead ones included).
 """
 
+import dataclasses
 import threading
 import time
 
@@ -48,6 +49,11 @@ CFG = ModelConfig(
     vocab=64, d_model=32, n_heads=2, n_layers=1, d_ff=64,
     max_seq=32, head_dim=16, dtype=jnp.float32, use_pallas=False,
 )
+# an int8 pool: a rebuild from the ledger recomputes the quantised pages,
+# and has to land on the tokens the first build gave. Two layers, so that
+# what the second reads of the first's cache reaches the logits
+LAYOUTS = {"exact": CFG,
+           "int8": dataclasses.replace(CFG, kv_int8=True, n_layers=2)}
 PAGE = 8
 # long enough that an armed kill always lands MID-stream: the client
 # takes a few head tokens then arms, and the engine keeps producing in
@@ -80,17 +86,21 @@ def _prompt(seed, n=5):
 P1, P2, P3 = _prompt(1, 5), _prompt(2, 6), _prompt(3, 5)
 
 
-@pytest.fixture(scope="module")
-def refs(params):
-    """Single-engine reference streams for P1/P2/P3 (greedy decode is
-    deterministic, so per-prompt streams are slot-count-invariant)."""
-    eng = ServingEngine(params, CFG, ServingConfig(**{**BASE, "slots": 3}))
+def _reference(params, cfg=CFG):
+    eng = ServingEngine(params, cfg, ServingConfig(**{**BASE, "slots": 3}))
     eng.start()
     try:
         return [list(eng.submit(p, max_new_tokens=STEPS).stream())
                 for p in (P1, P2, P3)]
     finally:
         eng.stop()
+
+
+@pytest.fixture(scope="module")
+def refs(params):
+    """Single-engine reference streams for P1/P2/P3 (greedy decode is
+    deterministic, so per-prompt streams are slot-count-invariant)."""
+    return _reference(params)
 
 
 class PinPolicy(RoutePolicy):
@@ -107,12 +117,12 @@ class PinPolicy(RoutePolicy):
 
 
 def _fleet(params, names=("a", "b", "c"), faults_for=None, fc=None,
-           **fleet_kw):
+           cfg=CFG, **fleet_kw):
     """Build a fleet of fresh engines; ``faults_for`` maps engine name ->
     FaultPlan (the engine-side seams)."""
     faults_for = faults_for or {}
     engines = {
-        n: ServingEngine(params, CFG, ServingConfig(
+        n: ServingEngine(params, cfg, ServingConfig(
             **BASE, faults=faults_for.get(n)))
         for n in names
     }
@@ -224,15 +234,20 @@ def test_routing_steers_off_high_duty(params):
 # --------------------------------------------------------------- failover
 
 
-def test_kill_one_of_three_failover_token_equal(params, refs):
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_kill_one_of_three_failover_token_equal(params, refs, layout):
     """The acceptance bar: one of three engines dies without saying
     goodbye while holding two live streams and one still-waiting request
     (slots=2). Every stream finishes token-equal on a survivor —
     started sessions rebuilt from the ledger through recompute-on-fault,
     the waiting one re-queued from the fleet's assignment record —
     failover_sessions equals the dead engine's session count, and the
-    corpse's pools audit clean (the reap; leak_check re-checks at
-    teardown)."""
+    corpse's and the survivors' pools audit clean (the reap; leak_check
+    re-checks at teardown). Over the exact pool and the int8 one."""
+    cfg = LAYOUTS[layout]
+    if cfg is not CFG:
+        params = init_params(jax.random.key(0), cfg)
+        refs = _reference(params, cfg)
     plan = FaultPlan()
     # throttle the doomed engine's decode (~10ms/token): recompute
     # needs the history to still FIT a prefill bucket (prompt 5 +
@@ -240,7 +255,7 @@ def test_kill_one_of_three_failover_token_equal(params, refs):
     # free-runs past it between the head reads and the arm() on a
     # loaded box — the death must land while the rebuild is possible
     plan.arm("delayed_fetch", count=100000, arg=0.01)
-    fleet, engines = _fleet(params, faults_for={"a": plan},
+    fleet, engines = _fleet(params, faults_for={"a": plan}, cfg=cfg,
                             fc={"route_policy": PinPolicy("a")})
     fleet.start()
     try:
@@ -261,10 +276,13 @@ def test_kill_one_of_three_failover_token_equal(params, refs):
         assert s["failover_faulted"] == 0
         assert s["engine_states"]["a"] == "DEAD"
         assert plan.snapshot()["injected"]["engine_death"] == 1
-        # the reap restored the corpse's audit invariants
-        sa = engines["a"].stats()
-        assert sa["kv_pool_free"] == sa["kv_pool_blocks"]
-        assert sa["active_slots"] == 0 and sa["parked_sessions"] == 0
+        # the reap restored the corpse's audit invariants, and the
+        # survivors hold nothing of the sessions they finished
+        for eng in engines.values():
+            se = eng.stats()
+            assert se["kv_pool_free"] == se["kv_pool_blocks"]
+            assert se["active_slots"] == 0 and se["parked_sessions"] == 0
+            assert se["swap_host_free"] == se["swap_host_blocks"]
         # survivors carried the rebuilt sessions (migrate-in counters)
         moved = sum(fleet.stats()["engines"][n]["migrations_in"]
                     for n in ("b", "c"))
@@ -402,6 +420,7 @@ def test_fleet_drain_routes_to_survivors(params, refs):
         assert sa["active_slots"] == 0 and sa["parked_sessions"] == 0
         assert sa["queued"] == 0
         assert sa["kv_pool_free"] == sa["kv_pool_blocks"]
+        assert fleet.stats()["failovers"] == 0  # a drain is no death
         with pytest.raises(RuntimeError, match="draining"):
             engines["a"].submit(P1)
         # the fleet front door still serves — routed around the drained
@@ -559,10 +578,13 @@ def test_journey_failover_stitched_with_bundle(params, refs):
     assert s["rebuild_p50_ms"] is not None
 
 
-def test_fleet_stats_and_ledger_shape(params):
+def test_fleet_stats_and_ledger_shape(params, refs):
     """The ledger records started sessions at flush boundaries (the
     exact migrate-handshake metadata), and stats() carries the fleet
-    counters plus per-engine snapshots under engine names."""
+    counters plus per-engine snapshots under engine names. The engine
+    decodes on while the test looks, so the entry is held to the
+    reference stream at whatever boundary it was recorded, not to the
+    last token the client happened to have read."""
     fleet, engines = _fleet(params, names=("a", "b"),
                             fc={"route_policy": PinPolicy("a")})
     fleet.start()
@@ -576,8 +598,9 @@ def test_fleet_stats_and_ledger_shape(params):
             entry = dict(fleet._ledger["a"][req])
         # the exact metadata-first handshake payload (PR 12's meta)
         assert not entry["unstarted"]
-        assert entry["pending"] == head[-1]
         assert entry["tokens"][:len(P1)] == P1
+        said = entry["tokens"][len(P1):] + [entry["pending"]]
+        assert said and said == refs[0][:len(said)]
         assert entry["seq_len"] == len(entry["tokens"])
         assert entry["hist_exact"] is True
         assert entry["n_pages"] >= 1

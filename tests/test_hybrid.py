@@ -484,6 +484,30 @@ def test_the_walk_bench_runs_at_cut_down_shapes(tmp_path):
     assert "max_abs_err_lse" not in granite
 
 
+def test_the_state_bench_runs_at_a_cut_down_shape(tmp_path):
+    """``benchmarks/ssm_state_bench.py --tiny`` (the loop PERF.md's PR 36
+    entry chose the state kernel's tile with) runs on the CPU, the kernel
+    interpreted over a stack of three layers, and holds the kernel's state
+    and readout to ``_ssd_step``'s; its times there are no speeds."""
+    import json
+    import pathlib
+    import subprocess
+    import sys
+
+    root = pathlib.Path(__file__).resolve().parent.parent
+    out = tmp_path / "bench.json"
+    done = subprocess.run(
+        [sys.executable, str(root / "benchmarks/ssm_state_bench.py"),
+         "--tiny", "--out", str(out)],
+        capture_output=True, text=True, timeout=300)
+    assert done.returncode == 0, done.stderr[-2000:]
+    result = json.loads(out.read_text())
+    assert result["device"] == "cpu" and result["shape"][0] == 3
+    kernel, xla = result["rows"]
+    assert (kernel["route"], xla["route"]) == ("kernel", "_ssd_step")
+    assert kernel["y_distance"] < 1e-5 and kernel["h_distance"] < 1e-5
+
+
 # ---------------------------------------------------------- admission
 
 

@@ -20,6 +20,7 @@ Fast (non-slow) tier. The contract under test, layered like the change:
   present but zero; park/resume refuse).
 """
 
+import dataclasses
 import time
 
 import jax
@@ -27,7 +28,13 @@ import jax.numpy as jnp
 import pytest
 
 from vtpu.models import ModelConfig, init_params
-from vtpu.serving import ServingConfig, ServingEngine, WaitQueue
+from vtpu.serving import (
+    FaultPlan,
+    FaultSpec,
+    ServingConfig,
+    ServingEngine,
+    WaitQueue,
+)
 
 CFG = ModelConfig(
     vocab=64, d_model=32, n_heads=2, n_layers=1, d_ff=64,
@@ -71,11 +78,19 @@ def refs(params):
         eng.stop()
 
 
+# ~10 ms a token on a started engine whose stream is parked after its
+# first token. The engine decodes whether or not the client reads: on a
+# loaded machine an unthrottled stream of 8 tokens ends before the park is
+# asked for, and parking a finished request is a no-op nothing waits out.
+THROTTLE = FaultSpec("delayed_fetch", at=0, count=100000, arg=0.01)
+
+
 def _wait_parked(eng, req, timeout=10.0):
     """Parks apply asynchronously at the next settled tick; block until
     this one lands (or the request finished first — a test bug)."""
     t0 = time.perf_counter()
     while req not in eng._parked:
+        assert req.status is None, "request finished before the park"
         assert time.perf_counter() - t0 < timeout, "park never landed"
         time.sleep(0.002)
 
@@ -84,7 +99,8 @@ def _park_evict_resume(params, serving, refs):
     """The canonical overcommit exercise: park P1 early, admit P2 into a
     pool too small for both (forcing eviction of the parked pages), then
     resume P1 and drain it. Returns (stream1, stream2, stats)."""
-    eng = ServingEngine(params, CFG, serving)
+    eng = ServingEngine(params, CFG, dataclasses.replace(
+        serving, faults=FaultPlan([THROTTLE])))
     eng.start()
     try:
         r1 = eng.submit(P1, max_new_tokens=STEPS)
@@ -169,7 +185,8 @@ def test_park_resume_resident_token_equal(params, refs):
     """No memory pressure: a parked session's pages stay pool-resident and
     resume is a pure table-row remap — stream equal to never-parked, zero
     swap traffic, park/resume counted."""
-    eng = ServingEngine(params, CFG, ServingConfig(**BASE, kv_swap=8))
+    eng = ServingEngine(params, CFG, ServingConfig(
+        **BASE, kv_swap=8, faults=FaultPlan([THROTTLE])))
     eng.start()
     try:
         r1 = eng.submit(P1, max_new_tokens=STEPS)
@@ -239,6 +256,56 @@ def test_crossover_prefers_recompute_over_swap_in(params, refs):
     assert stats["swap_in_bytes"] == 0  # ...but resume never read it back
     assert stats["swap_host_free"] == stats["swap_host_blocks"]
     assert stats["kv_pool_free"] == stats["kv_pool_blocks"]
+
+
+def test_fourfold_oversubscription_takes_both_restore_paths(params):
+    """One run at four times the pool: eight sessions, parked a wave of
+    two at a time after two tokens each, over a pool that holds one wave
+    and a host tier that holds half of what parks. Evictions past the tier
+    drop, so one run restores by swap-in and by recompute; every stream
+    equals the same request's on a pool with room for all, and the tick's
+    one fetch stands through every park, eviction and restore."""
+    ratio, slots, prompt_len, new = 4, 2, 5, 24
+    pages_per = -(-(prompt_len + new) // PAGE)
+    pool = slots * pages_per            # exactly one live wave fits
+    n = ratio * pool // pages_per
+    prompts = [_prompt(100 + i, prompt_len) for i in range(n)]
+    common = dict(BASE, slots=slots, max_new_tokens=new)
+    ref = ServingEngine(params, CFG, ServingConfig(**common))
+    ref.start()
+    try:
+        want = [list(r.stream()) for r in [
+            ref.submit(p, max_new_tokens=new) for p in prompts]]
+    finally:
+        ref.stop()
+    eng = ServingEngine(params, CFG, ServingConfig(
+        **common, kv_pool_blocks=pool, kv_swap=n * pages_per // 2,
+        faults=FaultPlan([THROTTLE])))
+    eng.start()
+    try:
+        reqs, got = [], []
+        for w0 in range(0, n, slots):
+            wave = [eng.submit(p, max_new_tokens=new)
+                    for p in prompts[w0:w0 + slots]]
+            got += [[r.out.get(timeout=60) for _ in range(2)] for r in wave]
+            for r in wave:
+                eng.park(r)
+            for r in wave:
+                _wait_parked(eng, r)
+            reqs += wave
+        for r, toks in zip(reqs, got):
+            eng.resume(r)
+            toks += list(r.stream())
+        stats = eng.stats()
+    finally:
+        eng.stop()
+    assert got == want
+    assert stats["parks"] == stats["resumes"] == n
+    assert stats["swap_out_bytes"] > 0 and stats["swap_in_bytes"] > 0
+    assert stats["fault_recomputes"] > 0
+    assert stats["device_gets_per_tick"] == 1.0
+    assert stats["kv_pool_free"] == stats["kv_pool_blocks"] == pool
+    assert stats["swap_host_free"] == stats["swap_host_blocks"]
 
 
 # ------------------------------------------------- eviction policy limits
@@ -470,7 +537,8 @@ def test_tp_mesh_eviction_roundtrip():
                 for p in (p1, p2)]
     finally:
         eng.stop()
-    serving = ServingConfig(**BASE, kv_pool_blocks=2, kv_swap=8)
+    serving = ServingConfig(**BASE, kv_pool_blocks=2, kv_swap=8,
+                            faults=FaultPlan([THROTTLE]))
     eng = ServingEngine(tp_params, cfg, serving, mesh=mesh)
     eng.start()
     try:

@@ -1,6 +1,8 @@
 """The seam between the engine and the models (ISSUE 30): imports point one
 way, the two cached-attention families share one adapter body and differ
-only where they must, and the options nobody set are gone."""
+only where they must, and the options nobody set are gone. And the seam
+around both (ISSUE 46): the program imports nothing that is built on it,
+and a record at the root has a reader."""
 
 import ast
 import dataclasses
@@ -79,6 +81,46 @@ def test_step_function_has_one_home(name):
                    for part in path.relative_to(root).parts)
         and f"vtpu.serving.engine.{name}" in _imported(path)]
     assert not asking
+
+
+ROOT = VTPU.parent
+BUILT_ON_THEM = {"benchmarks", "hack", "bench", "tests"}
+
+
+def test_the_product_and_the_harness_import_nothing_built_on_them():
+    """``vtpu/`` and ``vbench/`` stand on their own: a script, a study or a
+    test is built on them and never the other way round, or deleting one
+    would break the program (PR 46 deleted thirteen)."""
+    files = sorted(VTPU.rglob("*.py")) + sorted((ROOT / "vbench").rglob("*.py"))
+    assert files
+    wrong = {
+        str(path.relative_to(ROOT)): sorted(
+            name for name in _imported(path)
+            if name.split(".")[0] in BUILT_ON_THEM)
+        for path in files}
+    assert not {path: names for path, names in wrong.items() if names}
+
+
+READERS = ("vtpu", "vbench", "tests", "hack", "benchmarks", ".github")
+READER_FILES = ("bench.py", "README.md", "ROADMAP.md", "PERF.md")
+
+
+def test_every_record_at_the_root_has_a_reader():
+    """A ``*.json`` at the root is named by a file that could open it: one
+    that nothing names is evidence for nothing, which is how nineteen of
+    them came to sit there until PR 46."""
+    records = sorted(path.name for path in ROOT.glob("*.json"))
+    assert records
+    texts = [(ROOT / name).read_text() for name in READER_FILES]
+    texts += [
+        path.read_text(errors="ignore")
+        for top in READERS for path in sorted((ROOT / top).rglob("*"))
+        if path.is_file() and path.suffix != ".json"
+        and "__pycache__" not in path.parts]
+    unread = [r for r in records if not any(r in text for text in texts)]
+    assert not unread, (
+        f"{unread}: named by nothing under {READERS} nor by {READER_FILES}; "
+        f"give the record a reader or take it out")
 
 
 DENSE = ModelConfig(

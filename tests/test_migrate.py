@@ -23,6 +23,7 @@ Fast tier. The contract under test, layered like the change:
   slots, nothing parked or queued.
 """
 
+import dataclasses
 import time
 
 import jax
@@ -49,6 +50,12 @@ CFG = ModelConfig(
     vocab=64, d_model=32, n_heads=2, n_layers=1, d_ff=64,
     max_seq=32, head_dim=16, dtype=jnp.float32, use_pallas=False,
 )
+# an int8 pool: the payload carries the quantised planes and their scales,
+# and the stream has to go on from them unchanged. Two layers, so that what
+# the second reads of the first's cache reaches the logits: the one-layer
+# model's stream survives a payload that lost a plane
+LAYOUTS = {"exact": CFG,
+           "int8": dataclasses.replace(CFG, kv_int8=True, n_layers=2)}
 PAGE = 8
 STEPS = 8
 BASE = dict(slots=2, prefill_buckets=(8,), max_new_tokens=STEPS,
@@ -68,16 +75,20 @@ def _prompt(seed, n=5):
 P1, P2, P3 = _prompt(1, 5), _prompt(2, 6), _prompt(3, 5)
 
 
-@pytest.fixture(scope="module")
-def refs(params):
-    """Stay-put reference streams for P1/P2/P3 (one engine, no moves)."""
-    eng = ServingEngine(params, CFG, ServingConfig(**BASE))
+def _reference(params, cfg=CFG):
+    eng = ServingEngine(params, cfg, ServingConfig(**BASE))
     eng.start()
     try:
         return [list(eng.submit(p, max_new_tokens=STEPS).stream())
                 for p in (P1, P2, P3)]
     finally:
         eng.stop()
+
+
+@pytest.fixture(scope="module")
+def refs(params):
+    """Stay-put reference streams for P1/P2/P3 (one engine, no moves)."""
+    return _reference(params)
 
 
 def _wait_parked(eng, req, timeout=10.0):
@@ -88,9 +99,17 @@ def _wait_parked(eng, req, timeout=10.0):
         time.sleep(0.002)
 
 
-def _pair(params, src_kw=None, dst_kw=None):
-    src = ServingEngine(params, CFG, ServingConfig(**{**BASE, **(src_kw or {})}))
-    dst = ServingEngine(params, CFG, ServingConfig(**{**BASE, **(dst_kw or {})}))
+# ~10 ms a token on every source. The engine decodes whether or not the
+# client reads: on a loaded machine an unthrottled stream of 8 tokens ends
+# between a test's head reads and its park or its migrate(), and leaves
+# nothing to move ("request finished before the park", a path "completed").
+THROTTLE = FaultSpec("delayed_fetch", at=0, count=100000, arg=0.01)
+
+
+def _pair(params, src_kw=None, dst_kw=None, cfg=CFG):
+    src_kw = {"faults": FaultPlan([THROTTLE]), **(src_kw or {})}
+    src = ServingEngine(params, cfg, ServingConfig(**{**BASE, **src_kw}))
+    dst = ServingEngine(params, cfg, ServingConfig(**{**BASE, **(dst_kw or {})}))
     src.start()
     dst.start()
     return src, dst
@@ -108,13 +127,19 @@ def _pools_clean(*engines):
 # ------------------------------------------------------------- happy path
 
 
-def test_migrate_mid_stream_token_equal(params, refs):
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_migrate_mid_stream_token_equal(params, refs, layout):
     """The tentpole contract: a session migrated mid-stream resumes at
     exactly its next token (resident payload path — one D2H snapshot on
     the source, one staged H2D on the destination, a fused-row remap at
     resume), with the zero-extra-copy counter at 0 on both engines and
-    the handshake visible in both traces."""
-    src, dst = _pair(params)
+    the handshake visible in both traces. Over the exact pool and the
+    int8 one."""
+    cfg = LAYOUTS[layout]
+    if cfg is not CFG:
+        params = init_params(jax.random.key(0), cfg)
+        refs = _reference(params, cfg)
+    src, dst = _pair(params, cfg=cfg)
     try:
         r = src.submit(P1, max_new_tokens=STEPS)
         it = r.stream()
@@ -214,7 +239,8 @@ def test_migrate_tp2_head_shard_roundtrip():
         want = list(ref.submit(p, max_new_tokens=STEPS).stream())
     finally:
         ref.stop()
-    src = ServingEngine(tp_params, cfg, ServingConfig(**BASE), mesh=mesh)
+    src = ServingEngine(tp_params, cfg, ServingConfig(
+        **BASE, faults=FaultPlan([THROTTLE])), mesh=mesh)
     dst = ServingEngine(tp_params, cfg, ServingConfig(**BASE), mesh=mesh)
     src.start()
     dst.start()
@@ -226,8 +252,10 @@ def test_migrate_tp2_head_shard_roundtrip():
         got += list(it)
         assert got == want
         assert rep["path"] == "resident"
-        assert dst.stats()["tp"] == 2
-        assert src.stats()["migration_copies"] == 0
+        ss, ds = src.stats(), dst.stats()
+        assert ds["tp"] == 2
+        assert ss["migrate_out_bytes"] == ds["migrate_in_bytes"] > 0
+        assert ss["migration_copies"] == 0 and ds["migration_copies"] == 0
         _pools_clean(src, dst)
     finally:
         src.stop()
@@ -243,7 +271,8 @@ def test_migrate_src_death_rebuilds_from_history(params, refs):
     dropped, and the recompute-on-fault prefill path rebuilds the KV —
     the stream continues token-equal, no FAULTED terminal."""
     src = ServingEngine(params, CFG, ServingConfig(
-        **BASE, faults=FaultPlan([FaultSpec("migrate_src_death", at=0)])))
+        **BASE, faults=FaultPlan([FaultSpec("migrate_src_death", at=0),
+                                  THROTTLE])))
     dst = ServingEngine(params, CFG, ServingConfig(**BASE))
     src.start()
     dst.start()
@@ -273,7 +302,8 @@ def test_migrate_payload_loss_recomputes_or_faults(params, refs):
     with a typed FAULTED terminal — never a silent close, and nothing
     leaks on either engine."""
     # (a) rebuildable: recompute fallback, token-equal
-    src = ServingEngine(params, CFG, ServingConfig(**BASE))
+    src = ServingEngine(params, CFG, ServingConfig(
+        **BASE, faults=FaultPlan([THROTTLE])))
     dst = ServingEngine(params, CFG, ServingConfig(
         **BASE, faults=FaultPlan([FaultSpec("migrate_payload_loss", at=0)])))
     src.start()
@@ -292,7 +322,8 @@ def test_migrate_payload_loss_recomputes_or_faults(params, refs):
         dst.stop()
     # (b) unrebuildable: the destination has no chunked prefill and a
     # bucket smaller than the sequence — typed FAULTED, both pools clean
-    src = ServingEngine(params, CFG, ServingConfig(**BASE))
+    src = ServingEngine(params, CFG, ServingConfig(
+        **BASE, faults=FaultPlan([THROTTLE])))
     dst = ServingEngine(params, CFG, ServingConfig(
         slots=2, prefill_buckets=(8,), max_new_tokens=STEPS, kv_page=PAGE,
         kv_swap=0,
@@ -441,6 +472,9 @@ def test_drain_evacuates_live_parked_and_waiting(params, refs):
         assert s["swap_host_free"] == s["swap_host_blocks"]
         assert s["draining"] is True
         assert dst.stats()["draining"] is False
+        # an evacuation pays what a migration pays and no copy more
+        assert s["migration_copies"] == 0
+        assert dst.stats()["migration_copies"] == 0
     finally:
         src.stop()
         dst.stop()

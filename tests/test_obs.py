@@ -450,6 +450,82 @@ def test_trace_off_engine_still_reports_percentiles(params):
     assert stats["device_gets_per_tick"] == 1.0
 
 
+def test_tracing_adds_no_fetch_and_no_sync(params):
+    """What the ring costs, counted and not timed: the same requests
+    through an engine with the ring off and one with it on give the same
+    streams with one fetch a tick on both and the same blocking admission
+    syncs (none); the arm with the ring recorded events, the other
+    none."""
+    prompts = [_prompt(40 + i, 5) for i in range(4)]
+
+    def arm(trace_events):
+        eng = ServingEngine(params, CFG, ServingConfig(
+            slots=2, prefill_buckets=(8,), max_new_tokens=6,
+            trace_events=trace_events))
+        eng.start()
+        try:
+            reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
+            return [list(r.stream()) for r in reqs], eng.stats()
+        finally:
+            eng.stop()
+
+    off_streams, off = arm(0)
+    on_streams, on = arm(16384)
+    assert on_streams == off_streams
+    assert on["device_gets_per_tick"] == off["device_gets_per_tick"] == 1.0
+    assert on["admission_syncs"] == off["admission_syncs"] == 0
+    assert on["trace_events_recorded"] > 0
+    assert off["trace_events_recorded"] == 0
+
+
+def test_fleet_plane_off_records_nothing_on_stitches_every_request(params):
+    """The fleet's half of the same count, over two fleets of three that
+    differ only in whether the plane is on (the engines' rings, the
+    control ring, the journeys): off records no event and ends no
+    journey; on ends one journey a request, every one of them with its
+    hops' tokens summing to what was delivered; and neither adds a fetch
+    to a tick or a sync to an admission."""
+    from vtpu.serving import EngineFleet, FleetConfig
+
+    prompts = [_prompt(60 + i, 5) for i in range(6)]
+
+    def arm(on):
+        engines = {n: ServingEngine(params, CFG, ServingConfig(
+            slots=2, prefill_buckets=(8,), max_new_tokens=6, kv_page=8,
+            kv_swap=4, trace_events=16384 if on else 0))
+            for n in ("a", "b", "c")}
+        fleet = EngineFleet(engines, FleetConfig(
+            miss_ms=2000.0, trace_events=4096 if on else 0))
+        fleet.start()
+        try:
+            reqs = [fleet.submit(p, max_new_tokens=6) for p in prompts]
+            streams = [list(r.stream()) for r in reqs]
+            # journeys close on the monitor's prune pass
+            t0 = time.perf_counter()
+            while on and fleet.stats()["journeys_ended"] < len(reqs):
+                assert time.perf_counter() - t0 < 30, "journeys never ended"
+                time.sleep(0.002)
+            return streams, fleet.stats()
+        finally:
+            fleet.stop()
+
+    off_streams, off = arm(False)
+    on_streams, on = arm(True)
+    assert on_streams == off_streams
+    for fs in (off, on):
+        assert all(s["device_gets_per_tick"] in (None, 1.0)
+                   and s["admission_syncs"] == 0
+                   for s in fs["engines"].values())
+    assert off["fleet_trace_events_recorded"] == 0
+    assert off["journeys_ended"] == 0
+    assert all(s["trace_events_recorded"] == 0
+               for s in off["engines"].values())
+    assert on["fleet_trace_events_recorded"] > 0
+    assert sum(s["trace_events_recorded"]
+               for s in on["engines"].values()) > 0
+    assert on["journeys_ended"] == on["journeys_conserved"] == len(prompts)
+
+
 def test_shed_and_fault_events_attribute_stream_ends(params):
     """Failure-domain trace fidelity (ISSUE 12 satellite): a shed and a
     contained fault land as ``shed``/``fault`` events in the ring, the

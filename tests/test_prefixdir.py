@@ -355,6 +355,56 @@ def test_pid_api_validation(params, prefix_refs):
         fleet.stop()
 
 
+def test_seeded_zipf_draw_is_accounted_and_routed_to_residents(
+        params, prefix_refs):
+    """Traffic, not one submit: twelve requests drawn by a seeded zipf law
+    over four registered prefixes, placed hottest first on the least
+    loaded of three engines, with hot replication on. Every prefix-aware
+    submit lands as exactly one directory hit or one miss, whatever the
+    monitor replicated meanwhile; the share routed onto a resident is
+    above what routing blind to residency could reach (replicas over
+    engines); admission copied nothing; and every stream is its prefix's
+    reference stream."""
+    import numpy as np
+
+    names, n_requests, max_replicas = ("a", "b", "c"), 12, 2
+    prefixes = [PRE, OPRE, list(range(33, 49)), list(range(48, 64))]
+    rng = np.random.default_rng(7)
+    weights = 1.0 / np.arange(1, len(prefixes) + 1) ** 1.2
+    weights /= weights.sum()
+    draw = [int(i) for i in rng.choice(len(prefixes), size=n_requests,
+                                       p=weights)]
+    assert len(set(draw)) > 1  # the draw mixes hot and cold
+    fleet, _engines = _fleet(params, names=names, fc={
+        "prefix_replicate_hits": 3, "prefix_max_replicas": max_replicas})
+    fleet.start()
+    try:
+        load = dict.fromkeys(names, 0.0)
+        for i in np.argsort(-weights):
+            tgt = min(names, key=lambda n: (load[n], n))
+            fleet.register_prefix(prefixes[i], engine=tgt)
+            load[tgt] += float(weights[i])
+        reqs = [fleet.submit(SUF, prefix_tokens=prefixes[i],
+                             max_new_tokens=STEPS) for i in draw]
+        streams = [list(r.stream()) for r in reqs]
+        assert all(r.status == Status.OK for r in reqs)
+        # a local resident stamps its hit from the loop thread at the
+        # share: every stream has ended, so every share has happened
+        s = fleet.stats()
+    finally:
+        fleet.stop()
+    assert (s["prefix_directory_hits"]
+            + s["prefix_directory_misses"]) == n_requests
+    assert s["prefix_routes"] / n_requests > max_replicas / len(names)
+    for n in names:
+        assert s["engines"][n]["prefix_install_copies"] == 0
+    by_prefix = {}
+    for i, toks in zip(draw, streams):
+        assert by_prefix.setdefault(i, toks) == toks
+    assert by_prefix[0] == prefix_refs["prefix"]
+    assert by_prefix[1] == prefix_refs["other"]
+
+
 # ------------------------------------------------- replication and spill
 
 

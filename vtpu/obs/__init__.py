@@ -2,8 +2,8 @@
 profiling, and the unified ``vtpu_serving_*`` Prometheus exporter.
 
 All host-side (nothing here ever touches the device — the overhead
-contract benchmarks/obs_bench.py gates is that tracing adds zero host
-syncs and stays within 2% tokens/sec of tracing-off). What the DEVICE did
+contract tests/test_obs.py holds is that tracing adds no fetch and no
+host sync; its cost in tokens/sec is not measured). What the DEVICE did
 is named by ``jax.named_scope``s from one vocabulary, ``vtpu.ops.SCOPES``
 (docs/design.md, observability: a new step or kernel takes a name from it
 or adds one), and by the programs' names: ``jit_step`` decodes,

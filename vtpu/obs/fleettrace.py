@@ -40,8 +40,8 @@ failover yields a loadable black box instead of a reaped mystery.
 
 Everything here keeps PR 7's bars: bounded memory (bounded ring, bounded
 journey map, bounded bundle set, bounded reservoirs), host-only (nothing
-touches the device — zero added syncs), and the ≤2% overhead envelope
-gated by ``benchmarks/obs_bench.py --fleet``.
+touches the device — zero added syncs: tests/test_obs.py's fleet arms
+count them; the cost in speed is not measured).
 """
 
 from __future__ import annotations
@@ -93,9 +93,9 @@ _FAILOVER_KINDS = ("failover",)
 def validate_bundle(bundle) -> bool:
     """Is *bundle* a well-formed post-mortem black box? One definition of
     the contract — JSON round-trips losslessly, the ledger census and
-    trace events are present and non-empty — shared by every bench that
-    gates on it (fleet_bench, chaos_bench, obs_bench --fleet), so the
-    contract cannot drift per-copy."""
+    trace events are present and non-empty — shared by every test that
+    holds a dead engine to it (tests/test_faults.py's fleet schedule), so
+    the contract cannot drift per-copy."""
     if bundle is None:
         return False
     try:

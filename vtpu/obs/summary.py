@@ -4,8 +4,8 @@ PR 3 established the convention (bench.py): driver artifacts that truncate
 long stdout or parse only the last line must still get a self-contained
 headline — ``{"summary": true, "metric": ..., "value": ..., "verdict":
 ...}`` as the FINAL stdout line. PR 4-6 re-implemented the dict inline in
-each bench; this helper is the single implementation they all share
-(bench.py, paged_kv_bench, overcommit_bench, prefill_bench, obs_bench).
+each bench; this helper is the single implementation, and bench.py is
+the caller that is left (the engine-feature benches went with PR 46).
 """
 
 from __future__ import annotations

@@ -12,8 +12,8 @@ preallocated ring and derives the spans offline:
 Recording cost is the contract: one sequence bump under an uncontended
 lock (SeqCounter), one ``time.monotonic_ns`` stamp, one tuple, one
 list-slot store. No allocation beyond the tuple, and NOTHING device-side — tracing can never add a host sync
-(benchmarks/obs_bench.py gates ``device_gets_per_tick == 1.0`` and the
-2% tokens/sec envelope with tracing on).
+(tests/test_obs.py holds ``device_gets_per_tick == 1.0`` and equal admission
+syncs with the ring on and off; what it costs in speed is not measured).
 
 The ring is bounded: when it wraps, the oldest events fall off and
 ``events_dropped`` says how many. Span derivation, JSONL export and the
@@ -102,7 +102,7 @@ TERMINAL_NAMES = {v: k for k, v in TERMINAL_CODES.items()}
 
 # The disaggregated handoff lifecycle (prefill worker -> decode loop) as an
 # in-order subsequence — single-sourced like the restore sequences below so
-# benchmarks/disagg_bench.py and tests/test_disagg.py assert the same thing.
+# tests/test_disagg.py asserts it of every layout it runs.
 HANDOFF_SEQUENCE = (
     "submit", "queue_depart", "prefill_start", "prefill_chunk",
     "first_token", "handoff", "pool_install", "admit", "token", "retire")
@@ -115,8 +115,8 @@ FIELDS = ("seq", "ts_ns", "event", "rid", "slot", "val")
 
 # The lifecycle contracts the two overcommit restore paths must trace as
 # (in-order subsequences of a session's event stream) — single-sourced
-# here so benchmarks/obs_bench.py and tests/test_obs.py assert the SAME
-# sequences and cannot drift apart.
+# here so tests/test_obs.py's round trip and any later reader assert the
+# SAME sequences and cannot drift apart.
 SWAP_RESTORE_SEQUENCE = (
     "submit", "queue_depart", "admit", "first_token", "token", "park",
     "evict", "swap_out", "resume", "swap_in", "token", "retire")
@@ -128,7 +128,7 @@ DROP_RESTORE_SEQUENCE = (
 # (the destination assigns a fresh rid at install): the source trace ends
 # at migrate_out, the destination trace starts at migrate_in and carries
 # the stream to its retire. Single-sourced so tests/test_migrate.py and
-# benchmarks/migrate_bench.py assert the same handshake.
+# any later reader assert the same handshake.
 MIGRATE_SRC_SEQUENCE = (
     "submit", "admit", "first_token", "token", "park", "migrate_out")
 MIGRATE_DST_SEQUENCE = ("migrate_in", "resume", "token", "retire")

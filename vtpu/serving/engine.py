@@ -65,6 +65,11 @@ log = logging.getLogger(__name__)
 # compile-once)
 SWAP_STAGE_BLOCKS = 8
 
+# The fetch watchdog's clock, under a name of its own so that a test can
+# put one it drives in its place: on a CPU an interpreted kernel's tick
+# outlasts any watchdog short enough for a test
+_watchdog_clock = time.perf_counter
+
 
 @dataclasses.dataclass(frozen=True)
 class ServingConfig:
@@ -227,8 +232,8 @@ class ServingConfig:
     # spans, JSONL, Chrome trace_event dumps. 0 disables the ring (the
     # latency reservoirs behind itl/ttft percentiles stay on — they ARE
     # the stats() telemetry). Recording is host-only and lock-light; the
-    # overhead contract (obs_bench.py) is zero added host syncs and
-    # tokens/sec within 2% of tracing-off.
+    # overhead contract (tests/test_obs.py) is no added fetch and no
+    # added host sync; the cost in tokens/sec is not measured.
     trace_events: int = 16384
     # --- disaggregated prefill/decode (vtpu/serving/disagg) --------------
     # A DisaggConfig splits the engine into role-specialized workers over
@@ -3581,7 +3586,7 @@ class ServingEngine:
         self._stats["bytes_fetched"] += sum(
             a.size * a.dtype.itemsize
             for a in jax.tree_util.tree_leaves(arrays))
-        t0 = time.perf_counter()
+        t0 = _watchdog_clock()
         # fetch phase = device wait + transfer: on the pipelined loop this
         # is the time the host blocks for the in-flight tick to finish —
         # the device-bound share of the tick, attributed separately from
@@ -3593,7 +3598,7 @@ class ServingEngine:
                 # transfer would — what the watchdog below exists to catch
                 time.sleep(spec.arg or 0.05)
             out = jax.device_get(arrays)
-        dt = time.perf_counter() - t0
+        dt = _watchdog_clock() - t0
         wd = self.serving.fetch_watchdog_ms
         if wd:
             if dt * 1e3 > wd:
@@ -3604,7 +3609,7 @@ class ServingEngine:
                 # the recovery streak; a full grace window of them
                 # un-degrades one rung, and the clock restarts so every
                 # further rung needs its own window
-                now = time.perf_counter()
+                now = _watchdog_clock()
                 if self._healthy_since is None:
                     self._healthy_since = now
                 elif ((now - self._healthy_since) * 1e3
@@ -4046,8 +4051,8 @@ class ServingEngine:
         # device_gets_per_token is the explicit per-token reading of the
         # same contract (1.0 with the loop off, 1/k with a k-tick loop);
         # the host's share per inner tick is tick_phase_ms'
-        # mean_ms_per_tick. These are the headline numbers decode_bench
-        # --loop-k sweeps.
+        # mean_ms_per_tick. tests/test_device_loop.py holds both to 1/k
+        # exactly.
         s["decode_loop_k"] = self._loop_k or 1
         s["device_gets_per_token"] = (
             round(s["tick_fetches"] / ticks, 4) if ticks else None)
@@ -4070,8 +4075,8 @@ class ServingEngine:
             s[key] = round(v * 1e3, 3) if v is not None else None
         # prefill-execution component of TTFT (queue departure -> first
         # token): with the queue-wait reservoir above it attributes a TTFT
-        # regression to waiting vs prefilling — the split the disagg A/B
-        # and the ttft_benchmark /stats endpoint report
+        # regression to waiting vs prefilling — the split a span carries
+        # (tests/test_disagg.py) and the ttft_benchmark /stats endpoint reports
         pexec = sorted(self.trace.prefill_exec_samples())
         for q, key in ((0.5, "prefill_exec_p50_ms"),
                        (0.99, "prefill_exec_p99_ms")):

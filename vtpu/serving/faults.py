@@ -4,8 +4,8 @@ Every recovery path the engine promises — shed, contain, re-queue,
 degrade — is unreachable from a clean test run: the allocator never runs
 dry on cue, workers don't die on schedule, and a device fetch stalls only
 when real hardware misbehaves. This module makes each seam triggerable
-ON SCHEDULE so tier-1 and the chaos soak (benchmarks/chaos_bench.py) can
-exercise the recovery machinery reproducibly.
+ON SCHEDULE so tier-1, a seam a test and a schedule of them in one run
+(tests/test_faults.py), exercises the recovery machinery reproducibly.
 
 A ``FaultPlan`` is a set of ``FaultSpec``\\s, each naming a SEAM and the
 arrival indices at which it fires. The engine (and the disagg prefill

@@ -206,7 +206,7 @@ class FleetConfig:
     # the fleet observability plane (vtpu/obs/fleettrace.FleetTrace):
     # control-event ring capacity. 0 disables the WHOLE plane — no
     # control events, no journey stitching, no flight-recorder bundles —
-    # the knob the obs_bench fleet overhead A/B flips.
+    # the knob tests/test_obs.py's two fleet arms differ in.
     trace_events: int = 4096
     # bounded journey registry / post-mortem bundle set sizes
     trace_journeys: int = 4096
