@@ -790,6 +790,125 @@ def test_state_shaped_ops_finds_the_parents_three_visits():
     assert _state_shaped_ops(after, stack) == []
 
 
+# -- block-sparse attention beside linear attention, at its cell's sizes ------
+
+SALA_SLOTS, SALA_BLOCKS = 96, 30001
+
+
+def _sala_shapes(v5e):
+    """MiniCPM-SALA's ``SparseLinearConfig`` at the published widths in the
+    stage `sala_longsessions` serves (S L L L S L L L), with the adapter's
+    params and the cell's engine state (96 slots, 30000 blocks of 64) as
+    shapes on the described chip."""
+    from vtpu.models import sparselinear as M
+
+    cfg = M.SparseLinearConfig(
+        vocab=73448, d_model=4096, n_heads=32, n_kv_heads=2, head_dim=128,
+        d_ff=16384, lin_heads=32, lin_head_dim=128, ssd_chunk=256,
+        layer_types=tuple((["sparse"] + ["linear"] * 3) * 2),
+        layer_index=tuple(range(0, 32, 4)),
+        kernel_stride=16, block_size=64, window_size=2048, init_blocks=1,
+        topk=64, dense_len=8192, dim_model_base=256, max_seq=49152)
+    chip = SingleDeviceSharding(v5e[0])
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=chip), tree)
+
+    params = M.hold_projections(on_chip(jax.eval_shape(
+        lambda: M.init_sparselinear_params(jax.random.key(0), cfg))), cfg)
+    state = on_chip(jax.eval_shape(lambda: M.init_sparselinear_state(
+        cfg, SALA_SLOTS, 64, SALA_BLOCKS)))
+    return M, cfg, params, state, on_chip
+
+
+@pytest.mark.parametrize("program,window", [("step", 32768), ("chunk", 16384)])
+def test_sparse_linear_programs_compile_at_the_cells_sizes(
+        v5e, monkeypatch, program, window):
+    """The decode step (the selection's table a key/value head walked by
+    the grouped kernel, 16 queries a slot over pool rows of [1, 128]; the
+    linear layers' rows moved by the state kernel with a key and a query a
+    head) and a 512-token chunk (the selection's mask over the gathered
+    window) compile for a v5e and fit the chip beside the state. Neither
+    lays a pool plane out anew nor copies one; the step updates the rows in
+    place, inside the state kernel: outside the custom calls nothing
+    computes, copies, slices or updates an array of a layer's rows' or the
+    stack's shape (what PR 36's test holds for the hybrid family, whose
+    kernel this is)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    M, cfg, params, state, on_chip = _sala_shapes(v5e)
+    i32 = lambda *shape: on_chip(jnp.zeros(shape, jnp.int32))  # noqa: E731
+    if program == "step":
+        compiled = jax.jit(
+            M.sparselinear_decode_step, static_argnums=(1, 5),
+            donate_argnums=(2,)
+        ).lower(params, cfg, state, i32(SALA_SLOTS),
+                on_chip(jnp.zeros((SALA_SLOTS,), bool)), window).compile()
+    else:
+        compiled = jax.jit(
+            M.sparselinear_prefill_chunk, static_argnums=(1, 7),
+            donate_argnums=(2,)
+        ).lower(params, cfg, state, i32(1, 512), i32(), i32(), i32(), window,
+                i32(window // 64)).compile()
+    mem = compiled.memory_analysis()
+    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert peak < 0.8 * 16 * 2**30, peak
+    recurrent = math.prod(state["s"].shape) * 4
+    assert recurrent == 96 * 6 * 32 * 128 * 128 * 4
+    assert mem.alias_size_in_bytes > recurrent   # the state is updated in place
+    text = compiled.as_text()
+    for plane in ("k", "ck"):
+        big = count_pool_sized_ops(text, math.prod(state[plane].shape))
+        assert "copy" not in big and "transpose" not in big, (plane, big)
+    if program == "step":
+        # the grouped walk a sparse layer and key/value head (2 x 2), the
+        # state kernel a run of linear layers (one loop body each: 2)
+        assert text.count("tpu_custom_call") == 4 + 2
+        one_layer = recurrent // cfg.n_linear_layers
+        assert mem.temp_size_in_bytes < 5 * one_layer, mem.temp_size_in_bytes
+        assert _state_shaped_ops(text, state["s"].shape) == []
+
+
+@pytest.mark.parametrize("window", [16384, 24576, 32768, 40960, 49152])
+def test_sparse_linear_chunks_attend_in_the_kernel_under_the_mask(
+        v5e, monkeypatch, window):
+    """At every read window of `sala_longsessions` a chunk's sparse layers
+    attend in ``chunk_attn`` with the selection's mask as an operand (a
+    call a layer and key/value head: 2 x 2), and none falls to the XLA form
+    beside it, whose float32 scores [32, 512, window] made the first ramp
+    miss its limit (PERF.md section 6, PR 47). The cell reports no
+    ``chunk_attn_*`` metric (their lists are pinned), so this is what holds
+    the route. Traced at the cell's widths, not compiled: the 16384 window
+    is compiled above."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    M, cfg, params, state, on_chip = _sala_shapes(v5e)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    jaxpr = jax.make_jaxpr(
+        M.sparselinear_prefill_chunk, static_argnums=(1, 7))(
+            params, cfg, state, i32(1, 512), i32(), i32(), i32(), window,
+            i32(window // 64))
+    calls = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "pallas_call"]
+    assert len(calls) == 4
+    from vtpu.ops import chunk_attn
+
+    for call in calls:
+        assert call.params["name"] == "chunk_attn"
+        # [N, Hk (one a call), key blocks, T x G, mask blocks a key block]
+        flags = call.invars[-1].aval
+        bk = chunk_attn.key_block(window)
+        assert flags.shape == (1, 1, window // bk, 512 * 16, bk // 64)
+        assert flags.dtype == jnp.bfloat16
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
 # -- the window family at its cell's sizes -----------------------------------
 
 SWA_SLOTS, SWA_BLOCKS = 96, 14001

@@ -16,13 +16,15 @@ from vtpu.models.blockdiff import BlockDiffConfig
 from vtpu.models.hybrid import HybridConfig, init_hybrid_params
 from vtpu.models.latent import LatentConfig, init_latent_params
 from vtpu.models.moe import MoEConfig, init_moe_params
+from vtpu.models.sparselinear import (
+    SparseLinearConfig, init_sparselinear_params)
 from vtpu.models.swa import SwaConfig, init_swa_params
 from vtpu.obs.tickprof import HOST_PHASES, TickProfiler, host_ms_per_tick
 from vtpu.ops import SCOPES
 from vtpu.serving import ServingConfig, ServingEngine
 from vtpu.serving.adapters import (
     BlockDiffSlotModel, HybridSlotModel, LatentSlotModel, MoeSlotModel,
-    WindowSlotModel)
+    SparseLinearSlotModel, WindowSlotModel)
 
 PAGE, CHUNK, BUCKET = 8, 8, 16
 DENSE = ModelConfig(
@@ -54,7 +56,19 @@ BLOCKDIFF = BlockDiffConfig(
     vocab=64, d_model=32, n_heads=4, n_kv_heads=2, n_layers=2, d_ff=16,
     n_experts=8, held=(2, 4), top_k=2, max_seq=32, head_dim=16,
     dtype=jnp.float32, mask_token_id=63)
+# block-sparse attention beside linear attention: a page a selection block,
+# a window of two pages past ``dense_len``, so every program selects
+SPARSELIN = SparseLinearConfig(
+    vocab=64, d_model=32, layer_types=("sparse", "linear", "sparse"),
+    n_heads=4, n_kv_heads=2, head_dim=16, d_ff=64, lin_heads=2,
+    lin_head_dim=16, ssd_chunk=4, kernel_stride=2, block_size=PAGE,
+    window_size=PAGE, topk=1, dense_len=PAGE, max_seq=32, dtype=jnp.float32)
 BLOCK = {"dense": {"mlp"}, "moe": {"route", "experts"}}
+# that family: the selection's two parts and the linear layers' two nested
+# under ``attn`` as the latent and Mamba parts are, the selected pages
+# walked by the paged pool's routes, a chunk's window attended under the
+# selection's mask inside ``gather_attn`` / ``chunk_attn``
+SELECTS = {"attn", "indexer", "select", "ssm_scan", "ssm_gate", "mlp"}
 # generation by blocks: a pass's attention nested under ``attn`` as the
 # other families' own parts are; on the kernel's route the walk of the pool
 # (the kernel ``paged_attn``) and its preparation of its queries lie inside
@@ -100,6 +114,11 @@ def _engine(family: str, route, **serving):
     if family == "blockdiff":
         model = BlockDiffSlotModel(
             init_moe_params(jax.random.key(0), BLOCKDIFF), BLOCKDIFF,
+            kv_page=cfg.kv_page, paged_attn=cfg.paged_attn)
+        return ServingEngine(serving=cfg, model=model)
+    if family == "sparselinear":
+        model = SparseLinearSlotModel(
+            init_sparselinear_params(jax.random.key(0), SPARSELIN), SPARSELIN,
             kv_page=cfg.kv_page, paged_attn=cfg.paged_attn)
         return ServingEngine(serving=cfg, model=model)
     if family == "hybrid":
@@ -167,6 +186,14 @@ CASES = [
      | {"sample"}),
     ("hybrid", "kernel", "admit", TRUNK | SSM | {"chunk_attn", "sample"}),
     ("hybrid", "kernel", "chunk", TRUNK | SSM
+     | {"gather_attn", "chunk_attn"}),
+    ("sparselinear", "kernel", "decode", TRUNK | SELECTS | ROUTE["kernel"]
+     | {"sample"}),
+    ("sparselinear", "gather", "decode", TRUNK | SELECTS | ROUTE["gather"]
+     | {"sample"}),
+    ("sparselinear", "kernel", "admit", TRUNK | SELECTS
+     | {"gather_attn", "chunk_attn", "sample"}),
+    ("sparselinear", "kernel", "chunk", TRUNK | SELECTS
      | {"gather_attn", "chunk_attn"}),
     ("swa", "kernel", "decode", TRUNK | WINDOW | ROUTE["kernel"]
      | {"sample"}),

@@ -141,9 +141,9 @@ class HybridConfig:
 def layer_runs(layer_types: tuple) -> list:
     """[(kind, first, end)]: the model's order as runs of one kind, each
     with its span in that kind's own stack."""
-    runs, seen = [], {"mamba": 0, "attention": 0}
+    runs, seen = [], {}
     for kind in layer_types:
-        i = seen[kind]
+        i = seen.get(kind, 0)
         if runs and runs[-1][0] == kind:
             runs[-1] = (kind, runs[-1][1], i + 1)
         else:
@@ -230,7 +230,9 @@ def step_in_kernel(t: int) -> bool:
 def _step_operands(xs, dt, a, bm, cm):
     """A one-token step's operands in float32: (decay [B, H], dt * x
     [B, H, P], B [B, N], C [B, N]) from xs [B, 1, H, P], dt [B, 1, H], a
-    [H], bm, cm [B, 1, N]."""
+    [H], bm, cm [B, 1, N]; or, with a row of B and C a head (a linear
+    attention's key and query: vtpu/models/sparselinear.py), bm, cm
+    [B, 1, H, N] -> B, C [B, H, N]."""
     f32 = jnp.float32
     dt = dt[:, 0]
     return (jnp.exp(dt * a), dt[..., None] * xs[:, 0].astype(f32),
@@ -239,9 +241,13 @@ def _step_operands(xs, dt, a, bm, cm):
 
 def _ssd_step(xs, dt, a, bm, cm, h):
     """The recurrence, one token a row. xs [B, 1, H, P]; dt [B, 1, H]
-    float32 (0: the state passes through); a [H]; bm, cm [B, 1, N]; h
-    [B, H, P, N] float32 -> (y [B, 1, H, P] float32, h)."""
+    float32 (0: the state passes through); a [H]; bm, cm [B, 1, N] (or
+    [B, 1, H, N]: a row a head); h [B, H, P, N] float32 -> (y [B, 1, H, P]
+    float32, h)."""
     decay, dx, bm, cm = _step_operands(xs, dt, a, bm, cm)
+    if bm.ndim == 3:  # a row a head
+        h = h * decay[..., None, None] + dx[..., None] * bm[:, :, None, :]
+        return jnp.einsum("bhpn,bhn->bhp", h, cm)[:, None], h
     h = h * decay[..., None, None] + dx[..., None] * bm[:, None, None, :]
     y = jnp.einsum("bhpn,bn->bhp", h, cm)
     return y[:, None], h
@@ -253,7 +259,8 @@ def _ssd_chunked(xs, dt, a, bm, cm, h, chunk: int):
     running sum of ``dt A``: ``y_i = sum_{j <= i} exp(cum_i - cum_j) (C_i .
     B_j) dt_j x_j + exp(cum_i) C_i . h_in``; a chunk hands on ``h_out =
     exp(cum_Q) h_in + sum_j exp(cum_Q - cum_j) dt_j x_j (outer) B_j``.
-    Shapes as ``_ssd_step`` with T in the place of 1; the products take
+    Shapes as ``_ssd_step`` with T in the place of 1 (bm, cm [B, T, N], or
+    [B, T, H, N] a row a head); the products take
     their operands in xs's dtype and accumulate in float32, the decays are
     float32 throughout."""
     b, t, nh, p = xs.shape
@@ -268,18 +275,22 @@ def _ssd_chunked(xs, dt, a, bm, cm, h, chunk: int):
     cum_h = jnp.swapaxes(cum, 2, 3)  # [B, C, H, Q]
     xdt = (xs.astype(f32) * dt[..., None]).astype(xs.dtype).reshape(
         b, nc, q, nh, p)
-    bm, cm = bm.reshape(b, nc, q, n), cm.reshape(b, nc, q, n)
+    # B and C a row shared by the heads, or (rank 4) a row a head
+    shared = bm.ndim == 3
+    rows = (b, nc, q, n) if shared else (b, nc, q, nh, n)
+    bm, cm = bm.reshape(rows), cm.reshape(rows)
     # inside a chunk: the masked C B^T product under the decay between j, i
-    cb = jnp.einsum("bcin,bcjn->bcij", cm, bm, preferred_element_type=f32)
+    cb = jnp.einsum("bcin,bcjn->bcij" if shared else "bcihn,bcjhn->bchij",
+                    cm, bm, preferred_element_type=f32)
     seg = cum_h[..., :, None] - cum_h[..., None, :]  # [B, C, H, Qi, Qj]
     lower = jnp.arange(q)[:, None] >= jnp.arange(q)[None, :]
     mix = (jnp.exp(jnp.where(lower, seg, -jnp.inf))
-           * cb[:, :, None]).astype(xs.dtype)
+           * (cb[:, :, None] if shared else cb)).astype(xs.dtype)
     y = jnp.einsum("bchij,bcjhp->bcihp", mix, xdt, preferred_element_type=f32)
     # what each chunk adds to the state, and the state each one starts from
     to_end = jnp.exp(cum[:, :, -1:, :] - cum)  # [B, C, Q, H]
     added = jnp.einsum(
-        "bcjhp,bcjn->bchpn",
+        "bcjhp,bcjn->bchpn" if shared else "bcjhp,bcjhn->bchpn",
         (xdt.astype(f32) * to_end[..., None]).astype(xs.dtype), bm,
         preferred_element_type=f32)
     whole = jnp.exp(cum[:, :, -1, :])  # [B, C, H]
@@ -292,7 +303,8 @@ def _ssd_chunked(xs, dt, a, bm, cm, h, chunk: int):
         carry, h, (jnp.swapaxes(whole, 0, 1), jnp.swapaxes(added, 0, 1)))
     starts = jnp.swapaxes(starts, 0, 1)  # [B, C, H, P, N]: h entering a chunk
     y = y + jnp.einsum(
-        "bcin,bchpn->bcihp", cm.astype(f32), starts,
+        "bcin,bchpn->bcihp" if shared else "bcihn,bchpn->bcihp",
+        cm.astype(f32), starts,
         precision=jax.lax.Precision.HIGHEST) * jnp.exp(cum)[..., None]
     return y.reshape(b, nc * q, nh, p)[:, :t], h
 

@@ -95,6 +95,12 @@ COUNTERS = {
                              "Of those, the tokens attention read: the "
                              "smaller of a slot's length and the "
                              "selection's size"),
+    "select_rows": ("select_rows",
+                    "Dispatched slot-ticks of such a model whose query "
+                    "selected what it read"),
+    "select_rows_dense": ("select_rows_dense",
+                          "Dispatched slot-ticks that attended all they "
+                          "saw: at most the model's dense length"),
     "chunk_attn_launches": ("chunk_attn_launches",
                             "Prefill chunks dispatched for a model whose "
                             "attention reads a selection"),

@@ -19,8 +19,9 @@ the next. A key/value head's G query heads are folded into its rows
 (``[T x G, Dk]``, as ``attention._fold_query_groups`` folds them), tiled by
 ``_ROWS``.
 
-**The one mask is ``reach [N, T]``**: how many window rows a query reads,
-from row 0. ``len + i + 1`` for a causal chunk, the end of the query's own
+**The mask is ``reach [N, T]``** (and, for a family that selects blocks
+of its cache, ``keep [N, T, Hk, Nb]`` beside it: ``chunk_attention``): how
+many window rows a query reads, from row 0. ``len + i + 1`` for a causal chunk, the end of the query's own
 block under ``cfg.attn_block``, ``position + 1`` for
 ``window_attn.full_attention``.
 
@@ -201,14 +202,25 @@ def fits(q_shape, keys_shape, values_shape, itemsize: int) -> bool:
                        itemsize) <= _VMEM_BYTES - (8 << 20)
 
 
+def takes_mask(keep_shape, w: int) -> bool:
+    """Whether the kernel reads a selection's mask of these blocks over a
+    window of ``w``: whole mask blocks a key block, at least eight of them
+    (a row's flags are a matrix operand)."""
+    bk = key_block(w)
+    nb = keep_shape[-1]
+    return w % nb == 0 and bk % (w // nb) == 0 and bk // (w // nb) >= 8
+
+
 def attend_window(q, keys, values, reach, scale: float, in_xla, mesh=None,
-                  layer=None, window: int | None = None):
+                  layer=None, window: int | None = None, keep=None):
     """The one place a chunk's attention over its gathered window is
     routed: under the scope ``chunk_attn`` (inside the caller's ``attn`` or
     ``gather_attn``), ``chunk_attention`` where ``takes`` the shapes, else
     ``in_xla()``, the caller's XLA code for the same attention. ``layer``
     and ``window`` as ``chunk_attention`` takes them: the rule reads one
-    layer's window of the stack."""
+    layer's window of the stack. ``keep``: a selection's mask by blocks
+    (``chunk_attention``), for a family whose queries read the blocks they
+    keep (vtpu/models/sparselinear.py)."""
 
     def a_window(planes):
         shape = planes.shape if layer is None else planes.shape[1:]
@@ -217,9 +229,12 @@ def attend_window(q, keys, values, reach, scale: float, in_xla, mesh=None,
         return jax.ShapeDtypeStruct(shape, planes.dtype)
 
     with jax.named_scope("chunk_attn"):
-        if takes(q, a_window(keys), a_window(values), reach, mesh):
+        if takes(q, a_window(keys), a_window(values), reach, mesh) and (
+                keep is None or takes_mask(
+                    keep.shape, a_window(keys).shape[1])):
+            mask = {} if keep is None else {"keep": keep}
             return chunk_attention(q, keys, values, reach, scale,
-                                   layer=layer, window=window)
+                                   layer=layer, window=window, **mask)
         return in_xla()
 
 
@@ -256,13 +271,18 @@ def _row_of_block(ref, r, bk: int, rows: int):
 
 
 def _kernel(ends_ref, lows_ref, lay_ref, q_ref, reach_ref, k_ref, v_ref,
-            o_ref, m_ref, l_ref, acc_ref, *, scale, dk, dv, rows):
+            *refs, scale, dk, dv, rows, kept=0):
     """One sequence's tile of query rows against one block of the window,
     every key/value head. q_ref [1, Hk, rt, Dk]; reach_ref [1, rt, 1]; k_ref
     and v_ref [1, 1, bk x R, L] (a layer's block, heads under one another)
     or [1, 1, bk, Hk x D] (side by side); o_ref [1, Hk, rt, Dv]; scratch m_ref, l_ref [Hk, rt, 1],
-    acc_ref [Hk, rt, Dv], float32. ``lay_ref`` is the index maps'."""
+    acc_ref [Hk, rt, Dv], float32. ``lay_ref`` is the index maps'. With
+    ``kept`` (window positions a block of a selection's mask), ``refs``
+    leads with keep_ref [1, Hk, 1, rt, bk / kept]: which of the key
+    block's mask blocks each query row reads (1.0 or 0.0)."""
     del lay_ref
+    keep_ref = refs[0] if kept else None
+    o_ref, m_ref, l_ref, acc_ref = refs[-4:]
     i, kb = pl.program_id(0), pl.program_id(2)
     hk = q_ref.shape[1]
     bk = k_ref.shape[2] // max(rows, 1)
@@ -280,6 +300,16 @@ def _kernel(ends_ref, lows_ref, lay_ref, q_ref, reach_ref, k_ref, v_ref,
         if masked:
             at = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
             s = jnp.where(at < reach_ref[0], s, -jnp.inf)
+        if kept:
+            # a row's flags a mask block, spread over the block's positions
+            # on the MXU: [rt, bk / kept] x [bk / kept, bk]
+            per = bk // kept
+            spread = (jax.lax.broadcasted_iota(jnp.int32, (per, bk), 1)
+                      // kept == jax.lax.broadcasted_iota(
+                          jnp.int32, (per, bk), 0)).astype(keep_ref.dtype)
+            s = jnp.where(jnp.dot(keep_ref[0, h, 0], spread,
+                                  preferred_element_type=jnp.float32) > 0.5,
+                          s, -jnp.inf)
         m_old = m_ref[h]
         m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
         e = jnp.exp(s - m_new)
@@ -336,7 +366,8 @@ def chunk_attention(q: jax.Array, keys: jax.Array, values: jax.Array,
                     reach: jax.Array, scale: float, layer=None,
                     window: int | None = None, interpret: bool = False,
                     keys_a_step: int | None = None,
-                    rows_a_step: int | None = None) -> jax.Array:
+                    rows_a_step: int | None = None,
+                    keep: jax.Array | None = None) -> jax.Array:
     """q ``[N, T, Hq, Dk]``; keys and values the window all T queries of a
     sequence share, as stored: ``[N, W, R, L]`` (a token's R rows of L
     lanes, ``L // Dk`` heads a row) or ``[N, W, Hk x Dk]`` and ``[N, W,
@@ -352,7 +383,13 @@ def chunk_attention(q: jax.Array, keys: jax.Array, values: jax.Array,
     first ``window`` of the W positions stored (a slot's row of a dense
     cache under a read bucket), by the grid's extent: no slice either. The
     kernel is ``chunk_attn`` in a trace, under the caller's scope;
-    ``keys_a_step`` and ``rows_a_step`` are the piece bench's."""
+    ``keys_a_step`` and ``rows_a_step`` are the piece bench's.
+
+    ``keep`` ``[N, T, Hk, Nb]`` bool (a selection's mask by blocks of ``W /
+    Nb`` window positions, a key/value head; ``takes_mask`` says which
+    shapes): query i of head group h also reads only the blocks it keeps,
+    ``reach`` still the bound inside one. A row must keep a position it
+    reaches (a causal query keeps its own block)."""
     if layer is None:
         keys, values, layer = keys[None], values[None], 0
     n, t, hq, _ = q.shape
@@ -382,14 +419,26 @@ def chunk_attention(q: jax.Array, keys: jax.Array, values: jax.Array,
     def plane(x):
         return pl.BlockSpec((1, 1, bk * max(rows, 1), x.shape[3]), block)
 
+    masks, mask_specs, kept = [], [], 0
+    if keep is not None:
+        # [N, T, Hk, Nb] -> [N, Hk, key blocks, T x G, mask blocks a key
+        # block]: a grid step's flags are one block of whole rows
+        kept = w // keep.shape[3]
+        per = bk // kept
+        flags = jnp.repeat(keep, g, axis=1).reshape(n, t * g, hk, w // bk, per)
+        masks = [flags.transpose(0, 2, 3, 1, 4).astype(q.dtype)]
+        mask_specs = [pl.BlockSpec(
+            (1, hk, 1, rt, per), lambda i, r, kb, ends, *_: (
+                i, 0, jnp.minimum(kb, (ends[i] - 1) // bk), r, 0))]
     out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, dk=dk, dv=dv, rows=rows),
+        functools.partial(_kernel, scale=scale, dk=dk, dv=dv, rows=rows,
+                          kept=kept),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(n, t * g // rt, w // bk),
             in_specs=[
                 pl.BlockSpec((1, hk, rt, dk), tile),
                 pl.BlockSpec((1, rt, 1), lambda i, r, kb, *_: (i, r, 0)),
-                plane(keys), plane(values),
+                plane(keys), plane(values), *mask_specs,
             ],
             out_specs=pl.BlockSpec((1, hk, rt, dv), tile),
             scratch_shapes=[
@@ -402,6 +451,6 @@ def chunk_attention(q: jax.Array, keys: jax.Array, values: jax.Array,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_BYTES),
         interpret=interpret, name="chunk_attn",
-    )(ends, lows, lay, qf, reach_rows, keys, values)
+    )(ends, lows, lay, qf, reach_rows, keys, values, *masks)
     return out.reshape(n, hk, t, g, dv).transpose(0, 2, 1, 3, 4).reshape(
         n, t, hq, dv)

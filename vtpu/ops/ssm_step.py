@@ -6,6 +6,10 @@ A layer ``l`` of a step, all float32::
     h[l, b, hd] <- h[l, b, hd] * decay[b, hd] + dx[b, hd, :] (outer) B[b, :]   # [P, N]
     y[b, hd, :] <- h[l, b, hd] . C[b, :]      # from the tile just written
 
+(``B`` and ``C`` one row the heads of a slot share, Mamba-2's single group,
+or a row a head, ``B[b, hd, :]``: a linear-attention layer's key and query,
+whose state is the same stack with P the value's width and N the key's.)
+
 The kernel takes the whole stacked state ``h [Lm, B, H, P, N]`` as the
 engine stores it, aliased input to output, with the layer index a
 scalar-prefetch argument that the block specs' index maps read: a loop over
@@ -51,17 +55,21 @@ def _eye(n):
 
 def _kernel(lay_ref, decay_ref, dx_ref, b_ref, c_ref, h_ref, y_ref, ho_ref):
     """One slot's tile of heads. decay_ref [B * H] (SMEM); dx_ref, y_ref
-    [1, heads, P]; b_ref, c_ref [1, 1, N]; h_ref, ho_ref [1, 1, heads, P, N]."""
+    [1, heads, P]; b_ref, c_ref [1, 1, N] (a row the heads share) or
+    [1, heads, N] (a row a head); h_ref, ho_ref [1, 1, heads, P, N]."""
     del lay_ref  # the index maps read it
     heads, p = dx_ref.shape[1:]
     first = (pl.program_id(0) * pl.num_programs(1) + pl.program_id(1)) * heads
-    b_row, c_row = b_ref[0], c_ref[0]  # [1, N]
+    per_head = b_ref.shape[1] != 1
+    b_row, c_row = b_ref[0], c_ref[0]  # [1, N]: the shared row
     diagonal = _eye(p)
     lane = jax.lax.broadcasted_iota(jnp.int32, (p, heads), 1)
     y_t = jnp.zeros((p, heads), jnp.float32)
     for j in range(heads):
         dx_col = jnp.sum(jnp.where(diagonal, dx_ref[0, j:j + 1, :], 0.0),
                          axis=-1, keepdims=True)  # [P, 1]
+        if per_head:
+            b_row, c_row = b_ref[0, j:j + 1, :], c_ref[0, j:j + 1, :]
         h = h_ref[0, 0, j] * decay_ref[first + j] + dx_col * b_row
         ho_ref[0, 0, j] = h
         y_t = jnp.where(
@@ -74,12 +82,17 @@ def _kernel(lay_ref, decay_ref, dx_ref, b_ref, c_ref, h_ref, y_ref, ho_ref):
 
 def ssm_state_step(h, layer, decay, dx, bm, cm, interpret: bool = False):
     """h [Lm, B, H, P, N] float32 (the stack, donated); layer: an int or a
-    traced int32 scalar; decay [B, H], dx [B, H, P], bm, cm [B, N], float32
-    -> (y [B, H, P] float32, h with layer ``layer`` moved one token on and
-    every other layer as it stood)."""
+    traced int32 scalar; decay [B, H], dx [B, H, P], bm, cm [B, N] (a row
+    of B and C the heads share: Mamba-2's one group) or [B, H, N] (a row a
+    head: a linear attention's key and query), float32 -> (y [B, H, P]
+    float32, h with layer ``layer`` moved one token on and every other
+    layer as it stood)."""
     _, b, nh, p, n = h.shape
     heads = _TILE_HEADS if nh % _TILE_HEADS == 0 else nh
     tile = (1, 1, heads, p, n)
+    per_head = bm.ndim == 3
+    if not per_head:
+        bm, cm = bm[:, None], cm[:, None]
 
     def state_at(i, g, lay, dec):
         return lay[0], i, g, 0, 0
@@ -95,8 +108,8 @@ def ssm_state_step(h, layer, decay, dx, bm, cm, interpret: bool = False):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(b, nh // heads),
             in_specs=[pl.BlockSpec((1, heads, p), slot_heads),
-                      pl.BlockSpec((1, 1, n), slot),
-                      pl.BlockSpec((1, 1, n), slot),
+                      *[pl.BlockSpec((1, heads, n), slot_heads) if per_head
+                        else pl.BlockSpec((1, 1, n), slot)] * 2,
                       pl.BlockSpec(tile, state_at)],
             out_specs=[pl.BlockSpec((1, heads, p), slot_heads),
                        pl.BlockSpec(tile, state_at)]),
@@ -108,5 +121,5 @@ def ssm_state_step(h, layer, decay, dx, bm, cm, interpret: bool = False):
             vmem_limit_bytes=16 * heads * p * n + (16 << 20)),
         interpret=interpret, name="ssm_state_step",
     )(jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)), decay.reshape(-1),
-      dx, bm[:, None], cm[:, None], h)
+      dx, bm, cm, h)
     return y, h

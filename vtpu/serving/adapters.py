@@ -43,7 +43,8 @@ import jax
 import jax.numpy as jnp
 
 from vtpu.models import (
-    blockdiff, hybrid, moe, slots as slot_steps, swa, transformer,
+    blockdiff, hybrid, moe, slots as slot_steps, sparselinear, swa,
+    transformer,
 )
 from vtpu.models.hybrid import (
     hybrid_decode_step,
@@ -869,6 +870,163 @@ class HybridSlotModel(_CountsHeadChunks):
         if block_ids is None:  # the slot's own table row
             block_ids = state["table"][slot, :window // self.kv_page]
         return hybrid_prefill_chunk(
+            params, self.cfg, state, chunk, slot, offset, new_len, window,
+            block_ids)
+
+
+class SparseLinearSlotModel(_CountsHeadChunks):
+    """Block-sparse attention layers among linear-attention layers
+    (vtpu/models/sparselinear): a session's state is of three kinds, pages
+    of the paged pool (a plane a layer and key/value head), the compressed
+    keys that the selection scores (a plane walked by the same page table)
+    and a slot-indexed row of matrices a head for the linear layers, in one
+    engine state, so the allocator, batched and chunked admission, the read
+    windows and the sampler serve it as they serve the other families.
+
+    It states what the engine cannot know of it: ``read_windows``,
+    ``kv_bytes_per_token`` (the sparse layers' keys, values and compressed
+    keys), ``recurrent_state_bytes`` (the rows, whatever a session's
+    length), ``attn_select_topk`` (the tokens of the blocks a query keeps)
+    with ``attn_select_dense_len`` (up to which a query attends all it
+    sees) and ``pool_planes``. Paged only, a page a selection block;
+    ``paged_attn`` forces the route of the selected pages' walk as the
+    dense family's does. What a session of three kinds of state cannot do
+    yet is refused by name, each with the mechanism that is missing
+    (``check_serving``, ``refuses``): ``HybridSlotModel``'s list, for the
+    same reasons and one more plane."""
+
+    supports_kv_buckets = True
+    mesh = None
+    pool_planes = ("k", "v", "ck")
+    refuses = {
+        "register_prefix": (
+            "a shared prefix is pages and their compressed keys; a session "
+            "that starts from one also needs the linear layers' rows as "
+            "they stood at the prefix's last token, and no snapshot of "
+            "them is kept at a boundary"),
+        "drain": (
+            "migration ships a session as pool pages; the compressed keys' "
+            "plane and the linear layers' rows have no staging"),
+    }
+
+    def __init__(self, params: Any, cfg: Any, kv_page: Optional[int] = None,
+                 kv_pool_blocks: Optional[int] = None,
+                 read_windows: Optional[tuple] = None,
+                 paged_attn: Optional[str] = None,
+                 mesh: Optional[Any] = None):
+        if mesh is not None:
+            raise ValueError(
+                "SparseLinearSlotModel has no sharding rule for the linear "
+                "layers' rows nor for a selection a key/value head (a "
+                "head-sharded state beside a head-sharded pool): pass no "
+                "mesh")
+        if kv_page is None:
+            raise ValueError(
+                "SparseLinearSlotModel has a paged cache only: set kv_page")
+        if kv_page != cfg.block_size:
+            raise ValueError(
+                f"SparseLinearSlotModel selects whole pages: kv_page "
+                f"{kv_page} must be the selection's block_size "
+                f"{cfg.block_size}")
+        if cfg.kv_int8:
+            raise ValueError(
+                "SparseLinearSlotModel has no int8 cache: the compressed "
+                "keys are means of the stored keys, and the per-head "
+                "scales' planes do not follow a plane a head (kv_int8="
+                "False)")
+        if paged_attn is not None and paged_attn not in PAGED_ATTN_ROUTES:
+            raise ValueError(
+                f"paged_attn must be one of {PAGED_ATTN_ROUTES} or None "
+                f"(auto), got {paged_attn!r}")
+        _check_read_windows(read_windows, kv_page, cfg.max_seq)
+        self.params = sparselinear.hold_projections(params, cfg)
+        self.cfg = cfg
+        self.max_context = cfg.max_seq
+        self.kv_page = kv_page
+        self.kv_pool_blocks = kv_pool_blocks
+        self.n_kv_blocks = None
+        self.paged_attn = paged_attn
+        self.read_windows = tuple(sorted(read_windows)) if read_windows else None
+        self.kv_bytes_per_token = cfg.kv_bytes_per_token
+        self.attn_select_topk = cfg.topk * cfg.block_size
+        self.attn_select_dense_len = cfg.dense_len
+
+    def check_serving(self, serving) -> None:
+        """Refuse the ServingConfig options this family cannot serve."""
+        if serving.spec_tokens:
+            raise ValueError(
+                "SparseLinearSlotModel has no spec_step: a rejected draft "
+                "would need the linear layers' rows rolled back to the "
+                "last accepted token, and a step keeps no earlier copy "
+                "(spec_tokens=0)")
+        if serving.kv_swap is not None:
+            raise ValueError(
+                "SparseLinearSlotModel cannot park or swap a session: its "
+                "pages and compressed keys could be staged, its linear "
+                "layers' rows have no snapshot at the boundary "
+                "(kv_swap=None; park, resume and migrate need it)")
+        if serving.disagg is not None:
+            raise ValueError(
+                "SparseLinearSlotModel has no slot-less prefill: a prefill "
+                "worker fills pool blocks, and the linear layers' rows "
+                "have no home outside a slot (disagg=None)")
+
+    def recurrent_state_bytes(self, slots: int) -> int:
+        return slots * self.cfg.recurrent_bytes_per_slot
+
+    def _chunk_attn_shapes(self, queries: int, window: int):
+        """A sparse layer's chunk: a key/value head's G query heads over
+        its own plane of the window, a token a row."""
+        cfg = self.cfg
+        plane = jax.ShapeDtypeStruct((1, window, cfg.head_dim), cfg.dtype)
+        return (jax.ShapeDtypeStruct(
+            (1, queries, cfg.group, cfg.head_dim), cfg.dtype), plane, plane)
+
+    def chunk_keys_attended(self, queries: int, end: int,
+                            window: int) -> tuple[bool, int]:
+        """A window past ``dense_len`` is attended under the selection's
+        mask: in the chunk kernel where it also takes the mask's blocks
+        (``chunk_attn.takes_mask``), the rule the traced program applies."""
+        if window > self.cfg.dense_len and not chunk_attn.takes_mask(
+                (window // self.kv_page,), window):
+            return False, window
+        return super().chunk_keys_attended(queries, end, window)
+
+    def ssm_step_in_kernel(self) -> bool:
+        """Whether a decode step traced now updates the linear layers' rows
+        in the Pallas kernel: the question the trace itself asks."""
+        return sparselinear.step_in_kernel(1)
+
+    def init_state(self, slots: int):
+        self.n_kv_blocks = _pool_blocks(self, slots)
+        return sparselinear.init_sparselinear_state(
+            self.cfg, slots, self.kv_page, self.n_kv_blocks)
+
+    def prefill_into_slot(self, params, state, padded, slot, true_len):
+        logits, new = self.prefill_into_slots(
+            params, state, padded, jnp.asarray(slot)[None],
+            jnp.asarray(true_len)[None])
+        return logits[0], new
+
+    def prefill_into_slots(self, params, state, padded, slots, true_lens):
+        return sparselinear.sparselinear_prefill_rows(
+            params, self.cfg, state, padded, slots, true_lens)
+
+    def decode_step(self, params, state, tokens, active, kv_bucket,
+                    unroll=False):
+        del unroll  # sparse layers unrolled, linear runs looped: _walk
+        return sparselinear.sparselinear_decode_step(
+            params, self.cfg, state, tokens, active,
+            kv_bucket or self.max_context, paged_attn=self.paged_attn)
+
+    def prefill_chunk_into_slot(self, params, state, chunk, slot, offset,
+                                new_len, kv_bucket=0, unroll=False,
+                                block_ids=None):
+        del unroll
+        window = kv_bucket or self.max_context
+        if block_ids is None:  # the slot's own table row
+            block_ids = state["table"][slot, :window // self.kv_page]
+        return sparselinear.sparselinear_prefill_chunk(
             params, self.cfg, state, chunk, slot, offset, new_len, window,
             block_ids)
 

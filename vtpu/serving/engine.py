@@ -897,6 +897,7 @@ class ServingEngine:
         # per-tick route counters must read the same value)
         self._paged_attn = getattr(model, "paged_attn", None)
         self._select_topk = getattr(model, "attn_select_topk", None)
+        self._select_dense = getattr(model, "attn_select_dense_len", None)
         self._latent_walk = getattr(model, "walks_latent_plane", False)
         self._chunk_expands = getattr(model, "chunk_attn_expands", None)
         self._chunk_keys = getattr(model, "chunk_keys_attended", None)
@@ -1467,6 +1468,12 @@ class ServingEngine:
                        # of a slot's length and the selection's size); 0
                        # for every other model
                        "attn_visible_tokens": 0, "attn_selected_tokens": 0,
+                       # ... and the dispatched slot-ticks of such a model
+                       # whose query selected, and those that attended all
+                       # they saw because that was at most the model's
+                       # ``attn_select_dense_len`` tokens (a model that
+                       # states none selects always)
+                       "select_rows": 0, "select_rows_dense": 0,
                        # ... and the prefill chunks dispatched for such a
                        # model, with those of them whose length put their
                        # attention in the expanded form (the model's
@@ -3658,8 +3665,12 @@ class ServingEngine:
             self._stats["attn_visible_tokens"] += (
                 sum(lens) + wrote * len(lens)) * ticks
         if self._select_topk:
+            whole = [ln + 1 <= (self._select_dense or 0) for ln in lens]
             self._stats["attn_selected_tokens"] += sum(
-                min(ln + 1, self._select_topk) for ln in lens) * ticks
+                ln + 1 if w else min(ln + 1, self._select_topk)
+                for ln, w in zip(lens, whole)) * ticks
+            self._stats["select_rows_dense"] += sum(whole) * ticks
+            self._stats["select_rows"] += (len(lens) - sum(whole)) * ticks
         if self._window_ring:
             self._stats["window_rows_read"] += sum(
                 min(ln + 1, self._window_ring) for ln in lens) * ticks
